@@ -165,7 +165,8 @@ class DataGenBase:
         # spawn, not fork: the calling process usually has a live multithreaded
         # JAX runtime, and forking it is a documented deadlock hazard. Workers
         # only import numpy/pyarrow (every jax import in this module is lazy),
-        # so spawn startup is cheap.
+        # so spawn startup is cheap — and they never touch jax, so they never
+        # contend for the chip the caller holds (one process per chip).
         ctx = mp.get_context("spawn")
         with ctx.Pool(n_processes) as pool:
             pool.map(

@@ -6,7 +6,7 @@
 # `t0 = perf_counter(); ...; x.block_until_ready(); perf_counter() - t0`
 # idiom — produces numbers the attribution ledger never sees, double-syncs
 # boundaries the plane already times, and drifts from the execute/compile/
-# host/idle taxonomy docs/observability.md documents.
+# host/idle split docs/observability.md documents.
 #
 # Two findings:
 #   * any `jax.profiler.*` reference (trace, TraceAnnotation, start_trace,

@@ -2,7 +2,7 @@
 # AST port of the regex-era perf_counter rule: stage timing inside the
 # framework goes through telemetry spans (spark_rapids_ml_tpu/telemetry.py),
 # not hand-rolled perf_counter deltas — ad-hoc timing is invisible to the
-# registry/JSONL sinks and drifts from the span taxonomy. The AST form
+# registry/JSONL sinks and drifts from the span naming scheme. The AST form
 # matches actual references to `time.perf_counter` (call or bare handle,
 # through any import alias), so the string "perf_counter" in a comment or
 # docstring no longer trips the gate.

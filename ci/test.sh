@@ -19,7 +19,7 @@ echo "== static analysis (AST lint: ci/analysis — compile, invariants, registr
 # (incl. wall_s + cache hit count) lands next to the regression verdict
 python -m ci.analysis --json-out "$ARTIFACTS/analysis_verdict.json" --time-budget 60
 
-echo "== perf regression gate (report-only against the checked-in BENCH trajectory)"
+echo "== perf regression gate (report-only; no BENCH_r*.json is checked in, so the verdict is no-data)"
 python -m benchmark.regression --report-only --out "$ARTIFACTS/regression_verdict.json"
 
 echo "== ops snapshot artifact (SLO verdicts + decision log + tenant accounting + efficiency attribution)"

@@ -45,6 +45,10 @@ def _env_float(name: str, default: float) -> float:
         return default
 
 
+_DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".srml_cache"
+)
+
 # Global framework configuration — the analog of the reference's Spark-conf tier
 # (`spark.sql.execution.arrow.maxRecordsPerBatch`, `spark.rapids.ml.uvm.enabled`;
 # reference core.py:660-665, clustering.py:775-779).
@@ -125,10 +129,14 @@ config: Dict[str, Any] = {
     # information bounds it).
     "stream_chunk_rows": 0,
     # --- multi-fit execution engine (docs/performance.md) ----------------
-    # XLA persistent compilation cache directory: compiled programs (the
-    # transform bucket ladder, batched sweep solvers) survive process
-    # restarts. Seeded from SRML_COMPILE_CACHE_DIR; None disables.
-    "compilation_cache_dir": os.environ.get("SRML_COMPILE_CACHE_DIR") or None,
+    # XLA persistent compilation cache directory (the autotune table lives
+    # beside it): compiled programs survive process restarts — a cold
+    # d=3000 PCA compile alone is minutes. Where JAX_COMPILATION_CACHE_DIR
+    # is set, that directory is used and no other is ever configured;
+    # otherwise ONE fixed, git-ignored directory in the checkout — the path
+    # is part of the cache key, so it must not move between processes.
+    "compilation_cache_dir": os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    or _DEFAULT_COMPILE_CACHE_DIR,
     # smallest rung of the transform bucket ladder: serving batches pad up a
     # geometric (x2) ladder of row counts starting here, so `predict`
     # compiles once per rung instead of once per distinct tail shape
@@ -254,7 +262,7 @@ config: Dict[str, Any] = {
     # distance-core block planner times a small (block_rows, block_k)
     # candidate grid on-device and persists the winner as JSON beside the
     # XLA compile cache (compilation_cache_dir). SRML_AUTOTUNE=0 disables;
-    # off-TPU (or cold-start) the static half-VMEM heuristic is used, so
+    # off-TPU (or cold-start) the static VMEM-fit heuristic is used, so
     # CPU/CI behavior is unchanged.
     "autotune_enabled": os.environ.get("SRML_AUTOTUNE", "1")
     not in ("", "0", "false", "off"),
@@ -1422,7 +1430,7 @@ class _TpuCaller(_TpuCommon):
         from .parallel import TpuContext
         from .parallel.mesh import dtype_scope, ensure_compilation_cache
 
-        compile_cache_on = ensure_compilation_cache()
+        ensure_compilation_cache()
 
         # Route through the caller's process group when one is active (the
         # reference's train-UDF-inside-CumlContext shape, core.py:768-781);
@@ -1490,7 +1498,7 @@ class _TpuCaller(_TpuCommon):
                 telemetry.registry().gauge(
                     "fit.compile_overhead_s_est", solve_times[0] - min(solve_times[1:])
                 )
-            if solve_times and compile_cache_on:
+            if solve_times:
                 # first-call wall time under the persistent compilation cache:
                 # across bench rounds this gauge falling toward the repeat
                 # solve time IS the cache working (docs/observability.md)

@@ -700,17 +700,21 @@ def is_oom_error(exc: BaseException) -> bool:
     convert to `HbmBudgetError` (and retry once out-of-core). Matches XLA's
     RESOURCE_EXHAUSTED surface (jaxlib raises it as a RuntimeError subclass)
     and plain MemoryError; an already-typed `HbmBudgetError` is NOT matched —
-    it must propagate, not re-enter the conversion."""
+    it must propagate, not re-enter the conversion. Neither is a kernel that
+    does not COMPILE: Mosaic reports a scoped-VMEM overflow as
+    RESOURCE_EXHAUSTED too ("Ran out of memory in memory space vmem"), but
+    streaming the rows runs the same kernel into the same refusal — it must
+    surface as itself, once."""
     if isinstance(exc, HbmBudgetError):
         return False
-    if not isinstance(exc, (RuntimeError, MemoryError)):
+    if isinstance(exc, MemoryError):
+        return True
+    if not isinstance(exc, RuntimeError):
         return False
     msg = str(exc)
-    return (
-        "RESOURCE_EXHAUSTED" in msg
-        or "out of memory" in msg.lower()
-        or isinstance(exc, MemoryError)
-    )
+    if "memory space vmem" in msg or "Mosaic" in msg:
+        return False
+    return "RESOURCE_EXHAUSTED" in msg or "out of memory" in msg.lower()
 
 
 def as_hbm_budget_error(exc: BaseException) -> HbmBudgetError:

@@ -404,7 +404,11 @@ class KMeansModel(_KMeansParams, _TpuModelWithColumns):
             return jax.device_put(centers.astype(dtype), default_local_device())
 
         def predict(state, xb):
-            return kmeans_predict(xb.astype(dtype), state)
+            # a batch the distributed transform row-sharded over a mesh
+            # (core.PredictProgram.dispatch) is assigned shard by shard
+            sharding = getattr(xb, "sharding", None)
+            mesh = sharding.mesh if len(getattr(sharding, "device_set", ())) > 1 else None
+            return kmeans_predict(xb.astype(dtype), state, mesh=mesh)
 
         return construct, predict, None
 

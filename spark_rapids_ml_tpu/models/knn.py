@@ -376,8 +376,8 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
     def _serve_workspace_terms(self, bucket_rows_count, itemsize) -> Dict[str, int]:
         # the tiled top-k merge's live blocks per dispatched bucket: the
         # [bucket, k_tile] distance block (VMEM-sized item tiles on the
-        # kernel path; the one-matmul [bucket, n] fallback on CPU/older
-        # jaxlibs) plus the [bucket, k] best-list carry x2 (d2 + index) —
+        # kernel path; the one-matmul [bucket, n] form on CPU) plus the
+        # [bucket, k] best-list carry x2 (d2 + index) —
         # the distance core is exactly why no [bucket, n_items] block lands
         # in HBM on the kernel path
         from ..ops import distance as dist
@@ -388,7 +388,10 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
         if dist.kernel_mode() == "jnp":
             k_tile = max(1, n_items)
         else:
-            plan = dist.plan_blocks(b, max(1, n_items), self._serve_n_cols(), itemsize)
+            plan = dist.plan_blocks(
+                b, max(1, n_items), self._serve_n_cols(),
+                np.float32 if itemsize == 4 else np.float64,
+            )
             k_tile = max(plan[1], 128) if plan is not None else max(1, n_items)
         return {
             "topk_block": b * min(k_tile, max(1, n_items)) * itemsize,

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
 from typing import Optional, Tuple
 
@@ -29,14 +30,17 @@ def build(force: bool = False) -> str:
     """Build libsrml_native.so with CMake (reference jvm/native build step)."""
     if os.path.exists(_lib_path()) and not force:
         return _lib_path()
-    os.makedirs(_BUILD_DIR, exist_ok=True)
+    # configure FRESH from the committed native/ sources: a build directory
+    # left behind by another checkout carries a CMakeCache.txt with that
+    # checkout's paths, which CMake refuses to reuse
+    shutil.rmtree(_BUILD_DIR, ignore_errors=True)
     subprocess.run(
-        ["cmake", "-DCMAKE_BUILD_TYPE=Release", ".."],
-        cwd=_BUILD_DIR, check=True, capture_output=True,
+        ["cmake", "-S", _NATIVE_DIR, "-B", _BUILD_DIR, "-DCMAKE_BUILD_TYPE=Release"],
+        check=True, capture_output=True,
     )
     subprocess.run(
-        ["cmake", "--build", ".", "--parallel"],
-        cwd=_BUILD_DIR, check=True, capture_output=True,
+        ["cmake", "--build", _BUILD_DIR, "--parallel"],
+        check=True, capture_output=True,
     )
     return _lib_path()
 

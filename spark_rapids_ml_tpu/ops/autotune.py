@@ -2,23 +2,26 @@
 # Measured kernel autotuner for the tiled distance core's block planner
 # (docs/performance.md "Kernel autotuner").
 #
-# The static `plan_blocks` heuristic (ops/distance.py) fits half a v5e
-# core's VMEM and is a fine cold-start default, but the best (block_rows,
-# block_k) tiling is a property of the part and the shape, not of a fixed
-# budget. This module measures it: on first TPU contact per (shape-class,
-# dtype, fast-flag) it times a small candidate grid of tilings ON DEVICE,
-# picks the winner, and persists the table as JSON beside the XLA compile
-# cache (`config["compilation_cache_dir"]`) so later PROCESSES reuse the
+# The static `plan_blocks` heuristic (ops/distance.py) takes the largest
+# blocks that fit the declared scoped-VMEM limit and is a fine cold-start
+# default, but the best (block_rows, block_k) tiling is a property of the
+# part and the shape, not of a fixed budget. This module measures it: on
+# first TPU contact per (shape-class, dtype, fast-flag) it times a small
+# candidate grid of tilings ON DEVICE, picks the winner, and persists the
+# table as JSON beside the XLA compile cache
+# (`parallel.mesh.compilation_cache_dir()`) so later PROCESSES reuse the
 # measurement instead of redoing it — the same amortization contract as the
 # compile cache itself.
 #
 # Degradation contract (pinned by tests/test_autotune.py and the
 # ci/analysis fixture pair): a missing, malformed, stale-version, or
 # unwritable table NEVER fails a fit — every failure path returns "no
-# entry" and the caller falls back to the heuristic. `SRML_AUTOTUNE=0`
-# (config["autotune_enabled"]) disables lookup and measurement entirely;
-# off-TPU (kernel_mode() != "pallas") nothing is ever measured, so CPU/CI
-# behavior is byte-identical to the heuristic-only planner.
+# entry" and the caller falls back to the heuristic. A candidate tiling the
+# compiler refuses is infeasible and skipped; the session goes on with the
+# rest. `SRML_AUTOTUNE=0` (config["autotune_enabled"]) disables lookup and
+# measurement entirely; off-TPU (kernel_mode() != "pallas") nothing is ever
+# measured, so CPU/CI behavior is byte-identical to the heuristic-only
+# planner.
 #
 # `lookup` runs at TRACE time (the block planner is called while tracing
 # the jitted assignment programs); `ensure` — the actual measurement — is
@@ -74,15 +77,11 @@ def shape_class(n_rows: int, k_side: int, d: int, dtype: Any, fast: bool) -> str
     return f"r{_bucket(n_rows)}:k{_bucket(k_side)}:d{int(d)}:{np.dtype(dtype).name}:{mode}"
 
 
-def table_path() -> Optional[str]:
-    """Where the measured table persists: beside the XLA compile cache.
-    None (cache dir unset) = in-memory only for this process."""
-    from ..core import config
+def table_path() -> str:
+    """Where the measured table persists: beside the XLA compile cache."""
+    from ..parallel.mesh import compilation_cache_dir
 
-    cache_dir = config.get("compilation_cache_dir")
-    if not cache_dir:
-        return None
-    return os.path.join(str(cache_dir), _TABLE_BASENAME)
+    return os.path.join(compilation_cache_dir(), _TABLE_BASENAME)
 
 
 def _count(name: str, key: str) -> None:
@@ -101,7 +100,7 @@ def _load_table_locked() -> Dict[str, Any]:
         return _TABLE
     entries: Dict[str, Any] = {}
     path = table_path()
-    if path is not None and os.path.exists(path):
+    if os.path.exists(path):
         try:
             with open(path, "r", encoding="utf-8") as f:
                 raw = json.load(f)
@@ -132,7 +131,7 @@ def _persist_locked() -> None:
     discipline); persistence failure is silent: the in-memory table still
     serves this process."""
     path = table_path()
-    if path is None or _TABLE is None:
+    if _TABLE is None:
         return
     tmp = f"{path}.tmp{os.getpid()}"
     try:
@@ -179,18 +178,17 @@ def record(
 def _candidates(n_rows: int, k_side: int, d: int, dtype: Any, fast: bool) -> List[Tuple[int, int]]:
     """VMEM-feasible candidate tilings for this shape, heuristic pick
     included (the tuner can only match or beat the static planner)."""
-    from .distance import _VMEM_BUDGET_BYTES, effective_itemsize, plan_blocks
+    from .distance import block_vmem_bytes, plan_blocks, vmem_limit_bytes
 
-    itemsize = effective_itemsize(dtype, fast)
-    budget = _VMEM_BUDGET_BYTES // max(1, itemsize)
+    limit = vmem_limit_bytes()
     out: List[Tuple[int, int]] = []
-    heuristic = plan_blocks(n_rows, k_side, d, itemsize)
+    heuristic = plan_blocks(n_rows, k_side, d, dtype, fast)
     if heuristic is not None:
         out.append(heuristic)
     for br in _CANDIDATE_BR:
         for bk in _CANDIDATE_BK:
             # same VMEM-fit predicate the static planner budgets against
-            if br * d + bk * d + br * bk > budget:
+            if block_vmem_bytes(br, bk, d, dtype, fast) > limit:
                 continue
             cand = (min(br, max(1, n_rows)), min(bk, max(1, k_side)))
             if cand not in out:
@@ -204,30 +202,31 @@ def _default_timer(n_rows: int, k_side: int, d: int, dtype: Any, fast: bool) -> 
     time per candidate, first call per candidate excluded (compile)."""
     import numpy as np
 
+    import jax
     import jax.numpy as jnp
 
     from ..core import config
-    from .distance import _c_sq, _pl_argmin
+    from .distance import assign_argmin
 
     rows = int(min(max(1, n_rows), 4096))
     k = int(min(max(1, k_side), 2048))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((rows, d)), dtype=dtype)
     c = jnp.asarray(rng.standard_normal((k, d)), dtype=dtype)
-    c_sq = _c_sq(c)
     try:
         repeats = max(1, int(config.get("autotune_repeats", 3)))
     except (TypeError, ValueError):
         repeats = 3
 
     def timer(br: int, bk: int) -> float:
+        # through assign_argmin, so rows and centers are padded to whole
+        # blocks and every candidate scans ALL k centers
+        fn = jax.jit(
+            lambda x, c: assign_argmin(x, c, fast=fast, block_rows=br, block_k=bk)
+        )
+
         def run() -> None:
-            mind, best = _pl_argmin(
-                x, c, c_sq, block_rows=min(br, rows), block_k=min(bk, k),
-                fast=fast, interpret=False,
-            )
-            mind.block_until_ready()
-            best.block_until_ready()
+            jax.block_until_ready(fn(x, c))
 
         run()  # compile + warm
         best_t = float("inf")
@@ -254,8 +253,9 @@ def ensure(
     disabled, off-TPU without an injected timer, or no feasible candidates.
     Solver drivers call this eagerly BEFORE their jitted loop, where shapes
     are host-known; the traced planner then hits the table via `lookup`.
-    A timer that raises degrades to the heuristic — measurement must never
-    fail a fit."""
+    A candidate whose timer raises is skipped; when EVERY candidate raises
+    (the heuristic's pick included) the fit's own kernels cannot run either,
+    and that is raised with the last cause."""
     if not enabled():
         return None
     key = shape_class(n_rows, k_side, d, dtype, fast)
@@ -274,23 +274,34 @@ def ensure(
         return None
     best: Optional[Tuple[int, int]] = None
     best_t = float("inf")
-    try:
-        # the whole measurement session is one compile-ledger entry: every
-        # candidate run compiles its own kernel variant, and the efficiency
-        # plane should see the session's wall as compile time, not idle
-        with telemetry.compile_event("autotune.measure", key):
-            for br, bk in candidates:
+    refused: Optional[Exception] = None
+    # the whole measurement session is one compile-ledger entry: every
+    # candidate run compiles its own kernel variant, and the efficiency
+    # plane should see the session's wall as compile time, not idle
+    with telemetry.compile_event("autotune.measure", key):
+        for br, bk in candidates:
+            try:
                 t = float(timer(br, bk))
-                if t < best_t:
-                    best_t, best = t, (br, bk)
-    except Exception:
-        # a failed measurement (kernel error on an exotic part, OOM on a
-        # candidate) must not fail the fit — the heuristic keeps planning
-        with _LOCK:
-            _count("autotune.table_errors", "table_errors")
-        return None
+            except Exception as e:
+                # a tiling the compiler (or the part) refuses is INFEASIBLE,
+                # not a reason to drop the candidates that do run
+                from ..utils import get_logger
+
+                get_logger("autotune").warning(
+                    "candidate blocks (%d, %d) for %s skipped: %s: %s",
+                    br, bk, key, type(e).__name__, e,
+                )
+                refused = e
+                continue
+            if t < best_t:
+                best_t, best = t, (br, bk)
     if best is None:
-        return None
+        # every tiling was refused, the heuristic's own pick among them: the
+        # fit's kernels cannot run either, so say so here with the cause
+        raise RuntimeError(
+            f"no candidate tiling for {key} compiles and runs "
+            f"(tried {candidates})"
+        ) from refused
     with _LOCK:  # held-ok: the table lock exists to serialize exactly this load+mutate+atomic-rewrite of a tiny JSON; no other lock is ever taken under it
         _count("autotune.measurements", "measurements")
         table = _load_table_locked()

@@ -32,7 +32,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..parallel.mesh import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.mesh import ROWS_AXIS

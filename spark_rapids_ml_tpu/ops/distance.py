@@ -7,8 +7,8 @@
 # reduction (argmin for KMeans assignment, top-k for kNN/UMAP/CAGRA, an
 # eps-threshold count for DBSCAN). Before this module each of
 # kmeans/knn/dbscan/umap/cagra hand-rolled that loop — and the hand-rolled
-# KMeans form fell ~2.2x going from 400k to 1M rows (BENCH_r01 ~226k -> r03
-# ~100k rows/sec/chip at k=1000): at k=1000 the un-k-tiled `[batch, k]`
+# KMeans form fell ~2.2x going from 400k to 1M rows (pre-ledger rounds r01
+# ~226k -> r03 ~100k rows/sec/chip at k=1000): at k=1000 the un-k-tiled `[batch, k]`
 # distance block plus its one-hot twin stop fitting close to the compute and
 # the MXU starves. This module is the single owner of that loop:
 #
@@ -17,17 +17,17 @@
 #     double-buffers the HBM->VMEM tile fetches), with the argmin merged
 #     IN-KERNEL across k tiles — a `[rows_tile, k]` matrix never exists in
 #     HBM, which is exactly the r01->r03 cliff;
-#   * a bit-compatible pure-jnp fallback: the same formulas as one XLA
-#     program (what CPU CI and older jaxlibs run); parity between the two is
-#     pinned by tests/test_distance.py (rtol 1e-9 f64, exact assignments f32)
-#     across tile boundaries, ragged tails, weights, and the `fast`
-#     precision mode;
+#   * a bit-compatible pure-jnp form: the same formulas as one XLA program
+#     (what CPU runs); parity between the two is pinned by
+#     tests/test_distance.py (rtol 1e-9 f64, exact assignments f32) across
+#     tile boundaries, ragged tails, weights, and the `fast` precision mode;
 #   * the backend probe (`kernel_mode`) that picks between them once per
-#     process: Pallas only on a TPU backend whose jaxlib passes a tiny
-#     end-to-end kernel self-test; `SRML_DISTANCE_KERNEL` overrides
-#     (`pallas` | `jnp` | `interpret` — the interpret form runs the REAL
-#     kernels through the Pallas interpreter, which is how CPU CI exercises
-#     kernel code paths at all).
+#     process from the FRAMEWORK's devices: on a TPU the kernels are the
+#     contract — a tiny end-to-end self-test runs once and a compile or
+#     parity failure RAISES with the compiler's message, it never demotes to
+#     jnp; `SRML_DISTANCE_KERNEL` overrides (`pallas` | `jnp` | `interpret`
+#     — the interpret form runs the REAL kernels through the Pallas
+#     interpreter, which is how CPU CI exercises kernel code paths at all).
 #
 # The ci/analysis `raw-distance` rule forbids re-growing private copies:
 # `jnp.argmin` / `lax.top_k` over a locally-built `x @ c.T`-shaped operand
@@ -53,11 +53,21 @@ from .. import telemetry
 # invalid (the config default matches this).
 _DEFAULT_TILE_ROWS = 4096
 
-# VMEM budget the kernel block planner fits (x block + k-side block + the
-# [block_rows, block_k] distance block, each double-buffered by the grid
-# pipeline). Half of a v5e core's ~16 MB, leaving the other half for the
-# pipeline's second buffers and compiler scratch.
-_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+# Scoped-VMEM limit the kernels DECLARE to Mosaic (`pltpu.CompilerParams
+# (vmem_limit_bytes=...)`) and the block planner fits, keyed by the device's
+# `device_kind`. Mosaic's default scoped limit on a v5e is 16 MiB, which the
+# double-buffered f32 [512, d] blocks of the protocol width (d=3000) exceed;
+# 64 MiB is half of the v5e core's 128 MiB VMEM, leaving the rest to XLA's
+# own fusions. A kind that is not listed is an error, never the v5e value:
+# a part with less VMEM would compile blocks it cannot hold. "cpu" is the
+# Pallas INTERPRETER (CI parity runs): no VMEM exists there, the entry only
+# makes CI plan the blocks the v5e would.
+_VMEM_LIMIT_BYTES = {
+    "TPU v5 lite": 64 << 20,
+    "cpu": 64 << 20,
+}
+
+_LANES = 128  # the last block dim occupies whole 128-lane tiles in VMEM
 
 _MODE: Optional[str] = None  # kernel_mode() cache: "pallas" | "interpret" | "jnp"
 
@@ -77,32 +87,72 @@ def tile_rows() -> int:
     return v if v > 0 else _DEFAULT_TILE_ROWS
 
 
+def vmem_limit_bytes() -> int:
+    """The scoped-VMEM limit for the framework's device kind (see
+    `_VMEM_LIMIT_BYTES`); raises for a kind nobody has sized."""
+    from ..parallel.mesh import default_devices
+
+    kind = default_devices()[0].device_kind
+    try:
+        return _VMEM_LIMIT_BYTES[kind]
+    except KeyError:
+        raise RuntimeError(
+            f"no scoped-VMEM limit is recorded for device kind {kind!r}; add "
+            "it to ops/distance.py _VMEM_LIMIT_BYTES (known: "
+            f"{sorted(_VMEM_LIMIT_BYTES)})"
+        ) from None
+
+
+def block_vmem_bytes(br: int, bk: int, d: int, dtype, fast: bool) -> int:
+    """Upper bound on the VMEM one grid step of the widest kernel holds at
+    blocks (br, bk) — what the planner fits under `vmem_limit_bytes()`:
+
+      * the two [block, d] operand/result blocks at their STORED dtype (the
+        bf16 cast of the fast path happens after the load), each
+        double-buffered by the grid pipeline;
+      * what the MXU contraction peels off them: the fast path's bf16 copies
+        of both blocks, or — `_kdot` asks Mosaic for an fp32 contraction —
+        the hi/mid/lo bf16 splits of the [br, d] row block and the f32
+        residuals they are taken from, 17 bytes per element as measured;
+      * `_pl_accumulate`'s [bk, d] f32 dot result before it lands in the
+        output block;
+      * the [br, bk] f32 distance / one-hot block, its bf16 or int32 twin,
+        and `_pl_d2_block`'s double-buffered [br, bk] output;
+      * the lane-sparse (n, 1) column blocks (weights, assignments, minima,
+        counts): one 128-lane tile per 8 rows, double-buffered.
+
+    Calibrated against Mosaic itself: for each kernel, blocks from 128 to
+    512 and d in {3072, 7168}, the smallest `vmem_limit_bytes` an AOT
+    compile for a v5e accepts stays below this bound (e.g. f32 argmin at
+    512x256, d=7168 needs 99 MiB, 111 MiB accounted here; fast accumulate at
+    512x512, d=3072 needs 32 MiB, 41 MiB accounted)."""
+    item = jnp.dtype(dtype).itemsize
+    dp = -(-d // _LANES) * _LANES
+    blocks = 2 * (br + bk) * dp * item
+    peeled = (br + bk) * dp * 2 if fast else br * dp * 17
+    dot_out = bk * dp * 4
+    tile = 3 * br * bk * 4
+    columns = 2 * (3 * br + bk) * _LANES * 4
+    return blocks + peeled + dot_out + tile + columns
+
+
 def plan_blocks(
-    n_rows: int, k_side: int, d: int, itemsize: int = 4
+    n_rows: int, k_side: int, d: int, dtype=jnp.float32, fast: bool = False
 ) -> Optional[Tuple[int, int]]:
-    """Kernel-internal (block_rows, block_k) so one x block [br, d], one
-    k-side block [bk, d] and the [br, bk] distance block fit the VMEM
-    budget. Returns None when even the floor blocks don't fit (enormous d)
-    — callers fall back to the jnp path then."""
-    budget = _VMEM_BUDGET_BYTES // max(1, itemsize)
+    """Kernel-internal (block_rows, block_k): the largest blocks whose
+    `block_vmem_bytes` fit the declared scoped-VMEM limit. Returns None when
+    even the floor blocks don't fit (enormous d) — callers take the jnp
+    form then."""
+    limit = vmem_limit_bytes()
     br, bk = 512, 512
-    while br * d + bk * d + br * bk > budget and (br > 8 or bk > 128):
+    while block_vmem_bytes(br, bk, d, dtype, fast) > limit and (br > 8 or bk > 128):
         if bk > 128:
             bk //= 2
         elif br > 8:
             br //= 2
-    if br * d + bk * d + br * bk > budget:
+    if block_vmem_bytes(br, bk, d, dtype, fast) > limit:
         return None
     return min(br, max(1, n_rows)), min(bk, max(1, k_side))
-
-
-def effective_itemsize(dtype, fast: bool) -> int:
-    """Bytes per element the kernel blocks ACTUALLY hold on-chip: the fast
-    path casts its VMEM tiles to bf16 (2 bytes), so planning with the input
-    dtype's itemsize (4 for f32) would budget half the tile the core can
-    hold. Pinned by tests/test_autotune.py."""
-    size = jnp.dtype(dtype).itemsize
-    return min(size, 2) if fast else size
 
 
 def _plan(
@@ -110,9 +160,13 @@ def _plan(
 ) -> Optional[Tuple[int, int]]:
     """Block plan for one kernel dispatch: the measured autotuner's
     persisted winner when one exists for this (shape-class, dtype, fast)
-    — else the static half-VMEM heuristic over the EFFECTIVE on-chip
-    itemsize. None still means "fall back to the jnp path" (enormous d)."""
-    heuristic = plan_blocks(n_rows, k_side, d, effective_itemsize(dtype, fast))
+    — else the static heuristic. None means "take the jnp form": enormous
+    d, or float64 rows on the compiled path — Mosaic holds no f64 (and
+    lowers no int64 index under the x64 mode f64 fits run in); XLA emulates
+    f64 on the TPU, the interpreter runs it as is."""
+    if not _interpret() and jnp.dtype(dtype).itemsize > 4:
+        return None
+    heuristic = plan_blocks(n_rows, k_side, d, dtype, fast)
     if heuristic is None:
         return None
     from . import autotune
@@ -121,15 +175,62 @@ def _plan(
     return tuned if tuned is not None else heuristic
 
 
+def _call_params(interpret: bool) -> dict:
+    """The `pallas_call` keywords every kernel here shares: interpreted, or
+    compiled with the scoped-VMEM limit the planner budgeted against stated
+    to Mosaic."""
+    if interpret:
+        return {"interpret": True}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {
+        "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes())
+    }
+
+
+def shard_map_check_vma() -> bool:
+    """`check_vma` for a `shard_map` whose body runs these kernels: on —
+    the compiled `pallas_call`s state how their outputs vary (`_vary_alike`)
+    — except while the kernels are INTERPRETED. The interpreter evaluates
+    the kernel body under the caller's shard_map trace, and in jax 0.9.0 the
+    vma check rejects its first constant ("Primitive mul requires varying
+    manual axes to match"); the TPU-specific interpreter passes the check
+    but deadlocks on the 8-device CPU mesh."""
+    return not _interpret()
+
+
+def _vary_alike(*operands):
+    """Under `shard_map` (check_vma) a `pallas_call` needs its operands typed
+    as varying over the SAME mesh axes — the kernel body contracts a
+    per-shard row block with replicated centers — and its out_shape structs
+    must state how the outputs vary, or the trace is rejected. Returns
+    (vma, operands) with every operand cast to the union; outside a
+    shard_map the set is empty and nothing happens."""
+    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    cast = tuple(
+        jax.lax.pcast(o, tuple(vma - jax.typeof(o).vma), to="varying")
+        if vma - jax.typeof(o).vma else o
+        for o in operands
+    )
+    return vma, cast
+
+
+def _vary_like(ref, *values) -> tuple:
+    """`values` typed as varying like `ref`: a loop carry initialised from
+    constants must vary like the per-shard data folded into it."""
+    return _vary_alike(ref, *values)[1][1:]
+
+
 # ---------------------------------------------------------- backend probe ---
 
 
 def kernel_mode() -> str:
     """Which inner-loop implementation this process runs: "pallas" (TPU
-    backend, kernels verified by a tiny self-test), "interpret" (the real
+    devices, kernels verified by a tiny self-test), "interpret" (the real
     kernels through the Pallas interpreter — CI parity testing), or "jnp"
-    (the bit-compatible fallback; CPU and older jaxlibs). Resolved once;
-    `SRML_DISTANCE_KERNEL` overrides."""
+    (the bit-compatible XLA form; what CPU runs). Resolved once;
+    `SRML_DISTANCE_KERNEL` overrides. On a TPU a failing self-test raises —
+    it is never answered with "jnp"."""
     global _MODE
     if _MODE is None:
         _MODE = _probe()
@@ -147,26 +248,46 @@ def _probe() -> str:
     if env == "interpret":
         return "interpret"
     if env == "pallas":
-        # explicit override really FORCES the kernel path: no self-test
-        # fallback — an operator debugging a kernel failure needs it to
-        # surface at the kernel call, not be silently probed away
+        # explicit override: no self-test — an operator debugging a kernel
+        # failure needs it to surface at the kernel call
         return "pallas"
-    if jax.default_backend() != "tpu":
-        return "jnp"
-    try:
-        import numpy as np
+    from ..parallel.mesh import default_devices
 
-        x = jnp.asarray(np.arange(64, dtype=np.float32).reshape(8, 8) / 64.0)
-        c = jnp.asarray(np.arange(32, dtype=np.float32).reshape(4, 8) / 32.0)
+    dev = default_devices()[0]
+    if dev.platform != "tpu":
+        return "jnp"
+    _self_test(dev)
+    return "pallas"
+
+
+def _self_test(dev) -> None:
+    """Compile and run `_pl_argmin` once on `dev` against the jnp formula.
+    On a TPU the kernel path is the contract: a Mosaic compile error or a
+    wrong answer raises here, with the compiler's message, instead of
+    quietly running every neighbor-family fit as a different program."""
+    import numpy as np
+
+    x = jax.device_put(np.arange(64, dtype=np.float32).reshape(8, 8) / 64.0, dev)
+    c = jax.device_put(np.arange(32, dtype=np.float32).reshape(4, 8) / 32.0, dev)
+    try:
         mind, best = _pl_argmin(x, c, _c_sq(c), block_rows=8, block_k=4,
                                 fast=False, interpret=False)
-        ref_d2 = _c_sq(c)[None, :] - 2.0 * (x @ c.T)
-        ok = np.allclose(np.asarray(mind), np.asarray(jnp.min(ref_d2, 1)), rtol=1e-5)
-        ok &= bool(np.all(np.asarray(best) == np.asarray(jnp.argmin(ref_d2, 1))))
-        return "pallas" if ok else "jnp"
-    except Exception:
-        # older jaxlib / no Mosaic lowering: the fallback is the contract
-        return "jnp"
+        mind, best = np.asarray(mind), np.asarray(best)
+    except Exception as e:
+        raise RuntimeError(
+            f"the Pallas distance kernels do not compile/run on {dev.device_kind!r}: "
+            f"{type(e).__name__}: {e}"
+        ) from e
+    ref_d2 = np.asarray(_c_sq(c)[None, :] - 2.0 * (x @ c.T))
+    if not (
+        np.allclose(mind, ref_d2.min(axis=1), rtol=1e-5)
+        and np.array_equal(best, ref_d2.argmin(axis=1))
+    ):
+        raise RuntimeError(
+            f"the Pallas argmin kernel disagrees with the jnp formula on "
+            f"{dev.device_kind!r}: min {mind.tolist()} vs {ref_d2.min(axis=1).tolist()}, "
+            f"argmin {best.tolist()} vs {ref_d2.argmin(axis=1).tolist()}"
+        )
 
 
 def _use_kernel() -> bool:
@@ -218,7 +339,23 @@ def _note(name: str) -> None:
 # [block_k, d] with full-depth dots, so each distance entry is ONE dot
 # reduction — bitwise identical to the fallback's single big matmul slice-
 # for-slice (the parity suite leans on this). The block planner refuses
-# (-> jnp fallback) when full-depth blocks cannot fit VMEM.
+# (-> jnp form) when full-depth blocks cannot fit VMEM.
+
+
+def _kdot(a: jax.Array, b: jax.Array, fast: bool) -> jax.Array:
+    """The in-kernel MXU contraction at a STATED precision. Mosaic lowers
+    only DEFAULT and HIGHEST, so the calling solver's ambient
+    `jax.default_matmul_precision` (KMeans runs under the
+    "BF16_BF16_F32_X3" preset, kNN under "float32") must not reach the
+    kernel's dot: `fast` is one bf16 pass with f32 accumulation, otherwise
+    the full-f32 contraction the jnp form gets on CPU."""
+    if fast:
+        return jnp.dot(
+            a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+            precision=jax.lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32,
+        ).astype(a.dtype)
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 def _pl_argmin(
@@ -243,23 +380,15 @@ def _pl_argmin(
     n_rb = B // block_rows
     n_kb = kp // block_k
     dtype = x.dtype
+    vma, (x, c_pad, c_sq_pad) = _vary_alike(x, c_pad, c_sq_pad)
 
     def kernel(x_ref, c_ref, csq_ref, mind_ref, best_ref):
         kb = pl.program_id(1)
-        xb = x_ref[...]
-        cb = c_ref[...]
-        if fast:
-            xc = jnp.dot(
-                xb.astype(jnp.bfloat16), cb.astype(jnp.bfloat16).T,
-                preferred_element_type=jnp.float32,
-            ).astype(dtype)
-        else:
-            xc = jnp.dot(xb, cb.T)
-        d2 = csq_ref[...] - 2.0 * xc  # [br, bk]
+        d2 = csq_ref[...] - 2.0 * _kdot(x_ref[...], c_ref[...].T, fast)  # [br, bk]
         blk_min = jnp.min(d2, axis=1, keepdims=True)
-        blk_arg = (
-            jnp.argmin(d2, axis=1).astype(jnp.int32)[:, None] + kb * block_k
-        )
+        # index dtype pinned: under x64 `jnp.argmin` asks for int64 indices,
+        # which Mosaic does not lower
+        blk_arg = jax.lax.argmin(d2, 1, jnp.int32)[:, None] + kb * block_k
 
         @pl.when(kb == 0)
         def _init():
@@ -286,10 +415,10 @@ def _pl_argmin(
             pl.BlockSpec((block_rows, 1), lambda r, k: (r, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, 1), dtype),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1), dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, 1), jnp.int32, vma=vma),
         ],
-        interpret=interpret,
+        **_call_params(interpret),
     )(x, c_pad, c_sq_pad[None, :])
     return mind[:, 0], best[:, 0]
 
@@ -315,6 +444,7 @@ def _pl_accumulate(
     n_rb = B // block_rows
     n_kb = kp // block_k
     dtype = x.dtype
+    vma, (x, w, assign) = _vary_alike(x, w, assign)
 
     def kernel(x_ref, w_ref, a_ref, sums_ref, counts_ref):
         kb = pl.program_id(0)
@@ -324,13 +454,7 @@ def _pl_accumulate(
         ab = a_ref[...]  # [br, 1]
         ids = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
         oh = jnp.where(ab == ids, wb, jnp.zeros((), dtype))  # [br, bk]
-        if fast:
-            contrib = jnp.dot(
-                oh.astype(jnp.bfloat16).T, xb.astype(jnp.bfloat16),
-                preferred_element_type=jnp.float32,
-            ).astype(dtype)
-        else:
-            contrib = jnp.dot(oh.T, xb)
+        contrib = _kdot(oh.T, xb, fast)
 
         @pl.when(rb == 0)
         def _init():
@@ -355,10 +479,10 @@ def _pl_accumulate(
             pl.BlockSpec((block_k, 1), lambda k, r: (k, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((kp, d), dtype),
-            jax.ShapeDtypeStruct((kp, 1), dtype),
+            jax.ShapeDtypeStruct((kp, d), dtype, vma=vma),
+            jax.ShapeDtypeStruct((kp, 1), dtype, vma=vma),
         ],
-        interpret=interpret,
+        **_call_params(interpret),
     )(x, w[:, None], assign[:, None].astype(jnp.int32))
     return sums, counts[:, 0]
 
@@ -381,18 +505,10 @@ def _pl_d2_block(
     kt = xt.shape[0]
     n_rb = B // block_rows
     dtype = q.dtype
+    vma, (q, xt, xt_sq) = _vary_alike(q, xt, xt_sq)
 
     def kernel(q_ref, x_ref, xsq_ref, out_ref):
-        qb = q_ref[...]
-        xb = x_ref[...]
-        if fast:
-            dots = jnp.dot(
-                qb.astype(jnp.bfloat16), xb.astype(jnp.bfloat16).T,
-                preferred_element_type=jnp.float32,
-            ).astype(dtype)
-        else:
-            dots = jnp.dot(qb, xb.T)
-        out_ref[...] = xsq_ref[...] - 2.0 * dots
+        out_ref[...] = xsq_ref[...] - 2.0 * _kdot(q_ref[...], x_ref[...].T, fast)
 
     return pl.pallas_call(
         kernel,
@@ -403,8 +519,8 @@ def _pl_d2_block(
             pl.BlockSpec((1, kt), lambda r: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, kt), lambda r: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, kt), dtype),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((B, kt), dtype, vma=vma),
+        **_call_params(interpret),
     )(q, xt, xt_sq[None, :])
 
 
@@ -503,7 +619,7 @@ def assign_accumulate(
 
 def tile_assign_accumulate(
     Xl: jax.Array, wl: jax.Array, centers: jax.Array, batch_rows: int,
-    fast: bool = False, spmd: bool = True,
+    fast: bool = False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Scan one device's rows in tiles; returns (sums [k,d], counts [k],
     inertia) — the whole-shard Lloyd accumulation every KMeans path shares.
@@ -523,18 +639,14 @@ def tile_assign_accumulate(
         s, c, i = assign_accumulate(xb, wb, centers, fast=fast)
         return (sums + s, counts + c, inertia + i), None
 
-    init = (
+    # under a shard_map the carry varies like the per-shard accumulators
+    # (vma typing); the meshless 1-device program has no axis to cast over
+    init = _vary_like(
+        Xl,
         jnp.zeros((k, d), Xl.dtype),
         jnp.zeros((k,), Xl.dtype),
         jnp.zeros((), Xl.dtype),
     )
-    if spmd:
-        # carry must be typed as varying over the mesh axis to match the
-        # per-shard accumulators (JAX shard_map vma typing); the meshless
-        # 1-device program has no axis to cast over
-        from ..parallel.mesh import ROWS_AXIS, pcast_varying
-
-        init = jax.tree.map(lambda t: pcast_varying(t, ROWS_AXIS), init)
     batch_rows = min(batch_rows, nl)
     n_full = (nl // batch_rows) * batch_rows
 
@@ -578,7 +690,9 @@ def argmin_assign(
         a = assign_argmin(xb, centers, fast=fast)[1]
         return jax.lax.dynamic_update_slice(out, a, (s0,))
 
-    return jax.lax.fori_loop(0, n_tiles, body, jnp.zeros((n,), jnp.int32))
+    # under a shard_map the carry varies like the rows it is computed from
+    (out0,) = _vary_like(X, jnp.zeros((n,), jnp.int32))
+    return jax.lax.fori_loop(0, n_tiles, body, out0)
 
 
 # ----------------------------------------------------------- top-k (kNN) ----
@@ -660,7 +774,9 @@ def topk_tile(
         neg_d, pos = jax.lax.top_k(-cat_d, kk)
         return -neg_d, jnp.take_along_axis(cat_i, pos, axis=1)
 
-    init = (
+    # under a shard_map the best lists vary like the item shard they scan
+    init = _vary_like(
+        items,
         jnp.full((q.shape[0], kk), jnp.inf, items.dtype),
         jnp.zeros((q.shape[0], kk), jnp.int32),
     )

@@ -19,6 +19,7 @@ from .mesh import (  # noqa: F401
     chip_scope,
     current_chip_scope,
     default_devices,
+    device_platforms,
     ensure_compilation_cache,
     get_mesh,
     make_global_rows,

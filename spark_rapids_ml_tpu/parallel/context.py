@@ -1046,23 +1046,6 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _distributed_is_initialized() -> bool:
-    """`jax.distributed.is_initialized()` where available (newer jax); on
-    0.4.x fall back to the distributed global state's client handle. Both
-    probe WITHOUT touching the XLA backend (unlike jax.process_count())."""
-    import jax
-
-    checker = getattr(jax.distributed, "is_initialized", None)
-    if checker is not None:
-        return bool(checker())
-    try:
-        from jax._src import distributed as _dist
-
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:  # pragma: no cover - conservative default
-        return False
-
-
 # The context active for the current fit call, set by TpuContext.__enter__.
 # Estimators pick this up so `with TpuContext(...): est.fit(local_df)` routes
 # the fit through the caller's process group — the analog of the reference's
@@ -1165,7 +1148,7 @@ class TpuContext:
             # probe distributed state WITHOUT jax.process_count(): that call
             # initializes the XLA backend, after which distributed init is
             # rejected
-            if not _distributed_is_initialized():
+            if not jax.distributed.is_initialized():
                 if self.rank == 0:
                     coordinator = json.dumps({"addr": f"{socket.gethostname()}:{_free_port()}"})
                 else:

@@ -22,25 +22,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import telemetry
 from ..errors import MeshTopologyError
 
-try:  # jax >= 0.6 exports shard_map at the top level
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x: experimental home, same keyword signature
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 ROWS_AXIS = "rows"
 # outer axis of a hierarchical mesh: one step per jax.distributed process
 # group (DCN hops cross process boundaries; ICI stays inside one group)
 DCN_AXIS = "dcn"
 
-
-def pcast_varying(t, axis_name: str):
-    """Type `t` as varying over `axis_name` inside a shard_map body — the
-    newer-jax `lax.pcast(..., to="varying")` vma typing. On jax builds without
-    `pcast` (<= 0.4.x shard_map) there is no varying-axes type system and the
-    value is already per-shard, so this is the identity."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(t, axis_name, to="varying")
-    return t
 
 # Device-resolution hook: which devices the framework runs on. Overridable for
 # tests (virtual multi-device CPU mesh while a real TPU backend is registered)
@@ -101,6 +87,14 @@ def default_devices() -> list:
     if platform:
         return list(jax.devices(platform))
     return list(jax.devices())
+
+
+def device_platforms() -> list:
+    """Every platform a run would touch: those of the framework's device
+    pool plus jax's default backend. A chip measurement (chip_smoke.py,
+    bench.py, the protocol runner) runs only when this is exactly
+    ``["tpu"]`` — never on whatever happens to be there."""
+    return sorted({d.platform for d in default_devices()} | {jax.default_backend()})
 
 
 def default_local_device():
@@ -320,92 +314,45 @@ def replicated(mesh: Mesh) -> NamedSharding:
 _COMPILE_CACHE_DIR: Optional[str] = None  # dir currently wired into jax, if any
 
 
-def ensure_compilation_cache() -> bool:
-    """Point XLA's PERSISTENT compilation cache at
-    ``core.config["compilation_cache_dir"]`` (seeded from
-    ``SRML_COMPILE_CACHE_DIR``), so compiled programs survive process
-    restarts — a transform fleet's bucket-ladder programs and a sweep's
-    batched solver compile once per cluster, not once per process. Called
-    from the fit and transform entry points; re-pointing the config dir
-    takes effect on the next call. Returns whether a cache dir is active."""
+def compilation_cache_dir() -> str:
+    """The ONE persistent-cache directory of this process (XLA programs and
+    the autotune table beside them): `JAX_COMPILATION_CACHE_DIR` where it is
+    set — the program then never configures another — else
+    ``core.config["compilation_cache_dir"]``, whose default is a fixed
+    git-ignored directory in the checkout."""
+    import os
+
+    from ..core import _DEFAULT_COMPILE_CACHE_DIR, config
+
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        config.get("compilation_cache_dir") or _DEFAULT_COMPILE_CACHE_DIR
+    )
+
+
+def ensure_compilation_cache() -> None:
+    """Point XLA's PERSISTENT compilation cache at `compilation_cache_dir()`,
+    so compiled programs survive process restarts — a transform fleet's
+    bucket-ladder programs and a sweep's batched solver compile once per
+    cluster, not once per process. Called from the fit, transform and
+    serving-load entry points; re-pointing the config dir takes effect on
+    the next call. A CPU device pool is left as the environment configured
+    it: XLA:CPU programs compile in moments, and this jaxlib's CPU loader
+    logs a machine-feature warning for every cached entry it reads back."""
     global _COMPILE_CACHE_DIR
-    from ..core import config
+    path = compilation_cache_dir()
+    if path == _COMPILE_CACHE_DIR or default_devices()[0].platform == "cpu":
+        return
+    from jax.experimental.compilation_cache import compilation_cache
 
-    path = config.get("compilation_cache_dir") or None
-    if path == _COMPILE_CACHE_DIR:
-        return path is not None
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        if path is not None:
-            # default thresholds skip sub-second programs — the dispatch-bound
-            # serving shapes this cache exists for; persist everything
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            try:
-                jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-            except Exception:  # older jax: knob absent, default is fine
-                pass
-    except Exception as e:  # pragma: no cover - jax build without the cache
-        from ..utils import get_logger
-
-        get_logger("mesh").warning(
-            "could not enable the persistent compilation cache at %r (%s: %s)",
-            path, type(e).__name__, e,
-        )
-        return False
+    jax.config.update("jax_compilation_cache_dir", path)
+    # default thresholds skip sub-second programs — the dispatch-bound
+    # serving shapes this cache exists for; persist everything
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # jax latches "is the cache in use" at the process's FIRST compile; one
+    # that ran before this call (a probe, user code) latched the old answer
+    compilation_cache.reset_cache()
     _COMPILE_CACHE_DIR = path
-    return path is not None
-
-
-_PRECISION_SUPPORT: dict = {}
-
-
-def _matmul_precision_supported(precision: str, platform: str) -> bool:
-    """Probe whether `platform`'s dot_general accepts `precision` by lowering
-    a tiny jitted dot against an input committed to that platform's device 0
-    (jit compiles for the committed device, not the default backend). Only
-    DEFINITIVE verdicts are cached: a backend rejecting the mode raises
-    ValueError; any other error is a transient probe failure — fall back to
-    float32 for this call but re-probe next time instead of pinning the
-    process to the fallback forever."""
-    key = (precision, platform)
-    if key in _PRECISION_SUPPORT:
-        return _PRECISION_SUPPORT[key]
-    # validate the NAME first, outside the probe: a typo'd precision string
-    # raises here (config-level ValueError) and must surface to the caller,
-    # not be cached as "backend rejects this mode"
-    with jax.default_matmul_precision(precision):
-        pass
-    try:
-        x = jax.device_put(np.zeros((2, 2), np.float32), jax.devices(platform)[0])
-        with jax.default_matmul_precision(precision):
-            jax.jit(lambda a: a @ a).lower(x).compile()
-        _PRECISION_SUPPORT[key] = True
-    except ValueError:  # "precision ... is not supported": definitive rejection
-        _PRECISION_SUPPORT[key] = False
-    except Exception as e:  # transient (OOM/backend hiccup): don't cache
-        from ..utils import get_logger
-
-        get_logger("mesh").warning(
-            "matmul precision probe for %r on %s failed transiently (%s: %s); "
-            "using float32 for this call", precision, platform, type(e).__name__, e,
-        )
-        return False
-    return _PRECISION_SUPPORT[key]
-
-
-def effective_matmul_precision(precision: str) -> str:
-    """`precision`, downgraded to plain "float32" when the FRAMEWORK devices'
-    backend rejects it. Reduced-pass MXU algorithm presets
-    ("BF16_BF16_F32_X3", ...) are TPU modes; CPU lowering on older jax builds
-    raises for them instead of ignoring the hint. Probed per (precision,
-    platform) — the framework's device pool can differ from jax's default
-    backend (set_devices('cpu') virtual mesh alongside a registered TPU)."""
-    if precision in ("float32", "highest", "default"):
-        return precision  # universally supported: skip the probe compile
-    platform = default_devices()[0].platform
-    if _matmul_precision_supported(precision, platform):
-        return precision
-    return "float32"
 
 
 @contextlib.contextmanager
@@ -439,17 +386,10 @@ def dtype_scope(dtype, matmul_precision: str = "float32"):
     """
     with contextlib.ExitStack() as stack:
         if np.dtype(dtype) == np.float64 and not jax.config.jax_enable_x64:
-            # scoped x64: top-level jax.enable_x64 on newer jax, the
-            # experimental home on 0.4.x
-            _enable_x64 = getattr(jax, "enable_x64", None)
-            if _enable_x64 is None:
-                from jax.experimental import enable_x64 as _enable_x64
-            stack.enter_context(_enable_x64(True))  # jax config State: scoped context
+            stack.enter_context(jax.enable_x64(True))  # scoped x64
         if np.dtype(dtype) == np.float64:
             matmul_precision = "float32"  # f64 runs don't want a reduced-pass MXU mode
-        stack.enter_context(
-            jax.default_matmul_precision(effective_matmul_precision(matmul_precision))
-        )
+        stack.enter_context(jax.default_matmul_precision(matmul_precision))
         yield
 
 

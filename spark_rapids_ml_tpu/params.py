@@ -404,12 +404,9 @@ class _TpuParams(_TpuClass, Params):
         The reference infers one worker per cluster GPU (params.py:430-500); here
         a worker is one mesh device (chip), so local device count is the default.
         """
-        try:
-            from .parallel.mesh import default_devices
+        from .parallel.mesh import default_devices
 
-            return max(1, len(default_devices()))
-        except Exception:  # pragma: no cover - jax is a hard dep in practice
-            return 1
+        return max(1, len(default_devices()))
 
     @property
     def float32_inputs(self) -> bool:

@@ -28,9 +28,9 @@
 #     collective of its own.
 #   * Per-iteration convergence traces from jitted solvers use
 #     `jax.debug.callback` and are gated SEPARATELY (`SRML_TRACE_CONVERGENCE`
-#     / `enable(convergence=True)`): a host callback per L-BFGS iteration is
-#     free on CPU but a dispatch round-trip through a remote TPU tunnel, so
-#     it never rides along with plain counter telemetry. The gate is read at
+#     / `enable(convergence=True)`): a host callback per L-BFGS iteration
+#     stalls the device program on the host every iteration, so it never
+#     rides along with plain counter telemetry. The gate is read at
 #     TRACE time — toggling it after a solver shape has compiled does not
 #     retrace that shape.
 #

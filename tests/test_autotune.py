@@ -1,16 +1,18 @@
 #
 # Measured block autotuner (spark_rapids_ml_tpu/ops/autotune.py,
 # docs/performance.md "Kernel autotuner") and the planner it overrides
-# (distance.effective_itemsize / _plan). The acceptance contract:
+# (distance.block_vmem_bytes / plan_blocks / _plan). The acceptance contract:
 #
-#   - the fast path budgets VMEM at the EFFECTIVE on-chip itemsize (bf16
-#     blocks = 2 bytes), never the input dtype's;
+#   - the planner budgets what the kernels really hold in VMEM: blocks at
+#     their STORED dtype, double-buffered, plus the fast path's bf16 copies
+#     — so the fast plan is never larger than the f32 plan;
 #   - a measured winner persists as JSON beside the compile cache and is
 #     reused ACROSS PROCESSES (simulated here by dropping the in-memory
 #     cache), hit/miss counters pinned;
-#   - every degradation path — disabled, off-TPU, malformed table, stale
-#     version, bad entries, raising timer, unset cache dir — falls back to
-#     the heuristic without raising; a fit never fails in the tuner.
+#   - every table degradation path — disabled, off-TPU, malformed table,
+#     stale version, bad entries, unset cache dir — falls back to the
+#     heuristic without raising; a candidate the compiler refuses is
+#     skipped, and only when EVERY candidate is refused does ensure raise.
 #
 import json
 import os
@@ -25,18 +27,20 @@ from spark_rapids_ml_tpu import telemetry
 from spark_rapids_ml_tpu.ops import autotune
 from spark_rapids_ml_tpu.ops.distance import (
     _plan,
-    effective_itemsize,
+    block_vmem_bytes,
     plan_blocks,
+    vmem_limit_bytes,
 )
 
 _KEYS = ("compilation_cache_dir", "autotune_enabled", "autotune_repeats")
 
 
 @pytest.fixture
-def tuner(tmp_path):
+def tuner(tmp_path, monkeypatch):
     """Isolated tuner: private table directory, clean in-memory cache and
     counters, config restored exactly (other files' fits must keep seeing
     the real settings)."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)  # it would win
     saved = {k: core_mod.config[k] for k in _KEYS}
     core_mod.config["compilation_cache_dir"] = str(tmp_path)
     core_mod.config["autotune_enabled"] = True
@@ -63,27 +67,35 @@ def _fake_timer(best=(256, 256)):
     return timer
 
 
-# ------------------------------------------------------ planner itemsize ----
+# ------------------------------------------------------ planner accounting --
 
 
-def test_effective_itemsize_pins():
-    assert effective_itemsize(jnp.float32, fast=False) == 4
-    assert effective_itemsize(jnp.float32, fast=True) == 2
-    assert effective_itemsize(jnp.float64, fast=False) == 8
-    # the fast path stages bf16 blocks regardless of the ambient dtype
-    assert effective_itemsize(jnp.float64, fast=True) == 2
-    assert effective_itemsize(jnp.bfloat16, fast=False) == 2
+def test_block_vmem_bytes_counts_stored_dtype_and_double_buffers():
+    d = 3072  # lane-aligned, so the terms are exact
+    full = block_vmem_bytes(512, 512, d, jnp.float32, fast=False)
+    fast = block_vmem_bytes(512, 512, d, jnp.float32, fast=True)
+    # the two [512, d] f32 blocks, each double-buffered, are the floor —
+    # exactly the 24 MiB Mosaic reports for this shape on a v5e
+    assert 2 * (512 + 512) * d * 4 == 24 << 20 < fast
+    # both modes hold the SAME f32 blocks; the fast path adds a bf16 copy of
+    # both, the fp32 contraction the row block's bf16 splits and residuals
+    assert fast - (512 + 512) * d * 2 == full - 512 * d * 17
+    # a non-128-multiple depth occupies whole lane tiles
+    assert block_vmem_bytes(512, 512, 3000, jnp.float32, False) == full
+    # f64 blocks are twice as wide
+    assert block_vmem_bytes(512, 512, d, jnp.float64, False) > full
 
 
-def test_fast_plan_budgets_double_elements(tuner):
-    # a VMEM-tight depth: at 4-byte f32 the heuristic must shrink blocks,
-    # at the 2-byte effective itemsize the same shape fits bigger tiles
-    d = 3000
-    full = plan_blocks(4096, 4096, d, effective_itemsize(jnp.float32, False))
-    fast = plan_blocks(4096, 4096, d, effective_itemsize(jnp.float32, True))
+def test_full_precision_plan_never_outgrows_the_fast_plan(tuner):
+    # a VMEM-tight depth: both modes must shrink, full precision at least
+    # as much (it peels 17 bytes per row-block element, fast 2 per element)
+    d = 7168
+    full = plan_blocks(4096, 4096, d, jnp.float32, False)
+    fast = plan_blocks(4096, 4096, d, jnp.float32, True)
     assert full is not None and fast is not None
-    assert fast[0] * fast[1] > full[0] * full[1]
-    # _plan threads the same effective itemsize (no table entry here)
+    assert fast != (512, 512)
+    assert full[0] * full[1] <= fast[0] * fast[1]
+    # _plan threads the same accounting (no table entry here)
     assert _plan(4096, 4096, d, jnp.float32, False) == full
     assert _plan(4096, 4096, d, jnp.float32, True) == fast
 
@@ -135,16 +147,16 @@ def test_lookup_miss_counts_and_falls_back(tuner):
     assert stats["misses"] == 1 and stats["hits"] == 0
     assert telemetry.registry().snapshot()["counters"]["autotune.misses"] == 1
     # the planner still plans (heuristic)
-    assert _plan(4096, 512, 64, jnp.float32, False) == plan_blocks(4096, 512, 64, 4)
+    assert _plan(4096, 512, 64, jnp.float32, False) == plan_blocks(4096, 512, 64)
 
 
 def test_candidates_respect_vmem_and_include_heuristic(tuner):
-    cands = autotune._candidates(4096, 4096, 3000, jnp.float32, False)
-    heuristic = plan_blocks(4096, 4096, 3000, 4)
+    cands = autotune._candidates(4096, 4096, 7168, jnp.float32, True)
+    heuristic = plan_blocks(4096, 4096, 7168, jnp.float32, True)
     assert cands[0] == heuristic
-    budget = 8 * 1024 * 1024 // 4
+    assert (512, 512) not in cands  # over the limit at this depth
     for br, bk in cands:
-        assert br * 3000 + bk * 3000 + br * bk <= budget
+        assert block_vmem_bytes(br, bk, 7168, jnp.float32, True) <= vmem_limit_bytes()
 
 
 # ------------------------------------------------------ degradation ---------
@@ -189,12 +201,35 @@ def test_bad_entry_shapes_filtered(tuner):
     assert stats["table_errors"] == 3 and stats["entries"] == 1
 
 
-def test_raising_timer_never_fails_the_fit(tuner):
-    def timer(br, bk):
-        raise RuntimeError("exotic part says no")
+def test_refused_candidate_is_skipped_not_fatal(tuner):
+    # the compiler refuses the heuristic's own pick (a Mosaic VMEM error):
+    # the session goes on, a feasible candidate wins, nothing is counted as
+    # a table error and the heuristic is NOT silently handed back
+    heuristic = plan_blocks(4096, 512, 64, jnp.float32, True)
+    inner = _fake_timer(best=(256, 256))
 
-    assert autotune.ensure(4096, 512, 64, jnp.float32, True, timer=timer) is None
-    assert autotune.stats()["table_errors"] == 1
+    def timer(br, bk):
+        if (br, bk) == heuristic:
+            raise RuntimeError(
+                "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem"
+            )
+        return inner(br, bk)
+
+    won = autotune.ensure(4096, 512, 64, jnp.float32, True, timer=timer)
+    assert won == (256, 256) and won != heuristic
+    assert autotune.stats()["table_errors"] == 0
+    assert autotune.stats()["measurements"] == 1
+    assert _plan(4096, 512, 64, jnp.float32, True) == (256, 256)
+
+
+def test_every_candidate_refused_raises_with_the_cause(tuner):
+    def timer(br, bk):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    with pytest.raises(RuntimeError, match="no candidate tiling") as ei:
+        autotune.ensure(4096, 512, 64, jnp.float32, True, timer=timer)
+    assert "Mosaic failed to compile" in str(ei.value.__cause__)
+    assert autotune.stats()["table_errors"] == 0
     assert not os.path.exists(os.path.join(str(tuner), "srml_autotune.json"))
 
 
@@ -217,17 +252,24 @@ def test_off_tpu_without_timer_measures_nothing(tuner):
     assert autotune.stats()["measurements"] == 0
 
 
-def test_no_cache_dir_stays_in_memory(tuner):
-    core_mod.config["compilation_cache_dir"] = None
-    assert autotune.table_path() is None
-    won = autotune.ensure(4096, 512, 64, jnp.float32, True, timer=_fake_timer())
-    assert won == (256, 256)
-    # in-memory table serves this process...
-    assert autotune.lookup(4096, 512, 64, jnp.float32, True) == (256, 256)
-    # ...but a "new process" starts cold (nothing was persisted anywhere)
-    autotune.reset()
-    assert autotune.lookup(4096, 512, 64, jnp.float32, True) is None
+def test_table_lives_beside_the_compile_cache(tuner, monkeypatch, tmp_path):
+    from spark_rapids_ml_tpu.parallel.mesh import compilation_cache_dir
+
+    # config-resolved directory (JAX_COMPILATION_CACHE_DIR unset by the fixture)
+    assert autotune.table_path() == os.path.join(str(tuner), "srml_autotune.json")
+    # where JAX_COMPILATION_CACHE_DIR is set it wins, and the table follows
+    env_dir = tmp_path / "from_env"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env_dir))
+    assert compilation_cache_dir() == str(env_dir)
+    autotune.ensure(4096, 512, 64, jnp.float32, True, timer=_fake_timer())
+    assert os.path.exists(env_dir / "srml_autotune.json")
     assert not os.path.exists(os.path.join(str(tuner), "srml_autotune.json"))
+    # a None config is never "no cache": it resolves to the fixed default
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    core_mod.config["compilation_cache_dir"] = None
+    assert autotune.table_path() == os.path.join(
+        core_mod._DEFAULT_COMPILE_CACHE_DIR, "srml_autotune.json"
+    )
 
 
 def test_env_seed_of_autotune_enabled(monkeypatch):

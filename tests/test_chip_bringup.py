@@ -1,0 +1,368 @@
+#
+# What the chip run (chip_smoke.py) depends on, as far as a CPU can check it:
+#
+#   (a) the kernel path under `shard_map`: KMeans / NearestNeighbors / DBSCAN
+#       through the ESTIMATORS on the 4- and 8-device mesh with the kernels
+#       interpreted — jax rejects a `pallas_call` whose out_shape has no
+#       `vma` there, which no ops-level kernel test can see;
+#   (b) where the persistent compile cache (and the autotune table) lives;
+#   (c) a TPU whose kernel self-test fails RAISES — it never becomes "jnp";
+#   (d) a kernel that does not compile surfaces as itself, once: not an HBM
+#       OOM, no streaming retry, no HbmBudgetError;
+#   (e) the block planner's accounted VMEM stays under the limit it declares
+#       for every shape the issue lists — and those kernels really compile
+#       for a v5e (ahead of time, with the installed libtpu, no chip).
+#
+import os
+
+import numpy as np
+import pandas as pd
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from spark_rapids_ml_tpu import core, memory
+from spark_rapids_ml_tpu.errors import HbmBudgetError
+from spark_rapids_ml_tpu.ops import distance
+from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+class _FakeTpu:
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+    process_index = 0
+
+
+@pytest.fixture
+def kernel_calls(monkeypatch):
+    """Interpreted kernels for this test, plus a count of the `pallas_call`s
+    it traced: a jit cache hit on an earlier jnp-mode trace of the same
+    shapes would make the test pass without touching a kernel."""
+    calls = []
+    real = distance._call_params
+
+    def counting(interpret):
+        calls.append(interpret)
+        return real(interpret)
+
+    monkeypatch.setattr(distance, "_call_params", counting)
+    monkeypatch.setattr(distance, "_MODE", "interpret")
+    return calls
+
+
+# ------------------------------------------ (a) kernels under shard_map -----
+
+
+@pytest.mark.parametrize("workers", [4, 8])
+def test_kmeans_estimator_kernel_path_on_mesh(kernel_calls, workers):
+    from spark_rapids_ml_tpu.models.clustering import KMeans
+
+    rng = np.random.default_rng(11)
+    # odd sizes: fresh jit shapes, and rows that do not divide the mesh
+    X = (rng.normal(size=(8, 13)) * 6)[rng.integers(0, 8, 263)] + rng.normal(size=(263, 13))
+    df = pd.DataFrame({"features": list(X.astype(np.float32))})
+
+    def fit(n):
+        est = KMeans(k=8, maxIter=3, tol=0.0, seed=3, num_workers=n)
+        model = est.setFeaturesCol("features").fit(df)
+        return model, model.transform(df)["prediction"].to_numpy()
+
+    one, assign_one = fit(1)
+    many, assign_many = fit(workers)
+    assert kernel_calls and all(kernel_calls)  # interpreted kernels were traced
+    np.testing.assert_allclose(many.cluster_centers_, one.cluster_centers_, rtol=1e-4, atol=1e-4)
+    assert many.inertia_ == pytest.approx(one.inertia_, rel=1e-4)
+    np.testing.assert_array_equal(assign_many, assign_one)
+
+
+@pytest.mark.parametrize("workers", [4, 8])
+def test_knn_estimator_kernel_path_on_mesh(kernel_calls, workers):
+    from spark_rapids_ml_tpu.models.knn import NearestNeighbors
+
+    rng = np.random.default_rng(12)
+    X = rng.normal(size=(301, 11)).astype(np.float32)
+    df = pd.DataFrame({"features": list(X), "id": np.arange(301, dtype=np.int64)})
+
+    def search(n):
+        est = NearestNeighbors(k=5, num_workers=n).setInputCol("features").setIdCol("id")
+        _, _, knn_df = est.fit(df).kneighbors(df.iloc[:17])
+        return np.stack(knn_df["indices"].to_list()), np.stack(knn_df["distances"].to_list())
+
+    idx_one, dist_one = search(1)
+    idx_many, dist_many = search(workers)
+    assert kernel_calls and all(kernel_calls)
+    np.testing.assert_array_equal(idx_many, idx_one)
+    np.testing.assert_array_equal(idx_many[:, 0], np.arange(17))  # self first
+    np.testing.assert_allclose(dist_many[:, 1:], dist_one[:, 1:], rtol=1e-5)
+    # ||q||^2 - 2 q.x + ||x||^2 cancels to rounding, in a shard-dependent order
+    assert max(dist_many[:, 0].max(), dist_one[:, 0].max()) < 5e-3
+
+
+@pytest.mark.parametrize("workers", [4, 8])
+def test_dbscan_estimator_on_mesh_with_kernel_mode_on(kernel_calls, workers):
+    # DBSCAN's passes materialize their distance tile (ops/distance.pairwise_d2
+    # — plain XLA by design), so no kernel is traced; what is pinned is that
+    # the mesh fit runs with the kernel mode on and equals one device
+    from sklearn.datasets import make_blobs
+
+    from spark_rapids_ml_tpu.models.clustering import DBSCAN
+
+    x, _ = make_blobs(n_samples=203, centers=3, cluster_std=0.4, random_state=5)
+    df = pd.DataFrame({"features": list(x.astype(np.float64))})
+
+    def labels(n):
+        model = DBSCAN(eps=0.7, min_samples=4, num_workers=n).setFeaturesCol("features").fit(df)
+        return model.transform(df)["prediction"].to_numpy()
+
+    np.testing.assert_array_equal(labels(workers), labels(1))
+
+
+# ------------------------------------------ (b) compile cache resolution ----
+
+
+@pytest.fixture
+def jax_cache_restored():
+    """Tests that really wire a directory into jax put it back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    saved = (
+        jax.config.jax_persistent_cache_min_compile_time_secs,
+        jax.config.jax_persistent_cache_min_entry_size_bytes,
+    )
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", saved[0])
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", saved[1])
+    compilation_cache.reset_cache()
+    mesh_mod._COMPILE_CACHE_DIR = None
+
+
+def test_compile_cache_default_is_one_fixed_git_ignored_checkout_dir(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    # the retired variable is not read any more
+    monkeypatch.setenv("SRML_COMPILE_CACHE_DIR", "/nonexistent/elsewhere")
+    expected = os.path.join(REPO, ".srml_cache")
+    assert core._DEFAULT_COMPILE_CACHE_DIR == expected
+    assert mesh_mod.compilation_cache_dir() == core.config["compilation_cache_dir"] == expected
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".srml_cache/" in f.read().split()
+    # a fresh process resolves the very same path: nothing of pid or time
+    import subprocess
+    import sys
+
+    code = "from spark_rapids_ml_tpu.core import config; print(config['compilation_cache_dir'])"
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert out.stdout.strip() == expected, out.stderr
+
+
+def test_compile_cache_env_is_respected_and_never_overridden(
+    monkeypatch, tmp_path, jax_cache_restored
+):
+    env_dir = str(tmp_path / "from_env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    monkeypatch.setitem(core.config, "compilation_cache_dir", str(tmp_path / "from_config"))
+    assert mesh_mod.compilation_cache_dir() == env_dir
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append((k, v)), real_update(k, v))[1],
+    )
+    with mesh_mod.chip_scope([_FakeTpu()]):  # an accelerator pool gets wired
+        mesh_mod.ensure_compilation_cache()
+    dirs = [v for k, v in updates if k == "jax_compilation_cache_dir"]
+    assert dirs == [env_dir]  # that directory, and no other, ever
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    # the zero thresholds: sub-second serving programs persist too
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+
+
+def test_compile_cache_config_dir_used_when_env_unset(monkeypatch, tmp_path, jax_cache_restored):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setitem(core.config, "compilation_cache_dir", str(tmp_path / "from_config"))
+    with mesh_mod.chip_scope([_FakeTpu()]):
+        mesh_mod.ensure_compilation_cache()
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "from_config")
+
+
+# ------------------------------------------ (c) the probe never hides a TPU --
+
+
+@pytest.fixture
+def unprobed(monkeypatch):
+    monkeypatch.delenv("SRML_DISTANCE_KERNEL", raising=False)
+    monkeypatch.setattr(distance, "_MODE", None)
+    monkeypatch.setattr(jax, "device_put", lambda x, dev=None: jnp.asarray(x))
+
+
+def test_kernel_mode_raises_when_the_tpu_self_test_does_not_compile(unprobed, monkeypatch):
+    def refuse(*a, **k):
+        raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: Bad lhs type")
+
+    monkeypatch.setattr(distance, "_pl_argmin", refuse)
+    with mesh_mod.chip_scope([_FakeTpu()]):
+        with pytest.raises(RuntimeError, match="Mosaic failed to compile") as ei:
+            distance.kernel_mode()
+        assert "TPU v5 lite" in str(ei.value)
+        assert distance._MODE is None  # nothing cached: the next call raises too
+        with pytest.raises(RuntimeError, match="do not compile/run"):
+            distance.kernel_mode()
+
+
+def test_kernel_mode_raises_when_the_tpu_self_test_is_wrong(unprobed, monkeypatch):
+    wrong = lambda x, c, c_sq, **k: (jnp.zeros((8,)), jnp.zeros((8,), jnp.int32))  # noqa: E731
+    monkeypatch.setattr(distance, "_pl_argmin", wrong)
+    with mesh_mod.chip_scope([_FakeTpu()]):
+        with pytest.raises(RuntimeError, match="disagrees with the jnp formula"):
+            distance.kernel_mode()
+
+
+def test_kernel_mode_follows_the_framework_devices_not_the_default_backend(unprobed, monkeypatch):
+    # jax's default backend here is cpu; the FRAMEWORK pool says tpu: the
+    # probe runs the self-test (interpreted stand-in) and answers "pallas"
+    real = distance._pl_argmin
+    monkeypatch.setattr(
+        distance, "_pl_argmin", lambda *a, **k: real(*a, **{**k, "interpret": True})
+    )
+    with mesh_mod.chip_scope([_FakeTpu()]):
+        assert distance.kernel_mode() == "pallas"
+
+
+def test_unknown_device_kind_has_no_vmem_limit():
+    class Other(_FakeTpu):
+        device_kind = "TPU v9 imaginary"
+
+    with mesh_mod.chip_scope([Other()]):
+        with pytest.raises(RuntimeError, match="TPU v9 imaginary"):
+            distance.vmem_limit_bytes()
+        with pytest.raises(RuntimeError, match="_VMEM_LIMIT_BYTES"):
+            distance.plan_blocks(4096, 1000, 3000)
+
+
+# ------------------------- (d) a compile failure is not an OOM ---------------
+
+_VMEM_REFUSAL = (
+    "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while allocating "
+    "on stack for %pallas_call. Scoped allocation with size 19.10M and limit "
+    "16.00M exceeded scoped vmem limit by 3.10M."
+)
+
+
+def test_kernel_compile_failure_surfaces_once_with_no_streaming_retry(monkeypatch):
+    from spark_rapids_ml_tpu.models.clustering import KMeans
+    from spark_rapids_ml_tpu.ops import kmeans as ops_kmeans
+    from spark_rapids_ml_tpu.ops import streaming
+
+    calls = {"resident": 0, "streaming": 0}
+
+    def resident(*a, **k):
+        calls["resident"] += 1
+        raise RuntimeError(_VMEM_REFUSAL)
+
+    def streamed(*a, **k):
+        calls["streaming"] += 1
+        raise AssertionError("a compile failure must not be retried out-of-core")
+
+    monkeypatch.setattr(ops_kmeans, "kmeans_fit", resident)
+    monkeypatch.setattr(streaming, "kmeans_fit_streaming", streamed)
+    rng = np.random.default_rng(0)
+    df = pd.DataFrame({"features": list(rng.normal(size=(64, 4)).astype(np.float32))})
+    with pytest.raises(RuntimeError, match="memory space vmem") as ei:
+        KMeans(k=2, maxIter=2).setFeaturesCol("features").fit(df)
+    assert not isinstance(ei.value, HbmBudgetError)
+    assert calls == {"resident": 1, "streaming": 0}
+    # the classifier itself, and its real-OOM side, are pinned in test_memory
+    assert not memory.is_oom_error(ei.value)
+
+
+# ------------------------- (e) the planner vs the declared limit -------------
+
+# (rows per kernel dispatch, k side, depth): the Lloyd row tiles, the serving
+# ladder rungs (clamped to config["distance_tile_rows"]), the kNN item scan
+_ISSUE_SHAPES = (
+    [(rows, 1000, 3000) for rows in (32768, 65536)]
+    + [(rows, 1024, 3072) for rows in (32768, 65536)]
+    + [(rung, k, d) for rung in (256, 512, 1024, 2048, 4096) for k, d in ((1000, 3000), (1024, 3072))]
+    + [(256, 262_144, 3000), (4096, 1_000_000, 3000)]
+    + [(4096, 4096, 7168), (4096, 1000, 2999), (4096, 1000, 50)]
+)
+
+
+@pytest.mark.parametrize("fast", [True, False])
+def test_planned_blocks_fit_the_declared_vmem_limit(fast):
+    limit = distance.vmem_limit_bytes()
+    assert limit == distance._VMEM_LIMIT_BYTES["TPU v5 lite"]  # CI plans like the v5e
+    for rows, k, d in _ISSUE_SHAPES:
+        plan = distance.plan_blocks(rows, k, d, jnp.float32, fast)
+        assert plan is not None, (rows, k, d)
+        assert distance.block_vmem_bytes(*plan, d, jnp.float32, fast) <= limit, (rows, k, d, plan)
+    # the protocol width keeps the full 512x512 blocks in both modes
+    assert distance.plan_blocks(32768, 1000, 3000, jnp.float32, fast) == (512, 512)
+
+
+def test_distance_kernels_compile_for_a_v5e_ahead_of_time(monkeypatch):
+    """The parent's default KMeans fit did not compile at the protocol width
+    (Mosaic: 16.07M / 19.10M against a 16.00M scoped-VMEM limit). The
+    installed libtpu compiles for a v5e topology without a chip."""
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    except Exception as e:  # no libtpu on this machine: nothing to compile with
+        pytest.skip(f"no TPU compiler available ahead of time: {type(e).__name__}: {e}")
+    dev = topo.devices[0]
+    assert dev.device_kind in distance._VMEM_LIMIT_BYTES
+    monkeypatch.setattr(distance, "_MODE", "pallas")
+
+    def struct(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(dev))
+
+    # the suite runs under x64 (conftest); the f32 path the chip runs does not
+    with mesh_mod.chip_scope([dev]), jax.enable_x64(False):
+        # the Lloyd kernels at both row tiles and both widths the issue names
+        jax.jit(lambda x, w, c: distance.assign_accumulate(x, w, c, fast=True)).lower(
+            struct((32768, 3000)), struct((32768,)), struct((1000, 3000))
+        ).compile()
+        jax.jit(lambda x, w, c: distance.assign_accumulate(x, w, c, fast=False)).lower(
+            struct((65536, 3072)), struct((65536,)), struct((1024, 3072))
+        ).compile()
+        jax.jit(
+            lambda items, q, valid: distance.tile_topk(items, q, valid, 64)
+        ).lower(struct((262_144, 3000)), struct((256, 3000)), struct((262_144,), jnp.bool_)).compile()
+        # the estimator's own ambient precision must not reach the kernel's
+        # dot: Mosaic lowers no algorithm preset
+        with jax.default_matmul_precision("BF16_BF16_F32_X3"):
+            jax.jit(lambda x, c: distance.argmin_assign(x, c)).lower(
+                struct((8192, 3000)), struct((1000, 3000))
+            )
+        # and under shard_map WITH the vma check on (the compiled path keeps
+        # it): the four-chip Lloyd step, the row-sharded predict GSPMD cannot
+        # partition, and the sharded top-k scan (whose multi-tile carry only
+        # exists on the kernel path) all trace and lower
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from spark_rapids_ml_tpu.ops.kmeans import _lloyd_step, kmeans_predict
+        from spark_rapids_ml_tpu.ops.knn import _exact_knn_sharded
+
+        mesh = Mesh(np.asarray(topo.devices), (mesh_mod.ROWS_AXIS,))
+        rows, rep = P(mesh_mod.ROWS_AXIS), P()
+        assert distance.shard_map_check_vma()
+
+        def sharded(shape, spec, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+        X, C = sharded((262_144, 3000), rows), sharded((1000, 3000), rep)
+        _lloyd_step.lower(X, sharded((262_144,), rows), C, mesh=mesh, batch_rows=32768, fast=True)
+        kmeans_predict.lower(X, C, mesh=mesh)
+        _exact_knn_sharded.lower(
+            X, sharded((262_144,), rows, jnp.bool_), sharded((256, 3000), rep), mesh=mesh, k=64
+        )

@@ -260,8 +260,11 @@ def test_kernel_mode_env_override(monkeypatch):
 
 
 def test_plan_blocks_fits_budget_and_floors():
-    br, bk = distance.plan_blocks(4096, 1000, 3000, 4)
-    assert (br * 3000 + bk * 3000 + br * bk) * 4 <= distance._VMEM_BUDGET_BYTES
+    br, bk = distance.plan_blocks(4096, 1000, 3000)
+    assert (
+        distance.block_vmem_bytes(br, bk, 3000, np.float32, False)
+        <= distance.vmem_limit_bytes()
+    )
     assert br >= 8 and bk >= 128
     # absurd depth: nothing fits -> None (callers fall back to jnp)
-    assert distance.plan_blocks(4096, 1000, 50_000_000, 4) is None
+    assert distance.plan_blocks(4096, 1000, 50_000_000) is None

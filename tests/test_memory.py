@@ -304,6 +304,22 @@ def test_is_oom_error_matches_backend_shapes():
     assert not memory.is_oom_error(ValueError("RESOURCE_EXHAUSTED"))
     # an already-typed budget error must PROPAGATE, never re-enter conversion
     assert not memory.is_oom_error(HbmBudgetError("x"))
+    # a kernel that does not COMPILE is not an HBM OOM, although Mosaic
+    # spells its scoped-VMEM overflow RESOURCE_EXHAUSTED too (the message
+    # the v5e compiler gives for the parent's 512x512 fast plan at d=3000)
+    assert not memory.is_oom_error(RuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+        "allocating on stack for %pallas_call. Scoped allocation with size "
+        "16.07M and limit 16.00M exceeded scoped vmem limit by 72.0K."
+    ))
+    assert not memory.is_oom_error(RuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel: Bad lhs type"
+    ))
+    # the real thing, as the v5e reports it
+    assert memory.is_oom_error(RuntimeError(
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 26.49G of 15.75G hbm."
+    ))
 
 
 def test_as_hbm_budget_error_wraps_message():

@@ -407,25 +407,20 @@ def test_transform_zero_rows_multi_output(rng):
 # -------------------------------------------------- persistent compile cache --
 
 
-def test_compile_cache_dir_and_first_solve_gauge(tele, rng, tmp_path):
+def test_first_solve_gauge_and_cpu_pool_leaves_jax_cache_alone(tele, rng):
     import jax
 
-    old = core.config["compilation_cache_dir"]
-    core.config["compilation_cache_dir"] = str(tmp_path / "xla_cache")
-    try:
-        df = _reg_df(rng, n=60)
-        LinearRegression(float32_inputs=False).setFeaturesCol("features").fit(df)
-        snap = telemetry.snapshot()
-        # first-call wall time under the persistent cache is recorded for
-        # cross-round cache-efficacy tracking (BENCH JSON)
-        assert "fit.compile_cache_hit" in snap["gauges"]
-        assert snap["gauges"]["fit.compile_cache_hit"] > 0
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xla_cache")
-    finally:
-        core.config["compilation_cache_dir"] = old
-        from spark_rapids_ml_tpu.parallel.mesh import ensure_compilation_cache
-
-        ensure_compilation_cache()  # re-point jax at the restored config
+    before = jax.config.jax_compilation_cache_dir
+    df = _reg_df(rng, n=60)
+    LinearRegression(float32_inputs=False).setFeaturesCol("features").fit(df)
+    snap = telemetry.snapshot()
+    # first-call wall time is recorded for cross-run cache-efficacy tracking
+    assert snap["gauges"]["fit.compile_cache_hit"] > 0
+    # the cache directory is resolved (never None), but a CPU device pool is
+    # not wired into it: jax stays as the environment configured it (the
+    # resolution itself is pinned by tests/test_chip_bringup.py)
+    assert core.config["compilation_cache_dir"]
+    assert jax.config.jax_compilation_cache_dir == before
 
 
 def test_compile_probe_guarded_after_batching(tele, rng):

@@ -47,7 +47,7 @@ def test_make_global_rows_weights_mask_padding(mesh8):
 
 
 def test_live_psum_over_mesh(mesh8):
-    from spark_rapids_ml_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 
     x = np.arange(16, dtype=np.float32).reshape(16, 1)
     X, w, _ = make_global_rows(mesh8, x)
@@ -475,7 +475,9 @@ def test_shard_map_fold_grid_on_carved_submesh(mesh8):
     from jax.sharding import NamedSharding
 
     from spark_rapids_ml_tpu.parallel import submesh
-    from spark_rapids_ml_tpu.parallel.mesh import row_sharding, shard_map
+    from jax import shard_map
+
+    from spark_rapids_ml_tpu.parallel.mesh import row_sharding
 
     sub = submesh(mesh8, 4)
     n_rows = sub.devices.size * 2
