@@ -1869,15 +1869,16 @@ class _TpuModel(_TpuCommon):
         and the input block itself. {} (default) = no modeled workspace."""
         return {}
 
-    def _record_bucket(self, xp: np.ndarray, n_valid: int, on_mesh: bool) -> None:
-        """Bucket-ladder telemetry: rows padded, and — via a process-wide set
-        of (model class, bucketed shape, dtype, placement) signatures — a
+    def _record_bucket(self, xp: np.ndarray, on_mesh: bool) -> None:
+        """Bucket-ladder telemetry: via a process-wide set of (model class,
+        bucketed shape, dtype, placement) signatures, a
         `transform.bucket_programs` counter that advances only when a NEW
-        bucketed shape reaches `predict`. The shape set deliberately
-        survives `registry().reset()`: it mirrors the process-wide jit
-        cache, which a registry reset does not clear — a shape seen before
-        genuinely compiles nothing, so re-counting it would overstate
-        compile work. Readers wanting per-window numbers take counter
+        bucketed shape reaches `predict` (the rows a batch was padded by are
+        the `transform/pad` span's `rung` less its `rows`). The shape set
+        deliberately survives `registry().reset()`: it mirrors the
+        process-wide jit cache, which a registry reset does not clear — a
+        shape seen before genuinely compiles nothing, so re-counting it
+        would overstate compile work. Readers wanting per-window numbers take counter
         DELTAS. Asserting the counter stays at the ladder size while batch
         sizes vary freely is the test-side proof that serving compiles per
         bucket, not per tail shape."""
@@ -1886,7 +1887,6 @@ class _TpuModel(_TpuCommon):
         if not telemetry.enabled():
             return
         reg = telemetry.registry()
-        reg.inc("transform.bucket_pad_rows", int(xp.shape[0]) - int(n_valid))
         sig = (type(self).__name__, tuple(xp.shape), str(xp.dtype), on_mesh)
         with _BUCKET_LOCK:
             if sig not in _BUCKET_SHAPES:
@@ -2016,21 +2016,32 @@ class PredictProgram:
         fetch; returns (in-flight result, valid row count). A zero-row batch
         still dispatches one bucket-padded rung so multi-output models yield
         one correctly-shaped empty array per output at `fetch`."""
+        xp, n_valid = self.pad(xb)
+        return self.launch(xp), n_valid
+
+    def pad(self, xb: np.ndarray) -> Tuple[np.ndarray, int]:
+        """`dispatch`'s host half: the batch padded up to its rung (a copy
+        only where the rung is larger); returns (padded, valid row count)."""
+        from .parallel.mesh import bucket_rows
+
+        return bucket_rows(
+            np.asarray(xb), multiple=self.multiple, min_rows=self.bucket_min, cap=self.cap
+        )
+
+    def launch(self, xp: np.ndarray) -> Any:
+        """`dispatch`'s device half: place a padded batch and call `predict`,
+        up to its asynchronous return — NO host fetch."""
         import jax
 
-        from .parallel.mesh import bucket_rows, row_sharding
+        from .parallel.mesh import row_sharding
 
-        xb = np.asarray(xb)
-        xp, n_valid = bucket_rows(
-            xb, multiple=self.multiple, min_rows=self.bucket_min, cap=self.cap
-        )
-        self.model._record_bucket(xp, n_valid, self.mesh is not None)
+        self.model._record_bucket(xp, self.mesh is not None)
         sig = (tuple(xp.shape), str(xp.dtype))
         self.last_dispatch_new_shape = sig not in self._shapes_seen
         self._shapes_seen.add(sig)
         if self.mesh is not None:
             xp = jax.device_put(xp, row_sharding(self.mesh, xp.ndim))
-        return self.predict_fn(self.state, xp), n_valid
+        return self.predict_fn(self.state, xp)
 
     def fetch(self, result: Any, n_valid: int) -> Any:
         """THE device→host sync point: materialize the in-flight result and
@@ -2147,7 +2158,8 @@ class _TpuModelWithColumns(_TpuModel):
             if use_mesh:
                 mesh = get_mesh(n_dev)
                 batch *= n_dev  # per-device batch budget stays constant
-            program = PredictProgram(self, cap=batch, mesh=mesh)
+            with telemetry.span("construct", model=type(self).__name__):
+                program = PredictProgram(self, cap=batch, mesh=mesh)
             if telemetry.enabled():
                 reg = telemetry.registry()
                 reg.inc("transform.rows", n)
@@ -2163,21 +2175,50 @@ class _TpuModelWithColumns(_TpuModel):
                 xb = features[start:stop]
                 if hasattr(xb, "todense"):
                     xb = np.asarray(xb.todense())
-                result, n_valid = program.dispatch(np.asarray(xb))
-                outs.append(program.fetch(result, n_valid))
+                # the spans go round the program's calls, not inside them:
+                # the serving engine calls `dispatch`/`fetch` per request group
+                with telemetry.span("pad", rows=stop - start) as sp:
+                    xp, n_valid = program.pad(xb)
+                    sp.set(rung=int(xp.shape[0]))
+                with telemetry.span(
+                    "dispatch", rows=int(xp.shape[0]), bytes=int(xp.nbytes)
+                ) as sp:
+                    result = program.launch(xp)
+                    sp.set(new_shape=program.last_dispatch_new_shape)
+                with telemetry.span("fetch", rows=n_valid):
+                    outs.append(program.fetch(result, n_valid))
             if isinstance(outs[0], tuple):
                 return tuple(np.concatenate(parts, axis=0) for parts in zip(*outs))
             return np.concatenate(outs, axis=0)
 
     def transform(self, dataset: Any):
-        pdf = as_pandas(dataset)
-        extracted = self._pre_process_data(dataset, for_fit=False)
+        from . import telemetry
+
+        # `transform.extract` / `transform.assemble` are top-level spans on
+        # either side of `transform` (whose extent `_transform_arrays` keeps):
+        # the pandas column to one host array, and the answer frame
+        with telemetry.span("transform.extract") as sp:
+            pdf = as_pandas(dataset)
+            extracted = self._pre_process_data(dataset, for_fit=False)
+            if telemetry.enabled():
+                nbytes = int(
+                    extracted.features.data.nbytes if extracted.is_sparse
+                    else extracted.features.nbytes
+                )
+                sp.set(rows=extracted.n_rows, cols=extracted.n_cols,
+                       feature_kind=extracted.feature_kind, bytes=nbytes)
+                telemetry.registry().inc("transform.bytes_extracted", nbytes)
         result = self._transform_arrays(extracted.features)
-        out = pdf.copy(deep=False)
         names = self._out_column_names()
-        values_by_col = self._split_output(result, names, extracted)
-        for name, vals in values_by_col.items():
-            out[name] = vals
+        with telemetry.span("transform.assemble", rows=len(pdf), columns=len(names)):
+            out = pdf.copy(deep=False)
+            values_by_col = self._split_output(result, names, extracted)
+            for name, vals in values_by_col.items():
+                out[name] = vals
+            # the extraction's host array goes here, inside the span: left
+            # to the frame's teardown, handing its pages back is time of the
+            # call that no span covers
+            del extracted
         return out
 
     def _split_output(

@@ -508,6 +508,7 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
 
     def _get_tpu_fit_func(self, extracted: ExtractedData):
         from .. import checkpoint as _ckpt
+        from .. import telemetry
         from ..ops.logistic import (
             logistic_fit,
             logistic_fit_checkpointed,
@@ -520,85 +521,96 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
         def _fit(inputs: FitInputs, params: Dict[str, Any]) -> Dict[str, Any]:
             alpha = float(params["alpha"])
             l1_ratio = float(params["l1_ratio"])
-            classes = self._resolve_classes(labels_host, inputs)
+            # once-per-fit child spans of `fit/solve` (docs/observability.md):
+            # `init` is the host's preparation, `loop` the solver's dispatch
+            # (one asynchronous program here), `finish` the wait for it and
+            # the model's attributes brought to the host
+            with telemetry.span("init"):
+                classes = self._resolve_classes(labels_host, inputs)
+                if len(classes) > 1 and inputs.stream is None:
+                    multinomial, y_idx = self._fit_geometry(classes, labels_host, inputs)
+                    common = dict(
+                        k=len(classes),
+                        multinomial=multinomial,
+                        lam_l2=alpha * (1.0 - l1_ratio),
+                        lam_l1=alpha * l1_ratio,
+                        use_l1=alpha * l1_ratio > 0,
+                        **self._solver_statics(params),
+                    )
+                    # public warm start (fit(..., warm_start_from=...),
+                    # docs/scheduling.md "Warm starts"): seed the L-BFGS/OWL-QN
+                    # iterate from the donor's original-space coefficients — the
+                    # solver rebuilds the standardized flat iterate via the exact
+                    # inverse of its own fold-out (ops/logistic._warm_x0)
+                    warm_tuple = None
+                    _warm = getattr(self, "_warm_start", None)
+                    if _warm is not None:
+                        k_out = len(classes) if multinomial else 1
+                        wcoef = np.asarray(_warm["coef_"])
+                        if tuple(wcoef.shape) != (k_out, int(inputs.n_cols)):
+                            raise ValueError(
+                                f"warm-start coef shape {tuple(wcoef.shape)} does not "
+                                f"match this fit (k_out={k_out}, d={inputs.n_cols})"
+                            )
+                        from .. import telemetry as _telemetry
+
+                        if _telemetry.enabled():
+                            reg = _telemetry.registry()
+                            reg.inc("fit.warm_starts")
+                            reg.inc(
+                                "fit.warm_start_iterations_saved",
+                                int(_warm.get("n_iter_", 0) or 0),
+                            )
+                        warm_tuple = (
+                            wcoef.astype(inputs.dtype),
+                            np.asarray(_warm["intercept_"]).astype(inputs.dtype),
+                        )
+                    # elastic recovery: with a checkpoint cadence configured and a
+                    # store installed by the enclosing recoverable stage, the solver
+                    # loop runs host-segmented so an interrupted fit resumes from
+                    # the last boundary. Single-controller only: the segment
+                    # boundary host-fetches globally-sharded state, which a
+                    # multi-process rank cannot address alone.
+                    use_ckpt = _ckpt.solver_checkpoints_active() and (
+                        inputs.ctx is None or not inputs.ctx.is_spmd
+                    )
+                    ckpt_common = (
+                        dict(
+                            ckpt_key="logistic:" + repr(sorted(common.items())),
+                            placement_key=_ckpt.placement_key_of(inputs),
+                        )
+                        if use_ckpt
+                        else {}
+                    )
             if len(classes) == 1:
                 return self._degenerate_single_class(classes, inputs)
             if inputs.stream is not None:
                 return self._fit_streaming(
                     inputs, params, classes, labels_host, alpha, l1_ratio
                 )
-            multinomial, y_idx = self._fit_geometry(classes, labels_host, inputs)
-            common = dict(
-                k=len(classes),
-                multinomial=multinomial,
-                lam_l2=alpha * (1.0 - l1_ratio),
-                lam_l1=alpha * l1_ratio,
-                use_l1=alpha * l1_ratio > 0,
-                **self._solver_statics(params),
-            )
-            # public warm start (fit(..., warm_start_from=...),
-            # docs/scheduling.md "Warm starts"): seed the L-BFGS/OWL-QN
-            # iterate from the donor's original-space coefficients — the
-            # solver rebuilds the standardized flat iterate via the exact
-            # inverse of its own fold-out (ops/logistic._warm_x0)
-            warm_tuple = None
-            _warm = getattr(self, "_warm_start", None)
-            if _warm is not None:
-                k_out = len(classes) if multinomial else 1
-                wcoef = np.asarray(_warm["coef_"])
-                if tuple(wcoef.shape) != (k_out, int(inputs.n_cols)):
-                    raise ValueError(
-                        f"warm-start coef shape {tuple(wcoef.shape)} does not "
-                        f"match this fit (k_out={k_out}, d={inputs.n_cols})"
+            layout = "ell" if inputs.X_sparse is not None else "dense"
+            with telemetry.span(
+                "loop", solver_path=layout + ("_checkpointed" if use_ckpt else "")
+            ):
+                if inputs.X_sparse is not None:
+                    ell_val, ell_idx = inputs.ell_rows()
+                    w_dev = inputs.put_rows(np.asarray(inputs.w, dtype=inputs.dtype))
+                    fit_fn = logistic_fit_ell_checkpointed if use_ckpt else logistic_fit_ell
+                    state = fit_fn(
+                        ell_val, ell_idx, y_idx, w_dev, d=inputs.n_cols,
+                        warm_start=warm_tuple, **common, **ckpt_common,
                     )
-                from .. import telemetry as _telemetry
-
-                if _telemetry.enabled():
-                    reg = _telemetry.registry()
-                    reg.inc("fit.warm_starts")
-                    reg.inc(
-                        "fit.warm_start_iterations_saved",
-                        int(_warm.get("n_iter_", 0) or 0),
+                else:
+                    fit_fn = logistic_fit_checkpointed if use_ckpt else logistic_fit
+                    state = fit_fn(
+                        inputs.X, y_idx, inputs.w, warm_start=warm_tuple,
+                        **common, **ckpt_common,
                     )
-                warm_tuple = (
-                    wcoef.astype(inputs.dtype),
-                    np.asarray(_warm["intercept_"]).astype(inputs.dtype),
-                )
-            # elastic recovery: with a checkpoint cadence configured and a
-            # store installed by the enclosing recoverable stage, the solver
-            # loop runs host-segmented so an interrupted fit resumes from
-            # the last boundary. Single-controller only: the segment
-            # boundary host-fetches globally-sharded state, which a
-            # multi-process rank cannot address alone.
-            use_ckpt = _ckpt.solver_checkpoints_active() and (
-                inputs.ctx is None or not inputs.ctx.is_spmd
-            )
-            ckpt_common = (
-                dict(
-                    ckpt_key="logistic:" + repr(sorted(common.items())),
-                    placement_key=_ckpt.placement_key_of(inputs),
-                )
-                if use_ckpt
-                else {}
-            )
-            if inputs.X_sparse is not None:
-                ell_val, ell_idx = inputs.ell_rows()
-                w_dev = inputs.put_rows(np.asarray(inputs.w, dtype=inputs.dtype))
-                fit_fn = logistic_fit_ell_checkpointed if use_ckpt else logistic_fit_ell
-                state = fit_fn(
-                    ell_val, ell_idx, y_idx, w_dev, d=inputs.n_cols,
-                    warm_start=warm_tuple, **common, **ckpt_common,
-                )
-            else:
-                fit_fn = logistic_fit_checkpointed if use_ckpt else logistic_fit
-                state = fit_fn(
-                    inputs.X, y_idx, inputs.w, warm_start=warm_tuple,
-                    **common, **ckpt_common,
-                )
-            # ONE device->host fetch of the whole result, then the divergence
-            # guard runs on the already-fetched scalars (no extra sync)
-            state = {k: np.asarray(v) for k, v in state.items()}
-            return self._finalize_state(state, classes, inputs, common)
+            with telemetry.span("finish"):
+                # ONE device->host fetch of the whole result, then the divergence
+                # guard runs on the already-fetched scalars (no extra sync)
+                state = {k: np.asarray(v) for k, v in state.items()}
+                return self._finalize_state(state, classes, inputs, common)
 
         return _fit
 

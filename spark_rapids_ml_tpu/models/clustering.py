@@ -204,6 +204,7 @@ class KMeans(_KMeansParams, _TpuEstimator):
         }
 
     def _get_tpu_fit_func(self, extracted: ExtractedData):
+        from .. import telemetry
         from ..ops.kmeans import (
             kmeans_fit,
             kmeans_plus_plus_init,
@@ -244,37 +245,42 @@ class KMeans(_KMeansParams, _TpuEstimator):
                         int(warm.get("n_iter_", 0) or 0),
                     )
                 warm_centers = c0
-            # under multi-process SPMD the init must be computed from GLOBAL
-            # rows: every rank contributes a bounded sample (the whole local
-            # block when small), the rendezvous concatenates them in rank
-            # order, and every rank runs the SAME seeded init on the union —
-            # so all ranks enter the Lloyd loop with identical centers (the
-            # reference's distributed k-means|| init runs inside KMeansMG)
-            x_init, w_init = x_host, w_host
-            if warm_centers is None and inputs.ctx is not None and inputs.ctx.is_spmd:
-                cap = max(4 * k, 262_144 // inputs.ctx.nranks)
-                n_loc = x_host.shape[0]
-                if n_loc > cap:
-                    rs = np.random.default_rng(seed * 100_003 + inputs.ctx.rank)  # prng-ok: deliberate per-rank sampling of LOCAL rows; the allgather below hands every rank the identical union, so the seeded init agrees
-                    sel = np.sort(rs.choice(n_loc, cap, replace=False))
-                    xs = np.asarray(x_host[sel], dtype=np.float64)
-                    ws = None if w_host is None else np.asarray(w_host[sel], dtype=np.float64)
-                else:
-                    xs = np.asarray(x_host, dtype=np.float64)
-                    ws = None if w_host is None else np.asarray(w_host, dtype=np.float64)
-                x_init = inputs.allgather_array(xs)
-                w_init = None if ws is None else inputs.allgather_array(ws)
-            if warm_centers is not None:
-                centers0 = warm_centers  # the donor's iterate IS the init
-            elif init_mode == "random":
-                centers0 = random_init(x_init, k, seed)
-            elif k >= 64:
-                # true k-means|| for large k: O(rounds) device passes instead
-                # of k sequential host passes (minutes at the protocol k=1000)
-                centers0 = scalable_kmeans_init(x_init, k, seed, w_init)
-            else:  # small k: classic k-means++ (exactness-friendly for tests)
-                centers0 = kmeans_plus_plus_init(x_init, k, seed, w_init)
-            centers0 = centers0.astype(inputs.dtype)
+            # once-per-fit child spans of `fit/solve`: `init` here, `loop` and
+            # `finish` inside `kmeans_fit` (docs/observability.md)
+            with telemetry.span(
+                "init", init_mode="warm_start" if warm_centers is not None else init_mode
+            ):
+                # under multi-process SPMD the init must be computed from GLOBAL
+                # rows: every rank contributes a bounded sample (the whole local
+                # block when small), the rendezvous concatenates them in rank
+                # order, and every rank runs the SAME seeded init on the union —
+                # so all ranks enter the Lloyd loop with identical centers (the
+                # reference's distributed k-means|| init runs inside KMeansMG)
+                x_init, w_init = x_host, w_host
+                if warm_centers is None and inputs.ctx is not None and inputs.ctx.is_spmd:
+                    cap = max(4 * k, 262_144 // inputs.ctx.nranks)
+                    n_loc = x_host.shape[0]
+                    if n_loc > cap:
+                        rs = np.random.default_rng(seed * 100_003 + inputs.ctx.rank)  # prng-ok: deliberate per-rank sampling of LOCAL rows; the allgather below hands every rank the identical union, so the seeded init agrees
+                        sel = np.sort(rs.choice(n_loc, cap, replace=False))
+                        xs = np.asarray(x_host[sel], dtype=np.float64)
+                        ws = None if w_host is None else np.asarray(w_host[sel], dtype=np.float64)
+                    else:
+                        xs = np.asarray(x_host, dtype=np.float64)
+                        ws = None if w_host is None else np.asarray(w_host, dtype=np.float64)
+                    x_init = inputs.allgather_array(xs)
+                    w_init = None if ws is None else inputs.allgather_array(ws)
+                if warm_centers is not None:
+                    centers0 = warm_centers  # the donor's iterate IS the init
+                elif init_mode == "random":
+                    centers0 = random_init(x_init, k, seed)
+                elif k >= 64:
+                    # true k-means|| for large k: O(rounds) device passes instead
+                    # of k sequential host passes (minutes at the protocol k=1000)
+                    centers0 = scalable_kmeans_init(x_init, k, seed, w_init)
+                else:  # small k: classic k-means++ (exactness-friendly for tests)
+                    centers0 = kmeans_plus_plus_init(x_init, k, seed, w_init)
+                centers0 = centers0.astype(inputs.dtype)
             # `solver_precision="bf16"` (per-estimator or config-wide) forces
             # the bf16-compute/f32-accumulate in-loop path on both fit modes;
             # the legacy `distance_precision` knob keeps governing the
@@ -282,6 +288,16 @@ class KMeans(_KMeansParams, _TpuEstimator):
             from ..core import resolve_solver_precision
 
             solver_precision = resolve_solver_precision(params)
+
+            def attributes(state: Dict[str, Any]) -> Dict[str, Any]:
+                return {
+                    "cluster_centers_": np.asarray(state["cluster_centers_"]),
+                    "inertia_": float(state["inertia_"]),
+                    "n_iter_": int(state["n_iter_"]),
+                    "n_cols": inputs.n_cols,
+                    "dtype": np.dtype(inputs.dtype).name,
+                }
+
             if inputs.stream is not None:
                 # out-of-core: per-chunk assignment + center accumulation
                 # under the SAME deferred-convergence host loop and the SAME
@@ -301,35 +317,28 @@ class KMeans(_KMeansParams, _TpuEstimator):
                 inputs.stream.chunk_rows = max(
                     1, min(int(inputs.stream.chunk_rows), b * n_dev)
                 )
-                state = kmeans_fit_streaming(
+                return attributes(kmeans_fit_streaming(
                     inputs,
                     centers0,
                     max_iter=int(params["max_iter"]),
                     tol=float(params["tol"]),
                     precision_mode="fast" if solver_precision == "bf16" else "high",
-                )
-            else:
-                state = kmeans_fit(
-                    inputs.X,
-                    inputs.w,
-                    centers0,
-                    mesh=inputs.mesh,
-                    max_iter=int(params["max_iter"]),
-                    tol=float(params["tol"]),
-                    batch_rows=int(params.get("max_samples_per_batch", 32768)),
-                    precision_mode=(
-                        "fast"
-                        if solver_precision == "bf16"
-                        else str(params.get("distance_precision", "fast"))
-                    ),
-                )
-            return {
-                "cluster_centers_": np.asarray(state["cluster_centers_"]),
-                "inertia_": float(state["inertia_"]),
-                "n_iter_": int(state["n_iter_"]),
-                "n_cols": inputs.n_cols,
-                "dtype": np.dtype(inputs.dtype).name,
-            }
+                ))
+            return kmeans_fit(
+                inputs.X,
+                inputs.w,
+                centers0,
+                mesh=inputs.mesh,
+                max_iter=int(params["max_iter"]),
+                tol=float(params["tol"]),
+                batch_rows=int(params.get("max_samples_per_batch", 32768)),
+                precision_mode=(
+                    "fast"
+                    if solver_precision == "bf16"
+                    else str(params.get("distance_precision", "fast"))
+                ),
+                to_host=attributes,  # runs inside the solver's `finish` span
+            )
 
         return _fit
 

@@ -175,6 +175,29 @@ def _plan(
     return tuned if tuned is not None else heuristic
 
 
+def kernel_name(kernel: str, fast: bool) -> str:
+    """The name a kernel carries onto the device: `srml_<kernel>_<mode>`,
+    mode `bf16` (one-pass `fast` contraction) or `f32`. XLA's TPU compiler
+    names a Mosaic custom call after `pallas_call`'s `name=` (without one,
+    after the enclosing jit plus a uniquifier that moves with the program),
+    so a device trace tells argmin from accumulate, and the in-loop bf16
+    calls from the float32 final pass and predict, by this prefix: on one
+    chip, under `shard_map` and in the predict program alike. A contract
+    (docs/observability.md "Kernel names"): the benchmark's per-kernel
+    metrics match on it."""
+    return f"srml_{kernel}_{'bf16' if fast else 'f32'}"
+
+
+def block_plan(
+    n_rows: int, k_side: int, d: int, dtype, fast: bool
+) -> Optional[Tuple[int, int]]:
+    """The (block_rows, block_k) a kernel dispatch of this tile shape takes
+    in this process, or None where it takes the jnp form (no kernel mode,
+    or no plan fits). For callers that report the plan (the `fit/solve/loop`
+    span); resolves `kernel_mode()`, so call it outside a trace."""
+    return _plan(n_rows, k_side, d, dtype, fast) if _use_kernel() else None
+
+
 def _call_params(interpret: bool) -> dict:
     """The `pallas_call` keywords every kernel here shares: interpreted, or
     compiled with the scoped-VMEM limit the planner budgeted against stated
@@ -418,6 +441,7 @@ def _pl_argmin(
             jax.ShapeDtypeStruct((B, 1), dtype, vma=vma),
             jax.ShapeDtypeStruct((B, 1), jnp.int32, vma=vma),
         ],
+        name=kernel_name("argmin", fast),
         **_call_params(interpret),
     )(x, c_pad, c_sq_pad[None, :])
     return mind[:, 0], best[:, 0]
@@ -482,6 +506,7 @@ def _pl_accumulate(
             jax.ShapeDtypeStruct((kp, d), dtype, vma=vma),
             jax.ShapeDtypeStruct((kp, 1), dtype, vma=vma),
         ],
+        name=kernel_name("accumulate", fast),
         **_call_params(interpret),
     )(x, w[:, None], assign[:, None].astype(jnp.int32))
     return sums, counts[:, 0]
@@ -520,6 +545,7 @@ def _pl_d2_block(
         ],
         out_specs=pl.BlockSpec((block_rows, kt), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((B, kt), dtype, vma=vma),
+        name=kernel_name("d2_block", fast),
         **_call_params(interpret),
     )(q, xt, xt_sq[None, :])
 
@@ -677,7 +703,6 @@ def argmin_assign(
     fast-bf16 mode (docs/serving.md "bf16 serving"). Tiles are clamped back
     at the ragged tail (overlap rows recompute the same assignment — writes
     are idempotent), so no padded copy of X is ever made."""
-    _note("distance.argmin_programs")
     n = X.shape[0]
     tr = min(batch_rows or tile_rows(), max(n, 1))
     if n <= tr:

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Dict
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +28,7 @@ from ..parallel.mesh import ROWS_AXIS
 from .distance import (
     argmin_assign,
     assign_accumulate,
+    block_plan,
     min_d2_update,
     shard_map_check_vma,
     tile_assign_accumulate as _tile_assign_accumulate,
@@ -203,9 +204,17 @@ def kmeans_fit(
     batch_rows: int = 32768,
     precision_mode: str = "fast",
     final_inertia: bool = True,
+    to_host: Optional[Callable[[Dict[str, jax.Array]], Any]] = None,
 ) -> Dict[str, jax.Array]:
     """Lloyd's algorithm on a row-sharded global X. Returns
     cluster_centers_ [k,d], inertia_, n_iter_.
+
+    Two once-per-fit telemetry spans (children of the caller's `fit/solve`):
+    `loop` — dispatching the iterations up to the last shift fetch, with the
+    path taken and the block plan as attributes — and `finish` — the final
+    inertia pass and, through `to_host` (the estimator's conversion of the
+    returned state, run inside the span), the model's attributes brought to
+    the host. Neither adds a device synchronisation.
 
     Convergence: squared center movement <= tol (sklearn/cuML semantics; the
     reference maps Spark's `tol` straight through, clustering.py:96-108).
@@ -304,92 +313,108 @@ def kmeans_fit(
             n_iter = int(saved.iteration)
             ps = saved.state.get("prev_shift")
             prev_shift = None if ps is None else float(ps)
-    while n_iter < max_iter:
-        step_in = centers
-        centers, inertia, shift = step(centers, fast)
-        n_iter += 1
-        if prev_shift is not None:
-            # the deferred shift fetch is Lloyd's per-iteration sync — the
-            # efficiency attributor times the wait as `execute` (this IS the
-            # solver cadence point; no sync added)
-            with telemetry.device_wait("kmeans_shift"):
-                shift_host = float(prev_shift)  # host-fetch-ok: the DEFERRED convergence fetch (documented above) — overlapped with the current step's compute
-            if not math.isfinite(shift_host):
-                _raise_diverged(n_iter - 1, last_good, f"center shift = {shift_host}")
-            if _nc is not None:
-                # AFTER the divergence guard (typed SolverDivergedError owns
-                # non-finite shifts); sweeps the already-fetched scalar and
-                # records the iterate's dtype watermark without a new fetch
-                _nc("kmeans.iterate", solver="kmeans", iteration=n_iter - 1,
-                    watermark=centers.dtype, shift=shift_host)
-            if telemetry.enabled():
-                telemetry.record_convergence_point("kmeans.shift", n_iter - 1, shift_host)
-            if shift_host <= tol:
-                break
-        prev_shift = shift
-        last_good = step_in
-        if ckpt_store is not None and ckpt_every > 0 and n_iter % ckpt_every == 0:
-            # the cadence fetch of prev_shift syncs with the device — the
-            # documented checkpoint overhead; the float survives the
-            # round-trip exactly, so the resumed convergence pipeline sees
-            # the same value the uninterrupted run would
-            with telemetry.device_wait("kmeans_checkpoint"):
-                prev_shift = float(prev_shift)  # host-fetch-ok: checkpoint-cadence boundary (config["checkpoint_every_iters"])
-                centers_host = np.asarray(centers)  # host-fetch-ok: the checkpoint itself — replicated centers must land on host to survive
-            if _nc is not None:
-                # the checkpoint already fetched the full iterate: sweep it
-                # (a non-finite checkpoint would poison every later resume)
-                _nc("kmeans.checkpoint", solver="kmeans", iteration=n_iter,
-                    centers=centers_host)
-            with telemetry.host_section("kmeans_checkpoint"):
-                ckpt_store.save(ckpt_key, _ckpt.SolverCheckpoint(
-                    solver="kmeans", iteration=n_iter,
-                    state={
-                        "centers": centers_host,
-                        "prev_shift": prev_shift,
-                        # the divergence-fallback iterate (one step behind)
-                        "last_good": np.asarray(last_good),  # host-fetch-ok: checkpoint payload (one step behind, for divergence fallback)
-                    },
-                ))
-            # mid-solve fault injection points (`fail:stage=solve` and
-            # `oom:stage=solve` plans): both fire AFTER the boundary
-            # checkpoint landed, so a retried fit — bounded transient retry
-            # or the OOM demotion to the streaming path — provably resumes
-            # instead of restarting Lloyd from scratch
-            from ..parallel import chaos
+    rows_dev = max(1, -(-X.shape[0] // mesh.devices.size))
+    tile = min(batch_rows, rows_dev)
+    # resolved OUTSIDE any trace: the plan the tile programs will take (None
+    # = the jnp form), which also settles `distance.kernel_mode()` eagerly
+    block_rows, block_k = block_plan(
+        tile, centers.shape[0], X.shape[1], X.dtype, fast
+    ) or (None, None)
+    with telemetry.span(
+        "loop",
+        solver_path="host_tiled" if host_tiled else "fused_1dev" if one_dev else "shard_map",
+        tiles_per_iter=-(-rows_dev // tile),
+        block_rows=block_rows,
+        block_k=block_k,
+    ):
+        while n_iter < max_iter:
+            step_in = centers
+            centers, inertia, shift = step(centers, fast)
+            n_iter += 1
+            if prev_shift is not None:
+                # the deferred shift fetch is Lloyd's per-iteration sync — the
+                # efficiency attributor times the wait as `execute` (this IS the
+                # solver cadence point; no sync added)
+                with telemetry.device_wait("kmeans_shift"):
+                    shift_host = float(prev_shift)  # host-fetch-ok: the DEFERRED convergence fetch (documented above) — overlapped with the current step's compute
+                if not math.isfinite(shift_host):
+                    _raise_diverged(n_iter - 1, last_good, f"center shift = {shift_host}")
+                if _nc is not None:
+                    # AFTER the divergence guard (typed SolverDivergedError owns
+                    # non-finite shifts); sweeps the already-fetched scalar and
+                    # records the iterate's dtype watermark without a new fetch
+                    _nc("kmeans.iterate", solver="kmeans", iteration=n_iter - 1,
+                        watermark=centers.dtype, shift=shift_host)
+                if telemetry.enabled():
+                    telemetry.record_convergence_point("kmeans.shift", n_iter - 1, shift_host)
+                if shift_host <= tol:
+                    break
+            prev_shift = shift
+            last_good = step_in
+            if ckpt_store is not None and ckpt_every > 0 and n_iter % ckpt_every == 0:
+                # the cadence fetch of prev_shift syncs with the device — the
+                # documented checkpoint overhead; the float survives the
+                # round-trip exactly, so the resumed convergence pipeline sees
+                # the same value the uninterrupted run would
+                with telemetry.device_wait("kmeans_checkpoint"):
+                    prev_shift = float(prev_shift)  # host-fetch-ok: checkpoint-cadence boundary (config["checkpoint_every_iters"])
+                    centers_host = np.asarray(centers)  # host-fetch-ok: the checkpoint itself — replicated centers must land on host to survive
+                if _nc is not None:
+                    # the checkpoint already fetched the full iterate: sweep it
+                    # (a non-finite checkpoint would poison every later resume)
+                    _nc("kmeans.checkpoint", solver="kmeans", iteration=n_iter,
+                        centers=centers_host)
+                with telemetry.host_section("kmeans_checkpoint"):
+                    ckpt_store.save(ckpt_key, _ckpt.SolverCheckpoint(
+                        solver="kmeans", iteration=n_iter,
+                        state={
+                            "centers": centers_host,
+                            "prev_shift": prev_shift,
+                            # the divergence-fallback iterate (one step behind)
+                            "last_good": np.asarray(last_good),  # host-fetch-ok: checkpoint payload (one step behind, for divergence fallback)
+                        },
+                    ))
+                # mid-solve fault injection points (`fail:stage=solve` and
+                # `oom:stage=solve` plans): both fire AFTER the boundary
+                # checkpoint landed, so a retried fit — bounded transient retry
+                # or the OOM demotion to the streaming path — provably resumes
+                # instead of restarting Lloyd from scratch
+                from ..parallel import chaos
 
-            chaos.maybe_fail_oom("solve", n_iter)
-            chaos.maybe_fail_stage("solve", n_iter)
-            # cooperative scheduler preemption (docs/scheduling.md): checked
-            # where the loop already host-fetched (the cadence shift fetch
-            # above), AFTER the boundary checkpoint landed — a preempted
-            # fit resumes from exactly this iterate
-            from ..scheduler.context import preemption_point
+                chaos.maybe_fail_oom("solve", n_iter)
+                chaos.maybe_fail_stage("solve", n_iter)
+                # cooperative scheduler preemption (docs/scheduling.md): checked
+                # where the loop already host-fetched (the cadence shift fetch
+                # above), AFTER the boundary checkpoint landed — a preempted
+                # fit resumes from exactly this iterate
+                from ..scheduler.context import preemption_point
 
-            preemption_point("kmeans", n_iter)
+                preemption_point("kmeans", n_iter)
     if telemetry.enabled():
         telemetry.record_solver_result("kmeans", n_iter=n_iter)
-    # inertia reported is one iteration stale; recompute once with final
-    # centers — always at high precision. Callers that don't consume inertia
-    # (e.g. the IVF coarse quantizer) skip the pass: the high-precision
-    # program is a separate ~79s compile in a fresh process. The stale value
-    # must not leak to them either — return NaN so accidental consumption is
-    # loud instead of subtly wrong.
-    if final_inertia:
-        _, inertia, _ = step(centers, False)
-        inertia_host = float(inertia)
-        if not math.isfinite(inertia_host):
-            # the loop's deferred check trails by one fetch: a divergence on
-            # the FINAL step (or a 1-iteration fit) is caught here, on the
-            # inertia scalar the caller fetches anyway
-            _raise_diverged(n_iter, last_good, f"final inertia = {inertia_host}")
-    else:
-        inertia = jnp.full((), jnp.nan, X.dtype)
-    return {
-        "cluster_centers_": centers,
-        "inertia_": inertia,
-        "n_iter_": jnp.asarray(n_iter, jnp.int32),
-    }
+    with telemetry.span("finish"):
+        # inertia reported is one iteration stale; recompute once with final
+        # centers — always at high precision. Callers that don't consume inertia
+        # (e.g. the IVF coarse quantizer) skip the pass: the high-precision
+        # program is a separate ~79s compile in a fresh process. The stale value
+        # must not leak to them either — return NaN so accidental consumption is
+        # loud instead of subtly wrong.
+        if final_inertia:
+            _, inertia, _ = step(centers, False)
+            inertia_host = float(inertia)
+            if not math.isfinite(inertia_host):
+                # the loop's deferred check trails by one fetch: a divergence on
+                # the FINAL step (or a 1-iteration fit) is caught here, on the
+                # inertia scalar the caller fetches anyway
+                _raise_diverged(n_iter, last_good, f"final inertia = {inertia_host}")
+        else:
+            inertia = jnp.full((), jnp.nan, X.dtype)
+        state = {
+            "cluster_centers_": centers,
+            "inertia_": inertia,
+            "n_iter_": jnp.asarray(n_iter, jnp.int32),
+        }
+        return state if to_host is None else to_host(state)
 
 
 @partial(jax.jit, static_argnames=("mesh",))

@@ -816,7 +816,10 @@ class MetricsRegistry:
 
     def delta(self, m: "MetricsRegistry._Mark") -> Dict[str, Any]:
         """Counters/histograms accumulated SINCE `m`, spans recorded since
-        `m`, and current gauges — the per-fit view attached to models."""
+        `m`, and current gauges — the per-fit view attached to models.
+        `spans_dropped` is how many of the spans recorded since `m` the trim
+        no longer holds (exact): a reader that sums or averages `spans`
+        must refuse a non-zero value rather than report over a cut list."""
         with self._lock:
             counters = {
                 k: v - m.counters.get(k, 0.0)
@@ -836,7 +839,8 @@ class MetricsRegistry:
             # the count since the mark is exact (monotone counter); if more
             # than the retained window were recorded, only the tail survives
             since = max(0, self._spans_total - m.spans_total)
-            spans = [dict(r) for r in self._spans[len(self._spans) - min(since, len(self._spans)):]] if since else []
+            kept = min(since, len(self._spans))
+            spans = [dict(r) for r in self._spans[len(self._spans) - kept:]] if kept else []
             # copy gauges UNDER the lock: the copy used to happen in the
             # return expression after releasing it, so a concurrent gauge()
             # could resize the dict mid-iteration (found by the
@@ -847,6 +851,7 @@ class MetricsRegistry:
             "gauges": gauges,
             "histograms": hists,
             "spans": spans,
+            "spans_dropped": since - kept,
         }
 
     def reset(self) -> None:
@@ -1022,6 +1027,9 @@ class _NoopSpan:
     def __exit__(self, *exc: Any) -> bool:
         return False
 
+    def set(self, **attrs: Any) -> None:
+        pass
+
 
 _NOOP_SPAN = _NoopSpan()
 
@@ -1079,6 +1087,12 @@ class _Span:
             _diag().record_event("span_fail", name=self.name, path=self.path,
                                  error=exc_type.__name__)
         return False
+
+    def set(self, **attrs: Any) -> None:
+        """Attributes known only inside the span (the rung a batch padded to,
+        the bytes an extraction returned): host metadata only, never a value
+        that has to be fetched from the device."""
+        self.attrs.update(attrs)
 
 
 def span(name: str, *, logger: Any = None, **attrs: Any):

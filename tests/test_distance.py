@@ -268,3 +268,88 @@ def test_plan_blocks_fits_budget_and_floors():
     assert br >= 8 and bk >= 128
     # absurd depth: nothing fits -> None (callers fall back to jnp)
     assert distance.plan_blocks(4096, 1000, 50_000_000) is None
+
+
+# ------------------------------------------------- stable kernel names -----
+#
+# docs/observability.md "Kernel names": every Pallas kernel of the core goes
+# to `pallas_call` as `srml_<kernel>_<mode>`, which is what XLA's TPU compiler
+# names the Mosaic custom call after — the same on one chip, under shard_map
+# and in the predict program. chipbench's per-kernel metrics match on it.
+
+
+def test_kernel_name_contract():
+    assert distance.kernel_name("argmin", True) == "srml_argmin_bf16"
+    assert distance.kernel_name("accumulate", False) == "srml_accumulate_f32"
+    assert distance.kernel_name("d2_block", False) == "srml_d2_block_f32"
+
+
+def test_kernels_pass_their_names_to_pallas_call(interpret_mode, monkeypatch):
+    """Where no TPU compiler is at hand: the names `pallas_call` is given,
+    through the interpreter, for the Lloyd tile (both modes), predict, top-k."""
+    from jax.experimental import pallas as pl
+
+    names = []
+    real = pl.pallas_call
+
+    def recording(*args, **kwargs):
+        names.append(kwargs.get("name"))
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(pl, "pallas_call", recording)
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(70, 9)), jnp.float32)
+    c = jnp.asarray(rng.normal(size=(5, 9)), jnp.float32)
+    w = jnp.ones((70,), jnp.float32)
+    distance.assign_accumulate(x, w, c, fast=True)
+    assert names == ["srml_argmin_bf16", "srml_accumulate_bf16"]
+    del names[:]
+    distance.assign_accumulate(x, w, c, fast=False)  # the final inertia pass's mode
+    assert names == ["srml_argmin_f32", "srml_accumulate_f32"]
+    del names[:]
+    distance.argmin_assign(x, c)  # the predict program's call
+    assert names == ["srml_argmin_f32"]
+    del names[:]
+    distance.tile_topk(x, x[:7], jnp.ones((70,), jnp.bool_), 3)
+    assert names and set(names) == {"srml_d2_block_f32"}
+
+
+def test_compiled_kernels_carry_their_names_on_one_chip_and_under_shard_map(monkeypatch):
+    """The op names a device trace will show, read from programs compiled
+    ahead of time for a v5e (no chip needed): `%srml_<kernel>_<mode>.<n> =
+    ... custom-call`, in the one-chip tile program, the predict program and
+    the four-chip `shard_map` Lloyd step."""
+    import re
+
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.ops.kmeans import _lloyd_step, _tile_accum_1dev, kmeans_predict
+    from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    except Exception as e:  # no libtpu on this machine: the test above holds the contract
+        pytest.skip(f"no TPU compiler available ahead of time: {type(e).__name__}: {e}")
+    monkeypatch.setattr(distance, "_MODE", "pallas")
+    dev = topo.devices[0]
+
+    def kernels(compiled):
+        return sorted(set(re.findall(r"%(srml_\w+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"", compiled.as_text())))
+
+    def one(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(dev))
+
+    with mesh_mod.chip_scope([dev]), jax.enable_x64(False):
+        acc = (one((1024, 256)), one((1024,)), one((40, 256)), one((40, 256)), one((40,)), one(()), one((), jnp.int32))
+        for fast, mode in ((True, "bf16"), (False, "f32")):
+            tile = _tile_accum_1dev.lower(*acc, size=512, fast=fast).compile()
+            assert kernels(tile) == [f"srml_accumulate_{mode}", f"srml_argmin_{mode}"]
+        assert kernels(kmeans_predict.lower(one((1024, 256)), one((40, 256))).compile()) == ["srml_argmin_f32"]
+        mesh = Mesh(np.asarray(topo.devices), (mesh_mod.ROWS_AXIS,))
+        rows, rep = P(mesh_mod.ROWS_AXIS), P()
+        sh = lambda shape, spec: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=NamedSharding(mesh, spec))
+        step = _lloyd_step.lower(sh((4096, 256), rows), sh((4096,), rows), sh((40, 256), rep),
+                                 mesh=mesh, batch_rows=512, fast=True).compile()
+        assert kernels(step) == ["srml_accumulate_bf16", "srml_argmin_bf16"]
