@@ -849,6 +849,14 @@ class _TpuCaller(_TpuCommon):
     # rest an over-budget fit raises HbmBudgetError instead of demoting.
     _supports_streaming_fit: bool = False
 
+    # The layout a resident fit's X is placed in (parallel/mesh.py
+    # `make_global_rows`): "default" leaves it to the device; "row_major" is
+    # for a solver that feeds row tiles of X to the Pallas distance kernels,
+    # whose operands are row-major (KMeans). The solver family decides, not
+    # the shape: the GLM matvecs read the same [n, 3000] block best as the
+    # device lays it out (docs/performance.md "Tiled distance core").
+    _x_layout: str = "default"
+
     # the memory.AdmissionDecision of the most recent fit attempt (stamped
     # onto model._fit_metrics by _call_fit_func)
     _last_admission: Any = None
@@ -946,7 +954,7 @@ class _TpuCaller(_TpuCommon):
 
         X, w, _ = make_global_rows(
             mesh, extracted.features.astype(dtype, copy=False), weights=weights,
-            local_rows_target=local_rows_target,
+            local_rows_target=local_rows_target, x_layout=self._x_layout,
         )
         y = None
         if extracted.label is not None:
@@ -982,8 +990,9 @@ class _TpuCaller(_TpuCommon):
         return None
 
     def _device_dataset_key(self, dataset: Any, ctx: Any) -> tuple:
-        """(dataset identity fingerprint, columns, dtype, mesh shape) — what
-        must match for a cached placement to be reusable by this fit."""
+        """(dataset identity fingerprint, columns, (dtype, sparse mode, X's
+        layout), mesh shape) — what must match for a cached placement to be
+        reusable by this fit."""
         from .data import dataset_fingerprint
 
         input_col, input_cols = self._get_input_columns()
@@ -1014,7 +1023,11 @@ class _TpuCaller(_TpuCommon):
                 weight_col,
                 id_col,
             ),
-            (np.dtype(np.float32 if self._float32_inputs else np.float64).name, sparse_optim),
+            (
+                np.dtype(np.float32 if self._float32_inputs else np.float64).name,
+                sparse_optim,
+                self._x_layout,
+            ),
             tuple(int(d.id) for d in ctx.mesh.devices.flatten()),
         )
 

@@ -106,6 +106,9 @@ class KMeans(_KMeansParams, _TpuEstimator):
     # per-chunk assignment + center accumulation: an over-HBM dataset demotes
     # to ops/streaming.kmeans_fit_streaming (same host loop, same checkpoints)
     _supports_streaming_fit = True
+    # every Lloyd iteration hands row tiles of X to the distance kernels
+    # (ops/distance.py), which read them row-major
+    _x_layout = "row_major"
 
     def _solver_workspace_terms(
         self, rows_per_device: int, n_cols: int, params: Dict[str, Any], itemsize: int

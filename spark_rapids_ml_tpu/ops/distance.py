@@ -654,7 +654,15 @@ def tile_assign_accumulate(
     and the ragged tail is one extra direct step. Neither `jnp.pad` of the
     shard nor a `lax.scan` over a reshaped view is safe here: both make XLA
     materialize a second X-sized buffer (11 GiB at the 1M x 3k benchmark
-    shape, measured) — the slice-in-loop form keeps X single-buffered."""
+    shape, measured) — the slice-in-loop form keeps X single-buffered.
+
+    The kernels read a tile row-major ({1,0}), and the program is compiled
+    for the layout Xl comes in, which the PLACEMENT decided
+    (parallel/mesh.py `make_global_rows`, asked by the estimator's
+    `_x_layout`): placed row-major, as KMeans asks, the slice and the row
+    norms are one fusion over the tile; left to a TPU at d = 3,000 Xl is
+    column-major and every tile is sliced, turned by a `copy` and read a
+    third time for its norms (docs/performance.md "Tiled distance core")."""
     _note("distance.assign_programs")
     nl, d = Xl.shape
     k = centers.shape[0]

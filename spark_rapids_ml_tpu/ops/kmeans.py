@@ -24,7 +24,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import telemetry
-from ..parallel.mesh import ROWS_AXIS
+from ..parallel.mesh import ROWS_AXIS, x_layout_of
 from .distance import (
     argmin_assign,
     assign_accumulate,
@@ -101,7 +101,15 @@ def _tile_accum_1dev(X, w, centers, sums, counts, inertia, start, *, size, fast=
     the fori_loop-of-dynamic_slice form gets a full X copy — so on one device
     the tile loop lives on the host and the (k,d) accumulators are DONATED
     device buffers updated in place. The per-tile math is the shared core's
-    fused assign+accumulate (ops/distance.py)."""
+    fused assign+accumulate (ops/distance.py).
+
+    The program is compiled for the layout the committed X has, which the
+    placement chose (parallel/mesh.py `make_global_rows`; KMeans asks for
+    row-major through `_x_layout`): row-major, the tile reaches the kernels
+    through one slice-and-norms fusion; in a TPU's default for [n, 3000]
+    (column-major) it is sliced, turned by a tile-sized `copy` and read again
+    for its norms, 12 times an iteration at the benchmark shape
+    (tests/test_distance.py pins both texts)."""
     xb = jax.lax.dynamic_slice_in_dim(X, start, size, 0)
     wb = jax.lax.dynamic_slice_in_dim(w, start, size, 0)
     s, c, i = assign_accumulate(xb, wb, centers, fast=fast)
@@ -211,7 +219,8 @@ def kmeans_fit(
 
     Two once-per-fit telemetry spans (children of the caller's `fit/solve`):
     `loop` — dispatching the iterations up to the last shift fetch, with the
-    path taken and the block plan as attributes — and `finish` — the final
+    path taken, the block plan and the layout X has on the device
+    (`x_layout`) as attributes — and `finish` — the final
     inertia pass and, through `to_host` (the estimator's conversion of the
     returned state, run inside the span), the model's attributes brought to
     the host. Neither adds a device synchronisation.
@@ -326,6 +335,7 @@ def kmeans_fit(
         tiles_per_iter=-(-rows_dev // tile),
         block_rows=block_rows,
         block_k=block_k,
+        x_layout=x_layout_of(X),
     ):
         while n_iter < max_iter:
             step_in = centers

@@ -10,9 +10,11 @@
 #
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
-from typing import Dict, Optional, Sequence, Tuple, Union
+import functools
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -468,6 +470,165 @@ def bucket_rows(
     return np.pad(x, pad_widths), n
 
 
+# ---------------------------------------------------------------------------
+# X's layout on the device (docs/performance.md "Tiled distance core").
+#
+# `jax.device_put(x, device)` leaves the layout to the device. A TPU picks, of
+# {1,0} (rows contiguous) and {0,1}, the one that pads the (8, 128) tiles
+# less: a float32 [393216, 3000] block comes out COLUMN-major (3,000 is not a
+# multiple of the 128 lanes, 393,216 is). The Pallas distance kernels state
+# {1,0} for their operands, so XLA turned every row tile of every Lloyd
+# iteration before them (half of a busy chip's time at that shape). An
+# estimator whose solver feeds row tiles of X to those kernels asks for
+# `X_ROW_MAJOR` (`_TpuCaller._x_layout`) and the placement below obliges; a
+# jitted step called with the committed array compiles for the layout it has.
+# ---------------------------------------------------------------------------
+
+X_DEFAULT = "default"  # whatever the device chooses: every placement but the one below
+X_ROW_MAJOR = "row_major"  # {1,0:T(8,128)}: the distance kernels' operand layout
+
+_ROW_MAJOR = (0, 1)  # `Layout.major_to_minor` of a [rows, d] block with rows contiguous
+
+# Rows reach a row-major buffer in pieces of about this many bytes (see
+# `_place_row_major`): two of them in flight bound what the placement holds
+# beyond X itself.
+_ROW_MAJOR_PIECE_BYTES = 48 << 20
+
+
+def _default_is_row_major(shape, dtype, device) -> bool:
+    from jax.experimental.layout import Layout
+
+    default = device.client.get_default_layout(np.dtype(dtype), tuple(shape), device)
+    return tuple(Layout.from_pjrt_layout(default).major_to_minor) == _ROW_MAJOR
+
+
+def row_major_format(shape, dtype, device):
+    """The `Format` that lays a [rows, d] block of `shape` out row-major on
+    `device`, or None where there is nothing to ask for: off the TPU (no
+    `Format` reaches a backend that may refuse one), for anything but a 2-D
+    block, and where row-major is the device's own choice for the shape (d a
+    multiple of 128). `shape` is what ONE device holds."""
+    if device.platform != "tpu" or len(shape) != 2 or _default_is_row_major(shape, dtype, device):
+        return None
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    return Format(Layout(major_to_minor=_ROW_MAJOR), SingleDeviceSharding(device))
+
+
+def x_layout_of(x) -> str:
+    """`X_ROW_MAJOR` where the 2-D device array `x` lies row-major although
+    its device would have chosen otherwise for a shard of that shape — i.e.
+    where a placement below engaged — and `X_DEFAULT` for every other array
+    (on CPU, and at d a multiple of 128, row-major IS the default)."""
+    layout = getattr(getattr(x, "format", None), "layout", None)
+    if layout is None or x.ndim != 2 or tuple(layout.major_to_minor) != _ROW_MAJOR:
+        return X_DEFAULT
+    device = next(iter(x.sharding.addressable_devices))
+    if _default_is_row_major(x.sharding.shard_shape(x.shape), x.dtype, device):
+        return X_DEFAULT
+    return X_ROW_MAJOR
+
+
+@contextlib.contextmanager
+def _no_persistent_compile_cache():
+    """Compile what runs inside in this process, whatever the persistent
+    cache holds. An executable that jax 0.9.0 / libtpu 0.0.34 reads back
+    from the cache has lost its OUTPUT layouts: the buffers it returns are
+    laid out as compiled, but report the device's default, and the next
+    program is then compiled for a layout its argument does not have
+    (`INVALID_ARGUMENT: ... expected parameter 0 of size ...`; my chip run,
+    PR 28). So the two small programs that MAKE a row-major buffer are never
+    read back; the programs that consume one are (a parameter's layout is
+    part of the compiled program and survives). jax latches whether the
+    cache is in use, hence the resets; a compile on another thread in the
+    meantime goes uncached, nothing else."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@functools.lru_cache(maxsize=None)
+def _row_major_zeros(fmt, shape, dtype):
+    """The jitted program that makes one device's row-major buffer (kept a
+    process long, like `_row_major_write`, so each compiles once a shape)."""
+    import jax.numpy as jnp
+
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=fmt)
+
+
+@functools.lru_cache(maxsize=None)
+def _row_major_write(fmt):
+    """`write(buf, piece, start)`: puts `piece` at row `start` of the
+    row-major `buf` IN PLACE (the buffer is donated and keeps its layout) and
+    returns the buffer with a scalar that is ready when the write is done."""
+
+    def write(buf, piece, start):
+        return jax.lax.dynamic_update_slice_in_dim(buf, piece, start, 0), start
+
+    return jax.jit(write, out_shardings=(fmt, None), donate_argnums=0)
+
+
+def _place_row_major(blocks: Sequence[np.ndarray], formats: Sequence) -> List[jax.Array]:
+    """One row-major device buffer per host block (equal shapes, one block
+    and one `row_major_format` a device).
+
+    jax 0.9.0 has no transfer INTO a chosen layout: `device_put(x, Format)`
+    places x in the default layout and relayouts it in one program, which
+    holds two X at once (8.9 GiB for the benchmark's 4.4; my chip run, PR
+    28) — the published 12.6 GB shape would no longer fit the one chip it
+    fits today. So each buffer is made on its device in the wanted layout
+    and the rows follow in pieces: a piece is placed as ever (all devices'
+    pieces in one batched `device_put`, so their transfers overlap) and a
+    donating program writes it into its rows of the buffer, turning 48 MB
+    instead of X. At most two pieces a device are alive, the one being
+    written and the one in transfer: peak = X + two pieces. Every piece has
+    the same row count (the last one is moved back over rows already
+    written rather than cut short), so one write program is compiled a
+    device — in this process: `_no_persistent_compile_cache`."""
+    rows, d = blocks[0].shape
+    dtype = blocks[0].dtype
+    step = max(8, _ROW_MAJOR_PIECE_BYTES // max(1, d * dtype.itemsize) // 8 * 8)
+    step = min(step, rows)
+    devices = [fmt.sharding for fmt in formats]
+    in_flight: collections.deque = collections.deque()
+    with _no_persistent_compile_cache():
+        bufs = [_row_major_zeros(fmt, (rows, d), dtype.name)() for fmt in formats]
+        for lo in range(0, rows, step):
+            lo = min(lo, rows - step)
+            pieces = jax.device_put([b[lo : lo + step] for b in blocks], devices)
+            written = []
+            for i, fmt in enumerate(formats):
+                bufs[i], done = _row_major_write(fmt)(bufs[i], pieces[i], np.int32(lo))
+                written.append(done)
+            del pieces
+            in_flight.append(written)
+            if len(in_flight) > 1:
+                jax.block_until_ready(in_flight.popleft())
+    return bufs
+
+
+def _place_blocks(blocks: Sequence[np.ndarray], devices: Sequence, x_layout: str) -> List[jax.Array]:
+    """One device array per host block: today's one batched `device_put`,
+    unless `x_layout` asks for row-major and `row_major_format` says the
+    devices would not give it (then `_place_row_major`, counted)."""
+    if x_layout not in (X_DEFAULT, X_ROW_MAJOR):
+        raise ValueError(f"x_layout={x_layout!r}: expected {X_DEFAULT!r} or {X_ROW_MAJOR!r}")
+    if x_layout == X_ROW_MAJOR and blocks[0].shape[0]:
+        formats = [row_major_format(b.shape, b.dtype, dev) for b, dev in zip(blocks, devices)]
+        if all(f is not None for f in formats):
+            telemetry.registry().inc("placement.row_major")
+            return _place_row_major(blocks, formats)
+    return jax.device_put(list(blocks), list(devices))
+
+
 def shard_row_slices(x: np.ndarray, n_dev: int) -> Tuple[list, int]:
     """Cut a host row block into `n_dev` equal per-shard pieces.
 
@@ -491,8 +652,10 @@ def shard_row_slices(x: np.ndarray, n_dev: int) -> Tuple[list, int]:
     return pieces, n_pad
 
 
-def place_row_shards(mesh: Mesh, x: np.ndarray) -> jax.Array:
-    """Place a host row block on the mesh shard-by-shard.
+def place_row_shards(mesh: Mesh, x: np.ndarray, x_layout: str = X_DEFAULT) -> jax.Array:
+    """Place a host row block on the mesh shard-by-shard, each shard in the
+    device's default layout or, for `x_layout=X_ROW_MAJOR`, row-major
+    (`_place_blocks`).
 
     The old path padded the whole block (full host copy) and handed one
     monolithic buffer to `jax.device_put`, staging a third copy and
@@ -511,18 +674,23 @@ def place_row_shards(mesh: Mesh, x: np.ndarray) -> jax.Array:
         reg.inc("placement.shards", len(pieces))
         reg.inc("placement.bytes", sum(p.nbytes for p in pieces))
         reg.inc("placement.rows_padded", n_pad - x.shape[0])
-    shards = jax.device_put(pieces, devices)
+    shards = _place_blocks(pieces, devices, x_layout)
     return jax.make_array_from_single_device_arrays(
         (n_pad,) + x.shape[1:], row_sharding(mesh, x.ndim), shards
     )
 
 
 def place_rows(
-    mesh: Mesh, x: np.ndarray, *, local_rows_target: Optional[int] = None
+    mesh: Mesh,
+    x: np.ndarray,
+    *,
+    local_rows_target: Optional[int] = None,
+    x_layout: str = X_DEFAULT,
 ) -> jax.Array:
-    """X-only `make_global_rows`: identical row layout/padding, no weight
-    vector built or placed — for callers laying out SEVERAL per-row arrays
-    that share one weight vector (ELL values+indices+labels)."""
+    """X-only `make_global_rows`: identical row layout/padding (and the same
+    `x_layout`), no weight vector built or placed — for callers laying out
+    SEVERAL per-row arrays that share one weight vector (ELL
+    values+indices+labels)."""
     x = np.ascontiguousarray(x)
     if jax.process_count() > 1:  # multi-process SPMD: x is this rank's block
         from jax.experimental import multihost_utils
@@ -545,14 +713,32 @@ def place_rows(
             reg.inc("placement.global_assembly_calls")
             reg.inc("placement.bytes", xp.nbytes)
             reg.inc("placement.rows_padded", local_rows_target - x.shape[0])
+        if x_layout != X_DEFAULT:
+            return _place_local_rows(mesh, xp, x_layout)
         return multihost_utils.host_local_array_to_global_array(xp, mesh, P(ROWS_AXIS))
     if mesh.devices.size == 1:
         if telemetry.enabled():
             reg = telemetry.registry()
             reg.inc("placement.device_put_calls")
             reg.inc("placement.bytes", x.nbytes)
-        return jax.device_put(x, mesh.devices.flatten()[0])
-    return place_row_shards(mesh, x)
+        # a plain device, not a 1-device NamedSharding: see make_global_rows
+        return _place_blocks([x], [mesh.devices.flatten()[0]], x_layout)[0]
+    return place_row_shards(mesh, x, x_layout)
+
+
+def _place_local_rows(mesh: Mesh, xp: np.ndarray, x_layout: str) -> jax.Array:
+    """`host_local_array_to_global_array(xp, mesh, P(ROWS_AXIS))` with the
+    local shards placed by `_place_blocks`: this process's padded block goes
+    to its own devices in the order the local mesh's row sharding gives, and
+    the global array is assembled from every process's shards."""
+    local_mesh = mesh.local_mesh
+    local_index = NamedSharding(local_mesh, P(ROWS_AXIS)).devices_indices_map(xp.shape)
+    devices = list(local_index)
+    shards = _place_blocks([xp[local_index[dev]] for dev in devices], devices, x_layout)
+    groups = mesh.shape[ROWS_AXIS] // local_mesh.shape[ROWS_AXIS]
+    return jax.make_array_from_single_device_arrays(
+        (xp.shape[0] * groups,) + xp.shape[1:], NamedSharding(mesh, P(ROWS_AXIS)), shards
+    )
 
 
 def stream_place_blocks(mesh: Mesh, host_blocks):
@@ -626,8 +812,18 @@ def make_global_rows(
     *,
     weights: Optional[np.ndarray] = None,
     local_rows_target: Optional[int] = None,
+    x_layout: str = X_DEFAULT,
 ) -> Tuple[jax.Array, jax.Array, int]:
     """Place a host row-block on the mesh as a row-sharded global array.
+
+    What layout X has on the device is the caller's to say: `X_DEFAULT`
+    leaves it to the device (a TPU stores a float32 [n, 3000] block with n a
+    multiple of 128 column-major), `X_ROW_MAJOR` — asked by an estimator
+    whose solver feeds row tiles of X to the distance kernels
+    (`_TpuCaller._x_layout`; KMeans) — has every device's rows contiguous,
+    the layout those kernels read, so no tile is turned before them. Off the
+    TPU, and where row-major is the device's default anyway, both are the
+    same call (`row_major_format`). `w` always takes the default.
 
     Pads rows and returns ``(X, w, n_valid)`` where `w` is a row-weight vector
     with zeros on padding rows (and the user's sample weights elsewhere).
@@ -661,17 +857,17 @@ def make_global_rows(
                 reg = telemetry.registry()
                 reg.inc("placement.device_put_calls", 2)
                 reg.inc("placement.bytes", x.nbytes + w_host.nbytes)
-            X = jax.device_put(x, dev)
+            X = _place_blocks([x], [dev], x_layout)[0]
             w = jax.device_put(w_host, dev)
         else:
-            X = place_row_shards(mesh, x)
+            X = place_row_shards(mesh, x, x_layout)
             w = place_row_shards(mesh, w_host)
     else:  # multi-process: x is this process's local block
         n_local_dev = jax.local_device_count()
         if local_rows_target is None:
             local_rows_target = -(-x.shape[0] // n_local_dev) * n_local_dev
         n_valid = x.shape[0]
-        X = place_rows(mesh, x, local_rows_target=local_rows_target)
+        X = place_rows(mesh, x, local_rows_target=local_rows_target, x_layout=x_layout)
         w = place_rows(
             mesh,
             np.asarray(weights, dtype=x.dtype if x.dtype.kind == "f" else np.float32),

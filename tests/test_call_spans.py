@@ -102,8 +102,10 @@ def test_kmeans_fit_records_init_loop_finish_under_solve(tele, rng, workers, pat
     assert [p for p in paths if p.startswith("fit/solve/")] == SOLVE_CHILDREN  # once per fit
     by_path = {s["path"]: _attrs(s) for s in spans}
     assert by_path["fit/solve/init"] == {"init_mode": init_mode}
-    # 600 rows in one tile per device; the jnp form (CPU) has no block plan
-    assert by_path["fit/solve/loop"] == {"solver_path": path, "tiles_per_iter": 1, "block_rows": None, "block_k": None}
+    # 600 rows in one tile per device; the jnp form (CPU) has no block plan, and no layout but the default
+    assert by_path["fit/solve/loop"] == {
+        "solver_path": path, "tiles_per_iter": 1, "block_rows": None, "block_k": None, "x_layout": "default",
+    }
     assert by_path["fit/solve/finish"] == {}
     wall = {s["path"]: s["wall_s"] for s in spans}
     assert sum(wall[p] for p in SOLVE_CHILDREN) <= wall["fit/solve"]

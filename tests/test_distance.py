@@ -353,3 +353,103 @@ def test_compiled_kernels_carry_their_names_on_one_chip_and_under_shard_map(monk
         step = _lloyd_step.lower(sh((4096, 256), rows), sh((4096,), rows), sh((40, 256), rep),
                                  mesh=mesh, batch_rows=512, fast=True).compile()
         assert kernels(step) == ["srml_accumulate_bf16", "srml_argmin_bf16"]
+
+
+# ------------------------------------------- X's layout under the kernels -----
+#
+# docs/performance.md "Tiled distance core": the kernels' operands are
+# row-major, and a float32 [n, 3000] block is column-major on a TPU unless the
+# placement says otherwise (parallel/mesh.py `row_major_format`, asked for by
+# KMeans). These compile, for a v5e and with no chip, the Lloyd programs the
+# way `kmeans_fit` calls them on an X placed by that path, and read the text.
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The described v5e:2x2 topology (this module is the one test file that
+    loads the TPU's compiler), or a skip where it cannot be described."""
+    from jax.experimental import topologies
+
+    env = pytest.MonkeyPatch()
+    env.setenv("TPU_SKIP_MDS_QUERY", "1")
+    try:
+        yield topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    except Exception as e:
+        pytest.skip(f"no TPU compiler available ahead of time: {type(e).__name__}: {e}")
+    finally:
+        env.undo()
+
+
+def _lloyd_program_text(v5e, program, d, x_layout):
+    """Compiled text of one Lloyd program at the benchmark cells' shapes
+    (`kmeans-p3k`: 393,216 rows on one chip through the host-tiled
+    `_tile_accum_1dev`; `kmeans-p3k-host4`: 1,048,576 rows over four chips
+    through `_lloyd_step`), with X's struct in the layout `make_global_rows`
+    gives it for `x_layout`, and the tile's and one device's X shape."""
+    from jax.experimental.layout import Format
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.ops.kmeans import _lloyd_step, _tile_accum_1dev
+    from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
+
+    k, tile = 1000, 32768
+    devices = list(v5e.devices) if program == "lloyd_step_4chips" else [v5e.devices[0]]
+    rows_dev = 262144 if program == "lloyd_step_4chips" else 393216
+    placed = None  # what `_place_blocks` asks of each device for its [rows_dev, d] block
+    if x_layout == mesh_mod.X_ROW_MAJOR:
+        placed = mesh_mod.row_major_format((rows_dev, d), np.float32, devices[0])
+    with mesh_mod.chip_scope(devices), jax.enable_x64(False):
+        if program == "tile_1dev":
+            one = SingleDeviceSharding(devices[0])
+            s = lambda shape, dtype=jnp.float32, sharding=one: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            lowered = _tile_accum_1dev.lower(
+                s((rows_dev, d), sharding=placed or one), s((rows_dev,)), s((k, d)),
+                s((k, d)), s((k,)), s(()), s((), jnp.int32), size=tile, fast=True,
+            )
+        else:
+            mesh = Mesh(np.asarray(devices), (mesh_mod.ROWS_AXIS,))
+            rows, rep = NamedSharding(mesh, P(mesh_mod.ROWS_AXIS)), NamedSharding(mesh, P())
+            x_sharding = rows if placed is None else Format(placed.layout, rows)
+            s = lambda shape, sharding: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+            lowered = _lloyd_step.lower(
+                s((4 * rows_dev, d), x_sharding), s((4 * rows_dev,), rows), s((k, d), rep),
+                mesh=mesh, batch_rows=tile, fast=True,
+            )
+        return lowered.compile().as_text(), (tile, d), (rows_dev, d)
+
+
+def _copied_shapes(text):
+    import re
+
+    return {(int(r), int(c)) for r, c in re.findall(r"= f32\[(\d+),(\d+)\]\{[^}]*\} copy\(", text)}
+
+
+def _x_entry_layout(text, x_shape):
+    import re
+
+    m = re.search(r"entry_computation_layout=\{\(f32\[%d,%d\]\{([\d,]+)" % x_shape, text)
+    assert m, "X is not the program's first parameter"
+    return m.group(1)
+
+
+@pytest.mark.parametrize("program", ["tile_1dev", "lloyd_step_4chips"])
+@pytest.mark.parametrize("d", [3000, 3072])
+def test_row_major_x_reaches_the_kernels_without_a_tile_copy(v5e, monkeypatch, program, d):
+    """Placed as KMeans asks, X enters the program row-major ({1,0}) and no
+    `copy` has the tile's or X's shape: the slice and the row norms are one
+    fusion. The control: at d = 3,000 the default layout is column-major and
+    its lowering still turns every tile (so this test reads the right text);
+    at d = 3,072 row-major is the default and nothing has to be asked."""
+    from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
+
+    monkeypatch.setattr(distance, "_MODE", "pallas")
+    text, tile_shape, x_shape = _lloyd_program_text(v5e, program, d, mesh_mod.X_ROW_MAJOR)
+    assert _x_entry_layout(text, x_shape) == "1,0"
+    assert not _copied_shapes(text) & {tile_shape, x_shape}
+    assert "srml_argmin_bf16" in text and "srml_accumulate_bf16" in text
+    asks = mesh_mod.row_major_format(x_shape, np.float32, v5e.devices[0]) is not None
+    assert asks == (d % 128 != 0)
+    if asks:
+        control, _, _ = _lloyd_program_text(v5e, program, d, mesh_mod.X_DEFAULT)
+        assert _x_entry_layout(control, x_shape) == "0,1"
+        assert tile_shape in _copied_shapes(control) and x_shape not in _copied_shapes(control)
