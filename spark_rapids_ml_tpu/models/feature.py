@@ -11,8 +11,10 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
+from .. import telemetry
 from ..core import FitInputs, _TpuEstimator, _TpuModelWithColumns
 from ..data import ExtractedData
 from ..params import (
@@ -58,7 +60,9 @@ class PCA(_PCAParams, _TpuEstimator):
 
     Distributed fit: single pass computing the weighted mean + d×d covariance
     with an MXU contraction per row shard and a GSPMD psum across chips, then a
-    replicated top-k symmetric eig with sign canonicalization — the TPU-native
+    replicated top-k symmetric eig (a block subspace iteration on k + p
+    columns; the full decomposition only where the block cannot answer:
+    ops/linalg.topk_eigh) with sign canonicalization — the TPU-native
     equivalent of the reference's `PCAMG.fit(parts, m, n, parts_rank_size, rank)`
     (reference feature.py:222-241).
     """
@@ -72,17 +76,26 @@ class PCA(_PCAParams, _TpuEstimator):
     def _solver_workspace_terms(
         self, rows_per_device: int, n_cols: int, params: Dict[str, Any], itemsize: int
     ) -> Dict[str, int]:
-        # replicated d x d covariance (+ eigenvector output of equal size)
-        # and the mean / variance d-vectors
+        # the replicated d x d covariance, the mean d-vector, and what the
+        # eigensolver that runs holds (ops/linalg.topk_eigh): the block
+        # iteration a handful of [d, block] arrays, the full decomposition a
+        # d x d eigenvector output beside its input
+        from ..ops.linalg import subspace_block
+
+        block = subspace_block(n_cols, int(params.get("n_components") or 1))
+        eig = 6 * n_cols * block if block is not None else n_cols * n_cols
         return {
-            "covariance": 2 * n_cols * n_cols * itemsize,
+            "covariance": n_cols * n_cols * itemsize,
+            "eigensolve": eig * itemsize,
             "vectors": 2 * n_cols * itemsize,
         }
 
     def _solver_flop_estimate(self, n_rows: int, n_cols: int) -> Optional[float]:
         # PCA roofline model (ops_plane/efficiency.py): the covariance
-        # einsum (2·n·d²) dominates; the d×d eigendecomposition (~9·d³) is
-        # negligible at n ≫ d and omitted.
+        # einsum (2·n·d²) dominates. The eigensolve is omitted: the block
+        # iteration is ~10 products of 2·d²·16, five orders below at the
+        # protocol's shape; the full decomposition (~9·d³), taken only where
+        # the block cannot answer, is negligible at n ≫ d.
         return 2.0 * n_rows * n_cols * n_cols
 
     def __init__(self, **kwargs: Any) -> None:
@@ -126,25 +139,22 @@ class PCA(_PCAParams, _TpuEstimator):
                 from ..ops.streaming import pca_fit_streaming
 
                 state = pca_fit_streaming(inputs, k=k, fast=fast)
-                out = {name: np.asarray(v) for name, v in state.items()}
-                check_pca_state(out, k=k)
-                record_pca_fit(out, k=k)
-                out["n_cols"] = inputs.n_cols
-                out["dtype"] = np.dtype(inputs.dtype).name
-                return out
             # elastic recovery: retain the (mean, covariance) statistics so a
             # transient retry (or a k sweep in this stage) skips the data pass
-            use_ckpt = _ckpt.solver_checkpoints_active() and (
+            elif _ckpt.solver_checkpoints_active() and (
                 inputs.ctx is None or not inputs.ctx.is_spmd
-            )
-            if use_ckpt:
+            ):
                 state = pca_fit_checkpointed(
                     inputs.X, inputs.w, k=k, fast=fast,
-                    placement_key=_ckpt.placement_key_of(inputs),
+                    placement_key=_ckpt.placement_key_of(inputs), mesh=inputs.mesh,
                 )
             else:
-                state = pca_fit(inputs.X, inputs.w, k=k, fast=fast)
-            out = {name: np.asarray(v) for name, v in state.items()}
+                state = pca_fit(inputs.X, inputs.w, k=k, fast=fast, mesh=inputs.mesh)
+            # once-per-fit child spans of `fit/solve` (docs/observability.md):
+            # `gram` and `eig` inside the calls above, `finish` the fetch of
+            # the model's attributes
+            with telemetry.span("finish"):  # the five attributes in one fetch
+                out = {name: np.asarray(v) for name, v in jax.device_get(state).items()}
             check_pca_state(out, k=k)  # guard on the host-fetched attributes
             record_pca_fit(out, k=k)
             out["n_cols"] = inputs.n_cols
@@ -222,8 +232,6 @@ class PCAModel(_PCAParams, _TpuModelWithColumns):
         return [f"{self.uid}__output"]
 
     def _get_transform_func(self):
-        import jax
-
         from ..ops.pca import pca_transform
         from ..parallel.mesh import default_local_device
 

@@ -12,7 +12,9 @@
 #
 from __future__ import annotations
 
-from typing import Tuple
+import math
+from functools import partial
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,14 +29,75 @@ def weighted_moments(X: jax.Array, w: jax.Array) -> Tuple[jax.Array, jax.Array, 
     return total_w, mean, var
 
 
+# Rows of one tile of the float32 centred sum below. On a v5e the MXU's
+# multi-pass float32 contraction over K rows carries a SYSTEMATIC relative
+# error that grows with K: one `highest` contraction over 393,216 rows reads
+# every entry 2.2e-5 low (the top eigenvalues 1.5e-5 low: worse than the same
+# contraction from bf16 inputs, whose one pass is unbiased and reads them
+# 6e-6 off), tiles of 32,768 rows 1.4e-6 high, tiles of 8,192 rows 1e-7 (my
+# chip runs, PERF.md PR 29). The tiles are added in float32 on the vector
+# unit; the d x d accumulator's traffic makes the gram 2.9 % slower at
+# d = 3,000 (0.2503 -> 0.2576 s), which is what float32 costs here.
+GRAM_TILE_ROWS = 8192
+
+
+def centered_gram(
+    X: jax.Array, w: jax.Array, mean: jax.Array, *, fast: bool = False
+) -> jax.Array:
+    """``Σ w_i (x_i-μ)(x_i-μ)ᵀ`` [d, d], accumulated over row tiles inside
+    the caller's one program: the mean is given (first pass), the centring
+    and the weighting are fused into each tile's contraction, so nothing of
+    X's size is written (the peak is X plus a few tiles). Never the
+    uncentred form. Up to `GRAM_TILE_ROWS` rows it is the one contraction it
+    always was, and so it is at any size under ``fast``: bf16 operands
+    (weights applied at full precision first), one unbiased MXU pass with
+    f32 accumulation, which tiles would only slow (0.0525 -> 0.0612 s)."""
+
+    def tile(xb, wb):
+        xc = xb - mean
+        if fast:
+            # weights applied at FULL precision first — a mixed-dtype einsum
+            # would promote the bf16 operand straight back to f32 and defeat
+            # the cast; the bf16 dot accumulates in f32 on the MXU
+            xcw = xc * wb[:, None]
+            return jnp.einsum(
+                "nd,ne->de", xcw.astype(jnp.bfloat16), xc.astype(jnp.bfloat16),
+                preferred_element_type=jnp.float32,
+            ).astype(X.dtype)
+        return jnp.einsum("nd,n,ne->de", xc, wb, xc)
+
+    n, tile_rows = X.shape[0], GRAM_TILE_ROWS
+    if fast or n <= tile_rows:
+        return tile(X, w)
+    whole = n // tile_rows
+
+    def body(i, acc):
+        xb = jax.lax.dynamic_slice_in_dim(X, i * tile_rows, tile_rows, axis=0)
+        wb = jax.lax.dynamic_slice_in_dim(w, i * tile_rows, tile_rows, axis=0)
+        return acc + tile(xb, wb)
+
+    # from the first tile's sum, not from zeros: under `shard_map` the carry is then typed as its updates are
+    gram = jax.lax.fori_loop(1, whole, body, tile(X[:tile_rows], w[:tile_rows]))
+    if n % tile_rows:
+        gram = gram + tile(X[whole * tile_rows :], w[whole * tile_rows :])
+    return gram
+
+
 def weighted_cov(
-    X: jax.Array, w: jax.Array, ddof: int = 1, fast: bool = False
+    X: jax.Array, w: jax.Array, ddof: int = 1, fast: bool = False, mesh=None
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Weighted covariance: returns (total_weight, mean [d], cov [d, d]).
 
     ``cov = Σ w_i (x_i-μ)(x_i-μ)ᵀ / (Σw - ddof)`` — matches the reference's
-    sample covariance (cuML PCA divides by n-1). The centered outer-product
-    contraction is one large MXU matmul per shard + one psum.
+    sample covariance (cuML PCA divides by n-1). Two passes over X in one
+    program: the mean, then the centred sum (`centered_gram`: MXU
+    contractions over row tiles).
+
+    ``mesh``: the mesh a row-sharded X lives on. Where a shard of a float32
+    contraction has more rows than one tile, each device runs the tile loop over its own rows under
+    `shard_map` and the sums are `psum`'d (a tile loop over the global array
+    would slice across shards); smaller shards, and callers that pass no
+    mesh, leave the one contraction and its psum to GSPMD as before.
 
     ``fast`` runs the big contraction bf16-in / f32-accumulate (the
     solver_precision="bf16" contract, docs/performance.md "Mixed-precision
@@ -42,23 +105,26 @@ def weighted_cov(
     [n,d]x[n,d] outer product is cast. Parity vs the full-precision cov is
     pinned by tests/test_precision.py.
     """
-    total_w = jnp.sum(w)
-    mean = jnp.einsum("n,nd->d", w, X) / total_w
-    Xc = X - mean
-    if fast:
-        # weights applied at FULL precision first — a mixed-dtype einsum
-        # would promote the bf16 operand straight back to f32 and defeat
-        # the cast; the bf16 dot accumulates in f32 on the MXU
-        Xcw = Xc * w[:, None]
-        cov = jnp.einsum(
-            "nd,ne->de",
-            Xcw.astype(jnp.bfloat16),
-            Xc.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        ).astype(X.dtype) / (total_w - ddof)
+    shards = 1 if mesh is None else int(mesh.devices.size)
+    if shards > 1 and not fast and X.shape[0] // shards > GRAM_TILE_ROWS:
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        from ..parallel.mesh import ROWS_AXIS
+
+        def local(Xl, wl):
+            total_w = jax.lax.psum(jnp.sum(wl), ROWS_AXIS)
+            mean = jax.lax.psum(jnp.einsum("n,nd->d", wl, Xl), ROWS_AXIS) / total_w
+            return total_w, mean, jax.lax.psum(centered_gram(Xl, wl, mean, fast=fast), ROWS_AXIS)
+
+        total_w, mean, gram = shard_map(
+            local, mesh=mesh, in_specs=(P(ROWS_AXIS, None), P(ROWS_AXIS)), out_specs=(P(), P(), P())
+        )(X, w)
     else:
-        cov = jnp.einsum("nd,n,ne->de", Xc, w, Xc) / (total_w - ddof)
-    return total_w, mean, cov
+        total_w = jnp.sum(w)
+        mean = jnp.einsum("n,nd->d", w, X) / total_w
+        gram = centered_gram(X, w, mean, fast=fast)
+    return total_w, mean, gram / (total_w - ddof)
 
 
 def sign_flip(components: jax.Array) -> jax.Array:
@@ -78,8 +144,125 @@ def topk_eigh_desc(sym: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
     Mirrors the reference JNI `calSVD` post-processing (eigDC + column/row
     reverse, rapidsml_jni.cu:215-269): LAPACK/XLA return ascending order, the
     framework contract is descending. Returns (eigvals [k], eigvecs [k, d]).
+    The `full` path of `topk_eigh`: on a TPU it decomposes the whole matrix
+    (at d = 3,000 that one program compiled for 268 s, PERF.md PR 29), so
+    `topk_eigh` takes it only where the block iteration below cannot answer.
     """
     evals, evecs = jnp.linalg.eigh(sym)  # ascending
     evals = evals[::-1][:k]
     comps = evecs.T[::-1][:k]
     return evals, comps
+
+
+# ---------------------------------------------------- top-k eigensolver -----
+#
+# The k largest eigenpairs of a symmetric d x d matrix without decomposing
+# the whole of it: subspace iteration on an oversampled block of k + p
+# columns with a Rayleigh-Ritz step every iteration. Per iteration one
+# [d, d] x [d, b] product, a QR of [d, b] and a b x b eigh: at d = 3,000,
+# k = 3 that is 16 columns, against the QDWH divide-and-conquer of all 3,000.
+
+EIG_BUDGET = 48  # iterations; a spectrum the block cannot split by then takes `full`
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def subspace_block(d: int, k: int):
+    """Columns of the iteration's block for the top k of d: k + p with
+    p = max(k, 8), up to a multiple of 8 (the block converges at the rate
+    lambda_{b+1} / lambda_k, so the oversampling is what makes near-equal
+    neighbours of lambda_k cheap). None where the block is over a quarter of
+    d: there it buys nothing over the full decomposition."""
+    block = -(-(k + max(k, 8)) // 8) * 8
+    return block if 4 * block <= d else None
+
+
+def eig_tolerance(dtype) -> float:
+    """A kept pair counts as converged at |C v - lambda v| <= this * lambda_1:
+    16 ulp. The iteration's floor in float32 reads 1e-7 to 8e-7 (the Ritz
+    values carry a few ulp of lambda_1 from the b x b projection; CPU and
+    v5e alike, PERF.md PR 29), so this is twice the worst floor seen."""
+    return 16.0 * float(jnp.finfo(dtype).eps)
+
+
+def _ritz(sym, Q):
+    """Rayleigh-Ritz on span(Q), Q orthonormal, from the one product C Q:
+    Ritz values descending, Ritz vectors V, C V, and each pair's residual."""
+    Y = jnp.dot(sym, Q, precision=_HIGHEST)
+    T = jnp.dot(Q.T, Y, precision=_HIGHEST)
+    theta, U = jnp.linalg.eigh(0.5 * (T + T.T))  # b x b, ascending
+    theta, U = theta[::-1], U[:, ::-1]
+    V = jnp.dot(Q, U, precision=_HIGHEST)
+    CV = jnp.dot(Y, U, precision=_HIGHEST)
+    return theta, V, CV, jnp.linalg.norm(CV - V * theta, axis=0)
+
+
+@partial(jax.jit, static_argnames=("k", "block"))
+def topk_eigh_subspace(sym: jax.Array, *, k: int, block: int):
+    """Block subspace iteration from a fixed start block (no seed: the same
+    matrix gives the same answer), every product at `highest` precision,
+    until each kept pair's residual is within `eig_tolerance` of lambda_1 and
+    a further step no longer halves it (the floor), or `EIG_BUDGET` iterations
+    have run. An iteration is one product with the
+    matrix: the next block is the orthonormalised C V that the last
+    Rayleigh-Ritz step already holds. Returns (eigvals [k] descending,
+    eigvecs [k, d], iterations run, max kept residual / lambda_1): the caller
+    decides from the last whether this is the answer (`topk_eigh`)."""
+    d = sym.shape[0]
+    tol = eig_tolerance(sym.dtype)
+    start = jax.random.normal(jax.random.PRNGKey(0), (d, block), sym.dtype)
+
+    def step(Y):
+        Q, _ = jnp.linalg.qr(Y)
+        theta, V, CV, res = _ritz(sym, Q)
+        scale = jnp.maximum(jnp.abs(theta[0]), jnp.finfo(sym.dtype).tiny)
+        return theta, V, CV, jnp.max(res[:k]) / scale
+
+    def cond(state):
+        i, _, _, _, residual, before = state
+        # on while over the tolerance, and under it for as long as a step still halves the
+        # residual: the steps that take it from the tolerance down to the floor are the cheapest
+        # accuracy there is (the eigenvectors' error is the residual over the gap). A residual
+        # that is not a number ends the loop too.
+        return (i < EIG_BUDGET) & ((residual > tol) | (residual < 0.5 * before))
+
+    def body(state):
+        i, _, _, CV, residual, _ = state
+        return (i + 1, *step(CV), residual)
+
+    first = (jnp.int32(0), *step(start), jnp.asarray(jnp.inf, sym.dtype))
+    iterations, theta, V, _, residual, _ = jax.lax.while_loop(cond, body, first)
+    return theta[:k], V[:, :k].T, iterations, residual
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _topk_eigh_full(sym: jax.Array, *, k: int):
+    evals, comps = topk_eigh_desc(sym, k)
+    R = jnp.dot(comps, sym, precision=_HIGHEST) - comps * evals[:, None]
+    scale = jnp.maximum(jnp.max(jnp.abs(evals)), jnp.finfo(sym.dtype).tiny)
+    return evals, comps, jnp.max(jnp.linalg.norm(R, axis=1)) / scale
+
+
+def topk_eigh(sym: jax.Array, k: int) -> Tuple[jax.Array, jax.Array, Dict[str, Any]]:
+    """The k largest eigenpairs of symmetric `sym`, descending: (eigvals [k],
+    eigvecs [k, d], what ran). Two programs and a choice made on the host
+    from what it observes: the block iteration where `subspace_block` gives a
+    block, and the full decomposition where it gives none or where the
+    iteration's residual is still over `eig_tolerance` when its budget is
+    spent (more near-equal eigenvalues at the top than the block has
+    columns). The same answer either way, to float32's floor; the full
+    program is compiled only by a fit that needs it. What ran: `eig_path`
+    ("topk" / "full"), `block`, `iterations`, `residual_max` (fetched: two
+    scalars)."""
+    block = subspace_block(int(sym.shape[0]), int(k))
+    iterations = 0
+    if block is not None:
+        evals, comps, iterations, residual = topk_eigh_subspace(sym, k=int(k), block=block)
+        iterations, residual = (v.item() for v in jax.device_get((iterations, residual)))  # one fetch
+        # a residual that is not a number comes from a matrix that is not one: no
+        # decomposition repairs it, the caller's divergence guard names it
+        if residual <= eig_tolerance(sym.dtype) or math.isnan(residual):
+            return evals, comps, {"eig_path": "topk", "block": block, "iterations": iterations,
+                                  "residual_max": residual}
+    evals, comps, residual = _topk_eigh_full(sym, k=int(k))
+    return evals, comps, {"eig_path": "full", "block": block or 0, "iterations": iterations,
+                          "residual_max": float(residual)}

@@ -6,7 +6,8 @@
 #   1. weighted mean + covariance of the row-sharded X — one fused MXU
 #      contraction per shard, GSPMD psum across the `rows` mesh axis
 #      (the NCCL-allreduce-of-covariance equivalent);
-#   2. replicated d×d symmetric eigendecomposition, top-k descending;
+#   2. replicated top-k symmetric eigensolve, descending (linalg.topk_eigh:
+#      a block subspace iteration, the full decomposition where it must);
 #   3. sign canonicalization (reference signFlip kernel parity,
 #      rapidsml_jni.cu:35-61).
 #
@@ -19,7 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry
-from .linalg import sign_flip, topk_eigh_desc, weighted_cov
+from ..parallel.mesh import x_layout_of
+from .linalg import sign_flip, topk_eigh, weighted_cov
 
 
 def check_pca_state(state: Dict, *, k: int) -> Dict:
@@ -59,9 +61,9 @@ def record_pca_fit(state: Dict[str, jax.Array], *, k: int) -> None:
     )
 
 
-@partial(jax.jit, static_argnames=("k", "fast"))
-def pca_fit(X: jax.Array, w: jax.Array, *, k: int, fast: bool = False) -> Dict[str, jax.Array]:
-    """Fit PCA on a row-sharded global X with padding/sample weights w.
+def pca_fit(X: jax.Array, w: jax.Array, *, k: int, fast: bool = False, mesh=None) -> Dict[str, jax.Array]:
+    """Fit PCA on a row-sharded global X with padding/sample weights w
+    (`mesh`: the mesh it is sharded over, see linalg.weighted_cov).
 
     Returns the model-state dict matching the reference's model attributes
     (reference feature.py:250-257): mean_, components_, explained_variance_,
@@ -70,21 +72,35 @@ def pca_fit(X: jax.Array, w: jax.Array, *, k: int, fast: bool = False) -> Dict[s
     at transform time). `fast` runs the covariance contraction bf16-in /
     f32-accumulate (linalg.weighted_cov); the eigendecomposition and every
     reported variance stay full precision.
+
+    Two programs run in turn, each under a span of its own (`gram`, then
+    `eig` inside the finish the checkpointed and the streaming fit share), so
+    that each span's wall is its program's.
     """
-    total_w, mean, cov = weighted_cov(X, w, ddof=1, fast=fast)
-    # one shared finish kernel with the checkpointed path (stats -> model),
-    # so the two entry points cannot drift
-    return _pca_finish(total_w, mean, cov, k=k)
+    return _pca_finish(*_gram_pass(X, w, fast=fast, mesh=mesh), k=k)
 
 
-@partial(jax.jit, static_argnames=("fast",))
-def _pca_stats(X: jax.Array, w: jax.Array, fast: bool = False):
-    return weighted_cov(X, w, ddof=1, fast=fast)
+@partial(jax.jit, static_argnames=("fast", "mesh"))
+def _pca_stats(X: jax.Array, w: jax.Array, fast: bool = False, mesh=None):
+    return weighted_cov(X, w, ddof=1, fast=fast, mesh=mesh)
 
 
-@partial(jax.jit, static_argnames=("k",))
-def _pca_finish(total_w, mean, cov, *, k: int) -> Dict[str, jax.Array]:
-    evals, comps = topk_eigh_desc(cov, k)
+def _gram_span(rows: int, d: int, fast: bool, x_layout: str):
+    """The `gram` span of one pass over X (a child of the caller's
+    `fit/solve`), and the count of it: a fit that reuses retained statistics
+    opens none and adds nothing to `pca.gram_passes`."""
+    telemetry.registry().inc("pca.gram_passes")
+    return telemetry.span("gram", rows=rows, d=d, precision="bf16" if fast else "f32", x_layout=x_layout)
+
+
+def _gram_pass(X: jax.Array, w: jax.Array, *, fast: bool, mesh=None):
+    """The one pass over a resident X: (total_w, mean, cov), ready."""
+    with _gram_span(int(X.shape[0]), int(X.shape[1]), fast, x_layout_of(X)):
+        return jax.block_until_ready(_pca_stats(X, w, fast=fast, mesh=mesh))
+
+
+@jax.jit
+def _pca_attrs(total_w, mean, cov, evals, comps) -> Dict[str, jax.Array]:
     evals = jnp.maximum(evals, 0.0)
     comps = sign_flip(comps)
     total_var = jnp.trace(cov)
@@ -99,9 +115,24 @@ def _pca_finish(total_w, mean, cov, *, k: int) -> Dict[str, jax.Array]:
     }
 
 
+def _pca_finish(total_w, mean, cov, *, k: int) -> Dict[str, jax.Array]:
+    """Statistics -> model state, shared by the resident, the checkpointed
+    and the streaming fit: the top-k eigensolve (`linalg.topk_eigh`: the
+    block iteration, or the full decomposition where that cannot answer)
+    under the `eig` span, then the model's attributes (one small program)."""
+    with telemetry.span("eig") as sp:
+        evals, comps, ran = topk_eigh(cov, k)
+        sp.set(**ran)
+    reg = telemetry.registry()
+    reg.inc("pca.eig_iterations", ran["iterations"])
+    if ran["eig_path"] == "full":
+        reg.inc("pca.eig_full")
+    return _pca_attrs(total_w, mean, cov, evals, comps)
+
+
 def pca_fit_checkpointed(
     X: jax.Array, w: jax.Array, *, k: int, fast: bool = False,
-    ckpt_key: str = "pca_stats", placement_key=None,
+    ckpt_key: str = "pca_stats", placement_key=None, mesh=None,
 ) -> Dict[str, jax.Array]:
     """`pca_fit` with the sufficient statistics — weighted (total_w, mean,
     covariance), the output of the ONE distributed data pass — retained on
@@ -122,7 +153,7 @@ def pca_fit_checkpointed(
         ckpt_key = ckpt_key + ":bf16"
 
     def compute() -> Dict:
-        total_w, mean, cov = _pca_stats(X, w, fast=fast)
+        total_w, mean, cov = _gram_pass(X, w, fast=fast, mesh=mesh)
         return {
             "total_w": np.asarray(total_w),
             "mean": np.asarray(mean),

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..parallel.mesh import stream_place_blocks
+from .linalg import centered_gram
 from ..utils import numcheck
 
 
@@ -251,19 +252,10 @@ def _moments_block(xb, wb):
 @partial(jax.jit, static_argnames=("fast",))
 def _cov_block(xb, wb, mean, fast: bool = False):
     """Per-chunk CENTERED outer-product sum: Σ w (x-μ)(x-μ)ᵀ. Padding rows
-    contribute (0-μ) terms scaled by w=0 — nothing. ``fast`` runs the outer
-    product bf16-in / f32-accumulate (weights applied at full precision
-    first — linalg.weighted_cov's contract)."""
-    xc = xb - mean
-    if fast:
-        xcw = xc * wb[:, None]
-        return jnp.einsum(
-            "nd,ne->de",
-            xcw.astype(jnp.bfloat16),
-            xc.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        ).astype(xb.dtype)
-    return jnp.einsum("nd,n,ne->de", xc, wb, xc)
+    contribute (0-μ) terms scaled by w=0 — nothing. The resident fit's own
+    contraction (linalg.centered_gram: row tiles, ``fast`` bf16-in /
+    f32-accumulate with the weights applied at full precision first)."""
+    return centered_gram(xb, wb, mean, fast=fast)
 
 
 def pca_fit_streaming(inputs: Any, *, k: int, fast: bool = False) -> Dict[str, jax.Array]:
@@ -276,12 +268,17 @@ def pca_fit_streaming(inputs: Any, *, k: int, fast: bool = False) -> Dict[str, j
     the eigendecomposition stay full precision."""
     from .. import checkpoint as _ckpt
     from ..parallel import chaos
-    from .pca import _pca_finish
+    from ..parallel.mesh import X_DEFAULT
+    from .pca import _gram_span, _pca_finish
 
     dtype = inputs.dtype
     w = np.asarray(inputs.w, dtype=dtype)
 
     def compute() -> Dict[str, np.ndarray]:
+        with _gram_span(int(inputs.n_valid), int(inputs.n_cols), fast, X_DEFAULT):  # both streamed passes
+            return stream_stats()
+
+    def stream_stats() -> Dict[str, np.ndarray]:
         sw = None
         sx = None
         _nc = numcheck.hook()  # SRML_NUMCHECK=1: sweep per-chunk host partials

@@ -366,3 +366,46 @@ def test_distance_kernels_compile_for_a_v5e_ahead_of_time(monkeypatch):
         _exact_knn_sharded.lower(
             X, sharded((262_144,), rows, jnp.bool_), sharded((256, 3000), rep), mesh=mesh, k=64
         )
+
+
+def test_the_pca_finish_at_d3000_decomposes_no_whole_matrix(monkeypatch):
+    """The parent's finish program called `eigh` on the whole 3,000 x 3,000
+    covariance to keep three eigenpairs: on a TPU that lowers to a QDWH
+    divide-and-conquer down to `@Eigh` custom calls on 256 x 256 blocks, and
+    the one program compiled for 268 s on a v5e (PERF.md, PR 29). The programs
+    a PCA(k=3) fit runs after its gram now hold no `@Eigh` wider than the
+    iteration's 16-column block, and compile for a v5e in seconds."""
+    import re
+    import time
+
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.ops import linalg, pca
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    except Exception as e:  # no libtpu on this machine: nothing to compile with
+        pytest.skip(f"no TPU compiler available ahead of time: {type(e).__name__}: {e}")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def eigh_widths(lowered):
+        return [int(n) for n in re.findall(r"custom_call @Eigh\(.*?\(tensor<(\d+)x\d+xf32>\)", lowered.as_text())]
+
+    d, k = 3000, 3
+    block = linalg.subspace_block(d, k)
+    assert block == 16
+    with jax.enable_x64(False):
+        # what the guard looks for is there to be found: the full path's program has the 256-wide base case
+        assert max(eigh_widths(linalg._topk_eigh_full.lower(struct(d, d), k=k))) > block
+        eig = linalg.topk_eigh_subspace.lower(struct(d, d), k=k, block=block)
+        attrs = pca._pca_attrs.lower(struct(), struct(d), struct(d, d), struct(k), struct(k, d))
+        assert set(eigh_widths(eig)) == {block} and eigh_widths(attrs) == []
+        t0 = time.perf_counter()
+        eig.compile()
+        attrs.compile()
+        assert time.perf_counter() - t0 < 60  # 5 s here; the issue's bound for any program of the fit

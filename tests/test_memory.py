@@ -113,10 +113,14 @@ def test_linear_workspace_terms_analytic():
     assert terms == {"gram": 12 * 12 * 8, "vectors": 4 * 12 * 8}
 
 
-def test_pca_workspace_terms_analytic():
+@pytest.mark.parametrize("d,eigensolve", [
+    (12, 12 * 12),  # k + p = 16 columns is over d / 4: the full decomposition's d x d eigenvector output
+    (3000, 6 * 3000 * 16),  # the block iteration: a handful of [d, 16] arrays, no d x d output
+])
+def test_pca_workspace_terms_analytic(d, eigensolve):
     est = PCA(k=3, float32_inputs=False)
-    terms = est._solver_workspace_terms(125, 12, dict(est._solver_params), 8)
-    assert terms == {"covariance": 2 * 12 * 12 * 8, "vectors": 2 * 12 * 8}
+    terms = est._solver_workspace_terms(125, d, dict(est._solver_params), 8)
+    assert terms == {"covariance": d * d * 8, "eigensolve": eigensolve * 8, "vectors": 2 * d * 8}
 
 
 def test_kmeans_workspace_terms_analytic():
