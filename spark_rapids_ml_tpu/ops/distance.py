@@ -211,6 +211,50 @@ def _call_params(interpret: bool) -> dict:
     }
 
 
+def _grid_call(kernel, *, grid, in_specs, out_specs, out_shape, name, interpret,
+               block_offset=()):
+    """The `pallas_call` of one kernel of this file. With `block_offset` (a
+    1-tuple holding an int32 [1] array, traced: `_tile_of`) the call takes it
+    first as a PREFETCHED SCALAR: every index map receives its ref as a
+    trailing argument (`_row_block` adds it to the row block index), the
+    kernel body does not see it. That is how a kernel reads its row blocks
+    out of an array larger than the tile it works on, where the array lies.
+    Without it: the plain call."""
+    from jax.experimental import pallas as pl
+
+    if not block_offset:
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, name=name, **_call_params(interpret),
+        )
+    from jax.experimental.pallas import tpu as pltpu
+
+    call = pl.pallas_call(
+        lambda _off_ref, *refs: kernel(*refs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        ),
+        out_shape=out_shape, name=name, **_call_params(interpret),
+    )
+    return lambda *operands: call(*block_offset, *operands)
+
+
+def _row_block(r, off):
+    """Row block index of X for grid row `r`: `off` is what the index map got
+    after the grid indices, () or the prefetched block offset's ref."""
+    return (off[0][0] + r if off else r, 0)
+
+
+def _tile_of(x, tile, block_rows):
+    """(rows, block offset) of a kernel's work: all of `x` and (), or, for
+    `tile = (start, rows)`, `rows` and the 1-tuple `_grid_call` takes: row
+    `start`, a multiple of `block_rows`, counted in blocks."""
+    if tile is None:
+        return x.shape[0], ()
+    start, rows = tile
+    return rows, ((jnp.asarray(start, jnp.int32) // block_rows).reshape(1),)
+
+
 def shard_map_check_vma() -> bool:
     """`check_vma` for a `shard_map` whose body runs these kernels: on —
     the compiled `pallas_call`s state how their outputs vary (`_vary_alike`)
@@ -382,7 +426,7 @@ def _kdot(a: jax.Array, b: jax.Array, fast: bool) -> jax.Array:
 
 
 def _pl_argmin(
-    x: jax.Array,  # [B, d] row tile
+    x: jax.Array,  # [B, d] row tile, or with `tile` the whole [n, d] block it lies in
     c_pad: jax.Array,  # [kp, d] centers, padded to a block_k multiple
     c_sq_pad: jax.Array,  # [kp] (+inf on padding rows)
     *,
@@ -390,20 +434,27 @@ def _pl_argmin(
     block_k: int,
     fast: bool,
     interpret: bool,
+    tile: Optional[Tuple[jax.Array, int]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused distance + running argmin: returns (min d2 [B] WITHOUT the
     ||x||^2 term, argmin index [B] int32). Grid = (row blocks, k blocks)
     with the k axis innermost: each step computes one [br, bk] distance
     block in VMEM and merges it into the carried per-row minimum — the full
-    [B, k] matrix never exists."""
+    [B, k] matrix never exists.
+
+    `tile = (start, rows)`: the B = `rows` rows of `x` from the (traced) row
+    `start` on, both multiples of `block_rows`, fetched block by block from
+    where they lie in `x` (`_grid_call`): the same grid, blocks and body as
+    on a slice of those rows, and no buffer of the tile's shape."""
     from jax.experimental import pallas as pl
 
-    B, d = x.shape
+    d = x.shape[1]
+    B, off = _tile_of(x, tile, block_rows)
     kp = c_pad.shape[0]
     n_rb = B // block_rows
     n_kb = kp // block_k
     dtype = x.dtype
-    vma, (x, c_pad, c_sq_pad) = _vary_alike(x, c_pad, c_sq_pad)
+    vma, (x, c_pad, c_sq_pad, *off) = _vary_alike(x, c_pad, c_sq_pad, *off)
 
     def kernel(x_ref, c_ref, csq_ref, mind_ref, best_ref):
         kb = pl.program_id(1)
@@ -425,30 +476,31 @@ def _pl_argmin(
             mind_ref[...] = jnp.where(take, blk_min, cur)
             best_ref[...] = jnp.where(take, blk_arg, best_ref[...])
 
-    mind, best = pl.pallas_call(
+    mind, best = _grid_call(
         kernel,
         grid=(n_rb, n_kb),
         in_specs=[
-            pl.BlockSpec((block_rows, d), lambda r, k: (r, 0)),
-            pl.BlockSpec((block_k, d), lambda r, k: (k, 0)),
-            pl.BlockSpec((1, block_k), lambda r, k: (0, k)),
+            pl.BlockSpec((block_rows, d), lambda r, k, *off: _row_block(r, off)),
+            pl.BlockSpec((block_k, d), lambda r, k, *_: (k, 0)),
+            pl.BlockSpec((1, block_k), lambda r, k, *_: (0, k)),
         ],
         out_specs=[
-            pl.BlockSpec((block_rows, 1), lambda r, k: (r, 0)),
-            pl.BlockSpec((block_rows, 1), lambda r, k: (r, 0)),
+            pl.BlockSpec((block_rows, 1), lambda r, k, *_: (r, 0)),
+            pl.BlockSpec((block_rows, 1), lambda r, k, *_: (r, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, 1), dtype, vma=vma),
             jax.ShapeDtypeStruct((B, 1), jnp.int32, vma=vma),
         ],
         name=kernel_name("argmin", fast),
-        **_call_params(interpret),
+        interpret=interpret,
+        block_offset=off,
     )(x, c_pad, c_sq_pad[None, :])
     return mind[:, 0], best[:, 0]
 
 
 def _pl_accumulate(
-    x: jax.Array,  # [B, d]
+    x: jax.Array,  # [B, d], or with `tile` the whole [n, d] block the B rows lie in
     w: jax.Array,  # [B]
     assign: jax.Array,  # [B] int32
     kp: int,  # padded center count (block_k multiple)
@@ -457,18 +509,21 @@ def _pl_accumulate(
     block_k: int,
     fast: bool,
     interpret: bool,
+    tile: Optional[Tuple[jax.Array, int]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Weighted one-hot accumulation: (sums [kp, d], counts [kp]). Grid =
     (k blocks, row blocks) with rows innermost: each step builds one
     [br, bk] one-hot block and accumulates its [bk, d] contribution — the
-    full [B, k] one-hot matrix never exists."""
+    full [B, k] one-hot matrix never exists. `tile`: as in `_pl_argmin`
+    (`w` and `assign` are the tile's own B entries either way)."""
     from jax.experimental import pallas as pl
 
-    B, d = x.shape
+    d = x.shape[1]
+    B, off = _tile_of(x, tile, block_rows)
     n_rb = B // block_rows
     n_kb = kp // block_k
     dtype = x.dtype
-    vma, (x, w, assign) = _vary_alike(x, w, assign)
+    vma, (x, w, assign, *off) = _vary_alike(x, w, assign, *off)
 
     def kernel(x_ref, w_ref, a_ref, sums_ref, counts_ref):
         kb = pl.program_id(0)
@@ -490,24 +545,25 @@ def _pl_accumulate(
             sums_ref[...] += contrib
             counts_ref[...] += jnp.sum(oh, axis=0)[:, None]
 
-    sums, counts = pl.pallas_call(
+    sums, counts = _grid_call(
         kernel,
         grid=(n_kb, n_rb),
         in_specs=[
-            pl.BlockSpec((block_rows, d), lambda k, r: (r, 0)),
-            pl.BlockSpec((block_rows, 1), lambda k, r: (r, 0)),
-            pl.BlockSpec((block_rows, 1), lambda k, r: (r, 0)),
+            pl.BlockSpec((block_rows, d), lambda k, r, *off: _row_block(r, off)),
+            pl.BlockSpec((block_rows, 1), lambda k, r, *_: (r, 0)),
+            pl.BlockSpec((block_rows, 1), lambda k, r, *_: (r, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_k, d), lambda k, r: (k, 0)),
-            pl.BlockSpec((block_k, 1), lambda k, r: (k, 0)),
+            pl.BlockSpec((block_k, d), lambda k, r, *_: (k, 0)),
+            pl.BlockSpec((block_k, 1), lambda k, r, *_: (k, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((kp, d), dtype, vma=vma),
             jax.ShapeDtypeStruct((kp, 1), dtype, vma=vma),
         ],
         name=kernel_name("accumulate", fast),
-        **_call_params(interpret),
+        interpret=interpret,
+        block_offset=off,
     )(x, w[:, None], assign[:, None].astype(jnp.int32))
     return sums, counts[:, 0]
 
@@ -595,83 +651,132 @@ def assign_argmin(
 
 
 def assign_accumulate(
-    xb: jax.Array,  # [B, d] one row tile
+    xb: jax.Array,  # [B, d] one row tile, or with `tile` the whole [n, d] block it lies in
     wb: jax.Array,  # [B] weights (0 on padding rows — they contribute nothing)
     centers: jax.Array,  # [k, d]
     *,
     fast: bool = False,
     block_rows: Optional[int] = None,
     block_k: Optional[int] = None,
+    x_sq: Optional[jax.Array] = None,
+    tile: Optional[Tuple[jax.Array, int]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One row tile's fused Lloyd contribution: (sums [k, d], counts [k],
     inertia scalar). THE kmeans inner loop: assignment (k-tiled argmin) plus
     the weighted one-hot accumulation, never materializing [B, k] on the
-    kernel path."""
+    kernel path.
+
+    `x_sq`: the tile's [B] squared row norms where the caller keeps them (a
+    KMeans fit makes them once); computed from `xb` otherwise.
+    `tile = (start, rows)`: the kernels read the B = `rows` rows from the
+    (traced) row `start` on out of `xb` where it lies (`_pl_argmin`), `wb`
+    and `x_sq` being the tile's own. For a caller that knows `xb` lies
+    row-major (no trace sees a layout: ops/kmeans.py `kmeans_fit` reads it
+    from the committed array), the kernels are on and `rows` is a multiple
+    of the plan's block rows (`block_plan`); anything else is the caller's
+    error."""
     k, d = centers.shape
-    plan = (
-        _plan(xb.shape[0], k, d, xb.dtype, fast)
-        if _use_kernel()
-        else None
-    )
+    n = xb.shape[0] if tile is None else tile[1]
+    plan = _plan(n, k, d, xb.dtype, fast) if _use_kernel() else None
+    br, bk = (block_rows or plan[0], block_k or plan[1]) if plan else (None, None)
+    if tile is not None and (x_sq is None or plan is None or n % br):
+        raise ValueError(
+            f"a {n}-row tile read in place needs its row norms, the kernels and "
+            f"whole row blocks (plan {plan}): slice the tile instead"
+        )
+    if x_sq is None:
+        x_sq = row_sq(xb)
     if plan is None:
         c_sq = _c_sq(centers)
         d2 = c_sq[None, :] - 2.0 * _mm(xb, centers.T, fast)
         assign = jnp.argmin(d2, axis=1)
-        min_d2 = jnp.min(d2, axis=1) + row_sq(xb)
+        min_d2 = jnp.min(d2, axis=1) + x_sq
         oh = jax.nn.one_hot(assign, k, dtype=xb.dtype) * wb[:, None]
         return (
             _mm(oh.T, xb, fast),
             jnp.sum(oh, axis=0),
             jnp.sum(jnp.maximum(min_d2, 0.0) * wb),
         )
-    br, bk = block_rows or plan[0], block_k or plan[1]
-    xp, n = _pad_rows_multiple(xb, br)
-    wp, _ = _pad_rows_multiple(wb, br)
+    xp, wp = xb, wb
+    if tile is None:
+        xp, _ = _pad_rows_multiple(xb, br)
+        wp, _ = _pad_rows_multiple(wb, br)
     cp, _ = _pad_rows_multiple(centers, bk)
     bk = min(bk, cp.shape[0])
     csq_p = jnp.pad(_c_sq(centers), (0, cp.shape[0] - k), constant_values=jnp.inf)
     mind, best = _pl_argmin(
         xp, cp, csq_p, block_rows=br, block_k=bk, fast=fast,
-        interpret=_interpret(),
+        interpret=_interpret(), tile=tile,
     )
     sums_p, counts_p = _pl_accumulate(
         xp, wp, best, cp.shape[0], block_rows=br, block_k=bk, fast=fast,
-        interpret=_interpret(),
+        interpret=_interpret(), tile=tile,
     )
-    min_d2 = mind[:n] + row_sq(xb)
+    min_d2 = mind[:n] + x_sq
     inertia = jnp.sum(jnp.maximum(min_d2, 0.0) * wb)
     return sums_p[:k], counts_p[:k], inertia
 
 
-def tile_assign_accumulate(
-    Xl: jax.Array, wl: jax.Array, centers: jax.Array, batch_rows: int,
+def assign_accumulate_rows(
+    X: jax.Array,  # [n, d] one device's rows
+    w: jax.Array,  # [n]
+    centers: jax.Array,  # [k, d]
+    x_sq: jax.Array,  # [n] squared row norms of X
+    start,  # first row of the tile (traced, or a Python int)
+    rows: int,
+    *,
     fast: bool = False,
+    in_place: bool = False,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """`assign_accumulate` over rows [start, start + rows) of X. `w` and
+    `x_sq` are sliced (1-D, 128 KB a tile at 32,768 rows). X is sliced too,
+    which makes the tile a buffer of its own (a Mosaic operand is a whole
+    array), unless `in_place`: then the kernels fetch the tile's blocks out
+    of X itself and no copy of any part of X is made. The CALLER vouches
+    for what `assign_accumulate` asks of a `tile`, the layout first: handed
+    a column-major X whole, XLA would turn all of it before every call."""
+    wb = jax.lax.dynamic_slice_in_dim(w, start, rows, 0)
+    qb = jax.lax.dynamic_slice_in_dim(x_sq, start, rows, 0)
+    if in_place:
+        return assign_accumulate(X, wb, centers, fast=fast, x_sq=qb, tile=(start, rows))
+    xb = jax.lax.dynamic_slice_in_dim(X, start, rows, 0)
+    return assign_accumulate(xb, wb, centers, fast=fast, x_sq=qb)
+
+
+def tile_assign_accumulate(
+    Xl: jax.Array, wl: jax.Array, centers: jax.Array, x_sq: jax.Array,
+    batch_rows: int, fast: bool = False, in_place: bool = False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Scan one device's rows in tiles; returns (sums [k,d], counts [k],
     inertia) — the whole-shard Lloyd accumulation every KMeans path shares.
+    `x_sq` holds the rows' squared norms (made once a fit, not once a tile).
 
-    Tiles are cut with `dynamic_slice` DIRECTLY out of Xl inside a fori_loop,
-    and the ragged tail is one extra direct step. Neither `jnp.pad` of the
-    shard nor a `lax.scan` over a reshaped view is safe here: both make XLA
-    materialize a second X-sized buffer (11 GiB at the 1M x 3k benchmark
-    shape, measured) — the slice-in-loop form keeps X single-buffered.
+    `in_place` (see `assign_accumulate_rows`; the caller's statement that Xl
+    lies row-major and a full tile is whole row blocks): the kernels of
+    each full tile index Xl itself by the loop's tile offset. The compiled
+    step then has no value of the tile's shape and no copy of Xl, inside the
+    fori_loop as outside one: XLA does not duplicate an operand that a
+    Mosaic kernel indexes (tests/test_distance.py pins the text for a v5e).
 
-    The kernels read a tile row-major ({1,0}), and the program is compiled
-    for the layout Xl comes in, which the PLACEMENT decided
-    (parallel/mesh.py `make_global_rows`, asked by the estimator's
-    `_x_layout`): placed row-major, as KMeans asks, the slice and the row
-    norms are one fusion over the tile; left to a TPU at d = 3,000 Xl is
-    column-major and every tile is sliced, turned by a `copy` and read a
-    third time for its norms (docs/performance.md "Tiled distance core")."""
+    Otherwise tiles are cut with `dynamic_slice` DIRECTLY out of Xl inside
+    the fori_loop, and the ragged tail is one such step either way. Neither
+    `jnp.pad` of the shard nor a `lax.scan` over a reshaped view is safe
+    here: both make XLA materialize a second X-sized buffer (11 GiB at the
+    1M x 3k benchmark shape, measured) — the slice-in-loop form keeps X
+    single-buffered, at the price of one tile-sized buffer written and read
+    back a tile; for an Xl that a TPU keeps column-major (its default at
+    d = 3,000) the slice is also turned by a `copy` before the kernels
+    (docs/performance.md "Tiled distance core")."""
     _note("distance.assign_programs")
     nl, d = Xl.shape
     k = centers.shape[0]
 
-    def step(carry, xw):
+    def step(carry, start, rows, tile_in_place):
         sums, counts, inertia = carry
-        xb, wb = xw
-        s, c, i = assign_accumulate(xb, wb, centers, fast=fast)
-        return (sums + s, counts + c, inertia + i), None
+        s, c, i = assign_accumulate_rows(
+            Xl, wl, centers, x_sq, start, rows, fast=fast, in_place=tile_in_place
+        )
+        return sums + s, counts + c, inertia + i
 
     # under a shard_map the carry varies like the per-shard accumulators
     # (vma typing); the meshless 1-device program has no axis to cast over
@@ -683,15 +788,13 @@ def tile_assign_accumulate(
     )
     batch_rows = min(batch_rows, nl)
     n_full = (nl // batch_rows) * batch_rows
-
-    def tile_body(i, carry):
-        xb = jax.lax.dynamic_slice_in_dim(Xl, i * batch_rows, batch_rows, 0)
-        wb = jax.lax.dynamic_slice_in_dim(wl, i * batch_rows, batch_rows, 0)
-        return step(carry, (xb, wb))[0]
-
-    carry = jax.lax.fori_loop(0, n_full // batch_rows, tile_body, init)
+    carry = jax.lax.fori_loop(
+        0, n_full // batch_rows,
+        lambda i, carry: step(carry, i * batch_rows, batch_rows, in_place),
+        init,
+    )
     if nl - n_full:
-        carry, _ = step(carry, (Xl[n_full:], wl[n_full:]))
+        carry = step(carry, n_full, nl - n_full, False)
     return carry
 
 

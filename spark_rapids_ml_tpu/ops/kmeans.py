@@ -24,12 +24,14 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import telemetry
-from ..parallel.mesh import ROWS_AXIS, x_layout_of
+from ..parallel.mesh import ROWS_AXIS, lies_row_major, x_layout_of
 from .distance import (
     argmin_assign,
     assign_accumulate,
+    assign_accumulate_rows,
     block_plan,
     min_d2_update,
+    row_sq,
     shard_map_check_vma,
     tile_assign_accumulate as _tile_assign_accumulate,
 )
@@ -50,20 +52,30 @@ def _finish_centers(sums, counts, inertia, centers):
 _finish_centers_jit = jax.jit(_finish_centers)
 
 
-@partial(jax.jit, static_argnames=("mesh", "batch_rows", "fast"))
-def _lloyd_step(X, w, centers, *, mesh, batch_rows, fast=False):
+# |x|^2 of every row, made ONCE a fit (X does not change within one): one
+# read of X, and every Lloyd step, the float32 final pass included, slices
+# its tile's 128 KB out of the result
+_row_norms = jax.jit(row_sq)
+
+
+@partial(jax.jit, static_argnames=("mesh", "batch_rows", "fast", "in_place"))
+def _lloyd_step(X, w, centers, x_sq, *, mesh, batch_rows, fast=False, in_place=False):
     """One Lloyd iteration as a TOP-LEVEL XLA program: per-shard tiled
     assignment + accumulation, psum'd (k,d) sums/counts/inertia, center update.
 
-    Kept out of a `lax.while_loop` deliberately: XLA duplicates any array whose
-    consumer sits inside nested loops (the tile scan inside a while body costs
-    +1 full copy of X — 11 GiB at the 1M x 3k benchmark shape, an OOM on one
-    chip). The iteration loop lives on the host instead; each step is one
-    dispatch (~ms) against seconds of compute, and the convergence scalar is a
-    replicated global value so every SPMD rank steps identically."""
+    Kept out of a `lax.while_loop` deliberately: XLA duplicates an array that
+    a SLICE inside nested loops consumes (the tile scan inside a while body
+    costs +1 full copy of X — 11 GiB at the 1M x 3k benchmark shape, an OOM on
+    one chip; an X that the kernels index `in_place` is not duplicated by a
+    loop round them, and whether that holds two loops deep is not tried). The
+    iteration loop lives on the host instead; each step is one dispatch (~ms)
+    against seconds of compute, and the convergence scalar is a replicated
+    global value so every SPMD rank steps identically."""
 
-    def local(Xl, wl):
-        sums, counts, inertia = _tile_assign_accumulate(Xl, wl, centers, batch_rows, fast)
+    def local(Xl, wl, ql):
+        sums, counts, inertia = _tile_assign_accumulate(
+            Xl, wl, centers, ql, batch_rows, fast, in_place
+        )
         sums = jax.lax.psum(sums, ROWS_AXIS)
         counts = jax.lax.psum(counts, ROWS_AXIS)
         inertia = jax.lax.psum(inertia, ROWS_AXIS)
@@ -72,52 +84,63 @@ def _lloyd_step(X, w, centers, *, mesh, batch_rows, fast=False):
     sums, counts, inertia = shard_map(
         local,
         mesh=mesh,
-        in_specs=(P(ROWS_AXIS, None), P(ROWS_AXIS)),
+        in_specs=(P(ROWS_AXIS, None), P(ROWS_AXIS), P(ROWS_AXIS)),
         out_specs=(P(), P(), P()),
         check_vma=shard_map_check_vma(),
-    )(X, w)
+    )(X, w, x_sq)
     return _finish_centers(sums, counts, inertia, centers)
 
 
-@partial(jax.jit, static_argnames=("batch_rows", "fast"))
-def _lloyd_step_fused_1dev(X, w, centers, *, batch_rows, fast=False):
+@partial(jax.jit, static_argnames=("batch_rows", "fast", "in_place"))
+def _lloyd_step_fused_1dev(X, w, centers, x_sq, *, batch_rows, fast=False, in_place=False):
     """One Lloyd iteration as ONE local program (no mesh, no collectives):
     the in-program tile scan of `_tile_assign_accumulate` plus the center
     update. This is the small-dataset single-device path — it must NOT touch
     a Mesh: under multi-process SPMD a 1-device `get_mesh(1)` holds GLOBAL
     device 0, which other ranks cannot address, while per-rank local fits
     (e.g. each rank's ANN coarse quantizer) run on the rank's own default
-    device. The in-program scan may double-buffer X (see _tile_accum_1dev) —
-    affordable below _ONE_DISPATCH_MAX_BYTES, where this path is used."""
-    sums, counts, inertia = _tile_assign_accumulate(X, w, centers, batch_rows, fast)
+    device. Where the tiles are sliced the in-program scan may double-buffer
+    X (see _tile_accum_1dev) — affordable below _ONE_DISPATCH_MAX_BYTES,
+    where this path is used."""
+    sums, counts, inertia = _tile_assign_accumulate(
+        X, w, centers, x_sq, batch_rows, fast, in_place
+    )
     return _finish_centers(sums, counts, inertia, centers)
 
 
-@partial(jax.jit, static_argnames=("size", "fast"), donate_argnums=(3, 4, 5))
-def _tile_accum_1dev(X, w, centers, sums, counts, inertia, start, *, size, fast=False):
-    """Single-device tile accumulation: dynamic_slice at the PROGRAM TOP LEVEL
-    (no in-program loop over X at all). XLA's choice to duplicate a loop-
-    consumed operand is size-dependent — at the 1M x 3k benchmark shape even
-    the fori_loop-of-dynamic_slice form gets a full X copy — so on one device
-    the tile loop lives on the host and the (k,d) accumulators are DONATED
-    device buffers updated in place. The per-tile math is the shared core's
-    fused assign+accumulate (ops/distance.py).
+@partial(jax.jit, static_argnames=("size", "fast", "in_place"), donate_argnums=(4, 5, 6))
+def _tile_accum_1dev(
+    X, w, centers, x_sq, sums, counts, inertia, start, *, size, fast=False, in_place=False
+):
+    """Single-device tile accumulation: one tile a program, at the PROGRAM
+    TOP LEVEL (no in-program loop over X at all). XLA's choice to duplicate
+    an operand that a slice inside a loop consumes is size-dependent — at
+    the 1M x 3k benchmark shape even the fori_loop-of-dynamic_slice form
+    gets a full X copy — so on one device the tile loop lives on the host
+    and the (k,d) accumulators are DONATED device buffers updated in place.
+    The per-tile math is the shared core's fused assign+accumulate
+    (ops/distance.py `assign_accumulate_rows`).
 
     The program is compiled for the layout the committed X has, which the
     placement chose (parallel/mesh.py `make_global_rows`; KMeans asks for
-    row-major through `_x_layout`): row-major, the tile reaches the kernels
-    through one slice-and-norms fusion; in a TPU's default for [n, 3000]
-    (column-major) it is sliced, turned by a tile-sized `copy` and read again
-    for its norms, 12 times an iteration at the benchmark shape
-    (tests/test_distance.py pins both texts)."""
-    xb = jax.lax.dynamic_slice_in_dim(X, start, size, 0)
-    wb = jax.lax.dynamic_slice_in_dim(w, start, size, 0)
-    s, c, i = assign_accumulate(xb, wb, centers, fast=fast)
+    row-major through `_x_layout`), and `kmeans_fit` says `in_place` where
+    that is row-major: the kernels then fetch their row blocks out of X by
+    `start`, and the compiled program holds no value of the tile's shape and
+    no copy (so the host loop is no longer what keeps X single-buffered:
+    folding it into one program is ROADMAP D3). Sliced, a row-major tile is
+    written out once by the `dynamic_slice`; in a TPU's default for
+    [n, 3000] (column-major) it is sliced and turned by a tile-sized
+    `copy`, 12 times an iteration at the benchmark shape
+    (tests/test_distance.py pins the three texts)."""
+    s, c, i = assign_accumulate_rows(
+        X, w, centers, x_sq, start, size, fast=fast, in_place=in_place
+    )
     return sums + s, counts + c, inertia + i
 
 
-def _lloyd_step_1dev(X, w, centers, batch_rows, fast=False):
-    """Host-tiled Lloyd iteration for a 1-device mesh (see _tile_accum_1dev)."""
+def _lloyd_step_1dev(X, w, centers, x_sq, batch_rows, fast=False, in_place=False):
+    """Host-tiled Lloyd iteration for a 1-device mesh (see _tile_accum_1dev);
+    the ragged tail is sliced whatever `in_place` says of the full tiles."""
     import numpy as np
 
     n, d = X.shape
@@ -130,12 +153,12 @@ def _lloyd_step_1dev(X, w, centers, batch_rows, fast=False):
     n_full = (n // batch_rows) * batch_rows
     for start in range(0, n_full, batch_rows):
         sums, counts, inertia = _tile_accum_1dev(
-            X, w, centers, sums, counts, inertia, np.int32(start),
-            size=batch_rows, fast=fast,
+            X, w, centers, x_sq, sums, counts, inertia, np.int32(start),
+            size=batch_rows, fast=fast, in_place=in_place,
         )
     if n - n_full:
         sums, counts, inertia = _tile_accum_1dev(
-            X, w, centers, sums, counts, inertia, np.int32(n_full),
+            X, w, centers, x_sq, sums, counts, inertia, np.int32(n_full),
             size=n - n_full, fast=fast,
         )
     return _finish_centers_jit(sums, counts, inertia, centers)
@@ -144,8 +167,10 @@ def _lloyd_step_1dev(X, w, centers, batch_rows, fast=False):
 # Below this size a 1-device fit takes the SAME one-dispatch-per-iteration
 # program as the mesh path (fori_loop of tiles inside one program). The
 # host-tiled `_lloyd_step_1dev` exists to keep the big-X regime
-# single-buffered (XLA copies a loop-consumed X at the 1M×3k protocol
-# shape), but it costs one dispatch PER TILE; below this cap the in-program
+# single-buffered (XLA copies an X that a slice inside a loop consumes at
+# the 1M×3k protocol shape; not one that the kernels index `in_place`, so
+# for a row-major X the split has lost its reason: ROADMAP D3), but it costs
+# one dispatch PER TILE; below this cap the in-program
 # X copy is affordable and one dispatch per iteration is the simpler form.
 # The iteration loop itself stays on the host (see `_lloyd_step`). Where the
 # cap belongs on the current machine is not measured (ROADMAP D12).
@@ -218,9 +243,10 @@ def kmeans_fit(
     cluster_centers_ [k,d], inertia_, n_iter_.
 
     Two once-per-fit telemetry spans (children of the caller's `fit/solve`):
-    `loop` — dispatching the iterations up to the last shift fetch, with the
-    path taken, the block plan and the layout X has on the device
-    (`x_layout`) as attributes — and `finish` — the final
+    `loop` — the row norms' one program and the iterations, dispatched up to
+    the last shift fetch, with the path taken, the block plan, the layout X
+    has on the device (`x_layout`) and how the kernels get at a tile of it
+    (`tile_access`: `in_place` or `sliced`) as attributes — and `finish` — the final
     inertia pass and, through `to_host` (the estimator's conversion of the
     returned state, run inside the span), the model's attributes brought to
     the host. Neither adds a device synchronisation.
@@ -262,12 +288,36 @@ def kmeans_fit(
     one_dev = mesh.devices.size == 1
     host_tiled = one_dev and X.size * X.dtype.itemsize > _ONE_DISPATCH_MAX_BYTES
 
-    def step(c, f):
+    rows_dev = max(1, -(-X.shape[0] // mesh.devices.size))
+    tile = min(batch_rows, rows_dev)
+    # resolved OUTSIDE any trace: the plan the tile programs will take, per
+    # precision mode (None = the jnp form; the float32 final pass plans its own
+    # blocks), which also settles `distance.kernel_mode()` eagerly
+    plans = {
+        f: block_plan(tile, centers.shape[0], X.shape[1], X.dtype, f) for f in {fast, False}
+    }
+    block_rows, block_k = plans[fast] or (None, None)
+    # ... and how the kernels get at a tile: where X lies row-major on its
+    # device (read here from the committed array; no trace sees a layout) and
+    # the tile is whole row blocks they index X itself, else the tile is sliced
+    # out first. A column-major X (the TPU's default at d = 3,000: every
+    # caller that did not place it row-major) must stay sliced: handed whole
+    # to a Mosaic operand it would be turned whole in every tile program.
+    row_major = lies_row_major(X)
+    in_place = {
+        f: row_major and plan is not None and tile % plan[0] == 0 for f, plan in plans.items()
+    }
+
+    def step(c, x_sq, f):
         if host_tiled:
-            return _lloyd_step_1dev(X, w, c, batch_rows, fast=f)
+            return _lloyd_step_1dev(X, w, c, x_sq, batch_rows, fast=f, in_place=in_place[f])
         if one_dev:  # meshless local program (see _lloyd_step_fused_1dev)
-            return _lloyd_step_fused_1dev(X, w, c, batch_rows=batch_rows, fast=f)
-        return _lloyd_step(X, w, c, mesh=mesh, batch_rows=batch_rows, fast=f)
+            return _lloyd_step_fused_1dev(
+                X, w, c, x_sq, batch_rows=batch_rows, fast=f, in_place=in_place[f]
+            )
+        return _lloyd_step(
+            X, w, c, x_sq, mesh=mesh, batch_rows=batch_rows, fast=f, in_place=in_place[f]
+        )
 
     # convergence is tested one iteration LATE: fetching the shift scalar
     # synchronizes with the device; checking the PREVIOUS iteration's shift
@@ -322,13 +372,6 @@ def kmeans_fit(
             n_iter = int(saved.iteration)
             ps = saved.state.get("prev_shift")
             prev_shift = None if ps is None else float(ps)
-    rows_dev = max(1, -(-X.shape[0] // mesh.devices.size))
-    tile = min(batch_rows, rows_dev)
-    # resolved OUTSIDE any trace: the plan the tile programs will take (None
-    # = the jnp form), which also settles `distance.kernel_mode()` eagerly
-    block_rows, block_k = block_plan(
-        tile, centers.shape[0], X.shape[1], X.dtype, fast
-    ) or (None, None)
     with telemetry.span(
         "loop",
         solver_path="host_tiled" if host_tiled else "fused_1dev" if one_dev else "shard_map",
@@ -336,10 +379,12 @@ def kmeans_fit(
         block_rows=block_rows,
         block_k=block_k,
         x_layout=x_layout_of(X),
+        tile_access="in_place" if in_place[fast] else "sliced",
     ):
+        x_sq = _row_norms(X)  # one asynchronous program a fit; nothing waits for it here
         while n_iter < max_iter:
             step_in = centers
-            centers, inertia, shift = step(centers, fast)
+            centers, inertia, shift = step(centers, x_sq, fast)
             n_iter += 1
             if prev_shift is not None:
                 # the deferred shift fetch is Lloyd's per-iteration sync — the
@@ -410,7 +455,7 @@ def kmeans_fit(
         # must not leak to them either — return NaN so accidental consumption is
         # loud instead of subtly wrong.
         if final_inertia:
-            _, inertia, _ = step(centers, False)
+            _, inertia, _ = step(centers, x_sq, False)
             inertia_host = float(inertia)
             if not math.isfinite(inertia_host):
                 # the loop's deferred check trails by one fetch: a divergence on

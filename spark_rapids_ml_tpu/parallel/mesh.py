@@ -516,13 +516,21 @@ def row_major_format(shape, dtype, device):
     return Format(Layout(major_to_minor=_ROW_MAJOR), SingleDeviceSharding(device))
 
 
+def lies_row_major(x) -> bool:
+    """Whether the 2-D device array `x` lies row-major on its devices, by
+    anyone's choice: a placement below, or the device's own (CPU; a TPU at d
+    a multiple of 128). Read from the committed array on the host: inside a
+    trace no layout is visible."""
+    layout = getattr(getattr(x, "format", None), "layout", None)
+    return layout is not None and x.ndim == 2 and tuple(layout.major_to_minor) == _ROW_MAJOR
+
+
 def x_layout_of(x) -> str:
     """`X_ROW_MAJOR` where the 2-D device array `x` lies row-major although
     its device would have chosen otherwise for a shard of that shape — i.e.
     where a placement below engaged — and `X_DEFAULT` for every other array
     (on CPU, and at d a multiple of 128, row-major IS the default)."""
-    layout = getattr(getattr(x, "format", None), "layout", None)
-    if layout is None or x.ndim != 2 or tuple(layout.major_to_minor) != _ROW_MAJOR:
+    if not lies_row_major(x):
         return X_DEFAULT
     device = next(iter(x.sharding.addressable_devices))
     if _default_is_row_major(x.sharding.shard_shape(x.shape), x.dtype, device):

@@ -102,9 +102,11 @@ def test_kmeans_fit_records_init_loop_finish_under_solve(tele, rng, workers, pat
     assert [p for p in paths if p.startswith("fit/solve/")] == SOLVE_CHILDREN  # once per fit
     by_path = {s["path"]: _attrs(s) for s in spans}
     assert by_path["fit/solve/init"] == {"init_mode": init_mode}
-    # 600 rows in one tile per device; the jnp form (CPU) has no block plan, and no layout but the default
+    # 600 rows in one tile per device; the jnp form (CPU) has no block plan, no layout but the default,
+    # and no kernel to read a tile where it lies
     assert by_path["fit/solve/loop"] == {
         "solver_path": path, "tiles_per_iter": 1, "block_rows": None, "block_k": None, "x_layout": "default",
+        "tile_access": "sliced",
     }
     assert by_path["fit/solve/finish"] == {}
     wall = {s["path"]: s["wall_s"] for s in spans}
@@ -120,6 +122,7 @@ def test_kmeans_loop_span_reports_the_block_plan_on_the_kernel_path(tele, rng, m
     KMeans(k=5, maxIter=2, initMode="random", seed=3, num_workers=1).setFeaturesCol("features").fit(df)
     loop = next(s for s in tele.delta(mark)["spans"] if s["path"] == "fit/solve/loop")
     assert (loop["block_rows"], loop["block_k"]) == distance.plan_blocks(300, 5, 16, np.float32, True)
+    assert loop["tile_access"] == "in_place"  # one 300-row tile in one 300-row block
 
 
 def test_logistic_fit_records_init_loop_finish_under_solve(tele, rng):

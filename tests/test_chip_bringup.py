@@ -361,7 +361,9 @@ def test_distance_kernels_compile_for_a_v5e_ahead_of_time(monkeypatch):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
 
         X, C = sharded((262_144, 3000), rows), sharded((1000, 3000), rep)
-        _lloyd_step.lower(X, sharded((262_144,), rows), C, mesh=mesh, batch_rows=32768, fast=True)
+        w = sharded((262_144,), rows)
+        for in_place in (False, True):  # the tile sliced out of X, and indexed where it lies
+            _lloyd_step.lower(X, w, C, w, mesh=mesh, batch_rows=32768, fast=True, in_place=in_place)
         kmeans_predict.lower(X, C, mesh=mesh)
         _exact_knn_sharded.lower(
             X, sharded((262_144,), rows, jnp.bool_), sharded((256, 3000), rep), mesh=mesh, k=64

@@ -117,6 +117,81 @@ def test_argmin_assign_ragged_tiles_match_bruteforce(jnp_mode):
     assert a.dtype == jnp.int32
 
 
+# --------------------------------------------- a tile read where it lies -----
+#
+# `assign_accumulate(..., tile=(start, rows))`: the kernels fetch the tile's
+# row blocks out of the whole X by a prefetched block offset instead of being
+# handed a slice. Same grid, same blocks, same body: every output is the
+# sliced form's bit for bit.
+
+
+def _tile_case(start, fast, n=96, rows=32):
+    X, C, w = _data(n, 5, 9, np.float32, seed=7 + start + int(fast))
+    w = w.at[start + 3].set(0.0).at[start + rows - 1].set(0.0)  # padding rows inside the tile
+    return X, C, w, distance.row_sq(X), slice(start, start + rows)
+
+
+@pytest.mark.parametrize("fast", [False, True])
+@pytest.mark.parametrize("start", [0, 32, 64])  # the first, a middle and the last tile of 96 rows
+def test_tile_read_in_place_is_bit_identical_to_the_slice(interpret_mode, fast, start):
+    X, C, w, x_sq, rows = _tile_case(start, fast)
+    blocks = dict(fast=fast, block_rows=8, block_k=4)
+    sliced = distance.assign_accumulate(X[rows], w[rows], C, **blocks)
+    whole = distance.assign_accumulate(
+        X, w[rows], C, x_sq=x_sq[rows], tile=(jnp.int32(start), 32), **blocks
+    )
+    for a, b in zip(sliced, whole):  # sums, counts, inertia
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # minima and assignments, from the argmin kernel itself
+    cp, _ = distance._pad_rows_multiple(C, 4)
+    csq = jnp.pad(distance._c_sq(C), (0, cp.shape[0] - C.shape[0]), constant_values=jnp.inf)
+    kernel = dict(block_rows=8, block_k=4, fast=fast, interpret=True)
+    mind, best = distance._pl_argmin(X[rows], cp, csq, **kernel)
+    mind_w, best_w = distance._pl_argmin(X, cp, csq, tile=(jnp.int32(start), 32), **kernel)
+    np.testing.assert_array_equal(np.asarray(mind), np.asarray(mind_w))
+    np.testing.assert_array_equal(np.asarray(best), np.asarray(best_w))
+    ref = jnp.argmin(jnp.sum(C * C, 1)[None, :] - 2.0 * distance._mm(X[rows], C.T, fast), axis=1)
+    assert (np.asarray(best_w) == np.asarray(ref)).all()
+
+
+@pytest.mark.parametrize("fast", [False, True])
+def test_traced_tile_offset_matches_the_slice(interpret_mode, fast):
+    # the host-tiled step's form: ONE jitted program, the offset an argument
+    X, C, w, x_sq, _ = _tile_case(0, fast)
+    program = jax.jit(distance.assign_accumulate_rows, static_argnames=("rows", "fast", "in_place"))
+    for start in (0, 32, 64):
+        args = (X, w, C, x_sq, np.int32(start))
+        sliced = program(*args, rows=32, fast=fast, in_place=False)
+        whole = program(*args, rows=32, fast=fast, in_place=True)
+        for a, b in zip(sliced, whole):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("n", [96, 100, 131])
+def test_shard_scan_in_place_keeps_the_ragged_tail_sliced(interpret_mode, n):
+    # 32-row tiles read in place, then a tail of 0, 4 or 3 rows (no multiple of
+    # the 32-row blocks the plan takes): sliced, and the whole scan agrees with
+    # the all-sliced scan bit for bit and with brute force
+    X, C, w, x_sq, _ = _tile_case(0, False, n=n)
+    sliced = distance.tile_assign_accumulate(X, w, C, x_sq, 32)
+    whole = distance.tile_assign_accumulate(X, w, C, x_sq, 32, in_place=True)
+    for a, b in zip(sliced, whole):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(whole, _fallback_assign_accumulate(X, w, C)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=1e-5)
+
+
+def test_a_tile_that_cannot_be_read_in_place_is_refused(interpret_mode):
+    X, C, w, x_sq, rows = _tile_case(0, False)
+    with pytest.raises(ValueError, match="slice the tile"):  # 30 rows are no whole 8-row blocks
+        distance.assign_accumulate(X, w[:30], C, x_sq=x_sq[:30], tile=(0, 30), block_rows=8, block_k=4)
+    with pytest.raises(ValueError, match="slice the tile"):  # no row norms to go with it
+        distance.assign_accumulate(X, w[rows], C, tile=(0, 32), block_rows=8, block_k=4)
+    distance._MODE = "jnp"  # (the fixture restores the mode)
+    with pytest.raises(ValueError, match="slice the tile"):  # no kernels
+        distance.assign_accumulate(X, w[rows], C, x_sq=x_sq[rows], tile=(0, 32))
+
+
 # ------------------------------------------------------------ top-k parity --
 
 
@@ -342,17 +417,20 @@ def test_compiled_kernels_carry_their_names_on_one_chip_and_under_shard_map(monk
         return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(dev))
 
     with mesh_mod.chip_scope([dev]), jax.enable_x64(False):
-        acc = (one((1024, 256)), one((1024,)), one((40, 256)), one((40, 256)), one((40,)), one(()), one((), jnp.int32))
+        acc = (one((1024, 256)), one((1024,)), one((40, 256)), one((1024,)),
+               one((40, 256)), one((40,)), one(()), one((), jnp.int32))
         for fast, mode in ((True, "bf16"), (False, "f32")):
-            tile = _tile_accum_1dev.lower(*acc, size=512, fast=fast).compile()
-            assert kernels(tile) == [f"srml_accumulate_{mode}", f"srml_argmin_{mode}"]
+            for in_place in (False, True):  # the kernels keep their names whichever way they get at the tile
+                tile = _tile_accum_1dev.lower(*acc, size=512, fast=fast, in_place=in_place).compile()
+                assert kernels(tile) == [f"srml_accumulate_{mode}", f"srml_argmin_{mode}"]
         assert kernels(kmeans_predict.lower(one((1024, 256)), one((40, 256))).compile()) == ["srml_argmin_f32"]
         mesh = Mesh(np.asarray(topo.devices), (mesh_mod.ROWS_AXIS,))
         rows, rep = P(mesh_mod.ROWS_AXIS), P()
         sh = lambda shape, spec: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=NamedSharding(mesh, spec))
-        step = _lloyd_step.lower(sh((4096, 256), rows), sh((4096,), rows), sh((40, 256), rep),
-                                 mesh=mesh, batch_rows=512, fast=True).compile()
-        assert kernels(step) == ["srml_accumulate_bf16", "srml_argmin_bf16"]
+        for in_place in (False, True):
+            step = _lloyd_step.lower(sh((4096, 256), rows), sh((4096,), rows), sh((40, 256), rep), sh((4096,), rows),
+                                     mesh=mesh, batch_rows=512, fast=True, in_place=in_place).compile()
+            assert kernels(step) == ["srml_accumulate_bf16", "srml_argmin_bf16"]
 
 
 # ------------------------------------------- X's layout under the kernels -----
@@ -380,12 +458,13 @@ def v5e():
         env.undo()
 
 
-def _lloyd_program_text(v5e, program, d, x_layout):
+def _lloyd_program_text(v5e, program, d, x_layout, in_place=False, fast=True):
     """Compiled text of one Lloyd program at the benchmark cells' shapes
     (`kmeans-p3k`: 393,216 rows on one chip through the host-tiled
     `_tile_accum_1dev`; `kmeans-p3k-host4`: 1,048,576 rows over four chips
     through `_lloyd_step`), with X's struct in the layout `make_global_rows`
-    gives it for `x_layout`, and the tile's and one device's X shape."""
+    gives it for `x_layout` and the tile access `kmeans_fit` would state for
+    it, and the tile's and one device's X shape."""
     from jax.experimental.layout import Format
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
@@ -403,8 +482,8 @@ def _lloyd_program_text(v5e, program, d, x_layout):
             one = SingleDeviceSharding(devices[0])
             s = lambda shape, dtype=jnp.float32, sharding=one: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
             lowered = _tile_accum_1dev.lower(
-                s((rows_dev, d), sharding=placed or one), s((rows_dev,)), s((k, d)),
-                s((k, d)), s((k,)), s(()), s((), jnp.int32), size=tile, fast=True,
+                s((rows_dev, d), sharding=placed or one), s((rows_dev,)), s((k, d)), s((rows_dev,)),
+                s((k, d)), s((k,)), s(()), s((), jnp.int32), size=tile, fast=fast, in_place=in_place,
             )
         else:
             mesh = Mesh(np.asarray(devices), (mesh_mod.ROWS_AXIS,))
@@ -413,7 +492,7 @@ def _lloyd_program_text(v5e, program, d, x_layout):
             s = lambda shape, sharding: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
             lowered = _lloyd_step.lower(
                 s((4 * rows_dev, d), x_sharding), s((4 * rows_dev,), rows), s((k, d), rep),
-                mesh=mesh, batch_rows=tile, fast=True,
+                s((4 * rows_dev,), rows), mesh=mesh, batch_rows=tile, fast=fast, in_place=in_place,
             )
         return lowered.compile().as_text(), (tile, d), (rows_dev, d)
 
@@ -432,21 +511,48 @@ def _x_entry_layout(text, x_shape):
     return m.group(1)
 
 
+def _kernel_calls(text):
+    """(kernel name, operand names) of every Mosaic custom call in `text`."""
+    import re
+
+    return re.findall(r"%(srml_\w+?)(?:\.\d+)? = [^\n]*? custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", text)
+
+
 @pytest.mark.parametrize("program", ["tile_1dev", "lloyd_step_4chips"])
 @pytest.mark.parametrize("d", [3000, 3072])
 def test_row_major_x_reaches_the_kernels_without_a_tile_copy(v5e, monkeypatch, program, d):
-    """Placed as KMeans asks, X enters the program row-major ({1,0}) and no
-    `copy` has the tile's or X's shape: the slice and the row norms are one
-    fusion. The control: at d = 3,000 the default layout is column-major and
-    its lowering still turns every tile (so this test reads the right text);
-    at d = 3,072 row-major is the default and nothing has to be asked."""
+    """Placed as KMeans asks, X enters the program row-major ({1,0}) and the
+    kernels index it where it lies (`tile_access` `in_place`): no value of the
+    tile's shape exists in the compiled program, bf16 loop step and float32
+    final pass alike, no `copy` has X's shape, and both custom calls take as
+    their X operand a value of one device's whole X. Sliced (what a ragged
+    tile keeps), the row-major tile is written out once and still never
+    turned. The control: at d = 3,000 the default layout is column-major and
+    its sliced lowering turns every tile (so this test reads the right text)
+    and never copies X whole; at d = 3,072 row-major is the default and
+    nothing has to be asked."""
+    import re
+
     from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
 
     monkeypatch.setattr(distance, "_MODE", "pallas")
-    text, tile_shape, x_shape = _lloyd_program_text(v5e, program, d, mesh_mod.X_ROW_MAJOR)
-    assert _x_entry_layout(text, x_shape) == "1,0"
-    assert not _copied_shapes(text) & {tile_shape, x_shape}
-    assert "srml_argmin_bf16" in text and "srml_accumulate_bf16" in text
+    for fast, mode in ((True, "bf16"), (False, "f32")):
+        text, tile_shape, x_shape = _lloyd_program_text(v5e, program, d, mesh_mod.X_ROW_MAJOR, in_place=True, fast=fast)
+        assert _x_entry_layout(text, x_shape) == "1,0"
+        assert "f32[%d,%d]" % tile_shape not in text
+        assert not _copied_shapes(text) & {tile_shape, x_shape}
+        calls = dict(_kernel_calls(text))
+        assert sorted(calls) == [f"srml_accumulate_{mode}", f"srml_argmin_{mode}"]
+        for operands in calls.values():
+            # (prefetched block offset, X, ...): X is the program's parameter on
+            # one chip, the tile loop's pass-through of it under shard_map
+            x_operand = operands.split(", ")[1]
+            assert re.search(r"%s = f32\[%d,%d\]\{1,0[^}]*\} (parameter|get-tuple-element)\(" % ((re.escape(x_operand),) + x_shape), text), x_operand
+    sliced, _, _ = _lloyd_program_text(v5e, program, d, mesh_mod.X_ROW_MAJOR)
+    assert _x_entry_layout(sliced, x_shape) == "1,0"
+    assert "f32[%d,%d]" % tile_shape in sliced
+    assert not _copied_shapes(sliced) & {tile_shape, x_shape}
+    assert "srml_argmin_bf16" in sliced and "srml_accumulate_bf16" in sliced
     asks = mesh_mod.row_major_format(x_shape, np.float32, v5e.devices[0]) is not None
     assert asks == (d % 128 != 0)
     if asks:

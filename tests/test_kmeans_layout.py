@@ -240,6 +240,117 @@ def test_centres_do_not_depend_on_who_placed_x(forced_row_major, tele, monkeypat
     assert asked.inertia_ == plain.inertia_ and asked.n_iter_ == plain.n_iter_
 
 
+# -------------------------------------------- how the kernels get at a tile -----
+#
+# Where X lies row-major, the kernels are on and a tile is whole row blocks,
+# `kmeans_fit` has the kernels index X where it lies (`tile_access`
+# `in_place`: no tile of X is copied); anything else slices the tile out first.
+
+
+def _loop_span(model):
+    (loop,) = [s for s in model._fit_metrics["spans"] if s["path"].endswith("solve/loop")]
+    return loop
+
+
+@pytest.mark.parametrize("solver_path, num_workers", [("fused_1dev", 1), ("host_tiled", 1), ("shard_map", 4)])
+def test_centres_do_not_depend_on_how_the_kernels_get_at_a_tile(tele, monkeypatch, rng, solver_path, num_workers):
+    """Kernels through the interpreter, 10 iterations, four 64-row tiles a
+    device: read in place, the fit gives the sliced fit's centres bit for
+    bit on every Lloyd path (the row norms alone are summed elsewhere)."""
+    from spark_rapids_ml_tpu.ops import kmeans as kmeans_ops
+
+    monkeypatch.setattr(distance, "_MODE", "interpret")
+    if solver_path == "host_tiled":
+        monkeypatch.setattr(kmeans_ops, "_ONE_DISPATCH_MAX_BYTES", 0)
+    df = _df(rng, n=256 * num_workers, d=9)
+
+    def fit():
+        est = KMeans(k=5, maxIter=10, tol=0.0, seed=3, initMode="random",
+                     num_workers=num_workers, max_samples_per_batch=64)
+        return est.setFeaturesCol("features").fit(df)
+
+    whole = fit()
+    assert _loop_span(whole)["tile_access"] == "in_place"
+    assert _loop_span(whole)["solver_path"] == solver_path and _loop_span(whole)["tiles_per_iter"] == 4
+    monkeypatch.setattr(kmeans_ops, "lies_row_major", lambda x: False)  # what a column-major X reads
+    sliced = fit()
+    assert _loop_span(sliced)["tile_access"] == "sliced"
+    np.testing.assert_array_equal(whole.cluster_centers_, sliced.cluster_centers_)
+    assert whole.n_iter_ == sliced.n_iter_ == 10
+    assert whole.inertia_ == pytest.approx(sliced.inertia_, rel=1e-6)
+
+
+@pytest.mark.parametrize("mode, rows, batch, expected", [
+    ("interpret", 256, 64, "in_place"),
+    ("interpret", 256, 32768, "in_place"),  # one tile: all of X
+    ("interpret", 700, 32768, "sliced"),  # 700 rows are no whole 512-row blocks
+    ("interpret", 250, 64, "in_place"),  # full tiles in place, the 58-row tail sliced
+    ("jnp", 256, 64, "sliced"),
+])
+def test_loop_span_says_how_the_kernels_get_at_a_tile(tele, monkeypatch, rng, mode, rows, batch, expected):
+    monkeypatch.setattr(distance, "_MODE", mode)
+    est = KMeans(k=3, maxIter=2, seed=1, initMode="random", num_workers=1, max_samples_per_batch=batch)
+    model = est.setFeaturesCol("features").fit(_df(rng, n=rows, d=6))
+    assert _loop_span(model)["tile_access"] == expected
+    assert _loop_span(model)["x_layout"] == "default"  # row-major by the CPU's own choice
+    assert np.isfinite(model.inertia_)
+
+
+def test_row_norms_are_made_once_a_fit(tele, monkeypatch, rng):
+    """One program a fit computes |x|^2, whatever the iteration count, and
+    the step programs take its result (the float32 final pass included)."""
+    from spark_rapids_ml_tpu.ops import kmeans as kmeans_ops
+
+    calls = []
+    real = kmeans_ops._row_norms
+
+    def counting(x):
+        calls.append(x.shape)
+        return real(x)
+
+    monkeypatch.setattr(kmeans_ops, "_row_norms", counting)
+    df = _df(rng, n=200, d=6)
+    for iters in (1, 7):
+        KMeans(k=3, maxIter=iters, tol=0.0, seed=1, initMode="random", num_workers=1).setFeaturesCol("features").fit(df)
+    assert calls == [(200, 6), (200, 6)]
+
+
+def test_lies_row_major_reads_the_committed_layout(rng):
+    X = jax.device_put(rng.normal(size=(64, 9)).astype(np.float32), jax.devices()[0])
+    assert mesh_mod.lies_row_major(X)  # this backend's own choice
+    assert not mesh_mod.lies_row_major(X[0])  # not a 2-D block
+    assert not mesh_mod.lies_row_major(np.asarray(X))  # not on a device
+    Xs, _, _ = mesh_mod.make_global_rows(get_mesh(4), np.asarray(X))
+    assert mesh_mod.lies_row_major(Xs)
+    assert not mesh_mod.lies_row_major(_column_major(np.asarray(X)))
+
+
+def _column_major(x):
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    return jax.device_put(x, Format(Layout(major_to_minor=(1, 0)), SingleDeviceSharding(jax.devices()[0])))
+
+
+def test_a_column_major_x_keeps_its_tiles_sliced(tele, monkeypatch, rng):
+    """What a TPU holds at d = 3,000 when nobody asked for row-major (the ANN
+    coarse quantizer's local fits): `kmeans_fit` reads the layout off the
+    array, slices, and gives the row-major fit's centres."""
+    from spark_rapids_ml_tpu.ops.kmeans import kmeans_fit
+
+    monkeypatch.setattr(distance, "_MODE", "interpret")
+    x = rng.normal(size=(256, 9)).astype(np.float32)
+    w = jax.numpy.ones((256,), np.float32)
+    centres = {}
+    for name, X in (("in_place", jax.device_put(x, jax.devices()[0])), ("sliced", _column_major(x))):
+        mark = tele.mark()
+        state = kmeans_fit(X, w, x[:4], mesh=get_mesh(1), max_iter=5, tol=0.0, batch_rows=64)
+        (loop,) = [s for s in tele.delta(mark)["spans"] if s["path"].endswith("loop")]
+        assert loop["tile_access"] == name
+        centres[name] = np.asarray(state["cluster_centers_"])
+    np.testing.assert_array_equal(centres["in_place"], centres["sliced"])
+
+
 # ------------------------------------------- the persistent compile cache -----
 
 
