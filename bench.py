@@ -250,10 +250,9 @@ def bench_kmeans(X, w, mesh) -> float:
 def bench_kmeans_bf16(X, w, mesh) -> float:
     """The solver_precision="bf16" k-means lane, measured exactly as a user
     gets it: one-pass bf16-compute/f32-accumulate assignment + accumulation
-    (distance-core fast path, autotuned block plan on TPU), final inertia at
-    full precision — no ambient matmul-precision override. Distinct from the
-    `kmeans` lane, which wraps its fit in the estimator's 3-pass-bf16
-    dtype_scope policy."""
+    (distance-core fast path), final inertia at full precision — no ambient
+    matmul-precision override. Distinct from the `kmeans` lane, which wraps
+    its fit in the estimator's 3-pass-bf16 dtype_scope policy."""
     import jax
 
     from spark_rapids_ml_tpu.ops.kmeans import kmeans_fit
@@ -753,17 +752,15 @@ def run_lanes(device: dict) -> int:
     # aggregates) embedded in the BENCH JSON line
     telemetry.record_device_memory()
     snap = telemetry.snapshot()
-    # precision provenance: which distance kernel actually ran, the session's
-    # solver_precision default, and the autotuner's hit/miss/measure counts —
-    # embedded so every BENCH record is interpretable without the stderr log
+    # precision provenance: which distance kernel actually ran and the
+    # session's solver_precision default — embedded so every BENCH record is
+    # interpretable without the stderr log
     from spark_rapids_ml_tpu.core import config as _srml_config
-    from spark_rapids_ml_tpu.ops import autotune as _autotune
     from spark_rapids_ml_tpu.ops.distance import kernel_mode as _kernel_mode
 
     snap["precision"] = {
         "distance_kernel_mode": _kernel_mode(),
         "solver_precision": _srml_config["solver_precision"],
-        "autotune": _autotune.stats(),
     }
     emit(results, snap, latency_lanes, ops_lanes, device=device, failed=failed)
     return 1 if failed else 0

@@ -185,16 +185,6 @@ def render(
             f"entr(ies), {comp.get('misses', 0)} miss(es) totalling "
             f"{comp.get('wall_s', 0.0):.3f}s, {comp.get('hits', 0)} hit(s)"
         )
-    tune = report.get("autotune") or {}
-    if tune.get("measurements") or tune.get("hits") or tune.get("misses"):
-        path = tune.get("table_path") or "in-memory"
-        lines.append(
-            f"autotune: {tune.get('hits', 0)} hit(s) / "
-            f"{tune.get('misses', 0)} miss(es), "
-            f"{tune.get('measurements', 0)} measurement(s), "
-            f"{tune.get('table_errors', 0)} table error(s), "
-            f"{tune.get('entries', 0)} table entr(ies) @ {path}"
-        )
     return "\n".join(lines)
 
 
@@ -363,7 +353,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         eff_doc = {
             "t": report.get("t"),
             "efficiency": report.get("efficiency") or {},
-            "autotune": report.get("autotune") or {},
         }
         with open(args.write_efficiency, "w") as f:
             json.dump(eff_doc, f, indent=2, default=str)

@@ -16,10 +16,8 @@
 #   * a `time.perf_counter` reference in a function whose IMMEDIATE body
 #     also references `block_until_ready` — the sync-then-clock device-
 #     timing idiom. Scoped to the immediate body (nested defs excluded) so
-#     timing a closure that syncs internally (the autotuner's measurement
-#     timer, already `# telemetry-ok`-waived for the bare-perf-counter
-#     rule) does not double-report; the PerfCounterRule still covers plain
-#     perf_counter use.
+#     timing a closure that syncs internally does not double-report; the
+#     PerfCounterRule still covers plain perf_counter use.
 #
 # Waiver: `# profiler-ok: <reason>`. Baseline: EMPTY — the tree is clean at
 # introduction and stays clean.

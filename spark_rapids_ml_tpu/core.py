@@ -129,12 +129,12 @@ config: Dict[str, Any] = {
     # information bounds it).
     "stream_chunk_rows": 0,
     # --- multi-fit execution engine (docs/performance.md) ----------------
-    # XLA persistent compilation cache directory (the autotune table lives
-    # beside it): compiled programs survive process restarts — a cold
-    # d=3000 PCA compile alone is minutes. Where JAX_COMPILATION_CACHE_DIR
-    # is set, that directory is used and no other is ever configured;
-    # otherwise ONE fixed, git-ignored directory in the checkout — the path
-    # is part of the cache key, so it must not move between processes.
+    # XLA persistent compilation cache directory: compiled programs survive
+    # process restarts — a cold d=3000 PCA compile alone is minutes. Where
+    # JAX_COMPILATION_CACHE_DIR is set, that directory is used and no other
+    # is ever configured; otherwise ONE fixed, git-ignored directory in the
+    # checkout — the path is part of the cache key, so it must not move
+    # between processes.
     "compilation_cache_dir": os.environ.get("JAX_COMPILATION_CACHE_DIR")
     or _DEFAULT_COMPILE_CACHE_DIR,
     # smallest rung of the transform bucket ladder: serving batches pad up a
@@ -257,18 +257,6 @@ config: Dict[str, Any] = {
     # both modes. Per-estimator override via the `solver_precision` solver
     # param; seeded from SRML_SOLVER_PRECISION.
     "solver_precision": os.environ.get("SRML_SOLVER_PRECISION") or "f32",
-    # --- measured kernel autotuner (ops/autotune.py) ---------------------
-    # on first TPU contact per (shape-class, dtype, fast-flag) the Pallas
-    # distance-core block planner times a small (block_rows, block_k)
-    # candidate grid on-device and persists the winner as JSON beside the
-    # XLA compile cache (compilation_cache_dir). SRML_AUTOTUNE=0 disables;
-    # off-TPU (or cold-start) the static VMEM-fit heuristic is used, so
-    # CPU/CI behavior is unchanged.
-    "autotune_enabled": os.environ.get("SRML_AUTOTUNE", "1")
-    not in ("", "0", "false", "off"),
-    # timing repeats per candidate tiling when the autotuner measures; the
-    # minimum over repeats is scored (robust to one-off scheduling noise)
-    "autotune_repeats": 3,
     # --- efficiency attribution plane (ops_plane/efficiency.py,
     # docs/observability.md "Efficiency plane") ---------------------------
     # per-device peak FLOP/s for the roofline/MFU gauges — the peak-spec

@@ -155,26 +155,6 @@ def plan_blocks(
     return min(br, max(1, n_rows)), min(bk, max(1, k_side))
 
 
-def _plan(
-    n_rows: int, k_side: int, d: int, dtype, fast: bool
-) -> Optional[Tuple[int, int]]:
-    """Block plan for one kernel dispatch: the measured autotuner's
-    persisted winner when one exists for this (shape-class, dtype, fast)
-    — else the static heuristic. None means "take the jnp form": enormous
-    d, or float64 rows on the compiled path — Mosaic holds no f64 (and
-    lowers no int64 index under the x64 mode f64 fits run in); XLA emulates
-    f64 on the TPU, the interpreter runs it as is."""
-    if not _interpret() and jnp.dtype(dtype).itemsize > 4:
-        return None
-    heuristic = plan_blocks(n_rows, k_side, d, dtype, fast)
-    if heuristic is None:
-        return None
-    from . import autotune
-
-    tuned = autotune.lookup(n_rows, k_side, d, dtype, fast)
-    return tuned if tuned is not None else heuristic
-
-
 def kernel_name(kernel: str, fast: bool) -> str:
     """The name a kernel carries onto the device: `srml_<kernel>_<mode>`,
     mode `bf16` (one-pass `fast` contraction) or `f32`. XLA's TPU compiler
@@ -192,10 +172,16 @@ def block_plan(
     n_rows: int, k_side: int, d: int, dtype, fast: bool
 ) -> Optional[Tuple[int, int]]:
     """The (block_rows, block_k) a kernel dispatch of this tile shape takes
-    in this process, or None where it takes the jnp form (no kernel mode,
-    or no plan fits). For callers that report the plan (the `fit/solve/loop`
-    span); resolves `kernel_mode()`, so call it outside a trace."""
-    return _plan(n_rows, k_side, d, dtype, fast) if _use_kernel() else None
+    in this process — `plan_blocks`' answer — or None where it takes the jnp
+    form: no kernel mode, no plan fits (enormous d), or float64 rows on the
+    compiled path — Mosaic holds no f64 (and lowers no int64 index under the
+    x64 mode f64 fits run in); XLA emulates f64 on the TPU, the interpreter
+    runs it as is. The kernel entry points below ask here, and so does a
+    caller that reports the plan (the `fit/solve/loop` span). The first call
+    resolves `kernel_mode()`: make it outside a trace."""
+    if not _use_kernel() or (not _interpret() and jnp.dtype(dtype).itemsize > 4):
+        return None
+    return plan_blocks(n_rows, k_side, d, dtype, fast)
 
 
 def _call_params(interpret: bool) -> dict:
@@ -631,11 +617,7 @@ def assign_argmin(
     ties are preserved across k blocks by the kernel's strict-< merge."""
     k, d = centers.shape
     c_sq = _c_sq(centers)
-    plan = (
-        _plan(xb.shape[0], k, d, xb.dtype, fast)
-        if _use_kernel()
-        else None
-    )
+    plan = block_plan(xb.shape[0], k, d, xb.dtype, fast)
     if plan is None:
         d2 = c_sq[None, :] - 2.0 * _mm(xb, centers.T, fast)
         return jnp.min(d2, axis=1), jnp.argmin(d2, axis=1).astype(jnp.int32)
@@ -677,7 +659,7 @@ def assign_accumulate(
     error."""
     k, d = centers.shape
     n = xb.shape[0] if tile is None else tile[1]
-    plan = _plan(n, k, d, xb.dtype, fast) if _use_kernel() else None
+    plan = block_plan(n, k, d, xb.dtype, fast)
     br, bk = (block_rows or plan[0], block_k or plan[1]) if plan else (None, None)
     if tile is not None and (x_sq is None or plan is None or n % br):
         raise ValueError(
@@ -861,7 +843,7 @@ def topk_tile(
     kk = min(kk, n)
     if item_sq is None:
         item_sq = row_sq(items)
-    plan = _plan(q.shape[0], n, d, q.dtype, fast) if _use_kernel() else None
+    plan = block_plan(q.shape[0], n, d, q.dtype, fast)
     use_kernel = plan is not None
     if k_tile is None:
         # fallback: one block (today's one-matmul shape, right for CPU);

@@ -253,9 +253,12 @@ def kmeans_fit(
 
     Convergence: squared center movement <= tol (sklearn/cuML semantics; the
     reference maps Spark's `tol` straight through, clustering.py:96-108).
-    Host-stepped loop of jitted `_lloyd_step` programs — see the step's
-    docstring for why the loop is not a `lax.while_loop`. Small single-device
-    datasets take the fused one-program path instead (_lloyd_fit_fused).
+    Host-stepped loop, one step an iteration (see `_lloyd_step`'s docstring
+    for why the loop is not a `lax.while_loop`): on a mesh the `shard_map`
+    program `_lloyd_step`; on one device `_lloyd_step_fused_1dev`, one program
+    an iteration, while X is under `_ONE_DISPATCH_MAX_BYTES`, and above it
+    the host-tiled `_lloyd_step_1dev`, one program a tile. In all three the
+    kernels' blocks are `distance.block_plan`'s.
 
     The deferred (pipelined) convergence check means `n_iter_` can be ONE
     HIGHER than sklearn/cuML would report for the same tol crossing — the
@@ -274,15 +277,6 @@ def kmeans_fit(
 
     centers = jnp.asarray(init_centers)
     fast = precision_mode == "fast" and X.dtype == jnp.float32
-    # measured autotuner (ops/autotune.py): make sure a tiling winner exists
-    # for this fit's tile shape BEFORE the jitted loop traces — the traced
-    # block planner then hits the persisted table; off-TPU (and with
-    # SRML_AUTOTUNE=0) this is a no-op and the static heuristic plans.
-    from . import autotune
-
-    autotune.ensure(
-        min(batch_rows, X.shape[0]), centers.shape[0], X.shape[1], X.dtype, fast
-    )
     inertia = jnp.zeros((), X.dtype)
     n_iter = 0
     one_dev = mesh.devices.size == 1

@@ -84,7 +84,6 @@ def report(
     alignment (docs/observability.md "Fleet plane"). `cluster=True` adds
     the last merged LIVE cluster view (`fleet.cluster_report()`)."""
     from .. import diagnostics, telemetry
-    from ..ops import autotune as _autotune
     from ..scheduler.ledger import global_ledger
 
     reg = telemetry.registry()
@@ -112,7 +111,6 @@ def report(
         "drift": drift.last_stats(),
         "serving": _serving_section(),
         "efficiency": efficiency.summary(),
-        "autotune": {**_autotune.stats(), "table_path": _autotune.table_path()},
         "telemetry": reg.snapshot(),
     }
     if cluster:

@@ -372,8 +372,8 @@ class _CompileEvent:
     sighting = miss: the body's wall is recorded as compile time (known
     bias: it includes the first execution) and attributed to the active
     scope's compile kind. Later sightings = hit: counted, nothing timed.
-    The ledger is process-wide — prewarm and autotune record with no scope
-    active. ``cache_hit`` is readable after entry."""
+    The ledger is process-wide — prewarm records with no scope active.
+    ``cache_hit`` is readable after entry."""
 
     __slots__ = ("program", "shape_key", "cache_hit", "_t0", "_sc")
 
@@ -448,8 +448,8 @@ _NOOP_COMPILE = _NoopCompileEvent()
 
 def compile_event(program: str, shape_key: str):
     """Ledger a jit entry point (fit solve, PredictProgram prewarm rung,
-    first-dispatch bucket, autotune measurement). Returns the shared no-op
-    when telemetry is disabled."""
+    first-dispatch bucket). Returns the shared no-op when telemetry is
+    disabled."""
     from .. import telemetry
 
     if not telemetry.enabled():

@@ -317,11 +317,10 @@ _COMPILE_CACHE_DIR: Optional[str] = None  # dir currently wired into jax, if any
 
 
 def compilation_cache_dir() -> str:
-    """The ONE persistent-cache directory of this process (XLA programs and
-    the autotune table beside them): `JAX_COMPILATION_CACHE_DIR` where it is
-    set — the program then never configures another — else
-    ``core.config["compilation_cache_dir"]``, whose default is a fixed
-    git-ignored directory in the checkout."""
+    """The ONE persistent-cache directory of this process (XLA programs):
+    `JAX_COMPILATION_CACHE_DIR` where it is set — the program then never
+    configures another — else ``core.config["compilation_cache_dir"]``, whose
+    default is a fixed git-ignored directory in the checkout."""
     import os
 
     from ..core import _DEFAULT_COMPILE_CACHE_DIR, config

@@ -128,7 +128,7 @@ def test_profiler_scope_false_positive_guards():
     )
     assert fs == []
     # a sync inside a NESTED function doesn't mark the enclosing timer as
-    # device-timing (the autotuner's measurement-closure shape)
+    # device-timing (a timer over a closure that syncs internally)
     fs = run(
         """
         import time

@@ -5,7 +5,7 @@
 #       through the ESTIMATORS on the 4- and 8-device mesh with the kernels
 #       interpreted — jax rejects a `pallas_call` whose out_shape has no
 #       `vma` there, which no ops-level kernel test can see;
-#   (b) where the persistent compile cache (and the autotune table) lives;
+#   (b) where the persistent compile cache lives;
 #   (c) a TPU whose kernel self-test fails RAISES — it never becomes "jnp";
 #   (d) a kernel that does not compile surfaces as itself, once: not an HBM
 #       OOM, no streaming retry, no HbmBudgetError;
@@ -148,6 +148,9 @@ def test_compile_cache_default_is_one_fixed_git_ignored_checkout_dir(monkeypatch
     expected = os.path.join(REPO, ".srml_cache")
     assert core._DEFAULT_COMPILE_CACHE_DIR == expected
     assert mesh_mod.compilation_cache_dir() == core.config["compilation_cache_dir"] == expected
+    # a None config is never "no cache": it resolves to the fixed default
+    monkeypatch.setitem(core.config, "compilation_cache_dir", None)
+    assert mesh_mod.compilation_cache_dir() == expected
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".srml_cache/" in f.read().split()
     # a fresh process resolves the very same path: nothing of pid or time

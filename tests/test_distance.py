@@ -9,6 +9,9 @@
 # program across all its Lloyd iterations (the distance.* counters tick at
 # TRACE time by design).
 #
+import json
+import pathlib
+
 import numpy as np
 import pytest
 
@@ -343,6 +346,209 @@ def test_plan_blocks_fits_budget_and_floors():
     assert br >= 8 and bk >= 128
     # absurd depth: nothing fits -> None (callers fall back to jnp)
     assert distance.plan_blocks(4096, 1000, 50_000_000) is None
+
+
+# ------------------------------------------------------ one block planner --
+#
+# `plan_blocks` is the only author of (block_rows, block_k), and `block_plan`
+# is what a dispatch takes of it in this process (docs/performance.md "Tiled
+# distance core"). Until PR 31 a measured table (`srml_autotune.json` beside
+# the compile cache, on by default) stood between the two; these hold what
+# its removal promised: such a file is never opened, the benchmark files'
+# leftover switch moves nothing, the cells' dispatches plan (512, 512), and
+# the kNN serving budget and the top-k program take one item block.
+
+CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "chipbench" / "configs"
+
+
+def _table_key(n_rows, k_side, d, fast):
+    """The deleted table's key for a float32 dispatch: rows and k-side
+    rounded up to a power of two, the depth exact, the mode spelled out."""
+    up = lambda v: 1 << (max(1, int(v)) - 1).bit_length()
+    return f"r{up(n_rows)}:k{up(k_side)}:d{d}:float32:{'fast' if fast else 'full'}"
+
+
+@pytest.fixture
+def planted_table(tmp_path, monkeypatch):
+    """A table as the deleted autotuner persisted it, naming (128, 128) for
+    every dispatch these tests plan, beside the compile cache this process
+    would use."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)  # it would win
+    monkeypatch.setitem(config, "compilation_cache_dir", str(tmp_path))
+    shapes = [(32768, 1000, 3000), (300, 5, 16)] + [(b, 640, 3000) for b in (128, 1024, 8192)]
+    entries = {_table_key(*shape, fast): [128, 128] for shape in shapes for fast in (True, False)}
+    (tmp_path / "srml_autotune.json").write_text(json.dumps({"version": 1, "entries": entries}))
+    return entries
+
+
+def test_block_vmem_bytes_counts_stored_dtype_and_double_buffers():
+    d = 3072  # lane-aligned, so the terms are exact
+    full = distance.block_vmem_bytes(512, 512, d, jnp.float32, fast=False)
+    fast = distance.block_vmem_bytes(512, 512, d, jnp.float32, fast=True)
+    # the two [512, d] f32 blocks, each double-buffered, are the floor —
+    # exactly the 24 MiB Mosaic reports for this shape on a v5e
+    assert 2 * (512 + 512) * d * 4 == 24 << 20 < fast
+    # both modes hold the SAME f32 blocks; the fast path adds a bf16 copy of
+    # both, the fp32 contraction the row block's bf16 splits and residuals
+    assert fast - (512 + 512) * d * 2 == full - 512 * d * 17
+    # a non-128-multiple depth occupies whole lane tiles
+    assert distance.block_vmem_bytes(512, 512, 3000, jnp.float32, False) == full
+    # f64 blocks are twice as wide
+    assert distance.block_vmem_bytes(512, 512, d, jnp.float64, False) > full
+
+
+def test_full_precision_plan_never_outgrows_the_fast_plan(interpret_mode):
+    # a VMEM-tight depth: both modes must shrink, full precision at least
+    # as much (it peels 17 bytes per row-block element, fast 2 per element)
+    d = 7168
+    full = distance.plan_blocks(4096, 4096, d, jnp.float32, False)
+    fast = distance.plan_blocks(4096, 4096, d, jnp.float32, True)
+    assert full is not None and fast is not None
+    assert fast != (512, 512)
+    assert full[0] * full[1] <= fast[0] * fast[1]
+    # block_plan threads the same accounting
+    assert distance.block_plan(4096, 4096, d, jnp.float32, False) == full
+    assert distance.block_plan(4096, 4096, d, jnp.float32, True) == fast
+
+
+@pytest.mark.parametrize("env", [None, "1"])
+@pytest.mark.parametrize("fast", [True, False])
+def test_a_persisted_table_is_never_opened(interpret_mode, planted_table, monkeypatch, fast, env):
+    """Neither the plan at the cells' Lloyd tile nor the blocks a fit reports
+    on its `loop` span follow a table left beside the compile cache, with the
+    deleted switch's environment variable set or not."""
+    from spark_rapids_ml_tpu.ops.kmeans import kmeans_fit
+    from spark_rapids_ml_tpu.parallel import get_mesh
+
+    if env is None:
+        monkeypatch.delenv("SRML_AUTOTUNE", raising=False)
+    else:
+        monkeypatch.setenv("SRML_AUTOTUNE", env)
+    assert _table_key(32768, 1000, 3000, fast) in planted_table
+    assert distance.block_plan(32768, 1000, 3000, jnp.float32, fast) == (512, 512)
+    rng = np.random.default_rng(31)
+    X = jnp.asarray(rng.normal(size=(300, 16)).astype(np.float32))
+    telemetry.enable()
+    try:
+        mark = telemetry.registry().mark()
+        kmeans_fit(
+            X, jnp.ones((300,), X.dtype), X[:5], mesh=get_mesh(1), max_iter=2, tol=0.0,
+            precision_mode="fast" if fast else "high",
+        )
+        loop = next(s for s in telemetry.registry().delta(mark)["spans"] if s["name"] == "loop")
+    finally:
+        telemetry.registry().reset()
+        telemetry.disable()
+    assert _table_key(300, 5, 16, fast) in planted_table
+    assert (loop["block_rows"], loop["block_k"]) == (300, 5) == distance.plan_blocks(300, 5, 16, jnp.float32, fast)
+
+
+def _cell_dispatch(name, dispatch):
+    """(rows, k, d, fast) of one kernel dispatch of a benchmark cell, from its
+    configuration file and the estimator's own defaults."""
+    from spark_rapids_ml_tpu.models.clustering import KMeans
+
+    cfg = json.loads((CONFIGS / f"{name}.json").read_text())
+    k, d = cfg["estimator"]["k"], cfg["d"]
+    if dispatch == "predict":  # a 65,536-row batch goes through the core in row tiles
+        return distance.tile_rows(), k, d, False
+    rows_dev = cfg["rows"] // cfg.get("num_workers", 1)
+    tile = min(KMeans()._solver_params["max_samples_per_batch"], rows_dev)
+    fast = dispatch == "loop" and cfg["precision"]["distance_precision"] == "fast"
+    return tile, k, d, fast
+
+
+@pytest.mark.parametrize(
+    "name,dispatch",
+    [
+        ("kmeans-p3k", "loop"),  # kmeans-p3k.refit: the bf16 Lloyd tile
+        ("kmeans-p3k", "final_pass"),  # ... and its float32 inertia pass
+        ("kmeans-p3k", "predict"),  # kmeans-p3k.transform
+        ("kmeans-p3k-host4", "loop"),  # one chip's tile of the four-chip shard
+    ],
+)
+def test_the_cells_dispatches_plan_512_by_512(monkeypatch, name, dispatch):
+    monkeypatch.setattr(distance, "_MODE", "pallas")  # the compiled path's answer
+    rows, k, d, fast = _cell_dispatch(name, dispatch)
+    assert (rows, k, d) in {(32768, 1000, 3000), (4096, 1000, 3000)}
+    assert distance.block_plan(rows, k, d, jnp.float32, fast) == (512, 512)
+    assert rows % 512 == 0  # whole row blocks: a tile can be read in place
+    assert distance.block_vmem_bytes(512, 512, d, jnp.float32, fast) <= distance.vmem_limit_bytes()
+
+
+def test_the_benchmark_files_autotune_switch_is_inert(interpret_mode):
+    """`core.config` has no autotune key; the switch the benchmark's
+    configuration files carried at PR 31, and whatever `program_config` they
+    carry now, lands in it as `chipbench/run.py` does it (a plain
+    `dict.update`) and changes no plan."""
+    assert not [key for key in config if key.startswith("autotune")]
+    shapes = [(32768, 1000, 3000, jnp.float32, f) for f in (True, False)] + [(4096, 1000, 3000, jnp.float32, False)]
+    before = [distance.block_plan(*shape) for shape in shapes]
+    landed = {"autotune_enabled": False}
+    for path in CONFIGS.glob("*.json"):
+        landed.update(json.loads(path.read_text()).get("program_config", {}))
+    landed = {key: value for key, value in landed.items() if key not in config}
+    config.update(landed)
+    try:
+        assert [distance.block_plan(*shape) for shape in shapes] == before == [(512, 512)] * 3
+    finally:
+        for key in landed:
+            del config[key]
+
+
+@pytest.mark.parametrize("bucket", [128, 1024, 8192])
+def test_knn_serving_budget_and_topk_program_take_one_item_block(interpret_mode, planted_table, monkeypatch, bucket):
+    """`_serve_workspace_terms` budgets the [bucket, k_tile] distance block
+    of the program `_serve_program` runs: the item block `topk_tile` hands the
+    d2-block kernel is the `k_tile` of the budget, table or no table."""
+    import pandas as pd
+
+    from spark_rapids_ml_tpu.models.knn import NearestNeighbors
+
+    n_items, d, kk = 640, 3000, 8
+    items = np.random.default_rng(5).normal(size=(n_items, d)).astype(np.float32)
+    model = NearestNeighbors(k=kk).setInputCol("features").fit(pd.DataFrame({"features": list(items)}))
+    terms = model._serve_workspace_terms(bucket, 4)
+    k_tile = terms["topk_block"] // (bucket * 4)
+    taken = []
+
+    def d2_block(qp, xt, xt_sq, **kw):
+        taken.append((kw["block_rows"], xt.shape[0]))
+        return jnp.zeros((qp.shape[0], xt.shape[0]), qp.dtype)
+
+    monkeypatch.setattr(distance, "_pl_d2_block", d2_block)
+    struct = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    jax.eval_shape(lambda q, it: distance.topk_tile(q, it, None, kk), struct(bucket, d), struct(n_items, d))
+    assert _table_key(bucket, n_items, d, False) in planted_table
+    assert taken and set(taken) == {(min(bucket, 512), k_tile)} and k_tile == 512
+
+
+@pytest.mark.parametrize("site", ["assign_argmin", "assign_accumulate", "topk_tile"])
+def test_float64_rows_take_the_jnp_form_on_the_compiled_path(monkeypatch, site):
+    """Mosaic holds no f64: with the kernels compiled (not interpreted),
+    every entry point answers float64 rows with the jnp form, and float32
+    rows still reach its kernel. `plan_blocks` itself plans f64 blocks (what
+    the interpreter runs, and what the kNN serving term budgets from)."""
+    def refuse(*a, **kw):
+        raise AssertionError("a kernel was reached")
+
+    for kernel in ("_pl_argmin", "_pl_accumulate", "_pl_d2_block"):
+        monkeypatch.setattr(distance, kernel, refuse)
+    X, C, w = _data(40, 6, 8, np.float64)
+    call = {
+        "assign_argmin": lambda x, c, w: distance.assign_argmin(x, c),
+        "assign_accumulate": lambda x, c, w: distance.assign_accumulate(x, w, c),
+        "topk_tile": lambda x, c, w: distance.topk_tile(c, x, None, 3),
+    }[site]
+    monkeypatch.setattr(distance, "_MODE", "jnp")
+    want = call(X, C, w)
+    monkeypatch.setattr(distance, "_MODE", "pallas")
+    assert distance.block_plan(40, 6, 8, np.float64, False) is None
+    assert distance.plan_blocks(40, 6, 8, np.float64, False) == (40, 6)
+    for got, ref in zip(call(X, C, w), want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    with pytest.raises(AssertionError, match="a kernel was reached"):
+        call(X.astype(jnp.float32), C.astype(jnp.float32), w.astype(jnp.float32))
 
 
 # ------------------------------------------------- stable kernel names -----
