@@ -31,7 +31,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 
 import numpy as np
 
-from .data import ExtractedData, as_pandas, extract_dataset, vectors_to_pandas_column
+from .data import DenseRows, ExtractedData, as_pandas, extract_dataset, vectors_to_pandas_column
 from .params import Param, Params, _TpuParams
 from .utils import get_logger, lockcheck
 
@@ -774,9 +774,16 @@ class _TpuCommon(_TpuParams):
     _matmul_precision: str = "float32"
 
     def _pre_process_data(
-        self, dataset: Any, for_fit: bool = True, defer_validation: bool = False
+        self,
+        dataset: Any,
+        for_fit: bool = True,
+        defer_validation: bool = False,
+        dense_rows: bool = False,
     ) -> ExtractedData:
         """Column selection + dense/CSR extraction (reference core.py:458-557).
+
+        ``dense_rows=True`` leaves a dense object column as `data.DenseRows`
+        for a caller that fills its own buffers (`extract_dataset`).
 
         ``defer_validation=True`` skips the eager opt-in NaN/Inf scan — the
         fit driver must run it itself (`data.run_deferred_validation`): full
@@ -815,6 +822,7 @@ class _TpuCommon(_TpuParams):
             float32_inputs=self._float32_inputs,
             enable_sparse_data_optim=sparse_optim,
             validate=not defer_validation,
+            dense_rows=dense_rows,
         )
         if for_fit and extracted.n_rows == 0:
             # reference raises the same way when a rank gets no rows (core.py:762-765)
@@ -1931,6 +1939,82 @@ class _TpuModel(_TpuCommon):
         return cls.read().load(path)
 
 
+class _PieceRing:
+    """The piece buffers a model keeps between its several-piece `transform`
+    calls. Fresh pages are what an extraction costs on a host without
+    transparent hugepages (a first write into a new 96 MB buffer takes ten
+    times the copy itself: PERF.md section 7), so a call does not make its
+    buffers anew: it TAKES them out of here (they are its own while it runs —
+    a concurrent call finds none and makes its own) and hands them back after
+    its last result was fetched, when nothing reads them any more. A call
+    that raises hands nothing back. At most `KEEP` are kept; `deque` makes
+    `take` and `give` atomic, so there is no lock to hold."""
+
+    KEEP = 2  # buffers a call fills in turn: one leaves the host while the other is filled
+
+    def __init__(self) -> None:
+        import collections
+
+        self._free: Any = collections.deque(maxlen=self.KEEP)
+
+    def take(self, shape: Tuple[int, int], dtype: Any) -> List[np.ndarray]:
+        bufs: List[np.ndarray] = []
+        for _ in range(self.KEEP):
+            try:
+                buf = self._free.pop()
+            except IndexError:
+                buf = None
+            if buf is None or buf.shape != shape or buf.dtype != dtype:
+                buf = np.empty(shape, dtype=dtype)
+            bufs.append(buf)
+        return bufs
+
+    def give(self, bufs: List[np.ndarray]) -> None:
+        self._free.extend(bufs)
+
+
+class _Pieces:
+    """The rows of one transform call as host pieces of `piece_rows` rows, in
+    order: row views of a block (CSR rows densified), or, with a `ring`, a
+    `DenseRows` column filled piece by piece into the ring's buffers in turn
+    (`filled`). `host(i)` of a filled piece overwrites the buffer piece
+    ``i - len(ring)`` had: the caller has waited for that piece's result.
+    Asked for the piece it returned last, it returns that again (`transform`
+    fills piece 0 inside its own span, the loop takes it from here)."""
+
+    def __init__(self, features: Any, piece_rows: int, ring: Optional[_PieceRing] = None) -> None:
+        n = int(features.shape[0])
+        self.features = features
+        self.piece_rows = int(piece_rows)
+        self.bounds = [(lo, min(lo + piece_rows, n)) for lo in range(0, n, piece_rows)] or [(0, 0)]
+        self.filled = ring is not None
+        self._pool = ring
+        self.ring: List[np.ndarray] = (
+            ring.take((self.piece_rows, int(features.shape[1])), features.dtype) if ring else []
+        )
+        self._last: Tuple[int, Any] = (-1, None)
+
+    def host(self, i: int) -> np.ndarray:
+        if i == self._last[0]:
+            return self._last[1]
+        lo, hi = self.bounds[i]
+        if self.filled:
+            xb = self.ring[i % len(self.ring)][: hi - lo]
+            self.features.fill(xb, lo, hi)
+        else:
+            xb = self.features[lo:hi]
+            if hasattr(xb, "todense"):
+                xb = np.asarray(xb.todense())
+        self._last = (i, xb)
+        return xb
+
+    def release(self) -> None:
+        """The ring back to the model: only after the call's last fetch."""
+        if self._pool is not None:
+            self._pool.give(self.ring)
+            self.ring = []
+
+
 # Process-wide record of bucketed shapes already handed to a `predict`
 # program (see `_TpuModel._record_bucket`).
 _BUCKET_LOCK = lockcheck.make_lock("core._BUCKET_LOCK")
@@ -1972,7 +2056,7 @@ class PredictProgram:
     ) -> None:
         import jax
 
-        from .parallel.mesh import replicated
+        from .parallel.mesh import default_local_device, replicated
 
         if construct is None or predict is None:
             c0, p0, _ = model._get_transform_func()
@@ -1981,6 +2065,9 @@ class PredictProgram:
         self.model = model
         self.predict_fn = predict
         self.mesh = mesh
+        # where `launch` places a batch off the mesh path: the device every
+        # `construct` puts the model's state on
+        self.device = default_local_device()
         self.multiple = int(mesh.devices.size) if mesh is not None else 1
         self.cap = int(cap) if cap else int(config["max_records_per_batch"]) * self.multiple
         self.bucket_min = int(config["transform_bucket_min_rows"])
@@ -2030,8 +2117,12 @@ class PredictProgram:
         )
 
     def launch(self, xp: np.ndarray) -> Any:
-        """`dispatch`'s device half: place a padded batch and call `predict`,
-        up to its asynchronous return — NO host fetch."""
+        """`dispatch`'s device half: place a padded batch and call `predict`
+        with the device array, up to its asynchronous return — NO host fetch.
+        The placement is explicit, so `predict`'s `xb.astype(dtype)` is the
+        device's (nothing, for a batch of the model's dtype) and never a host
+        copy of the batch; the runtime may read `xp` until the result is
+        ready, so a caller that reuses the buffer waits for that first."""
         import jax
 
         from .parallel.mesh import row_sharding
@@ -2040,9 +2131,8 @@ class PredictProgram:
         sig = (tuple(xp.shape), str(xp.dtype))
         self.last_dispatch_new_shape = sig not in self._shapes_seen
         self._shapes_seen.add(sig)
-        if self.mesh is not None:
-            xp = jax.device_put(xp, row_sharding(self.mesh, xp.ndim))
-        return self.predict_fn(self.state, xp)
+        where = self.device if self.mesh is None else row_sharding(self.mesh, xp.ndim)
+        return self.predict_fn(self.state, jax.device_put(xp, where))
 
     def fetch(self, result: Any, n_valid: int) -> Any:
         """THE device→host sync point: materialize the in-flight result and
@@ -2105,80 +2195,131 @@ class _TpuModelWithColumns(_TpuModel):
         """Names of appended columns; single-entry list for plain predictors."""
         return [self.getOrDefault("outputCol") if self.hasParam("outputCol") and self.isDefined("outputCol") else pred.prediction]
 
-    def _transform_arrays(self, features: Any) -> Any:
-        """Batched predict over a host feature block. The per-algo `predict` may
-        return one array or a tuple of arrays (multi-output models); each output
-        is concatenated across batches.
-
-        Every batch is padded UP to a geometric ladder of row buckets
-        (`mesh.bucket_rows`) and the outputs sliced back to the valid rows —
-        serving traffic with ragged batch sizes compiles one `predict`
-        program per bucket instead of one per distinct tail shape (and with
-        ``config["compilation_cache_dir"]`` set, those programs survive
-        process restarts). `predict` is row-parallel by contract, so padding
-        rows cannot influence valid rows' outputs.
-
-        The pad/dispatch/slice mechanics live in `PredictProgram` — the same
-        handle the serving plane keeps resident per model (docs/serving.md) —
-        so batch transform and long-lived serving cannot drift.
+    def _transform_plan(self, n_rows: int, row_bytes: int) -> Tuple[int, Any, int]:
+        """(batch rows, mesh or None, piece rows) of a call over `n_rows` rows
+        of `row_bytes` each.
 
         Small blocks run on one device (the reference's one-task-per-batch
         pandas_udf shape). At ``config["distributed_transform_min_rows"]`` rows
         and up, each batch is row-sharded over the full mesh with the model
         state replicated — every per-algo `predict` is a row-parallel jitted
         program, so GSPMD partitions it with zero collectives (the reference's
-        all-GPU parallel transform, core.py:1531-1635)."""
+        all-GPU parallel transform, core.py:1531-1635).
+
+        A piece is what one `predict` launch takes: the largest rung of the
+        bucket ladder whose bytes fit ``config["ingest_chunk_bytes"]``, the
+        batch at most; on the mesh path one row-sharded batch."""
+        import jax
+
+        from .parallel.mesh import bucket_ladder, default_devices, get_mesh
+
+        batch = int(config["max_records_per_batch"])
+        n_dev = min(self.num_workers, len(default_devices()))
+        # multi-process SPMD transforms rank-LOCAL batches: stay on local
+        # devices (sharding a local batch over the global mesh would mix
+        # ranks' unrelated rows and target non-addressable devices)
+        if (
+            n_rows >= int(config["distributed_transform_min_rows"])
+            and n_dev > 1
+            and jax.process_count() == 1
+        ):
+            # per-device batch budget stays constant
+            return batch * n_dev, get_mesh(n_dev), batch * n_dev
+        rungs = bucket_ladder(
+            batch, min_rows=int(config["transform_bucket_min_rows"]), cap=batch
+        )
+        fit = [r for r in rungs if r * row_bytes <= int(config["ingest_chunk_bytes"])]
+        return batch, None, min(batch, fit[-1] if fit else rungs[0])
+
+    def _transform_arrays(self, features: Any) -> Any:
+        """Batched predict over a host feature block (an array or a CSR
+        matrix), a piece (`_transform_plan`) at a time: each piece is a row
+        view, padded, placed, predicted and fetched in turn. The per-algo
+        `predict` may return one array or a tuple of arrays (multi-output
+        models); each output is concatenated across pieces."""
+        n, d = features.shape
+        batch, mesh, piece_rows = self._transform_plan(
+            n, d * np.dtype(features.dtype).itemsize
+        )
+        return self._transform_pieces(_Pieces(features, piece_rows), batch, mesh)
+
+    def _transform_pieces(self, pieces: "_Pieces", batch: int, mesh: Any) -> Any:
+        """`predict` over the pieces of one call, in order.
+
+        Every piece is padded UP to a geometric ladder of row buckets
+        (`mesh.bucket_rows`) and the outputs sliced back to the valid rows —
+        serving traffic with ragged batch sizes compiles one `predict`
+        program per bucket instead of one per distinct tail shape (and with
+        ``config["compilation_cache_dir"]`` set, those programs survive
+        process restarts). `predict` is row-parallel by contract, so padding
+        rows cannot influence valid rows' outputs, and predicting a piece at
+        a time gives the whole batch's answers.
+
+        The pad/dispatch/slice mechanics live in `PredictProgram` — the same
+        handle the serving plane keeps resident per model (docs/serving.md) —
+        so batch transform and long-lived serving cannot drift.
+
+        Row views go in lockstep (pad, dispatch, fetch a piece, under one
+        `transform` span). Pieces that are FILLED (`_Pieces.filled`: a dense
+        object column, several pieces) are pipelined on this one thread:
+        placement and `predict` return before the bytes have left the host,
+        so piece i leaves and runs while piece i + 1 is filled. A fill is the
+        top-level span `transform.extract`, so `transform` closes before it
+        and opens again after (one `transform` span a piece; the top-level
+        spans of a call never overlap), `transform/stall` is the wait for the
+        ring buffer the next fill takes (the result of the piece that held it:
+        until that is ready the runtime may still read the buffer), and the
+        results are fetched once the last piece is in flight. Piece 0 is the
+        caller's to fill, inside the call's first `transform.extract`."""
+        import contextlib
+
         import jax
 
         from . import telemetry
-        from .parallel.mesh import (
-            default_devices,
-            dtype_scope,
-            ensure_compilation_cache,
-            get_mesh,
-        )
+        from .parallel.mesh import dtype_scope, ensure_compilation_cache
 
         ensure_compilation_cache()
-        with telemetry.span(
-            "transform", model=type(self).__name__, rows=int(features.shape[0])
-        ), dtype_scope(
+        bounds, piped = pieces.bounds, pieces.filled
+        n = bounds[-1][1]
+        attrs: Dict[str, Any] = {"model": type(self).__name__}
+        if piped:
+            attrs.update(pieces=len(bounds), piece_rows=pieces.piece_rows)
+        program = None
+        outs: List[Any] = []
+        in_flight: List[Tuple[Any, int]] = []
+        with dtype_scope(
             np.float32 if self._float32_inputs else np.float64, self._matmul_precision
-        ):
-            n = features.shape[0]
-            batch = int(config["max_records_per_batch"])
-            n_dev = min(self.num_workers, len(default_devices()))
-            # multi-process SPMD transforms rank-LOCAL batches: stay on local
-            # devices (sharding a local batch over the global mesh would mix
-            # ranks' unrelated rows and target non-addressable devices)
-            use_mesh = (
-                n >= int(config["distributed_transform_min_rows"])
-                and n_dev > 1
-                and jax.process_count() == 1
-            )
-            mesh = None
-            if use_mesh:
-                mesh = get_mesh(n_dev)
-                batch *= n_dev  # per-device batch budget stays constant
-            with telemetry.span("construct", model=type(self).__name__):
-                program = PredictProgram(self, cap=batch, mesh=mesh)
-            if telemetry.enabled():
-                reg = telemetry.registry()
-                reg.inc("transform.rows", n)
-                reg.inc("transform.batches", -(-n // batch) if n else 1)
-            outs: List[Any] = []
-            # a zero-row block still runs ONE (bucket-padded) batch: the
+        ), contextlib.ExitStack() as call_span:
+            # a zero-row block still runs ONE (bucket-padded) piece: the
             # output arity/shape comes from `predict` itself, so multi-output
             # models return one correctly-shaped empty array PER output —
             # never a single bare zeros((0,)) that `_split_output` would
             # mis-map across its columns
-            for start in range(0, n, batch) if n else (0,):
-                stop = min(start + batch, n)
-                xb = features[start:stop]
-                if hasattr(xb, "todense"):
-                    xb = np.asarray(xb.todense())
+            for i, (lo, hi) in enumerate(bounds):
+                if piped and i:
+                    call_span.close()
+                    with telemetry.span("transform.extract", rows=hi - lo) as sp:
+                        xb = pieces.host(i)
+                        sp.set(bytes=int(xb.nbytes))
+                    telemetry.registry().inc("transform.bytes_extracted", xb.nbytes)
+                else:
+                    xb = pieces.host(i)
+                if i == 0 or piped:
+                    call_span.enter_context(
+                        telemetry.span("transform", rows=hi - lo if piped else n, **attrs)
+                    )
+                if program is None:
+                    with telemetry.span("construct", model=type(self).__name__):
+                        program = PredictProgram(self, cap=batch, mesh=mesh)
+                    if telemetry.enabled():
+                        reg = telemetry.registry()
+                        reg.inc("transform.rows", n)
+                        reg.inc("transform.batches", -(-n // batch) if n else 1)
+                        if piped:
+                            reg.inc("transform.pieces", len(bounds))
                 # the spans go round the program's calls, not inside them:
                 # the serving engine calls `dispatch`/`fetch` per request group
-                with telemetry.span("pad", rows=stop - start) as sp:
+                with telemetry.span("pad", rows=hi - lo) as sp:
                     xp, n_valid = program.pad(xb)
                     sp.set(rung=int(xp.shape[0]))
                 with telemetry.span(
@@ -2186,30 +2327,52 @@ class _TpuModelWithColumns(_TpuModel):
                 ) as sp:
                     result = program.launch(xp)
                     sp.set(new_shape=program.last_dispatch_new_shape)
+                if not piped:
+                    with telemetry.span("fetch", rows=n_valid):
+                        outs.append(program.fetch(result, n_valid))
+                    continue
+                in_flight.append((result, n_valid))
+                with telemetry.span("stall", piece=i):
+                    held_by = i + 1 - len(pieces.ring)
+                    if 0 <= held_by and i + 1 < len(bounds):
+                        jax.block_until_ready(in_flight[held_by][0])
+            for result, n_valid in in_flight:
                 with telemetry.span("fetch", rows=n_valid):
                     outs.append(program.fetch(result, n_valid))
-            if isinstance(outs[0], tuple):
-                return tuple(np.concatenate(parts, axis=0) for parts in zip(*outs))
-            return np.concatenate(outs, axis=0)
+        pieces.release()
+        if isinstance(outs[0], tuple):
+            return tuple(np.concatenate(parts, axis=0) for parts in zip(*outs))
+        return np.concatenate(outs, axis=0)
 
     def transform(self, dataset: Any):
         from . import telemetry
 
         # `transform.extract` / `transform.assemble` are top-level spans on
-        # either side of `transform` (whose extent `_transform_arrays` keeps):
-        # the pandas column to one host array, and the answer frame
+        # either side of `transform` (whose extent `_transform_pieces` keeps):
+        # the pandas column to host rows, and the answer frame. A dense
+        # object column whose batch is larger than an ingest chunk (wide
+        # rows: the bytes are worth overlapping) is not made one block: it
+        # goes through the call in pieces, each filled into one of a small
+        # ring of buffers the model keeps, and only piece 0 is filled here.
         with telemetry.span("transform.extract") as sp:
             pdf = as_pandas(dataset)
-            extracted = self._pre_process_data(dataset, for_fit=False)
+            extracted = self._pre_process_data(dataset, for_fit=False, dense_rows=True)
+            feats = extracted.features
+            n, d = extracted.n_rows, extracted.n_cols
+            batch, mesh, piece_rows = self._transform_plan(
+                n, d * np.dtype(feats.dtype).itemsize
+            )
+            piped = isinstance(feats, DenseRows) and piece_rows < min(batch, n)
+            if isinstance(feats, DenseRows) and not piped:
+                feats = extracted.features = feats.block()
+            ring = self.__dict__.setdefault("_piece_ring", _PieceRing()) if piped else None
+            pieces = _Pieces(feats, piece_rows, ring)
+            got = pieces.host(0) if piped else (feats.data if extracted.is_sparse else feats)
             if telemetry.enabled():
-                nbytes = int(
-                    extracted.features.data.nbytes if extracted.is_sparse
-                    else extracted.features.nbytes
-                )
-                sp.set(rows=extracted.n_rows, cols=extracted.n_cols,
-                       feature_kind=extracted.feature_kind, bytes=nbytes)
-                telemetry.registry().inc("transform.bytes_extracted", nbytes)
-        result = self._transform_arrays(extracted.features)
+                sp.set(rows=len(got) if piped else n, cols=d,
+                       feature_kind=extracted.feature_kind, bytes=int(got.nbytes))
+                telemetry.registry().inc("transform.bytes_extracted", int(got.nbytes))
+        result = self._transform_pieces(pieces, batch, mesh)
         names = self._out_column_names()
         with telemetry.span("transform.assemble", rows=len(pdf), columns=len(names)):
             out = pdf.copy(deep=False)
@@ -2219,7 +2382,7 @@ class _TpuModelWithColumns(_TpuModel):
             # the extraction's host array goes here, inside the span: left
             # to the frame's teardown, handing its pages back is time of the
             # call that no span covers
-            del extracted
+            del extracted, feats, pieces, got
         return out
 
     def _split_output(

@@ -230,15 +230,20 @@ def run_deferred_validation(
     )
 
 
+def _scans_at_extraction(validate: bool) -> bool:
+    """Whether this extraction runs the opt-in eager NaN/Inf scan
+    (``config["validate_ingest"]``, not deferred by the caller)."""
+    from .core import config
+
+    return bool(validate and config.get("validate_ingest", False))
+
+
 def _validate_ingest(
     extracted: "ExtractedData", label_col=None, weight_col=None
 ) -> None:
     """Opt-in eager NaN/Inf scan at extraction (``config["validate_ingest"]``)."""
-    from .core import config
-
-    if not config.get("validate_ingest", False):
-        return
-    validate_extracted(extracted, label_col, weight_col)
+    if _scans_at_extraction(True):
+        validate_extracted(extracted, label_col, weight_col)
 
 
 def _record_ingest(
@@ -272,32 +277,68 @@ def _record_ingest(
     return extracted
 
 
-def _fill_dense_chunked(values, n_cols: int, dtype, to_row) -> np.ndarray:
-    """Object column of per-row vectors -> preallocated [n, n_cols] block,
-    converted one row-chunk at a time (chunk size bounded by
-    ``core.config["ingest_chunk_bytes"]``) so the per-row temporaries never
-    exceed one chunk — the old whole-column ``np.stack`` held a full second
-    copy of the dataset in flight."""
-    from . import telemetry
+class DenseRows:
+    """A dense object column (one vector, array or list a row) that is not a
+    block yet: `fill` copies a row range into a buffer the caller keeps,
+    `block` makes the whole `[n, n_cols]` array (the fit's ingest).
 
-    n = len(values)
-    out = np.empty((n, n_cols), dtype=dtype)
-    step = ingest_chunk_rows(n_cols * np.dtype(dtype).itemsize)
-    for lo in range(0, n, step):
-        hi = min(lo + step, n)
-        out[lo:hi] = [to_row(v) for v in values[lo:hi]]
-        telemetry.registry().inc("ingest.chunks")
-    return out
+    `model.transform` walks such a column piece by piece through a small ring
+    of reused buffers (core.py `_TpuModelWithColumns.transform`), so no block
+    of the whole partition is made there. `to_row` turns one cell into its
+    1-D row (`DenseVector.toArray`); None where the cells are the rows."""
+
+    def __init__(self, values: np.ndarray, n_cols: int, dtype, to_row=None) -> None:
+        self.values = values
+        self.shape = (len(values), int(n_cols))
+        self.dtype = np.dtype(dtype)
+        self.to_row = to_row
+
+    @property
+    def nbytes(self) -> int:
+        return self.shape[0] * self.shape[1] * self.dtype.itemsize
+
+    def fill(self, out: np.ndarray, lo: int, hi: int) -> None:
+        """Rows ``[lo, hi)`` into the C-contiguous ``out`` (``[hi - lo,
+        n_cols]``), cast on the way: one pass, each row copied from the
+        object it is straight to its place (no list-to-array temporary)."""
+        rows = self.values[lo:hi]
+        if self.to_row is not None:
+            rows = [self.to_row(v) for v in rows]
+        n_cols = self.shape[1]
+        if out.shape != (hi - lo, n_cols) or not out.flags.c_contiguous:
+            raise ValueError(f"fill buffer {out.shape} is not a contiguous [{hi - lo}, {n_cols}] block")
+        if set(map(len, rows)) - {n_cols}:
+            bad = next(i for i, r in enumerate(rows) if len(r) != n_cols)
+            raise ValueError(
+                f"feature row {lo + bad} has {len(rows[bad])} entries where the column's first row has {n_cols}"
+            )
+        if len(rows):
+            np.concatenate(rows, out=out.reshape(-1), casting="unsafe")
+
+    def block(self) -> np.ndarray:
+        """The whole column as one preallocated block, filled a row-chunk at
+        a time (``core.config["ingest_chunk_bytes"]``; one `ingest.chunks`
+        count each)."""
+        from . import telemetry
+
+        n, n_cols = self.shape
+        out = np.empty(self.shape, dtype=self.dtype)
+        step = ingest_chunk_rows(n_cols * self.dtype.itemsize)
+        for lo in range(0, n, step):
+            hi = min(lo + step, n)
+            self.fill(out[lo:hi], lo, hi)
+            telemetry.registry().inc("ingest.chunks")
+        return out
 
 
 def _column_to_matrix(col, dtype) -> Tuple[Any, str]:
-    """Convert a single feature column (vectors / arrays / lists) to a 2-D block.
+    """Convert a single feature column (vectors / arrays / lists) to its rows.
 
-    Returns (matrix, kind) where kind is 'vector' when the column held
+    Returns (rows, kind) where kind is 'vector' when the column held
     Dense/SparseVector objects (so transform can emit vectors back) else 'array'.
-    Sparse rows produce a scipy CSR matrix. Dense conversion runs row-chunk by
-    row-chunk (``ingest_chunk_bytes``); the sparse path counts nnz first and
-    fills preallocated CSR arrays in place (no second full-nnz copy).
+    Sparse rows produce a scipy CSR matrix: the nnz are counted first and the
+    preallocated CSR arrays filled in place (no second full-nnz copy). Dense
+    rows come back as `DenseRows`, whose `block()` is the 2-D array.
     """
     values = col.to_numpy() if hasattr(col, "to_numpy") else np.asarray(col, dtype=object)
     if len(values) == 0:
@@ -343,12 +384,10 @@ def _column_to_matrix(col, dtype) -> Tuple[Any, str]:
                 (data, indices, indptr), shape=(n, size), dtype=dtype
             )
             return mat, "vector"
-        return _fill_dense_chunked(values, first.size, dtype, lambda v: v.toArray()), "vector"
+        return DenseRows(values, first.size, dtype, lambda v: v.toArray()), "vector"
     # plain array/list rows
-    if isinstance(first, np.ndarray) and first.ndim == 1:
-        return _fill_dense_chunked(values, len(first), dtype, lambda v: v), "array"
-    if isinstance(first, (list, tuple)):
-        return _fill_dense_chunked(values, len(first), dtype, np.asarray), "array"
+    if (isinstance(first, np.ndarray) and first.ndim == 1) or isinstance(first, (list, tuple)):
+        return DenseRows(values, len(first), dtype), "array"
     raise TypeError(f"Unsupported feature cell type {type(first)} in feature column")
 
 
@@ -363,13 +402,16 @@ def extract_dataset(
     float32_inputs: bool = True,
     enable_sparse_data_optim: Optional[bool] = None,
     validate: bool = True,
+    dense_rows: bool = False,
 ) -> ExtractedData:
     """Extract features (+label/weight/id) as contiguous blocks.
 
     ``enable_sparse_data_optim``: None autodetects (CSR kept sparse); True requires
     a sparse input (raises otherwise); False densifies (reference params.py:44-65).
     ``validate=False`` defers the opt-in NaN/Inf scan to the caller (see
-    `_record_ingest`).
+    `_record_ingest`). ``dense_rows=True`` leaves a dense object column as
+    `DenseRows` (the caller fills its own buffers from it, piece by piece)
+    unless the opt-in scan is on, which reads the whole block here.
     """
     dtype = np.float32 if float32_inputs else np.float64
 
@@ -438,6 +480,9 @@ def extract_dataset(
         if input_col not in pdf.columns:
             raise ValueError(f"feature column {input_col!r} not in dataset")
         features, kind = _column_to_matrix(pdf[input_col], dtype)
+        # the opt-in scan reads the whole block here, so it is made here
+        if isinstance(features, DenseRows) and (not dense_rows or _scans_at_extraction(validate)):
+            features = features.block()
         names = [input_col]
 
     if _sp is not None and _sp.issparse(features):

@@ -73,8 +73,11 @@ __all__ = [
 ]
 
 # Span records kept in-process (the JSONL sink receives every record; the
-# in-memory list is for snapshot()/summary() and stays bounded).
-_MAX_SPAN_RECORDS = 4096
+# in-memory list is for snapshot()/summary() and stays bounded). A window of
+# `MetricsRegistry.delta` has to fit: a several-piece `model.transform` call
+# records about fifty (six a piece), and a caller makes seven such calls a
+# second (a 20 s window of them: 6.6 k spans).
+_MAX_SPAN_RECORDS = 32768
 _MAX_CONVERGENCE_POINTS = 10_000
 # Most-recent observations retained per histogram for quantile() estimation
 # (serving latency p50/p99); the count/sum/min/max summary sees EVERY
