@@ -11,8 +11,10 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
+from .. import telemetry
 from ..core import FitInputs, _TpuEstimatorSupervised, _TpuModelWithColumns, pred
 from ..data import ExtractedData
 from ..params import (
@@ -143,6 +145,20 @@ class _LinearRegressionParams(
         }
 
 
+def _model_attrs(out: Dict[str, Any], inputs: FitInputs) -> Dict[str, Any]:
+    """A host-fetched solve state (ops/linear `_solve_from_stats`) as the
+    model's attributes; `rss_` and `sw_` are the training summary's sums."""
+    return {
+        "coef_": np.asarray(out["coef_"]),
+        "intercept_": float(out["intercept_"]),
+        "n_iter_": int(out["n_iter_"]),
+        "rss_": float(out["rss_"]),
+        "sw_": float(out["sw_"]),
+        "n_cols": inputs.n_cols,
+        "dtype": np.dtype(inputs.dtype).name,
+    }
+
+
 class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
     """LinearRegression estimator, drop-in for ``pyspark.ml.regression.LinearRegression``.
 
@@ -248,19 +264,6 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
                 # statistics checkpoint key: bf16 stats are keyed apart)
                 fast=resolve_solver_precision(params) == "bf16",
             )
-            if inputs.stream is not None:
-                # out-of-core: one streamed statistics pass, same replicated
-                # solve (docs/robustness.md "Memory safety")
-                from ..ops.streaming import linear_fit_streaming
-
-                state = linear_fit_streaming(inputs, **common)
-                return {
-                    "coef_": np.asarray(state["coef_"]),
-                    "intercept_": float(state["intercept_"]),
-                    "n_iter_": int(state["n_iter_"]),
-                    "n_cols": inputs.n_cols,
-                    "dtype": np.dtype(inputs.dtype).name,
-                }
             # elastic recovery: retain the sufficient statistics (the one
             # data pass) on host so a transient retry — and every further
             # sequential param set in this fit stage — solves without
@@ -274,7 +277,13 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
                 if use_ckpt
                 else {}
             )
-            if inputs.X_sparse is not None:
+            if inputs.stream is not None:
+                # out-of-core: streamed statistics passes, same replicated
+                # solve (docs/robustness.md "Memory safety")
+                from ..ops.streaming import linear_fit_streaming
+
+                state = linear_fit_streaming(inputs, **common)
+            elif inputs.X_sparse is not None:
                 ell_val, ell_idx = inputs.ell_rows()
                 fit_fn = linear_fit_ell_checkpointed if use_ckpt else linear_fit_ell
                 state = fit_fn(
@@ -288,14 +297,13 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
                 )
             else:
                 fit_fn = linear_fit_checkpointed if use_ckpt else linear_fit
-                state = fit_fn(inputs.X, inputs.y, inputs.w, **common, **ckpt_common)
-            return {
-                "coef_": np.asarray(state["coef_"]),
-                "intercept_": float(state["intercept_"]),
-                "n_iter_": int(state["n_iter_"]),
-                "n_cols": inputs.n_cols,
-                "dtype": np.dtype(inputs.dtype).name,
-            }
+                state = fit_fn(inputs.X, inputs.y, inputs.w, mesh=inputs.mesh, **common, **ckpt_common)
+            # once-per-fit child spans of `fit/solve` (docs/observability.md):
+            # `gram` and `cd` / `normal` inside the calls above, `finish` the
+            # fetch of the model's attributes
+            with telemetry.span("finish"):  # the five attributes in one fetch
+                out = jax.device_get(state)
+            return _model_attrs(out, inputs)
 
         return _fit
 
@@ -337,17 +345,12 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
                 )
             else:
                 stacked = linear_fit_batched(
-                    inputs.X, inputs.y, inputs.w, alphas, l1rs, **common
+                    inputs.X, inputs.y, inputs.w, alphas, l1rs, mesh=inputs.mesh, **common
                 )
-            stacked = {k: np.asarray(v) for k, v in stacked.items()}  # ONE fetch
+            with telemetry.span("finish"):  # ONE fetch
+                stacked = jax.device_get(stacked)
             return [
-                {
-                    "coef_": stacked["coef_"][i],
-                    "intercept_": float(stacked["intercept_"][i]),
-                    "n_iter_": int(stacked["n_iter_"][i]),
-                    "n_cols": inputs.n_cols,
-                    "dtype": np.dtype(inputs.dtype).name,
-                }
+                _model_attrs({k: v[i] for k, v in stacked.items()}, inputs)
                 for i in range(len(param_sets))
             ]
 
@@ -378,14 +381,21 @@ class LinearRegressionModel(_LinearRegressionParams, _TpuModelWithColumns):
         n_iter_: int = 0,
         n_cols: int = 0,
         dtype: str = "float32",
+        rss_: float = float("nan"),
+        sw_: float = 0.0,
         **kwargs: Any,
     ) -> None:
         super().__init__(
-            coef_=coef_, intercept_=intercept_, n_iter_=n_iter_, n_cols=n_cols, dtype=dtype
+            coef_=coef_, intercept_=intercept_, n_iter_=n_iter_, n_cols=n_cols, dtype=dtype,
+            rss_=rss_, sw_=sw_,
         )
         self.coef_ = np.asarray(coef_)
         self.intercept_ = float(intercept_)
         self.n_iter_ = int(n_iter_)
+        # the training summary's sums: weighted residual sum of squares and
+        # Σw (rmse = sqrt(rss_ / sw_)); nan / 0 for a model built without a fit
+        self.rss_ = float(rss_)
+        self.sw_ = float(sw_)
         self.n_cols = int(n_cols)
         self.dtype = dtype
 

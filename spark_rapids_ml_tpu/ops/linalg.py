@@ -41,6 +41,40 @@ def weighted_moments(X: jax.Array, w: jax.Array) -> Tuple[jax.Array, jax.Array, 
 GRAM_TILE_ROWS = 8192
 
 
+def _gram_tile(xc, wb, fast: bool, dtype):
+    """One tile's ``Σ w xc xcᵀ`` from rows already centred."""
+    if fast:
+        # weights applied at FULL precision first — a mixed-dtype einsum
+        # would promote the bf16 operand straight back to f32 and defeat
+        # the cast; the bf16 dot accumulates in f32 on the MXU
+        xcw = xc * wb[:, None]
+        return jnp.einsum(
+            "nd,ne->de", xcw.astype(jnp.bfloat16), xc.astype(jnp.bfloat16),
+            preferred_element_type=jnp.float32,
+        ).astype(dtype)
+    return jnp.einsum("nd,n,ne->de", xc, wb, xc)
+
+
+def _sum_over_row_tiles(tile, arrays, *, at_once: bool):
+    """``tile(*rows)`` (an array or a tuple of arrays) summed over tiles of
+    `GRAM_TILE_ROWS` rows of every array in `arrays`, in float32 on the
+    vector unit; one call where the rows fit one tile, or `at_once`."""
+    n, tile_rows = arrays[0].shape[0], GRAM_TILE_ROWS
+    if at_once or n <= tile_rows:
+        return tile(*arrays)
+    whole = n // tile_rows
+
+    def body(i, acc):
+        rows = [jax.lax.dynamic_slice_in_dim(a, i * tile_rows, tile_rows, axis=0) for a in arrays]
+        return jax.tree.map(jnp.add, acc, tile(*rows))
+
+    # from the first tile's sum, not from zeros: under `shard_map` the carry is then typed as its updates are
+    sums = jax.lax.fori_loop(1, whole, body, tile(*(a[:tile_rows] for a in arrays)))
+    if n % tile_rows:
+        sums = jax.tree.map(jnp.add, sums, tile(*(a[whole * tile_rows :] for a in arrays)))
+    return sums
+
+
 def centered_gram(
     X: jax.Array, w: jax.Array, mean: jax.Array, *, fast: bool = False
 ) -> jax.Array:
@@ -52,35 +86,50 @@ def centered_gram(
     always was, and so it is at any size under ``fast``: bf16 operands
     (weights applied at full precision first), one unbiased MXU pass with
     f32 accumulation, which tiles would only slow (0.0525 -> 0.0612 s)."""
+    return _sum_over_row_tiles(
+        lambda xb, wb: _gram_tile(xb - mean, wb, fast, X.dtype), (X, w), at_once=fast
+    )
 
-    def tile(xb, wb):
-        xc = xb - mean
-        if fast:
-            # weights applied at FULL precision first — a mixed-dtype einsum
-            # would promote the bf16 operand straight back to f32 and defeat
-            # the cast; the bf16 dot accumulates in f32 on the MXU
-            xcw = xc * wb[:, None]
-            return jnp.einsum(
-                "nd,ne->de", xcw.astype(jnp.bfloat16), xc.astype(jnp.bfloat16),
-                preferred_element_type=jnp.float32,
-            ).astype(X.dtype)
-        return jnp.einsum("nd,n,ne->de", xc, wb, xc)
 
-    n, tile_rows = X.shape[0], GRAM_TILE_ROWS
-    if fast or n <= tile_rows:
-        return tile(X, w)
-    whole = n // tile_rows
+def centered_moments(
+    X: jax.Array, y: jax.Array, w: jax.Array, x_mean: jax.Array, y_mean: jax.Array,
+    *, fast: bool = False,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """What a linear fit needs of (X, y) about given means, from the tiles of
+    `centered_gram` (its contraction, its `fast` arm): ``Σw(x-μ)(x-μ)ᵀ``
+    [d, d], and in the same tiles ``Σw(x-μ)(y-ȳ)`` [d] and ``Σw(y-ȳ)²`` at
+    full precision. Means of zero give the uncentred sums a fit without an
+    intercept solves from."""
 
-    def body(i, acc):
-        xb = jax.lax.dynamic_slice_in_dim(X, i * tile_rows, tile_rows, axis=0)
-        wb = jax.lax.dynamic_slice_in_dim(w, i * tile_rows, tile_rows, axis=0)
-        return acc + tile(xb, wb)
+    def tile(xb, yb, wb):
+        xc, yc = xb - x_mean, yb - y_mean
+        wy = wb * yc
+        return _gram_tile(xc, wb, fast, X.dtype), jnp.einsum("nd,n->d", xc, wy), jnp.sum(wy * yc)
 
-    # from the first tile's sum, not from zeros: under `shard_map` the carry is then typed as its updates are
-    gram = jax.lax.fori_loop(1, whole, body, tile(X[:tile_rows], w[:tile_rows]))
-    if n % tile_rows:
-        gram = gram + tile(X[whole * tile_rows :], w[whole * tile_rows :])
-    return gram
+    return _sum_over_row_tiles(tile, (X, y, w), at_once=fast)
+
+
+def _over_row_shards(local, arrays, *, mesh, fast: bool):
+    """``local(total, *arrays)`` where `total` adds a partial sum over the
+    row shards. Where a shard of a float32 contraction has more rows than one
+    tile, each device runs `local` (and its tile loop) over its own rows
+    under `shard_map` and `total` is a `psum` (a tile loop over the global
+    array would slice across shards); smaller shards, and callers that pass
+    no mesh, leave the one contraction and its psum to GSPMD."""
+    shards = 1 if mesh is None else int(mesh.devices.size)
+    if shards > 1 and not fast and arrays[0].shape[0] // shards > GRAM_TILE_ROWS:
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        from ..parallel.mesh import ROWS_AXIS
+
+        out_shapes = jax.eval_shape(partial(local, lambda v: v), *arrays)
+        return shard_map(
+            partial(local, lambda v: jax.lax.psum(v, ROWS_AXIS)), mesh=mesh,
+            in_specs=tuple(P(ROWS_AXIS, *(None,) * (a.ndim - 1)) for a in arrays),
+            out_specs=jax.tree.map(lambda _: P(), out_shapes),
+        )(*arrays)
+    return local(lambda v: v, *arrays)
 
 
 def weighted_cov(
@@ -93,11 +142,7 @@ def weighted_cov(
     program: the mean, then the centred sum (`centered_gram`: MXU
     contractions over row tiles).
 
-    ``mesh``: the mesh a row-sharded X lives on. Where a shard of a float32
-    contraction has more rows than one tile, each device runs the tile loop over its own rows under
-    `shard_map` and the sums are `psum`'d (a tile loop over the global array
-    would slice across shards); smaller shards, and callers that pass no
-    mesh, leave the one contraction and its psum to GSPMD as before.
+    ``mesh``: the mesh a row-sharded X lives on (`_over_row_shards`).
 
     ``fast`` runs the big contraction bf16-in / f32-accumulate (the
     solver_precision="bf16" contract, docs/performance.md "Mixed-precision
@@ -105,26 +150,35 @@ def weighted_cov(
     [n,d]x[n,d] outer product is cast. Parity vs the full-precision cov is
     pinned by tests/test_precision.py.
     """
-    shards = 1 if mesh is None else int(mesh.devices.size)
-    if shards > 1 and not fast and X.shape[0] // shards > GRAM_TILE_ROWS:
-        from jax import shard_map
-        from jax.sharding import PartitionSpec as P
 
-        from ..parallel.mesh import ROWS_AXIS
+    def local(total, Xl, wl):
+        total_w = total(jnp.sum(wl))
+        mean = total(jnp.einsum("n,nd->d", wl, Xl)) / total_w
+        return total_w, mean, total(centered_gram(Xl, wl, mean, fast=fast))
 
-        def local(Xl, wl):
-            total_w = jax.lax.psum(jnp.sum(wl), ROWS_AXIS)
-            mean = jax.lax.psum(jnp.einsum("n,nd->d", wl, Xl), ROWS_AXIS) / total_w
-            return total_w, mean, jax.lax.psum(centered_gram(Xl, wl, mean, fast=fast), ROWS_AXIS)
-
-        total_w, mean, gram = shard_map(
-            local, mesh=mesh, in_specs=(P(ROWS_AXIS, None), P(ROWS_AXIS)), out_specs=(P(), P(), P())
-        )(X, w)
-    else:
-        total_w = jnp.sum(w)
-        mean = jnp.einsum("n,nd->d", w, X) / total_w
-        gram = centered_gram(X, w, mean, fast=fast)
+    total_w, mean, gram = _over_row_shards(local, (X, w), mesh=mesh, fast=fast)
     return total_w, mean, gram / (total_w - ddof)
+
+
+def weighted_xy_moments(
+    X: jax.Array, y: jax.Array, w: jax.Array, *, center: bool = True, fast: bool = False, mesh=None
+):
+    """The statistics of a weighted linear fit, on `weighted_cov`'s two
+    passes: (Σw, x̄ [d], ȳ, Σw(x-x̄)(x-x̄)ᵀ [d, d], Σw(x-x̄)(y-ȳ) [d],
+    Σw(y-ȳ)²). ``center=False`` (a fit without an intercept) is the same
+    tile loop with both means given as zero, and no pass for them."""
+
+    def local(total, Xl, yl, wl):
+        total_w = total(jnp.sum(wl))
+        if center:
+            x_mean = total(jnp.einsum("n,nd->d", wl, Xl)) / total_w
+            y_mean = total(jnp.sum(wl * yl)) / total_w
+        else:
+            x_mean, y_mean = jnp.zeros(Xl.shape[1:], Xl.dtype), jnp.zeros((), Xl.dtype)
+        gram, xy, yy = centered_moments(Xl, yl, wl, x_mean, y_mean, fast=fast)
+        return total_w, x_mean, y_mean, total(gram), total(xy), total(yy)
+
+    return _over_row_shards(local, (X, y, w), mesh=mesh, fast=fast)
 
 
 def sign_flip(components: jax.Array) -> jax.Array:

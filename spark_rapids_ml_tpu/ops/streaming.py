@@ -46,7 +46,7 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..parallel.mesh import stream_place_blocks
-from .linalg import centered_gram
+from .linalg import centered_gram, centered_moments
 from ..utils import numcheck
 
 
@@ -150,49 +150,80 @@ def _ell_block_iter(
 # ------------------------------------------------------- linear / PCA -------
 
 
-def linear_streaming_stats(inputs: Any, fast: bool = False) -> Dict[str, np.ndarray]:
-    """One streamed pass accumulating the normal-equation sufficient
-    statistics (ops/linear._sufficient_stats tuple) — dense or padded-ELL.
-    Padding rows carry zero weight and zero features, so per-chunk partials
-    sum to exactly the resident statistics (up to summation rounding).
-    ``fast`` runs each chunk's stat contractions bf16-in / f32-accumulate;
-    the cross-chunk host accumulation stays at full precision."""
-    from .linear import _STATS_NAMES, _ell_stats_jit, _stats_jit
+@jax.jit
+def _xy_means_block(xb, yb, wb):
+    """Per-chunk weighted first moments: (Σw, Σw·x [d], Σw·y)."""
+    return jnp.sum(wb), jnp.einsum("n,nd->d", wb, xb), jnp.sum(wb * yb)
+
+
+@partial(jax.jit, static_argnames=("fast",))
+def _shifted_sums_block(xb, yb, wb, x_shift, y_shift, fast: bool = False):
+    """Per-chunk raw sums of the rows less a given shift, the resident fit's
+    own tiles (`linalg.centered_moments`): (Σw, Σw(x-s) [d], Σw(y-t),
+    Σw(x-s)(x-s)ᵀ [d, d], Σw(x-s)(y-t) [d], Σw(y-t)²)."""
+    gram, xy, yy = centered_moments(xb, yb, wb, x_shift, y_shift, fast=fast)
+    return (jnp.sum(wb), jnp.einsum("n,nd->d", wb, xb - x_shift), jnp.sum(wb * (yb - y_shift)),
+            gram, xy, yy)
+
+
+def linear_streaming_stats(
+    inputs: Any, fit_intercept: bool = True, fast: bool = False
+) -> Tuple[np.ndarray, ...]:
+    """The statistics of a linear fit (ops/linear `_STATS_NAMES`) from ONE
+    streamed pass: each chunk's raw sums (dense: the resident fit's tiled
+    contraction about zero; padded-ELL: the scatter-add), added up on the
+    host at full precision and centred on the [d, d] side (ops/linear
+    `_centred_from_raw`). Padding rows carry zero weight and zero features,
+    so per-chunk partials sum to exactly the resident statistics (up to
+    summation rounding).
+
+    ``fast`` runs each dense chunk's gram bf16-in / f32-accumulate, and what
+    it rounds has to be what the resident fit rounds, the CENTRED rows: so a
+    dense ``fast`` fit with an intercept streams the means first and takes
+    the chunks' sums about them (the [d, d] side then takes out only what
+    rounding left of the means)."""
+    from .linear import _centred_from_raw, _ell_raw_jit
 
     dtype = inputs.dtype
-    y = np.asarray(inputs.y, dtype=dtype)
-    w = np.asarray(inputs.w, dtype=dtype)
-    extras = {"y": y, "w": w}
-    acc: Optional[List[np.ndarray]] = None
+    extras = {"y": np.asarray(inputs.y, dtype=dtype), "w": np.asarray(inputs.w, dtype=dtype)}
+    raw_names = ("sw", "sx", "sy", "G", "c", "syy")
     _nc = numcheck.hook()  # SRML_NUMCHECK=1: sweep per-chunk host partials
-    if inputs.X_sparse is not None:
-        d = inputs.n_cols
-        for blk in stream_place_blocks(
-            inputs.mesh, _ell_block_iter(inputs, extras, cache=False)
-        ):
-            part = _ell_stats_jit(
-                blk["val"], blk["idx"], blk["y"], blk["w"], d=d, tile=8192,
-                fast=fast,
-            )
+
+    def summed(chunks, part_of, names=raw_names):
+        acc: Optional[List[np.ndarray]] = None
+        for blk in stream_place_blocks(inputs.mesh, chunks):
+            part = part_of(blk)
             # per-chunk partial fetch = the streaming pipeline's existing
             # sync; the efficiency attributor times the wait as `execute`
             with telemetry.device_wait("stream_chunk"):
                 part = [np.asarray(p) for p in part]
             if _nc is not None:
-                _nc("linear_stream.chunk", solver="linear_stream",
-                    **{n: p for n, p in zip(_STATS_NAMES, part)})
+                _nc("linear_stream.chunk", solver="linear_stream", **dict(zip(names, part)))
             acc = part if acc is None else [a + b for a, b in zip(acc, part)]
-    else:
-        for blk in stream_place_blocks(inputs.mesh, _dense_block_iter(inputs, extras)):
-            part = _stats_jit(blk["X"], blk["y"], blk["w"], fast=fast)
-            with telemetry.device_wait("stream_chunk"):
-                part = [np.asarray(p) for p in part]
-            if _nc is not None:
-                _nc("linear_stream.chunk", solver="linear_stream",
-                    **{n: p for n, p in zip(_STATS_NAMES, part)})
-            acc = part if acc is None else [a + b for a, b in zip(acc, part)]
-    assert acc is not None, "streaming stats over an empty dataset"
-    return {name: np.asarray(v) for name, v in zip(_STATS_NAMES, acc)}
+        assert acc is not None, "streaming stats over an empty dataset"
+        return acc
+
+    if inputs.X_sparse is not None:
+        return tuple(np.asarray(v) for v in _centred_from_raw(summed(
+            _ell_block_iter(inputs, extras, cache=False),
+            lambda blk: _ell_raw_jit(
+                blk["val"], blk["idx"], blk["y"], blk["w"], d=inputs.n_cols, tile=8192, fast=fast),
+        ), fit_intercept))
+    x_shift, y_shift = np.zeros(inputs.n_cols, dtype), np.zeros((), dtype)
+    if fast and fit_intercept:
+        sw, sx, sy = summed(
+            _dense_block_iter(inputs, extras),
+            lambda blk: _xy_means_block(blk["X"], blk["y"], blk["w"]), raw_names[:3],
+        )
+        x_shift, y_shift = sx / sw, sy / sw
+    xs_dev, ys_dev = jnp.asarray(x_shift, dtype), jnp.asarray(y_shift, dtype)
+    sw, xm, ym, Gc, cc, syc = _centred_from_raw(summed(
+        _dense_block_iter(inputs, extras),
+        lambda blk: _shifted_sums_block(blk["X"], blk["y"], blk["w"], xs_dev, ys_dev, fast=fast),
+    ), fit_intercept)
+    if fit_intercept:
+        xm, ym = x_shift + xm, y_shift + ym
+    return tuple(np.asarray(v) for v in (sw, xm, ym, Gc, cc, syc))
 
 
 def linear_fit_streaming(
@@ -207,35 +238,29 @@ def linear_fit_streaming(
     tol: float = 1e-6,
     fast: bool = False,
 ) -> Dict[str, jax.Array]:
-    """Out-of-core linear regression: the one streamed statistics pass feeds
-    the SAME replicated (d, d) solve as the resident path. The statistics are
+    """Out-of-core linear regression: the streamed statistics feed the SAME
+    replicated (d, d) solve as the resident path. The statistics are
     retained in the active `CheckpointStore` (when one is installed), so a
     transient retry — or every further param set of a sequential sweep —
-    skips the data pass, exactly like the resident checkpointed fit. `fast`
-    statistics are keyed apart from full-precision ones."""
-    from .. import checkpoint as _ckpt
-    from ..parallel import chaos
-    from .linear import _STATS_NAMES, _solve_stats_jit
+    skips the data passes, exactly like the resident checkpointed fit
+    (keyed apart by what they are taken about and by `fast`)."""
+    from ..parallel.mesh import X_DEFAULT
+    from .linear import _fit_from_retained_stats, _gram_span, stats_ckpt_key
 
     dtype = inputs.dtype
-    store = _ckpt.active_store()
-    key = "linear_stats_stream" + ("_ell" if inputs.X_sparse is not None else "")
-    if fast:
-        key = key + ":bf16"
-    pkey = ("stream", int(inputs.n_valid), int(inputs.n_cols), np.dtype(dtype).name)
-    if store is not None:
-        state = store.get_or_compute(
-            key, lambda: linear_streaming_stats(inputs, fast=fast), solver="linear",
-            placement_key=pkey,
-        )
-    else:
-        state = linear_streaming_stats(inputs, fast=fast)
-    chaos.maybe_fail_stage("solve", 0)
-    stats = tuple(jnp.asarray(state[n], dtype) for n in _STATS_NAMES)
-    return _solve_stats_jit(
-        stats, jnp.zeros((), dtype),
+    sparse = inputs.X_sparse is not None
+
+    def compute():
+        with _gram_span(int(inputs.n_valid), int(inputs.n_cols), fast, "ell" if sparse else X_DEFAULT):
+            return linear_streaming_stats(inputs, fit_intercept=fit_intercept, fast=fast)
+
+    return _fit_from_retained_stats(
+        compute, dtype,
         alpha=alpha, l1_ratio=l1_ratio, fit_intercept=fit_intercept,
-        standardize=standardize, use_cd=use_cd, max_iter=int(max_iter), tol=tol,
+        standardize=standardize, use_cd=use_cd, max_iter=max_iter, tol=tol,
+        ckpt_key=stats_ckpt_key(
+            "linear_stats_stream" + ("_ell" if sparse else ""), fit_intercept=fit_intercept, fast=fast),
+        placement_key=("stream", int(inputs.n_valid), int(inputs.n_cols), np.dtype(dtype).name),
     )
 
 
