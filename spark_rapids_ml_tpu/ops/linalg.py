@@ -41,18 +41,72 @@ def weighted_moments(X: jax.Array, w: jax.Array) -> Tuple[jax.Array, jax.Array, 
 GRAM_TILE_ROWS = 8192
 
 
-def _gram_tile(xc, wb, fast: bool, dtype):
-    """One tile's ``Σ w xc xcᵀ`` from rows already centred."""
+# Columns of one panel of a tile's contraction. The matrix is symmetric, so a
+# tile's sum is taken as column panels that cover the block upper triangle
+# only (panel s: rows s..s+c of the result from column s on), the panels are
+# what the tile loop carries and adds, and the full [d, d] is assembled once
+# after the loop (`_mirror`): its strictly-lower blocks are COPIES, the
+# transposes of the upper ones. Every entry kept is the contraction it always
+# was (the same rows, the same MXU passes, the same float32 tile adds); the
+# others are not computed. Up to one panel's width, and under `fast` at any
+# (`gram_panels`), the function is the whole contraction it was.
+# The width, from the statistics pass of both cells that run it at
+# [393,216, 3,000] on a v5e (PCA's / the linear fits', seconds a pass; my chip
+# runs, PERF.md PR 35): whole 0.2578 / 0.2645; 1,024 columns (0.667 of the
+# work) 0.1702 / 0.1712; 768 (0.625) 0.1615 / 0.1627; 512 (0.584) 0.1489 /
+# 0.1502; 384 (0.563) 0.1480 / 0.1467; 256 (0.542) 0.1430 / 0.1443; 128
+# (0.521) 0.1363 / 0.1375. A panel's convolution runs nearer the MXU's peak
+# than the whole one did (96 % at 512 columns against 88 %), so the time
+# follows the work down to the narrowest width tried. What narrower panels
+# cost is the program: one convolution a panel, and again for the first tile,
+# each its own code on the device (2.4 MiB whole, 13.4 MiB at 512 columns,
+# 20.6 MiB at 256, which the fit's peak shows: 4.434 / 4.445 / 4.452 GiB)
+# and its own compile (6 / 12 / 15-19 s from an empty cache), both growing
+# with d. 512 columns take 95 % of what 256 take and half the program.
+GRAM_PANEL_COLS = 512
+
+
+def gram_panels(d: int, fast: bool = False) -> Tuple[int, int]:
+    """(panels, panel_cols) of a [d, d] gram: how `centered_gram` splits it
+    (1 and d: the whole contraction)."""
+    if fast or d <= GRAM_PANEL_COLS:
+        return 1, d
+    return -(-d // GRAM_PANEL_COLS), GRAM_PANEL_COLS
+
+
+def _gram_panels(xc, wb, fast: bool, dtype):
+    """One tile's ``Σ w xc xcᵀ`` from rows already centred, as the tuple of
+    its upper panels: panel i is rows s..s+c of the result from column s on,
+    [c, d - s] with s = i·c (one [d, d] panel where d is one panel wide)."""
     if fast:
         # weights applied at FULL precision first — a mixed-dtype einsum
         # would promote the bf16 operand straight back to f32 and defeat
         # the cast; the bf16 dot accumulates in f32 on the MXU
         xcw = xc * wb[:, None]
-        return jnp.einsum(
+        return (jnp.einsum(
             "nd,ne->de", xcw.astype(jnp.bfloat16), xc.astype(jnp.bfloat16),
             preferred_element_type=jnp.float32,
-        ).astype(dtype)
-    return jnp.einsum("nd,n,ne->de", xc, wb, xc)
+        ).astype(dtype),)
+    d = xc.shape[1]
+    panels, c = gram_panels(d)
+    if panels == 1:
+        return (jnp.einsum("nd,n,ne->de", xc, wb, xc),)
+    return tuple(jnp.einsum("nd,n,ne->de", xc[:, s : s + c], wb, xc[:, s:]) for s in range(0, d, c))
+
+
+def _mirror(panels):
+    """The full symmetric [d, d] from its upper panels: each panel written
+    where it lies, and what hangs over its diagonal block transposed into
+    the strictly-lower blocks beneath that block."""
+    if len(panels) == 1:
+        return panels[0]
+    c, d = panels[0].shape
+    full = jnp.zeros((d, d), panels[0].dtype)
+    for i, p in enumerate(panels):
+        s = i * c
+        full = full.at[s : s + c, s:].set(p)
+        full = full.at[s + c :, s : s + c].set(p[:, c:].T)
+    return full
 
 
 def _sum_over_row_tiles(tile, arrays, *, at_once: bool):
@@ -82,13 +136,15 @@ def centered_gram(
     the caller's one program: the mean is given (first pass), the centring
     and the weighting are fused into each tile's contraction, so nothing of
     X's size is written (the peak is X plus a few tiles). Never the
-    uncentred form. Up to `GRAM_TILE_ROWS` rows it is the one contraction it
-    always was, and so it is at any size under ``fast``: bf16 operands
-    (weights applied at full precision first), one unbiased MXU pass with
-    f32 accumulation, which tiles would only slow (0.0525 -> 0.0612 s)."""
-    return _sum_over_row_tiles(
-        lambda xb, wb: _gram_tile(xb - mean, wb, fast, X.dtype), (X, w), at_once=fast
-    )
+    uncentred form. Wider than `GRAM_PANEL_COLS` only the block upper
+    triangle is contracted and the rest mirrored after the loop. Up to
+    `GRAM_TILE_ROWS` rows it is one contraction a panel, and so it is at any
+    size under ``fast``: bf16 operands (weights applied at full precision
+    first), one unbiased MXU pass with f32 accumulation, which tiles would
+    only slow (0.0525 -> 0.0612 s)."""
+    return _mirror(_sum_over_row_tiles(
+        lambda xb, wb: _gram_panels(xb - mean, wb, fast, X.dtype), (X, w), at_once=fast
+    ))
 
 
 def centered_moments(
@@ -96,7 +152,7 @@ def centered_moments(
     *, fast: bool = False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """What a linear fit needs of (X, y) about given means, from the tiles of
-    `centered_gram` (its contraction, its `fast` arm): ``Σw(x-μ)(x-μ)ᵀ``
+    `centered_gram` (its panels, its `fast` arm): ``Σw(x-μ)(x-μ)ᵀ``
     [d, d], and in the same tiles ``Σw(x-μ)(y-ȳ)`` [d] and ``Σw(y-ȳ)²`` at
     full precision. Means of zero give the uncentred sums a fit without an
     intercept solves from."""
@@ -104,9 +160,10 @@ def centered_moments(
     def tile(xb, yb, wb):
         xc, yc = xb - x_mean, yb - y_mean
         wy = wb * yc
-        return _gram_tile(xc, wb, fast, X.dtype), jnp.einsum("nd,n->d", xc, wy), jnp.sum(wy * yc)
+        return _gram_panels(xc, wb, fast, X.dtype), jnp.einsum("nd,n->d", xc, wy), jnp.sum(wy * yc)
 
-    return _sum_over_row_tiles(tile, (X, y, w), at_once=fast)
+    panels, xy, yy = _sum_over_row_tiles(tile, (X, y, w), at_once=fast)
+    return _mirror(panels), xy, yy
 
 
 def _over_row_shards(local, arrays, *, mesh, fast: bool):

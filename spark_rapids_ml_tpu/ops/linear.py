@@ -31,7 +31,7 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..parallel.mesh import x_layout_of
-from .linalg import weighted_xy_moments
+from .linalg import gram_panels, weighted_xy_moments
 
 # A fit's statistics, whoever made them: Σw, the means the sums are taken
 # about (x̄ [d] and ȳ; zeros for a fit without an intercept), and about them
@@ -54,10 +54,13 @@ def _gram_span(rows: int, d: int, fast: bool, x_layout: str):
     """The `gram` span of one pass over the data (a child of the caller's
     `fit/solve`; PCA's name, so that one set of metrics reads both), and the
     count of it: a fit from retained statistics opens none and adds nothing
-    to `linear.gram_passes`."""
+    to `linear.gram_passes`. `panels` and `panel_cols`: PCA's (the padded-ELL
+    pass scatter-adds its gram and has one panel)."""
     telemetry.registry().inc("linear.gram_passes")
+    panels, panel_cols = (1, d) if x_layout == "ell" else gram_panels(d, fast)
     return telemetry.span(
-        "gram", rows=rows, d=d, precision="bf16" if fast else "f32", x_layout=x_layout, targets=1
+        "gram", rows=rows, d=d, precision="bf16" if fast else "f32", x_layout=x_layout, targets=1,
+        panels=panels, panel_cols=panel_cols,
     )
 
 

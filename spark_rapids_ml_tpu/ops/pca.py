@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..parallel.mesh import x_layout_of
-from .linalg import sign_flip, topk_eigh, weighted_cov
+from .linalg import gram_panels, sign_flip, topk_eigh, weighted_cov
 
 
 def check_pca_state(state: Dict, *, k: int) -> Dict:
@@ -88,9 +88,15 @@ def _pca_stats(X: jax.Array, w: jax.Array, fast: bool = False, mesh=None):
 def _gram_span(rows: int, d: int, fast: bool, x_layout: str):
     """The `gram` span of one pass over X (a child of the caller's
     `fit/solve`), and the count of it: a fit that reuses retained statistics
-    opens none and adds nothing to `pca.gram_passes`."""
+    opens none and adds nothing to `pca.gram_passes`. `panels` and
+    `panel_cols` say how the contraction is split (`linalg.gram_panels`:
+    1 = the whole contraction, more = the block upper triangle only)."""
     telemetry.registry().inc("pca.gram_passes")
-    return telemetry.span("gram", rows=rows, d=d, precision="bf16" if fast else "f32", x_layout=x_layout)
+    panels, panel_cols = gram_panels(d, fast)
+    return telemetry.span(
+        "gram", rows=rows, d=d, precision="bf16" if fast else "f32", x_layout=x_layout,
+        panels=panels, panel_cols=panel_cols,
+    )
 
 
 def _gram_pass(X: jax.Array, w: jax.Array, *, fast: bool, mesh=None):

@@ -70,3 +70,28 @@ def mesh8():
 
     assert len(default_devices()) >= 8, "conftest must provide 8 CPU devices"
     return get_mesh(8)
+
+
+@pytest.fixture
+def gram_constants(monkeypatch):
+    """A function that patches `ops/linalg.py`'s `GRAM_TILE_ROWS` and
+    `GRAM_PANEL_COLS` (`tile_rows=`, `panel_cols=`) for the test. The jitted
+    statistics passes read them while tracing, so every patch, and the end of
+    the test, drops the traces."""
+    import jax
+
+    from spark_rapids_ml_tpu.ops import linalg
+
+    names = {"tile_rows": "GRAM_TILE_ROWS", "panel_cols": "GRAM_PANEL_COLS"}
+    patched = []
+
+    def patch(**constants):
+        for name, value in constants.items():
+            monkeypatch.setattr(linalg, names[name], value)
+        jax.clear_caches()
+        patched.append(constants)
+
+    yield patch
+    if patched:
+        jax.clear_caches()
+

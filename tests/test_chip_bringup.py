@@ -414,3 +414,41 @@ def test_the_pca_finish_at_d3000_decomposes_no_whole_matrix(monkeypatch):
         eig.compile()
         attrs.compile()
         assert time.perf_counter() - t0 < 60  # 5 s here; the issue's bound for any program of the fit
+
+
+def test_the_gram_at_d3000_compiles_as_panels_that_copy_nothing(monkeypatch):
+    """`_pca_stats` at the cells' shape compiled for a v5e: the float32 arm is
+    one loop whose body holds one convolution a panel of the block upper
+    triangle, each with its slice, centring and weighting fused in (its
+    temporaries stay under three tiles: nothing of X's size); the bf16 arm
+    is its one contraction with no temporaries. (Sliced into panels the bf16
+    arm's untiled operands were held as two bf16 copies of X, 4.4 GiB, which
+    is why `gram_panels` gives it one panel: PERF.md, PR 35.)"""
+    import re
+
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.ops import linalg, pca
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    except Exception as e:  # no libtpu on this machine: nothing to compile with
+        pytest.skip(f"no TPU compiler available ahead of time: {type(e).__name__}: {e}")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    n, d = 393_216, 3000
+    X = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    panels, _ = linalg.gram_panels(d)
+    assert panels > 1 and linalg.gram_panels(d, fast=True) == (1, d)
+    tile_bytes = linalg.GRAM_TILE_ROWS * d * 4
+    with jax.enable_x64(False), jax.default_matmul_precision("float32"):
+        f32 = pca._pca_stats.lower(X, w).compile()
+        bf16 = pca._pca_stats.lower(X, w, fast=True).compile()
+    text = f32.as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r" convolution\(", text)) == 2 * panels  # the first tile's and the loop body's
+    assert f32.memory_analysis().temp_size_in_bytes < 3 * tile_bytes
+    assert len(re.findall(r" convolution\(", bf16.as_text())) == 1
+    assert bf16.memory_analysis().temp_size_in_bytes < tile_bytes

@@ -219,7 +219,10 @@ def test_no_second_x():
 # ------------------------------------------------ (c) spans and counters ----
 
 
-def test_spans_and_counters_of_a_fit(telemetry_on):
+@pytest.mark.parametrize("panel_cols", [None, 24])
+def test_spans_and_counters_of_a_fit(telemetry_on, gram_constants, panel_cols):
+    if panel_cols:  # the gram's panel width patched under d
+        gram_constants(panel_cols=panel_cols)
     X, y = planted_rows(7, n=600)
     model = LinearRegression(**ESTIMATOR).setFeaturesCol("features").fit(pd.DataFrame({"features": list(X), "label": y}))
     metrics = model._fit_metrics
@@ -227,6 +230,8 @@ def test_spans_and_counters_of_a_fit(telemetry_on):
     assert {"fit/solve/gram", "fit/solve/cd", "fit/solve/finish"} <= set(spans) and "fit/solve/normal" not in spans
     gram, cd = spans["fit/solve/gram"], spans["fit/solve/cd"]
     assert (gram["d"], gram["precision"], gram["x_layout"], gram["targets"]) == (64, "f32", "default", 1)
+    # one panel (the whole contraction) at d = 64 under the real constant; three with it patched to 24 columns
+    assert (gram["panels"], gram["panel_cols"]) == ((3, 24) if panel_cols else (1, 64))
     assert gram["rows"] >= 600
     assert (cd["d"], cd["sweeps"], cd["stopped_by"]) == (64, 10, "max_iter") and cd["max_delta"] > 0
     assert cd["l1"] == pytest.approx(5e-6) and cd["l2"] == pytest.approx(5e-6)

@@ -178,8 +178,10 @@ def telemetry_on():
     telemetry.disable()
 
 
-@pytest.mark.parametrize("d,path", [(64, "topk"), (32, "full")])
-def test_spans_and_counters_of_a_fit(telemetry_on, d, path):
+@pytest.mark.parametrize("d,path,panel_cols", [(64, "topk", None), (32, "full", None), (64, "topk", 24)])
+def test_spans_and_counters_of_a_fit(telemetry_on, gram_constants, d, path, panel_cols):
+    if panel_cols:  # the gram's panel width patched under d
+        gram_constants(panel_cols=panel_cols)
     X = planted_rows(7, n=600, d=d)
     model = PCA(k=3).setInputCol("features").fit(pd.DataFrame({"features": list(X)}))
     metrics = model._fit_metrics
@@ -187,6 +189,8 @@ def test_spans_and_counters_of_a_fit(telemetry_on, d, path):
     assert {"fit/solve/gram", "fit/solve/eig", "fit/solve/finish"} <= set(spans)
     gram, eig = spans["fit/solve/gram"], spans["fit/solve/eig"]
     assert (gram["d"], gram["precision"], gram["x_layout"]) == (d, "f32", "default") and gram["rows"] >= 600
+    # one panel (the whole contraction) under the real constant; three with it patched to 24 columns
+    assert (gram["panels"], gram["panel_cols"]) == ((3, 24) if panel_cols else (1, d))
     assert eig["eig_path"] == path and eig["block"] == (16 if path == "topk" else 0)
     assert eig["residual_max"] <= 4e-6
     assert (eig["iterations"] > 0) == (path == "topk")
