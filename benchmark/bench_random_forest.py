@@ -20,7 +20,7 @@ class BenchmarkRandomForest(BenchmarkBase):
         "numTrees": (int, 0, "override protocol tree count"),
         "maxDepth": (int, 0, "override protocol depth"),
         "maxBins": (int, 128, "histogram bins (protocol: 128)"),
-        "node_chunk": (int, 256, "nodes processed per histogram pass (HBM knob)"),
+        "node_chunk": (int, 0, "nodes processed per histogram pass (HBM knob; 0 = what SEGMENT_BUDGET holds)"),
     }
 
     def gen_dataset(self, args, mesh):
@@ -115,7 +115,7 @@ class BenchmarkRandomForest(BenchmarkBase):
         def run():
             # quantile sketch from the row subsample (binning is part of the
             # fit, like cuRF's quantile computation)
-            edges = quantile_bins(xs, args.maxBins, seed=args.seed).astype(np.float32)
+            edges = quantile_bins(xs, args.maxBins).astype(np.float32)
             Xb = bin_features(data["X"], edges)
             if release_raw:
                 # the forest consumes ONLY the binned matrix; at protocol
@@ -139,12 +139,12 @@ class BenchmarkRandomForest(BenchmarkBase):
             stats_dev = jax.device_put(stats, row_sharding(mesh, 2))
             w = data["w"]
             return forest_fit(
-                Xb, stats_dev * w[:, None], w, args.seed, mesh=mesh,
+                Xb, stats_dev * w[:, None], w, args.seed, mesh=mesh, n_features=args.num_cols,
                 n_trees=n_trees, max_depth=depth, max_bins=args.maxBins,
                 max_features=max(1, int(np.sqrt(args.num_cols))) if clf else max(1, args.num_cols // 3),
                 impurity="gini" if clf else "variance",
                 node_chunk=args.node_chunk, bootstrap=True, subsample_rate=1.0,
-                min_instances=1.0, min_info_gain=0.0, n_stats=2 if clf else 3,
+                min_instances=1.0, min_info_gain=0.0, integer_stats=clf,
             )
 
         state = {}
@@ -156,7 +156,7 @@ class BenchmarkRandomForest(BenchmarkBase):
             return s
 
         _, sec = with_benchmark(f"random_forest[{args.task}] fit", timed)
-        self._state = {k: np.asarray(v) for k, v in state.items()}
+        self._state = {k: np.asarray(v)[:n_trees] for k, v in state.items() if k != "plan"}  # whole rounds cut to the trees asked for
         self._clf = clf
         self._depth = depth
         return {"fit": sec}
@@ -174,7 +174,7 @@ class BenchmarkRandomForest(BenchmarkBase):
         node_stats = _fill_empty_nodes(feature, self._state["node_stats"].astype(np.float64))
         from spark_rapids_ml_tpu.ops.trees import quantile_bins
 
-        edges = quantile_bins(data["X_sample"], args.maxBins, seed=args.seed)
+        edges = quantile_bins(data["X_sample"], args.maxBins)
         threshold = split_bins_to_thresholds(feature, self._state["split_bin"], edges)
         if self._clf:
             leaves = node_stats / np.maximum(node_stats.sum(axis=2, keepdims=True), 1e-30)

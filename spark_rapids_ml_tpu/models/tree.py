@@ -108,7 +108,7 @@ class _RandomForestParams(
             "bootstrap": True,
             "split_criterion": None,  # set by subclass default
             "random_state": 0,
-            "node_chunk": 256,
+            "node_chunk": 0,  # nodes a histogram pass: 0 = as many as ops.trees.SEGMENT_BUDGET holds
             "verbose": False,
         }
 
@@ -176,83 +176,155 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
         y = labels.astype(np.float64)
         return np.stack([np.ones_like(y), y, y * y], axis=1).astype(np.float32)
 
-    def _get_tpu_fit_func(self, extracted: ExtractedData):
-        from ..ops.trees import bin_features, forest_fit, quantile_bins, split_bins_to_thresholds
+    def _solver_workspace_terms(
+        self, rows_per_device: int, n_cols: int, params: Dict[str, Any], itemsize: int
+    ) -> Dict[str, int]:
+        # what a forest fit holds beside the placement (tests/test_memory.py
+        # pins each): the binned X kept with the placement; the searchsorted
+        # temporaries of one binning tile (five full-shape 4-byte arrays);
+        # the deepest pass's histogram with its prefix sums, right-hand
+        # statistics and gains (four arrays of its size); a row's node id,
+        # flag, statistics and bootstrapped statistics. A classifier is
+        # priced at two classes: the labels are not read before admission.
+        from ..ops.trees import BIN_TILE_CELLS, binned_cols, level_plan, plan_summary
 
-        x_host = extracted.features
-        labels_host = extracted.label
+        bins, S = int(params["n_bins"]), (2 if self._is_classification else 3)
+        m = resolve_max_features(params["max_features"], n_cols, self._is_classification)
+        plan = level_plan(int(params["max_depth"]), m, bins, S, int(params["node_chunk"]))
+        tile_rows = min(rows_per_device, max(1024, BIN_TILE_CELLS // max(n_cols, 1)))
+        return {
+            "binned_X": rows_per_device * binned_cols(n_cols) * (1 if bins <= 256 else 4),
+            "bin_tile": 5 * tile_rows * n_cols * 4,
+            "histogram": 4 * S * plan_summary(plan)["deepest_chunk"] * m * bins * 4,
+            "row_state": rows_per_device * (4 + 1 + 2 * S * itemsize),
+        }
 
-        def _fit(inputs: FitInputs, params: Dict[str, Any]) -> Dict[str, Any]:
-            d = inputs.n_cols
-            max_bins = int(params["n_bins"])
-            max_depth = int(params["max_depth"])
-            seed = int(params["random_state"] or 0)
+    def _placement_bins(self, inputs: FitInputs, extracted: ExtractedData, max_bins: int) -> Dict[str, Any]:
+        """What a forest fit needs of the placement and nothing of the fit's
+        own parameters but `maxBins`: the bin edges and the binned X (of the
+        features and `maxBins`), the class set and the per-row statistics (of
+        the labels and the kind of forest). MEMOIZED on `inputs.extra`, the
+        bins under a key that holds `maxBins`, the label's part under one
+        that holds the kind: a classifier and a regressor fitted on one
+        placement share the bins and not the statistics (as `FitInputs.ell_rows`
+        keeps the ELL tensors): inside a `device_dataset_scope` the second
+        fit builds none of them, fold masks (`with_row_mask` shares `extra`)
+        share them, and leaving the scope frees them with X. The sketch's
+        sample comes from a fixed stream (`ops.trees.sketch_rows`), never the
+        estimator seed."""
+        from .. import telemetry
+        from ..ops.trees import SKETCH_ROWS, bin_features, quantile_bins, sketch_rows
+
+        rows_key = ("_forest_rows", "classes" if self._is_classification else "moments")
+        rows = inputs.extra.get(rows_key)
+        if rows is None:
+            labels_host = extracted.label
             if self._is_classification:
                 # class set must be GLOBAL (a rank may hold a label subset)
                 import json
 
                 local_classes = np.unique(labels_host).astype(np.float64)
                 gathered = inputs.allgather_host(json.dumps(local_classes.tolist()))
-                classes = np.unique(
-                    np.concatenate([np.asarray(json.loads(g)) for g in gathered])
-                )
+                classes = np.unique(np.concatenate([np.asarray(json.loads(g)) for g in gathered]))
             else:
                 classes = np.zeros(0)
-            impurity = params["split_criterion"]
-            # quantile sketch rows must be GLOBAL too: each rank contributes a
-            # bounded sample, all ranks derive IDENTICAL bin edges from the
-            # union (cuRF's distributed quantile computation analog)
-            x_sketch = x_host
-            if inputs.ctx is not None and inputs.ctx.is_spmd:
-                cap = 100_000 // inputs.ctx.nranks
-                n_loc = x_host.shape[0]
-                if n_loc > cap:
-                    rs = np.random.default_rng(seed * 99_991 + inputs.ctx.rank)  # prng-ok: deliberate per-rank sampling of LOCAL sketch rows; the allgather below gives every rank the identical union, so all ranks derive the same bin edges
-                    sel = np.sort(rs.choice(n_loc, cap, replace=False))
-                    x_sketch = inputs.allgather_array(np.asarray(x_host[sel], dtype=np.float64))
-                else:
-                    x_sketch = inputs.allgather_array(np.asarray(x_host, dtype=np.float64))
-            edges_host = quantile_bins(x_sketch, max_bins, seed=seed)
-            edges = edges_host.astype(np.float32)
-            stats_host = self._row_stats(labels_host, classes)
+            rows = {"classes": classes, "stats": inputs.put_rows(self._row_stats(labels_host, classes))}
+            inputs.extra[rows_key] = rows
+        bins_key = ("_forest_bins", int(max_bins))
+        kept = inputs.extra.get(bins_key)
+        if kept is not None:
+            return {**kept, **rows, "reused": True}
+        x_host = extracted.features
+        # quantile sketch rows must be GLOBAL too: each rank contributes a
+        # bounded sample, all ranks derive IDENTICAL bin edges from the
+        # union (cuRF's distributed quantile computation analog)
+        x_sketch = x_host
+        if inputs.ctx is not None and inputs.ctx.is_spmd:
+            sel = sketch_rows(x_host.shape[0], SKETCH_ROWS // inputs.ctx.nranks, rank=inputs.ctx.rank)
+            x_sketch = inputs.allgather_array(np.asarray(x_host[sel], dtype=np.float64))
+        edges_host = quantile_bins(x_sketch, max_bins)
+        # bin the ALREADY device-resident features (inputs.X carries the
+        # padding; its rows are zero-weighted in inputs.w)
+        import jax
 
-            # bin the ALREADY device-resident features (inputs.X carries the
-            # user weights + padding zeros in inputs.w); user weights scale each
-            # row's histogram contribution and the bootstrap draw inside
-            # forest_fit multiplies on top
-            Xb_binned = bin_features(inputs.X, edges)
-            w = inputs.w
-            stats_global = inputs.put_rows(stats_host)
+        kept = {
+            "edges": edges_host,
+            "Xb": jax.block_until_ready(bin_features(inputs.X, edges_host.astype(np.float32))),
+        }
+        inputs.extra[bins_key] = kept
+        telemetry.registry().inc("forest.bin_passes")
+        return {**kept, **rows, "reused": False}
 
-            state = forest_fit(
-                Xb_binned,
-                stats_global * w[:, None],
-                w,
-                int(params["random_state"] or 0),
-                mesh=inputs.mesh,
-                n_trees=int(params["n_estimators"]),
-                max_depth=max_depth,
-                max_bins=max_bins,
-                max_features=resolve_max_features(params["max_features"], d, self._is_classification),
-                impurity=impurity,
-                node_chunk=int(params["node_chunk"]),
-                bootstrap=bool(params["bootstrap"]),
-                subsample_rate=float(params["max_samples"]),
-                min_instances=float(params["min_samples_leaf"]),
-                min_info_gain=float(params["min_impurity_decrease"]),
-                n_stats=stats_host.shape[1],
-            )
+    def _get_tpu_fit_func(self, extracted: ExtractedData):
+        from .. import telemetry
+        from ..ops.trees import forest_fit, plan_summary, split_bins_to_thresholds
+
+        def _fit(inputs: FitInputs, params: Dict[str, Any]) -> Dict[str, Any]:
+            import jax
+
+            d = inputs.n_cols
+            max_bins = int(params["n_bins"])
+            max_depth = int(params["max_depth"])
             n_trees = int(params["n_estimators"])
-            feature = np.asarray(state["feature"])[:n_trees]
-            split_bin = np.asarray(state["split_bin"])[:n_trees]
-            node_stats = np.asarray(state["node_stats"], dtype=np.float64)[:n_trees]
-            threshold = split_bins_to_thresholds(feature, split_bin, edges_host)
-            node_stats = _fill_empty_nodes(feature, node_stats)
+            m = resolve_max_features(params["max_features"], d, self._is_classification)
+            # once-per-fit child spans of `fit/solve` (docs/observability.md):
+            # `bin` (edges and the binned X, ready before it closes; `reused`
+            # where the placement had them), `grow` (every tree, the last
+            # level's program ready before it closes), `finish` (the one fetch
+            # of the forest and the host's fill)
+            with telemetry.span("bin", rows=int(inputs.X.shape[0]), d=d, bins=max_bins) as sp:
+                kept = self._placement_bins(inputs, extracted, max_bins)
+                sp.set(reused=kept["reused"])
+            # user weights scale each row's histogram contribution and the
+            # bootstrap draw inside forest_fit multiplies on top
+            w = inputs.w
+            with telemetry.span("grow", trees=n_trees, depth=max_depth, bins=max_bins, features_per_node=m) as sp:
+                state = forest_fit(
+                    kept["Xb"],
+                    kept["stats"] * w[:, None],
+                    w,
+                    int(params["random_state"] or 0),
+                    mesh=inputs.mesh,
+                    n_features=d,
+                    n_trees=n_trees,
+                    max_depth=max_depth,
+                    max_bins=max_bins,
+                    max_features=m,
+                    impurity=params["split_criterion"],
+                    node_chunk=int(params["node_chunk"]),
+                    bootstrap=bool(params["bootstrap"]),
+                    subsample_rate=float(params["max_samples"]),
+                    min_instances=float(params["min_samples_leaf"]),
+                    min_info_gain=float(params["min_impurity_decrease"]),
+                    # class counts times bootstrap counts are small integers
+                    # unless rows carry weights of their own
+                    integer_stats=self._is_classification and extracted.weight is None,
+                )
+                plan = state.pop("plan")
+                jax.block_until_ready(state)
+                summary = plan_summary(plan)
+                grown = int(state["feature"].shape[0])  # whole rounds: trees_per_dev x devices
+                sp.set(
+                    passes_per_tree=summary["passes_per_tree"], accumulate=summary["accumulate"],
+                    level_programs=(grown // inputs.mesh.devices.size) * len(plan), trees_grown=grown,
+                    sorted_levels=summary["sorted_levels"],
+                )
+                reg = telemetry.registry()
+                reg.inc("forest.trees", grown)
+                reg.inc("forest.levels", grown * len(plan))
+                reg.inc("forest.row_passes", grown * summary["passes_per_tree"])
+            with telemetry.span("finish"):  # ONE fetch: the forest's three arrays
+                out = jax.device_get(state)
+                feature = np.asarray(out["feature"])[:n_trees]
+                split_bin = np.asarray(out["split_bin"])[:n_trees]
+                node_stats = np.asarray(out["node_stats"], dtype=np.float64)[:n_trees]
+                threshold = split_bins_to_thresholds(feature, split_bin, kept["edges"])
+                node_stats = _fill_empty_nodes(feature, node_stats)
             return {
                 "feature": feature.astype(np.int32),
                 "threshold": threshold,
                 "node_stats": node_stats,
-                "classes_": classes,
+                "classes_": kept["classes"],
                 "num_trees": n_trees,
                 "max_depth": max_depth,
                 "n_cols": d,
@@ -264,13 +336,17 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
 
 def _fill_empty_nodes(feature: np.ndarray, node_stats: np.ndarray) -> np.ndarray:
     """Propagate parent stats into empty nodes so predict-time rows landing in a
-    training-empty branch fall back to the parent distribution."""
+    training-empty branch fall back to the parent distribution. A level at a
+    time (a node's parent lies on the level above, already filled): 0.12 s of
+    a 7-tree depth-13 fit's 3.8 s went node by node (PERF.md, PR 36)."""
     T, M, S = node_stats.shape
     out = node_stats.copy()
-    for i in range(1, M):
-        parent = (i - 1) // 2
-        empty = out[:, i, :].sum(axis=1) == 0
-        out[empty, i, :] = out[empty, parent, :]
+    first = 1
+    while first < M:
+        nodes = np.arange(first, min(2 * first + 1, M))
+        empty = out[:, nodes, :].sum(axis=2) == 0
+        out[:, nodes, :] = np.where(empty[:, :, None], out[:, (nodes - 1) // 2, :], out[:, nodes, :])
+        first = 2 * first + 1
     return out
 
 

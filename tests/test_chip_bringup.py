@@ -452,3 +452,72 @@ def test_the_gram_at_d3000_compiles_as_panels_that_copy_nothing(monkeypatch):
     assert f32.memory_analysis().temp_size_in_bytes < 3 * tile_bytes
     assert len(re.findall(r" convolution\(", bf16.as_text())) == 1
     assert bf16.memory_analysis().temp_size_in_bytes < tile_bytes
+
+
+@pytest.mark.parametrize("program", ["boot", "level_4_in_place", "level_12_sorted"])
+def test_a_forests_programs_at_the_protocols_shape_loop_only_in_the_accumulate(monkeypatch, program):
+    """The programs of the `rfc-p3k` fit (393,216 rows of 3,072 uint8 columns,
+    depth 13, 54 of 3,000 features a node, two classes) compiled for a v5e,
+    each in seconds (as it stood a deep level's program compiled in 12 to 70 s).
+    EVERY `while` of a level program carries the scope `srml_hist_accumulate`
+    in its metadata, and the bootstrap's program holds none: a trace's op
+    names do not carry the scope, so `kernel.hist_ms_per_fit` finds the
+    accumulate as the loops of the fit's programs, and a loop that is not the
+    accumulate's must not come into one of them without this test saying so.
+    A sorted level fetches whole rows of the binned X, which lies row-major by
+    the device's own choice at 3,072 columns: its temporaries stay under the
+    deepest histogram's few arrays, nothing of the binned X's size."""
+    import re
+    import time
+
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from spark_rapids_ml_tpu.ops import trees
+    from spark_rapids_ml_tpu.parallel.mesh import ROWS_AXIS
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    except Exception as e:  # no libtpu on this machine: nothing to compile with
+        pytest.skip(f"no TPU compiler available ahead of time: {type(e).__name__}: {e}")
+    mesh = Mesh(np.asarray(topo.devices[:1]), (ROWS_AXIS,))
+    n, d, m, bins, S, depth = 393_216, 3000, 54, 128, 2, 13
+    nodes = 2 ** (depth + 1) - 1
+
+    def struct(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype)
+    rows = (struct((n,), jnp.int32, ROWS_AXIS), struct((n,), jnp.bool_, ROWS_AXIS))
+    stw = struct((S, n), jnp.float32, None, ROWS_AXIS)
+    level_args = (
+        struct((n, trees.binned_cols(d)), jnp.uint8, ROWS_AXIS, None), stw, *rows,
+        struct((1, nodes), jnp.int32, ROWS_AXIS, None), struct((1, nodes), jnp.int32, ROWS_AXIS, None),
+        struct((1, nodes, S), jnp.float32, ROWS_AXIS, None, None), scalar(jnp.uint32), scalar(jnp.int32),
+    )
+    ordered = (struct((n,), jnp.int32, ROWS_AXIS), struct((n,), jnp.int32, ROWS_AXIS), stw, struct((1,), jnp.int32, ROWS_AXIS))
+    assert trees.binned_cols(d) == 3072
+    with jax.enable_x64(False):
+        progs = trees._forest_programs(mesh, n, d, S, "float32", 7, depth, bins, m, "gini", 0, True, True, 1.0, 1.0, 0.0)
+        plan = progs["plan"]
+        assert [lv["rows"] for lv in plan] == ["in_place"] * 5 + ["sorted"] * 8 and all(lv["passes"] == 1 for lv in plan)
+        t0 = time.perf_counter()
+        if program == "boot":
+            lowered = progs["boot"].lower(struct((n, S), jnp.float32, ROWS_AXIS, None), struct((n,), jnp.float32, ROWS_AXIS),
+                                          scalar(jnp.uint32), scalar(jnp.int32))
+        elif program == "level_4_in_place":
+            lowered = progs["levels"][4].lower(*level_args)
+        else:
+            lowered = progs["levels"][12].lower(*level_args, *ordered)
+        compiled = lowered.compile()
+        assert time.perf_counter() - t0 < 60  # 3 to 7 s here
+    loops = re.findall(r'= [^\n]* while\([^\n]*op_name="([^"]*)"', compiled.as_text())
+    if program == "boot":
+        assert loops == []
+    else:
+        assert loops and all(f"/{trees.HIST_SCOPE}/" in name for name in loops), loops
+        assert len(loops) == (1 if program == "level_4_in_place" else 2)  # the row tiles; the sorted tiles and their windows
+        deepest = S * 4096 * m * bins * 4
+        assert compiled.memory_analysis().temp_size_in_bytes < 4 * deepest < n * trees.binned_cols(d)
