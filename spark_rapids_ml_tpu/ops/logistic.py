@@ -49,10 +49,24 @@ def _make_scaling(X, w, standardize: bool, fit_intercept: bool):
     return mu, d_scale, total_w
 
 
+# Step candidates whose gradient the fused pass forms beside the direction's
+# logits (`_glm_step`): the first c entries of `_glm_qn_setup`'s `alphas`.
+# On a v5e at 393,216 x 3,000 the pass takes 6.3 ms at c = 1, 2 and 4 alike
+# (the read of X sets its pace) and the cell's fits accept one of the first
+# 1 / 2 / 4 in 18 / 22 / 25 of 25 iterations (PERF.md section 5, PR 37).
+GLM_SPECULATED = 4
+
+# How the L-BFGS loop of a dense fit reads X (`glm_pass_of`; the
+# `fit/solve/loop` span's `glm_pass` says "fused" for both fused forms).
+GLM_TWO_PRODUCTS = "two_products"
+GLM_FUSED = "fused"
+GLM_FUSED_INTERPRET = "fused_interpret"  # the same kernel through the Pallas interpreter (CI)
+
+
 def _glm_qn_setup(
     z_of, rowloss, rowloss_alphas, grad_from_z, z_shape, n_flat: int, dtype,
     penalty_terms, max_iter: int, tol: float, memory: int = 10,
-    n_alphas: int = 12, c1: float = 1e-4, x0=None,
+    n_alphas: int = 12, c1: float = 1e-4, x0=None, step_of=None,
 ):
     """L-BFGS specialized to GLM objectives: loss(p) = rowloss(z_of(p)) +
     penalty(p) with z LINEAR in p. Builds and returns the loop triple
@@ -61,8 +75,8 @@ def _glm_qn_setup(
     (`glm_qn_minimize_segmented`). `x0` warm-starts the iterate (the
     degraded-mesh portable resume; z0/g0/f0 are re-derived from it).
 
-    Two structural exploits of linearity keep every iteration at TWO passes
-    over the data matrix (the HBM-bandwidth floor for a logit model):
+    Two structural exploits of linearity keep every iteration at two passes
+    over the data matrix, the forward X·D and the gradient Xᵀ·r:
       1. Line search: along direction D the logits are z(p + a·D) = z_p + a·z_D,
          so ALL candidate step sizes are scored elementwise from one new matmul
          result (z_D) — no inner while_loop touches X. cuML's qn does the same;
@@ -73,13 +87,31 @@ def _glm_qn_setup(
          caller's `grad_from_z` — autodiff re-evaluating the forward would
          re-read X twice more per iteration.
 
+    With `step_of` (the fused pass, `_glm_step`) an iteration is ONE pass.
+    The residual of a row tile at the ACCEPTED step depends on every tile
+    (the search sums over all rows before it picks), so no pass can form the
+    gradient it will need; what is local to a tile is its residual at a
+    GIVEN step, and the candidates are a fixed list. So the pass that forms
+    z_D also forms Xᵀ·r at a window of `GLM_SPECULATED` candidates, the first
+    ones on an iteration's first trip. Where the search picks inside the
+    window (a hit), that candidate's product IS the gradient at the accepted
+    point. Where it does not (a miss), nothing is accepted and the loop makes
+    one more trip on the unchanged state with the window moved to the pick:
+    the same direction, the same z_D, the same pick, now a hit. X has one
+    reader in the loop either way, `it` counts accepted iterations only, and
+    the accepted step is the one the two-pass loop accepts. The state tuple
+    then carries two more scalars (the window's start, the first-trip hits).
+
     Interfaces (all jax-traceable):
       z_of(flat_params [F]) -> z [n, k_out]             (linear)
       rowloss(z) -> scalar                               (data term)
       rowloss_alphas(z_p, z_d, alphas [S]) -> [S]        (data term at p + a·d)
-      grad_from_z(flat_p, z) -> flat grad [F]            (incl. penalty grad)
+      grad_from_z(flat_p, z[, xr]) -> flat grad [F]      (incl. penalty grad;
+                                                          `xr`: Xᵀ·r at z, given)
       penalty_terms(flat_p, flat_d) -> (p0, p1, p2)      (penalty(p + a·d) =
                                                           p0 + a·p1 + a²·p2)
+      step_of(flat_d, z_p, a [c]) -> (z_d, xr [c, ...])  (optional: z_of(d), and
+                                                          Xᵀ·r at z_p + a_j·z_d)
     Returns (flat_params, objective, n_iter, stalled) — `stalled` is True when
     the run ended because the batched Armijo check found NO acceptable step
     (see the KNOWN LIMIT note below), not because tol/maxIter was reached.
@@ -104,12 +136,12 @@ def _glm_qn_setup(
     trace_convergence = telemetry.convergence_trace_enabled()  # traced-ok: the TRACE-TIME gate by design — callbacks exist only in programs traced while convergence tracing was on (docs/observability.md)
 
     def cond(state):
-        _, _, _, _, _, _, _, f_prev, f_cur, it, stalled = state
+        f_prev, f_cur, it, stalled = state[7:11]
         rel = jnp.abs(f_prev - f_cur) / jnp.maximum(jnp.abs(f_cur), 1.0)
         return jnp.logical_and(jnp.logical_and(it < max_iter, rel > tol), ~stalled)
 
     def body(state):
-        x, z_p, g, S, Y, rho, meta, f_prev, f_cur, it, _ = state
+        x, z_p, g, S, Y, rho, meta, f_prev, f_cur, it, _ = state[:11]
         count, pos = meta
         d = lbfgs_two_loop(g, S, Y, rho, count, pos, m)
         # fall back to steepest descent if the direction isn't a descent one
@@ -118,10 +150,17 @@ def _glm_qn_setup(
         # true directional derivative: g·d when the L-BFGS direction is kept,
         # -g·g only in the steepest-descent fallback branch
         gd = jnp.where(gd < 0, gd, -jnp.dot(g, g))
-        # batched Armijo over all candidates from ONE new logit evaluation
-        z_d = z_of(d)  # linear => z(x + a d) = z_p + a z_d     [X read 1]
-        p0, p1, p2 = penalty_terms(x, d)
         a = alphas.astype(x.dtype)
+        # batched Armijo over all candidates from ONE new logit evaluation
+        if step_of is None:
+            z_d = z_of(d)  # linear => z(x + a d) = z_p + a z_d     [X read 1]
+        else:
+            window, hits = state[11:]
+            c = GLM_SPECULATED
+            # past the list's end the window holds the step 0, which the search never sees
+            a_win = jax.lax.dynamic_slice(jnp.pad(a, (0, c - 1)), (window,), (c,))
+            z_d, xr_win = step_of(d, z_p, a_win)  # and Xᵀ·r at the window  [the X read]
+        p0, p1, p2 = penalty_terms(x, d)
         f_cand = rowloss_alphas(z_p, z_d, a) + p0 + a * p1 + a * a * p2
         ok_mask = f_cand <= f_cur + c1 * a * gd
         # LARGEST passing step (alphas sorted descending)
@@ -131,25 +170,40 @@ def _glm_qn_setup(
         f_new = f_cand[first_ok]
         xn = x + a_sel * d
         z_n = z_p + a_sel * z_d  # logits at the accepted point, no X pass
-        gn = grad_from_z(xn, z_n)  # analytic Xᵀ·residual          [X read 2]
+        if step_of is None:
+            gn = grad_from_z(xn, z_n)  # analytic Xᵀ·residual          [X read 2]
+            accept = ok
+        else:
+            at = first_ok.astype(window.dtype) - window
+            hit = (at >= 0) & (at < c)
+            gn = grad_from_z(xn, z_n, xr_win[jnp.clip(at, 0, c - 1)])
+            accept = ok & hit
+            miss = ok & ~hit  # this trip accepts nothing: the next one's window starts at the pick
         s = xn - x
         yv = gn - g
         sy = jnp.dot(s, yv)
-        do_update = ok & (sy > 1e-10)
+        do_update = accept & (sy > 1e-10)
         S = jnp.where(do_update, S.at[pos].set(s), S)
         Y = jnp.where(do_update, Y.at[pos].set(yv), Y)
         rho = jnp.where(do_update, rho.at[pos].set(1.0 / jnp.maximum(sy, 1e-30)), rho)
         count = jnp.where(do_update, jnp.minimum(count + 1, m), count)
         pos = jnp.where(do_update, (pos + 1) % m, pos)
-        x = jnp.where(ok, xn, x)
-        z_p = jnp.where(ok, z_n, z_p)
-        g = jnp.where(ok, gn, g)
-        f_out = jnp.where(ok, f_new, f_cur)
-        if trace_convergence:
+        x = jnp.where(accept, xn, x)
+        z_p = jnp.where(accept, z_n, z_p)
+        g = jnp.where(accept, gn, g)
+        f_out = jnp.where(accept, f_new, f_cur)
+        if trace_convergence:  # a miss's trip repeats its iteration's point
             jax.debug.callback(
                 partial(telemetry.record_convergence_point, "glm_qn"), it, f_out
             )
-        return x, z_p, g, S, Y, rho, (count, pos), f_cur, f_out, it + 1, ~ok
+        if step_of is None:
+            return x, z_p, g, S, Y, rho, (count, pos), f_cur, f_out, it + 1, ~ok
+        return (
+            x, z_p, g, S, Y, rho, (count, pos),
+            jnp.where(miss, f_prev, f_cur), f_out, it + (~miss).astype(it.dtype), ~ok,
+            jnp.where(miss, first_ok.astype(window.dtype), 0),
+            hits + (accept & (window == 0)).astype(hits.dtype),
+        )
 
     if x0 is None:
         x0 = jnp.zeros((n_flat,), dtype)
@@ -167,31 +221,33 @@ def _glm_qn_setup(
         (jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32)),
         jnp.asarray(jnp.inf, x0.dtype), f0, jnp.asarray(0, jnp.int32), jnp.asarray(False),
     )
+    if step_of is not None:
+        state0 += (jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32))
     return cond, body, state0
 
 
 def _glm_qn_minimize(
     z_of, rowloss, rowloss_alphas, grad_from_z, z_shape, n_flat: int, dtype,
     penalty_terms, max_iter: int, tol: float, memory: int = 10,
-    n_alphas: int = 12, c1: float = 1e-4, x0=None,
+    n_alphas: int = 12, c1: float = 1e-4, x0=None, step_of=None,
 ):
     """One-program GLM quasi-Newton minimization (see `_glm_qn_setup` for
-    the algorithm and its two structural exploits of linearity). `x0`
+    the algorithm and its structural exploits of linearity). `x0`
     warm-starts the iterate (the public warm_start_from API). Returns
-    (flat_params, objective, n_iter, stalled)."""
+    (flat_params, objective, n_iter, stalled, fused_hits): the last is None
+    without `step_of`, else the iterations whose first trip was a hit."""
     from .owlqn import freeze_when_done
 
     cond, body, state0 = _glm_qn_setup(
         z_of, rowloss, rowloss_alphas, grad_from_z, z_shape, n_flat, dtype,
-        penalty_terms, max_iter, tol, memory, n_alphas, c1, x0=x0,
+        penalty_terms, max_iter, tol, memory, n_alphas, c1, x0=x0, step_of=step_of,
     )
     # freeze_when_done makes the loop vmap-safe: batched hyperparameter
     # sweeps (vmap over lam_l2/lam_l1) step until the SLOWEST grid element
     # converges, and converged elements must hold their iterate exactly
-    x, _, _, _, _, _, _, _, obj, n_iter, stalled = jax.lax.while_loop(
-        cond, freeze_when_done(cond, body), state0
-    )
-    return x, obj, n_iter, stalled
+    state = jax.lax.while_loop(cond, freeze_when_done(cond, body), state0)
+    x, obj, n_iter, stalled = state[0], state[8], state[9], state[10]
+    return x, obj, n_iter, stalled, (None if step_of is None else state[12])
 
 
 def glm_qn_minimize_segmented(
@@ -326,7 +382,7 @@ def _lbfgs_minimize(loss, params0, max_iter: int, tol: float, memory: int = 10):
     jax.jit,
     static_argnames=(
         "k", "fit_intercept", "standardize", "max_iter", "lbfgs_memory", "multinomial", "use_l1",
-        "fast",
+        "fast", "glm_pass",
     ),
 )
 def logistic_fit(
@@ -347,21 +403,27 @@ def logistic_fit(
     lbfgs_memory: int = 10,
     fast: bool = False,
     warm_start=None,  # (coef [k_out, d], intercept [k_out]) original-space seed
+    glm_pass: str = GLM_TWO_PRODUCTS,  # `glm_pass_of(X, ...)`, asked on the host
 ) -> Dict[str, jax.Array]:
     """Returns coef_ [k_out, d] and intercept_ [k_out] in ORIGINAL feature space
     (standardization folded out), plus objective_ and n_iter_. `warm_start`
     seeds the iterate from a previous model's coefficients (the public
     warm_start_from API, docs/scheduling.md "Warm starts"). `fast` runs the
-    per-iteration matvecs bf16-in / f32-accumulate (`_dense_ops`)."""
+    per-iteration matvecs bf16-in / f32-accumulate (`_dense_ops`). With
+    `glm_pass` "fused" / "fused_interpret" the L-BFGS loop reads X once an
+    iteration (`_glm_step`) and the result carries `fused_hits_`."""
     d = X.shape[1]
     mu, d_scale, total_w = _make_scaling(X, w, standardize, fit_intercept)
     matvec, rmat = _dense_ops(X, fast)
+    step = None
+    if glm_pass != GLM_TWO_PRODUCTS:
+        step = partial(_glm_step, X, interpret=glm_pass == GLM_FUSED_INTERPRET)
     return _fit_common(
         matvec, rmat, X.shape[0],
         X.dtype, d, y_idx, w, mu, d_scale, total_w,
         k=k, multinomial=multinomial, lam_l2=lam_l2, lam_l1=lam_l1, use_l1=use_l1,
         fit_intercept=fit_intercept, max_iter=max_iter, tol=tol, lbfgs_memory=lbfgs_memory,
-        warm_start=warm_start,
+        warm_start=warm_start, step=step,
     )
 
 
@@ -451,6 +513,173 @@ def _dense_ops(X, fast: bool = False):
         ).astype(X.dtype)
 
     return matvec, rmat
+
+
+_LANES, _SUBLANES = 128, 8  # a float32 vector register: (8, 128)
+_GLM_TILE_ROWS = 1024  # rows of X a visit of the fused pass holds, at most: 12.3 MB at d = 3,000
+
+
+def _glm_tile_rows(n: int, d: int):
+    """Rows of X a visit of `_glm_step` holds: the most, of `_GLM_TILE_ROWS`
+    halved down to one register of lanes, that n has and whose VMEM (two
+    tiles in flight, the candidates' lane partials and d_eff twice each)
+    stays inside three quarters of the limit the kernels declare (1,024 up
+    to d = 3,780 on a v5e); None where none does."""
+    from . import distance
+
+    tn = _GLM_TILE_ROWS
+    while tn >= _LANES:
+        held = 4 * d * 2 * (tn + (GLM_SPECULATED + 1) * _LANES)
+        if tn <= n and held <= 3 * distance.vmem_limit_bytes() // 4:
+            return tn
+        tn //= 2
+    return None
+
+
+def glm_pass_of(X, *, multinomial: bool, use_l1: bool, fast: bool) -> str:
+    """How `logistic_fit` should read this X in its L-BFGS loop, decided from
+    what the committed array shows (asked on the host, outside any trace:
+    `kernel_mode` may run its self-test, and no layout is visible in a
+    trace). The fused pass (`_glm_step`) where the fit is the binomial
+    float32 L-BFGS at full precision, X lies on ONE device (GSPMD cannot
+    partition a Mosaic kernel; the `shard_map` form is not written) with its
+    ROWS ON THE LANES, as a TPU lays a float32 [n, 3000] block out (d not a
+    multiple of 128, n one: `parallel/mesh.py`), whole registers of columns
+    (d a multiple of 8) and a tile of rows that fits VMEM (`_glm_tile_rows`);
+    the interpreter takes any layout (it runs where CI asks for the real
+    kernel). Everything else keeps the two products: a row-major X wants a
+    second kernel body (its forward reduces over lanes), the multinomial
+    forward is a real matmul."""
+    from . import distance
+    from ..parallel.mesh import lies_row_major
+
+    mode = distance.kernel_mode()
+    if (
+        mode == "jnp" or multinomial or use_l1 or fast
+        or not isinstance(X, jax.Array) or X.ndim != 2 or X.dtype != jnp.float32
+        or len(X.devices()) != 1
+        or X.shape[1] % _SUBLANES or _glm_tile_rows(*X.shape) is None
+    ):
+        return GLM_TWO_PRODUCTS
+    if mode == "interpret":
+        return GLM_FUSED_INTERPRET
+    return GLM_TWO_PRODUCTS if lies_row_major(X) else GLM_FUSED
+
+
+def _glm_step_kernel(
+    scal_ref, xt_ref, db_ref, zp_ref, y_ref, ws_ref, zd_ref, g_ref, *, n: int, tn: int, c: int,
+):
+    """One visit of a [d, tn] tile of Xᵀ (tn rows of X on the lanes), a
+    register of columns (8 sublanes) a loop step, one (8, 128) register of X
+    an operation. Phase 1: z_d = Σ over sublanes of x·d_eff (`db_ref`: d_eff
+    along every lane) + the offset. Phase 2: for each of the c candidates
+    the tile's residual row r_j = ws·(σ(z_p + a_j·z_d) − y), and G_j += x·r_j
+    summed over the tile's registers of lanes to [d, 128] lane partials
+    (`g_ref`, in VMEM for the whole grid). Float32 products and sums on the
+    VPU; the tile is read from VMEM twice, from HBM once. `scal_ref` (SMEM):
+    the offset, then a_0..a_{c-1}. Lanes past row n (the last tile of a
+    ragged n) hold whatever the fetch left: they are zeroed in x and r
+    before they can reach a partial. (Whole [8, tn] operands with d_eff a
+    column broadcast along the lanes read shorter and ran 14 to 36 ms a
+    pass where this runs 6.3; a [1, 128] slice of a wider value does not
+    broadcast over sublanes in Mosaic, hence the slices of the refs:
+    PERF.md, PR 37.)"""
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(0)
+    d = xt_ref.shape[0]
+    lanes = [slice(k, k + _LANES) for k in range(0, tn, _LANES)]
+
+    @pl.when(i == 0)
+    def _():
+        g_ref[...] = jnp.zeros(g_ref.shape, g_ref.dtype)
+
+    def visit(ragged: bool):
+        def in_rows(sublanes, at):  # lanes of the tile that are rows of X
+            return i * tn + at.start + jax.lax.broadcasted_iota(jnp.int32, (sublanes, _LANES), 1) < n
+
+        def registers(s):
+            rows = pl.ds(pl.multiple_of(s * _SUBLANES, _SUBLANES), _SUBLANES)
+            x = [xt_ref[rows, at] for at in lanes]
+            return rows, ([jnp.where(ok, v, 0.0) for ok, v in zip(valid_x, x)] if ragged else x)
+
+        if ragged:
+            valid_x = [in_rows(_SUBLANES, at) for at in lanes]
+
+        def forward(s, acc):
+            rows, x = registers(s)
+            db = db_ref[rows, :]
+            return [a + v * db for a, v in zip(acc, x)]
+
+        zero = jnp.zeros((_SUBLANES, _LANES), jnp.float32)
+        acc = jax.lax.fori_loop(0, d // _SUBLANES, forward, [zero] * len(lanes))
+        over_sublanes = [[] for _ in range(c)]  # r_j of each register of lanes, the same in its eight sublanes
+        for a, at in zip(acc, lanes):
+            z_d = jnp.sum(a, axis=0, keepdims=True) + scal_ref[0, 0]
+            zd_ref[:, at] = z_d
+            z_p, y, ws = zp_ref[:, at], y_ref[:, at], ws_ref[:, at]
+            for j in range(c):
+                r = ws * (jax.nn.sigmoid(z_p + scal_ref[0, 1 + j] * z_d) - y)
+                if ragged:
+                    r = jnp.where(in_rows(1, at), r, 0.0)
+                over_sublanes[j].append(jnp.broadcast_to(r, (_SUBLANES, _LANES)))
+
+        def gradient(s, carry):
+            rows, x = registers(s)
+            for j in range(c):
+                g_ref[j, rows, :] += sum(v * r for v, r in zip(x, over_sublanes[j]))
+            return carry
+
+        jax.lax.fori_loop(0, d // _SUBLANES, gradient, 0)
+
+    if n % tn == 0:
+        visit(False)
+    else:
+        last = pl.num_programs(0) - 1
+        pl.when(i < last)(lambda: visit(False))
+        pl.when(i == last)(lambda: visit(True))
+
+
+def _glm_step(X, d_eff, offset, z_p, y, w_share, a, *, interpret: bool):
+    """The fused pass of a binomial L-BFGS iteration, `srml_glm_step_f32`:
+    ONE read of X gives z_d = X·d_eff + offset [n] and, for each candidate
+    step a_j, Xᵀ·r_j [c, d] with r_j = w_share·(σ(z_p + a_j·z_d) − y): the
+    gradient's data term at the point z_p + a_j·z_d. The kernel takes X as
+    Xᵀ, [d, n] with the rows of X on the lanes: for an X that lies
+    column-major (a v5e's choice at d = 3,000) that is the same bytes, and
+    no copy is made. `glm_pass_of` says which X this is written for."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from . import distance
+
+    n, d = X.shape
+    c = a.shape[0]
+    f32 = jnp.float32
+    tn = _glm_tile_rows(n, d)
+
+    def rows(v):
+        return v.astype(f32)[None, :]
+
+    row_tile = pl.BlockSpec((1, tn), lambda i: (0, i))
+    z_d, partials = pl.pallas_call(
+        partial(_glm_step_kernel, n=n, tn=tn, c=c),
+        grid=(pl.cdiv(n, tn),),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((d, tn), lambda i: (0, i)),
+            pl.BlockSpec((d, _LANES), lambda i: (0, 0)),
+            row_tile, row_tile, row_tile,
+        ],
+        out_specs=[row_tile, pl.BlockSpec((c, d, _LANES), lambda i: (0, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((1, n), f32), jax.ShapeDtypeStruct((c, d, _LANES), f32)],
+        name=distance.kernel_name("glm_step", False), **distance._call_params(interpret),
+    )(
+        jnp.concatenate([jnp.reshape(offset, (1,)), a]).astype(f32)[None, :],
+        X.T, jnp.broadcast_to(d_eff.astype(f32)[:, None], (d, _LANES)),
+        rows(z_p), rows(y), rows(w_share),
+    )
+    return z_d[0], jnp.sum(partials, axis=2)
 
 
 def _ell_ops(values, indices, d: int, fast: bool = False):
@@ -569,22 +798,27 @@ def logistic_fit_ell_batched(
 
 def _build_glm_problem(
     matvec, rmat, dtype, d, y_idx, w, mu, d_scale, total_w,
-    *, k, multinomial, lam_l2, fit_intercept,
+    *, k, multinomial, lam_l2, fit_intercept, step=None,
 ) -> Dict[str, Any]:
     """The GLM objective closures — z_of / rowloss / rowloss_alphas /
     penalty_terms / grad_from_z plus the flat-parameter geometry — shared by
     the one-program `_fit_common` path and the host-segmented checkpointing
-    driver (`logistic_fit_checkpointed`), so both trace the identical math."""
+    driver (`logistic_fit_checkpointed`), so both trace the identical math.
+    With `step` (binomial only: `_glm_step` over the fit's X) also `step_of`,
+    the fused pass in the solver's terms."""
     k_out = k if multinomial else 1
     n_flat = d * k_out + k_out
 
     def unflatten(xf):
         return xf[: d * k_out].reshape(d, k_out), xf[d * k_out :]
 
-    def z_of(xf):
+    def effective(xf):
         B, b0 = unflatten(xf)
         Beff = B * d_scale[:, None]
-        offset = (b0 - mu @ Beff) if fit_intercept else -(mu @ Beff)
+        return Beff, (b0 - mu @ Beff) if fit_intercept else -(mu @ Beff)
+
+    def z_of(xf):
+        Beff, offset = effective(xf)
         return matvec(Beff) + offset[None, :]  # LINEAR in (B, b0)
 
     if multinomial:
@@ -618,10 +852,11 @@ def _build_glm_problem(
             0.5 * lam_l2 * jnp.sum(Bd * Bd),
         )
 
-    def grad_from_z(xf, z):
+    def grad_from_z(xf, z, xr=None):
         """Analytic gradient from the logits: ∂loss/∂z is the GLM residual,
         the chain through z = matvec(B·d_scale) + (b0 − mu·Beff) is one
-        transposed data pass (rmat) plus tiny vector algebra."""
+        transposed data pass (rmat; or its result `xr` [d, k_out], where a
+        fused pass has formed it at these logits) plus tiny vector algebra."""
         B, _ = unflatten(xf)
         if multinomial:
             p = jax.nn.softmax(z, axis=1)
@@ -629,15 +864,26 @@ def _build_glm_problem(
         else:
             p = jax.nn.sigmoid(z[:, 0])
             r = ((w * (p - y)) / total_w)[:, None]  # [n, 1]
-        g_beff = rmat(r) - mu[:, None] * jnp.sum(r, axis=0)[None, :]  # [d, k_out]
+        if xr is None:
+            xr = rmat(r)
+        g_beff = xr - mu[:, None] * jnp.sum(r, axis=0)[None, :]  # [d, k_out]
         dB = g_beff * d_scale[:, None] + lam_l2 * B
         db0 = jnp.sum(r, axis=0) if fit_intercept else jnp.zeros((k_out,), dtype)
         return jnp.concatenate([dB.ravel(), db0])
 
+    step_of = None
+    if step is not None:
+        w_share = w / total_w
+
+        def step_of(df_, z_p, a):
+            Beff, offset = effective(df_)
+            z_d, xr = step(Beff[:, 0], offset[0], z_p[:, 0], y, w_share, a)
+            return z_d[:, None], xr[:, :, None]  # [n, 1], [c, d, 1]
+
     return dict(
         k_out=k_out, n_flat=n_flat, unflatten=unflatten, z_of=z_of,
         rowloss=rowloss, rowloss_alphas=rowloss_alphas,
-        penalty_terms=penalty_terms, grad_from_z=grad_from_z,
+        penalty_terms=penalty_terms, grad_from_z=grad_from_z, step_of=step_of,
     )
 
 
@@ -677,11 +923,11 @@ def _warm_x0(warm_start, d, k_out, mu, d_scale, fit_intercept, dtype):
 def _fit_common(
     matvec, rmat, n_rows, dtype, d, y_idx, w, mu, d_scale, total_w,
     *, k, multinomial, lam_l2, lam_l1, use_l1, fit_intercept, max_iter, tol, lbfgs_memory,
-    warm_start=None,
+    warm_start=None, step=None,
 ) -> Dict[str, jax.Array]:
     prob = _build_glm_problem(
         matvec, rmat, dtype, d, y_idx, w, mu, d_scale, total_w,
-        k=k, multinomial=multinomial, lam_l2=lam_l2, fit_intercept=fit_intercept,
+        k=k, multinomial=multinomial, lam_l2=lam_l2, fit_intercept=fit_intercept, step=step,
     )
     k_out, n_flat, unflatten = prob["k_out"], prob["n_flat"], prob["unflatten"]
     z_of, rowloss, rowloss_alphas = prob["z_of"], prob["rowloss"], prob["rowloss_alphas"]
@@ -710,17 +956,20 @@ def _fit_common(
             flat_loss, x0, l1_mask, lam_l1,
             max_iter=max_iter, tol=tol, memory=lbfgs_memory,
         )
-        stalled = jnp.asarray(False)
+        stalled, fused_hits = jnp.asarray(False), None
     else:
-        xf, obj, n_iter, stalled = _glm_qn_minimize(
+        xf, obj, n_iter, stalled, fused_hits = _glm_qn_minimize(
             z_of, rowloss, rowloss_alphas, grad_from_z, (n_rows, k_out), n_flat,
             dtype, penalty_terms, max_iter=max_iter, tol=tol, memory=lbfgs_memory,
-            x0=x_warm,
+            x0=x_warm, step_of=prob["step_of"],
         )
-    return _finish_glm(
+    state = _finish_glm(
         xf, obj, n_iter, stalled, unflatten, d_scale, mu,
         fit_intercept=fit_intercept, multinomial=multinomial,
     )
+    if fused_hits is not None:
+        state["fused_hits_"] = fused_hits
+    return state
 
 
 def _fit_common_checkpointed(

@@ -134,7 +134,8 @@ def test_logistic_fit_records_init_loop_finish_under_solve(tele, rng):
     at = paths.index("fit/solve/init")
     assert paths[at : at + 4] == SOLVE_CHILDREN + ["fit/solve"]
     by_path = {s["path"]: _attrs(s) for s in spans}
-    assert by_path["fit/solve/loop"] == {"solver_path": "dense"}
+    # the jnp form (CPU): the two products, nothing speculated (tests/test_logistic_fused.py)
+    assert by_path["fit/solve/loop"] == {"solver_path": "dense", "glm_pass": "two_products", "speculated": 0}
     assert by_path["fit/solve/init"] == {} and by_path["fit/solve/finish"] == {}
 
 

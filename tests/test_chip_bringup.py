@@ -454,6 +454,69 @@ def test_the_gram_at_d3000_compiles_as_panels_that_copy_nothing(monkeypatch):
     assert bf16.memory_analysis().temp_size_in_bytes < tile_bytes
 
 
+def test_the_logistic_loop_at_d3000_reads_x_once_where_it_lies(monkeypatch):
+    """`logistic_fit` at the cell's shape ([393216, 3000] float32, which a
+    v5e lays out column-major) compiled for a v5e with the fused pass: the
+    L-BFGS loop's body holds ONE op that reads an X-sized operand and it is
+    the Mosaic kernel `srml_glm_step_f32`, fed X through a bitcast (Xᵀ with
+    the rows on the lanes is the same bytes); nowhere in the module is an
+    X-sized buffer copied, turned or converted, and the temporaries are the
+    logits, the candidates' lane partials and the L-BFGS history."""
+    import re
+
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.ops import logistic
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    except Exception as e:  # no libtpu on this machine: nothing to compile with
+        pytest.skip(f"no TPU compiler available ahead of time: {type(e).__name__}: {e}")
+    dev = topo.devices[0]
+    one_chip = SingleDeviceSharding(dev)
+    n, d = 393_216, 3000
+    X = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    with mesh_mod.chip_scope([dev]), jax.enable_x64(False):
+        fit = logistic.logistic_fit.lower(
+            X, y, w, k=2, multinomial=False, lam_l2=1e-5, max_iter=25, tol=1e-30,
+            glm_pass=logistic.GLM_FUSED,
+        ).compile()
+        # and the kernel alone where the last tile of rows is ragged (its masked body)
+        ragged, vec = 100_000, lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+        jax.jit(lambda *a: logistic._glm_step(*a, interpret=False)).lower(
+            vec(ragged, d), vec(d), vec(), vec(ragged), vec(ragged), vec(ragged), vec(logistic.GLM_SPECULATED)
+        ).compile()
+    text = fit.as_text()
+    assert f"f32[{n},{d}]{{0,1:" in text.splitlines()[0]  # the entry's X: column-major, the device's choice
+    x_sized = re.compile(rf"f32\[({n},{d}|{d},{n})\]")
+    computations, entry = {}, None
+    for line in text.splitlines():
+        if line and not line.startswith(" ") and "{" in line:
+            name = line.split()[1] if line.startswith("ENTRY") else line.split()[0]
+            entry = name if line.startswith("ENTRY") else entry
+            computations[name] = body = []
+        elif line.startswith(" "):
+            body.append(line)
+    # the L-BFGS loop is the entry's `while` (the two-loop recursion's are inside its body)
+    (loop,) = [m for ln in computations[entry] for m in re.findall(r" while\(.*body=(%[\w.\-]+)", ln)]
+    ops = [re.match(r"\s+(?:ROOT )?(%[\w.\-]+) = (.*)", ln).groups() for ln in computations[loop]]
+    x_names = {name for name, rhs in ops if x_sized.match(rhs)}
+    assert x_names  # X rides the loop's state
+    readers = [
+        rhs for _, rhs in ops
+        if x_names & set(re.findall(r"%[\w.\-]+", rhs))
+        and not re.search(r" (get-tuple-element|bitcast|tuple)\(", rhs)
+    ]
+    assert len(readers) == 1 and "srml_glm_step_f32" in readers[0] and "tpu_custom_call" in readers[0], readers
+    moved = [ln for ln in text.splitlines() if re.search(r" = f32\[(%d,%d|%d,%d)\]\S* (copy|transpose|convert)\(" % (n, d, d, n), ln)]
+    assert not moved, moved
+    assert fit.memory_analysis().temp_size_in_bytes < 0.3 * 2**30
+
+
 @pytest.mark.parametrize("program", ["boot", "level_4_in_place", "level_12_sorted"])
 def test_a_forests_programs_at_the_protocols_shape_loop_only_in_the_accumulate(monkeypatch, program):
     """The programs of the `rfc-p3k` fit (393,216 rows of 3,072 uint8 columns,
