@@ -2335,7 +2335,8 @@ class _TpuModelWithColumns(_TpuModel):
                 with telemetry.span("stall", piece=i):
                     held_by = i + 1 - len(pieces.ring)
                     if 0 <= held_by and i + 1 < len(bounds):
-                        jax.block_until_ready(in_flight[held_by][0])
+                        with telemetry.device_wait("transform_stall"):
+                            jax.block_until_ready(in_flight[held_by][0])
             for result, n_valid in in_flight:
                 with telemetry.span("fetch", rows=n_valid):
                     outs.append(program.fetch(result, n_valid))

@@ -248,6 +248,10 @@ class FlightRecorder:
     counted (never silent — `stats()`, the `flightrec.events_dropped` registry
     counter, and the `telemetry.summary()` health line all expose them)."""
 
+    # ring overwrites of every recorder of this process: what the registry's
+    # `flightrec.events_dropped` follows (`MetricsRegistry._sync_flightrec`)
+    overwritten = 0
+
     def __init__(self, capacity: Optional[int] = None, enabled: Optional[bool] = None):
         if capacity is None:
             try:
@@ -266,25 +270,25 @@ class FlightRecorder:
 
     # -- record (the hot path) ---------------------------------------------
     def record(self, kind: str, **fields: Any) -> None:
+        if self.enabled:
+            self.record_as(_rank(), trace_tags(), kind, fields)
+
+    def record_as(self, rank: int, tags: Dict[str, Any], kind: str, fields: Dict[str, Any]) -> None:
+        """`record` for a caller that has resolved this process's rank and
+        the trace tags already (a telemetry span does, once for its two
+        events and its record)."""
         if not self.enabled:
             return
-        ev = {"t": time.time(), "kind": kind, "rank": _rank(), **trace_tags(), **fields}
+        ev = {"t": time.time(), "kind": kind, "rank": rank, **tags, **fields}
         with self._lock:
-            dropped = self._buf[self._next] is not None
+            if self._buf[self._next] is not None:
+                # counted here alone: the registry's `flightrec.events_dropped`
+                # is brought up to `overwritten` where a snapshot is taken
+                self._dropped += 1
+                FlightRecorder.overwritten += 1
             self._buf[self._next] = ev
             self._next = (self._next + 1) % self.capacity
             self._total += 1
-            if dropped:
-                self._dropped += 1
-        if dropped:
-            # surface truncation through the registry too (when telemetry is
-            # on) so it rides model._fit_metrics and the bench snapshot
-            try:
-                from . import telemetry
-
-                telemetry.registry().inc("flightrec.events_dropped")
-            except Exception:  # pragma: no cover - teardown ordering
-                pass
 
     # -- read --------------------------------------------------------------
     def events(self) -> List[Dict[str, Any]]:

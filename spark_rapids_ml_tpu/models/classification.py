@@ -633,7 +633,8 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
                 # started before the first is waited for: a wait apiece cost 0.9 ms
                 # on a v5e), then the divergence guard runs on the already-fetched
                 # scalars (no extra sync)
-                state = jax.device_get(state)
+                with telemetry.device_wait("finish"):
+                    state = jax.device_get(state)
                 return self._finalize_state(state, classes, inputs, common)
 
         return _fit

@@ -153,7 +153,7 @@ class PCA(_PCAParams, _TpuEstimator):
             # once-per-fit child spans of `fit/solve` (docs/observability.md):
             # `gram` and `eig` inside the calls above, `finish` the fetch of
             # the model's attributes
-            with telemetry.span("finish"):  # the five attributes in one fetch
+            with telemetry.span("finish"), telemetry.device_wait("finish"):  # the five attributes in one fetch
                 out = {name: np.asarray(v) for name, v in jax.device_get(state).items()}
             check_pca_state(out, k=k)  # guard on the host-fetched attributes
             record_pca_fit(out, k=k)

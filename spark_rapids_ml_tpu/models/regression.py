@@ -301,7 +301,7 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
             # once-per-fit child spans of `fit/solve` (docs/observability.md):
             # `gram` and `cd` / `normal` inside the calls above, `finish` the
             # fetch of the model's attributes
-            with telemetry.span("finish"):  # the five attributes in one fetch
+            with telemetry.span("finish"), telemetry.device_wait("finish"):  # the five attributes in one fetch
                 out = jax.device_get(state)
             return _model_attrs(out, inputs)
 
@@ -347,7 +347,7 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
                 stacked = linear_fit_batched(
                     inputs.X, inputs.y, inputs.w, alphas, l1rs, mesh=inputs.mesh, **common
                 )
-            with telemetry.span("finish"):  # ONE fetch
+            with telemetry.span("finish"), telemetry.device_wait("finish"):  # ONE fetch
                 stacked = jax.device_get(stacked)
             return [
                 _model_attrs({k: v[i] for k, v in stacked.items()}, inputs)

@@ -247,10 +247,9 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
         # padding; its rows are zero-weighted in inputs.w)
         import jax
 
-        kept = {
-            "edges": edges_host,
-            "Xb": jax.block_until_ready(bin_features(inputs.X, edges_host.astype(np.float32))),
-        }
+        binned = bin_features(inputs.X, edges_host.astype(np.float32))
+        with telemetry.device_wait("bin"):
+            kept = {"edges": edges_host, "Xb": jax.block_until_ready(binned)}
         inputs.extra[bins_key] = kept
         telemetry.registry().inc("forest.bin_passes")
         return {**kept, **rows, "reused": False}
@@ -301,7 +300,8 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                     integer_stats=self._is_classification and extracted.weight is None,
                 )
                 plan = state.pop("plan")
-                jax.block_until_ready(state)
+                with telemetry.device_wait("grow"):
+                    jax.block_until_ready(state)
                 summary = plan_summary(plan)
                 grown = int(state["feature"].shape[0])  # whole rounds: trees_per_dev x devices
                 sp.set(
@@ -314,7 +314,8 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                 reg.inc("forest.levels", grown * len(plan))
                 reg.inc("forest.row_passes", grown * summary["passes_per_tree"])
             with telemetry.span("finish"):  # ONE fetch: the forest's three arrays
-                out = jax.device_get(state)
+                with telemetry.device_wait("finish"):
+                    out = jax.device_get(state)
                 feature = np.asarray(out["feature"])[:n_trees]
                 split_bin = np.asarray(out["split_bin"])[:n_trees]
                 node_stats = np.asarray(out["node_stats"], dtype=np.float64)[:n_trees]

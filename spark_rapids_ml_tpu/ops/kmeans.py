@@ -450,7 +450,8 @@ def kmeans_fit(
         # loud instead of subtly wrong.
         if final_inertia:
             _, inertia, _ = step(centers, x_sq, False)
-            inertia_host = float(inertia)
+            with telemetry.device_wait("kmeans_inertia"):
+                inertia_host = float(inertia)
             if not math.isfinite(inertia_host):
                 # the loop's deferred check trails by one fetch: a divergence on
                 # the FINAL step (or a 1-iteration fit) is caught here, on the
@@ -463,7 +464,10 @@ def kmeans_fit(
             "inertia_": inertia,
             "n_iter_": jnp.asarray(n_iter, jnp.int32),
         }
-        return state if to_host is None else to_host(state)
+        if to_host is None:
+            return state
+        with telemetry.device_wait("kmeans_finish"):
+            return to_host(state)
 
 
 @partial(jax.jit, static_argnames=("mesh",))
@@ -579,7 +583,8 @@ def scalable_kmeans_init(x_host, k: int, seed: int, sample_weight=None, rounds: 
     cand_list = [np.ascontiguousarray(first)]
     min_d2 = _min_d2_update(xd, jax.device_put(cand_list[0]), jnp.full((n_sub,), np.inf, jnp.float32))
     for _ in range(rounds):
-        probs = np.maximum(np.asarray(min_d2), 0.0) * sw  # host-fetch-ok: one fetch per k-means|| seeding ROUND (host does the ∝d² sampling); rounds is small and fixed
+        with telemetry.device_wait("kmeans_init"):
+            probs = np.maximum(np.asarray(min_d2), 0.0) * sw  # host-fetch-ok: one fetch per k-means|| seeding ROUND (host does the ∝d² sampling); rounds is small and fixed
         s = probs.sum()
         # without-replacement sampling needs enough nonzero-probability rows
         n_new = min(l, n_sub, int(np.count_nonzero(probs)))
@@ -594,7 +599,9 @@ def scalable_kmeans_init(x_host, k: int, seed: int, sample_weight=None, rounds: 
     cand = np.concatenate(cand_list, axis=0)
     # weight candidates by how many points they own (one assignment pass);
     # duplicate (padding) rows lose every argmin tie, so they get weight 0
-    assign = np.asarray(_assign_nearest(xd, jax.device_put(cand)))
+    assign = _assign_nearest(xd, jax.device_put(cand))
+    with telemetry.device_wait("kmeans_init"):
+        assign = np.asarray(assign)
     weights = np.bincount(assign, weights=sw, minlength=len(cand)).astype(np.float32)
     # reduce the small weighted candidate set to k with k-means++ ON DEVICE
     # (one dispatch; the host loop costs ~50s at the ANN build's k=1024)
@@ -603,7 +610,8 @@ def scalable_kmeans_init(x_host, k: int, seed: int, sample_weight=None, rounds: 
         jax.device_put(np.maximum(weights, 1e-12)),
         seed + 1, k=k,
     )
-    return np.asarray(centers, dtype=np.float64)
+    with telemetry.device_wait("kmeans_init"):
+        return np.asarray(centers, dtype=np.float64)
 
 
 def kmeans_plus_plus_init(x_host, k: int, seed: int, sample_weight=None):

@@ -19,6 +19,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
+
 
 def weighted_moments(X: jax.Array, w: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (total_weight, mean [d], var [d]) with padding rows zero-weighted."""
@@ -368,12 +370,15 @@ def topk_eigh(sym: jax.Array, k: int) -> Tuple[jax.Array, jax.Array, Dict[str, A
     iterations = 0
     if block is not None:
         evals, comps, iterations, residual = topk_eigh_subspace(sym, k=int(k), block=block)
-        iterations, residual = (v.item() for v in jax.device_get((iterations, residual)))  # one fetch
+        with telemetry.device_wait("eig"):
+            iterations, residual = (v.item() for v in jax.device_get((iterations, residual)))  # one fetch
         # a residual that is not a number comes from a matrix that is not one: no
         # decomposition repairs it, the caller's divergence guard names it
         if residual <= eig_tolerance(sym.dtype) or math.isnan(residual):
             return evals, comps, {"eig_path": "topk", "block": block, "iterations": iterations,
                                   "residual_max": residual}
     evals, comps, residual = _topk_eigh_full(sym, k=int(k))
+    with telemetry.device_wait("eig"):
+        residual = float(residual)
     return evals, comps, {"eig_path": "full", "block": block or 0, "iterations": iterations,
-                          "residual_max": float(residual)}
+                          "residual_max": residual}

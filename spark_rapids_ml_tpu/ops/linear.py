@@ -67,9 +67,9 @@ def _gram_span(rows: int, d: int, fast: bool, x_layout: str):
 def _gram_pass(X, y, w, *, fit_intercept: bool, fast: bool, mesh=None):
     """The statistics of a resident dense (X, y), ready, under their span."""
     with _gram_span(int(X.shape[0]), int(X.shape[1]), fast, x_layout_of(X)):
-        return jax.block_until_ready(
-            _dense_stats(X, y, w, fit_intercept=fit_intercept, fast=fast, mesh=mesh)
-        )
+        stats = _dense_stats(X, y, w, fit_intercept=fit_intercept, fast=fast, mesh=mesh)
+        with telemetry.device_wait("gram"):
+            return jax.block_until_ready(stats)
 
 
 def _cd_elastic_net(A, r, lam, l1_ratio, max_iter, tol, kernel=None):
@@ -279,10 +279,12 @@ def linear_fit(
 def _ell_pass(values, indices, y, w, *, d: int, tile: int, fit_intercept: bool, fast: bool):
     """The statistics of padded-ELL rows, ready, under the `gram` span."""
     with _gram_span(int(values.shape[0]), int(d), fast, "ell"):
-        return jax.block_until_ready(_ell_stats(
+        stats = _ell_stats(
             values, indices, y, w, d=d, tile=min(tile, values.shape[0]),
             fit_intercept=fit_intercept, fast=fast,
-        ))
+        )
+        with telemetry.device_wait("gram"):
+            return jax.block_until_ready(stats)
 
 
 def linear_fit_ell(
@@ -516,8 +518,10 @@ def _solve(stats, *, alpha, l1_ratio, use_cd, max_iter, tol, grid=False, **stati
             tol=tol, grid=grid, cd_kernel=cd_kernel, **statics,
         )
         if not use_cd:
-            return jax.block_until_ready(state)
-        sweeps, max_delta = (np.asarray(v) for v in jax.device_get((state["n_iter_"], state.pop("max_delta_"))))
+            with telemetry.device_wait("normal"):
+                return jax.block_until_ready(state)
+        with telemetry.device_wait("cd"):
+            sweeps, max_delta = (np.asarray(v) for v in jax.device_get((state["n_iter_"], state.pop("max_delta_"))))
         ran_out = bool(np.any((sweeps >= int(max_iter)) & (max_delta > tol)))
         l1 = np.asarray(alpha, np.float64) * np.asarray(l1_ratio, np.float64)
         sp.set(

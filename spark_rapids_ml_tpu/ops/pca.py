@@ -102,7 +102,9 @@ def _gram_span(rows: int, d: int, fast: bool, x_layout: str):
 def _gram_pass(X: jax.Array, w: jax.Array, *, fast: bool, mesh=None):
     """The one pass over a resident X: (total_w, mean, cov), ready."""
     with _gram_span(int(X.shape[0]), int(X.shape[1]), fast, x_layout_of(X)):
-        return jax.block_until_ready(_pca_stats(X, w, fast=fast, mesh=mesh))
+        stats = _pca_stats(X, w, fast=fast, mesh=mesh)
+        with telemetry.device_wait("gram"):
+            return jax.block_until_ready(stats)
 
 
 @jax.jit

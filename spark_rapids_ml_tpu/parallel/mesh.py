@@ -618,7 +618,8 @@ def _place_row_major(blocks: Sequence[np.ndarray], formats: Sequence) -> List[ja
             del pieces
             in_flight.append(written)
             if len(in_flight) > 1:
-                jax.block_until_ready(in_flight.popleft())
+                with telemetry.device_wait("row_major_piece"):
+                    jax.block_until_ready(in_flight.popleft())
     return bufs
 
 

@@ -37,10 +37,13 @@
 from __future__ import annotations
 
 import atexit
+import collections
 import contextlib
+import gc
 import json
 import os
 import re
+import resource
 import sys
 import threading
 import time
@@ -78,6 +81,24 @@ __all__ = [
 # records about fifty (six a piece), and a caller makes seven such calls a
 # second (a 20 s window of them: 6.6 k spans).
 _MAX_SPAN_RECORDS = 32768
+# The slow-call rule (docs/observability.md "Slow calls"): per top-level path a
+# ring of the last `_SLOW_RING` walls; once a path has been called
+# `_SLOW_AFTER` times, a call whose wall is over `_SLOW_RATIO` times the ring's
+# median AND over the median plus `_SLOW_EXCESS_S` is a slow call. The last
+# `_MAX_SLOW_CALLS` records are kept, each with at most `_MAX_SLOW_CALL_SPANS`
+# of the spans under it (the longest), and a table of the process's threads is
+# taken at most once in `_THREAD_TABLE_EVERY_S` (and for no more than
+# `_THREAD_TABLE_SHARE` of the process's time: the next one waits five
+# thousand times what the last one took) so that a slow call's own table has
+# a neighbour to be subtracted from.
+_SLOW_RING = 16
+_SLOW_AFTER = 8
+_SLOW_RATIO = 3.0
+_SLOW_EXCESS_S = 0.25
+_MAX_SLOW_CALLS = 8
+_MAX_SLOW_CALL_SPANS = 32
+_THREAD_TABLE_EVERY_S = 5.0
+_THREAD_TABLE_SHARE = 2e-4  # of the process's time at most: a table of 170 threads takes 10 ms on a v5e's host
 _MAX_CONVERGENCE_POINTS = 10_000
 # Most-recent observations retained per histogram for quantile() estimation
 # (serving latency p50/p99); the count/sum/min/max summary sees EVERY
@@ -100,7 +121,7 @@ class _State:
 
 
 _STATE = _State()
-_LOCAL = threading.local()  # per-thread span stack (nesting -> paths)
+_LOCAL = threading.local()  # per-thread stack of the open `_Span`s (nesting -> paths, waits -> the innermost)
 
 # Cached handle to the diagnostics module (trace tags + flight recorder).
 # Lazy: diagnostics never imports telemetry at module level and vice versa,
@@ -524,6 +545,14 @@ class MetricsRegistry:
         self._win_cfg: Optional[Tuple[float, int]] = None  # guarded-by: _lock
         self._win_counters: Dict[str, _CounterRing] = {}  # guarded-by: _lock
         self._win_hists: Dict[str, _HistRing] = {}  # guarded-by: _lock
+        # slow calls: per top-level path the last _SLOW_RING walls
+        self._calls: Dict[str, "collections.deque[float]"] = {}  # guarded-by: _lock
+        self._slow_calls: List[Dict[str, Any]] = []  # guarded-by: _lock
+        self._slow_total: int = 0  # guarded-by: _lock
+        self._threads: Optional[Dict[str, Any]] = None  # the last thread table  # guarded-by: _lock
+        self._threads_due: float = float("-inf")  # monotonic: no table before  # guarded-by: _lock
+        # the flight recorders' overwrites already in `flightrec.events_dropped`
+        self._flightrec_seen: int = 0  # guarded-by: _lock
 
     def _win(self) -> Tuple[float, int]:
         """Window params, resolved once per construction/reset (caller holds
@@ -588,11 +617,18 @@ class MetricsRegistry:
         wall_s: float,
         attrs: Dict[str, Any],
         t0: Optional[float] = None,
-    ) -> None:
+        rank: Optional[int] = None,
+        tags: Optional[Dict[str, Any]] = None,
+    ) -> Optional[Dict[str, Any]]:
+        """One span's record (returned). `rank` and `tags` are this
+        process's rank and the trace tags where the caller has resolved them
+        already (a `_Span` does, once for its record and its two
+        flight-recorder events)."""
         if not _STATE.on:
-            return
+            return None
         rec = {"kind": "span", "name": name, "path": path, "wall_s": wall_s,
-               "rank": _rank(), **_diag().trace_tags(), **attrs}
+               "rank": _rank() if rank is None else rank,
+               **(_diag().trace_tags() if tags is None else tags), **attrs}
         if t0 is not None:
             # wall-clock start: what lets trace_merge place this span on a
             # cross-rank timeline (perf_counter has no cross-process meaning)
@@ -604,6 +640,87 @@ class MetricsRegistry:
                 del self._spans[: -_MAX_SPAN_RECORDS // 2]
         self.observe(f"span.{path}", wall_s)
         _sink_write(rec)
+        return rec
+
+    # -- slow calls ----------------------------------------------------------
+    def open_call(self) -> Tuple[int, float, List[int]]:
+        """What a top-level span reads as it opens, for the slow-call record
+        (`close_call`): the count of spans recorded so far (those after it
+        are the call's), the compile ledger's seconds and the collections of
+        each generation."""
+        with self._lock:
+            h = self._hists.get("compile.wall_s")
+            return self._spans_total, h["sum"] if h else 0.0, [s["collections"] for s in gc.get_stats()]
+
+    def close_call(self, rec: Dict[str, Any], opened: Tuple[int, float, List[int]]) -> None:
+        """A top-level span's record `rec` against its path's last walls:
+        O(1) unless the call is slow or a thread table is due."""
+        path, wall = rec["path"], rec["wall_s"]
+        now = time.monotonic()
+        with self._lock:
+            ring = self._calls.get(path)
+            if ring is None:
+                ring = self._calls[path] = collections.deque(maxlen=_SLOW_RING)
+            median = None
+            if len(ring) >= _SLOW_AFTER and wall > _SLOW_EXCESS_S:
+                median = quantile_of(list(ring), 0.5)
+            ring.append(wall)
+            slow = median is not None and wall > _SLOW_RATIO * median and wall > median + _SLOW_EXCESS_S
+            if not slow and now < self._threads_due:
+                return
+        table = _thread_table()
+        took = time.monotonic() - now
+        with self._lock:
+            self._threads_due = now + max(_THREAD_TABLE_EVERY_S, took / _THREAD_TABLE_SHARE)
+            before, self._threads = self._threads, table or self._threads
+            if not slow:
+                return
+            since = min(len(self._spans), self._spans_total - opened[0])
+            under = [r for r in self._spans[len(self._spans) - since:] if r["path"].startswith(path + "/")]
+            usual = {}
+            for r in under:  # each path's mean wall over its other records, for `_excess_span`
+                h = self._hists.get("span." + r["path"])
+                n = h["count"] - 1.0 if h else 0.0
+                usual[r["path"]] = (h["sum"] - r["wall_s"]) / n if n > 0 else 0.0
+            h = self._hists.get("compile.wall_s")
+            compile_s = (h["sum"] if h else 0.0) - opened[1]
+        kept = sorted(under, key=lambda r: -r["wall_s"])[:_MAX_SLOW_CALL_SPANS]
+        kept.sort(key=lambda r: r.get("t0", 0.0))
+        record = {
+            "path": path,
+            "wall_s": wall,
+            "median_s": median,
+            "span": dict(rec),
+            "spans": [{k: r[k] for k in ("path", "t0", "wall_s", "wait_s", "waits") if k in r} for r in kept],
+            "spans_left_out": len(under) - len(kept),
+            "excess_in": _excess_span(under, usual, wall - median),
+            "gc": {"count": list(gc.get_count()),
+                   "collections": [s["collections"] - c0 for s, c0 in zip(gc.get_stats(), opened[2])]},
+            "compile_s": compile_s,
+            "threads": table,
+            "threads_before": before,
+        }
+        with self._lock:
+            self._slow_calls.append(record)
+            del self._slow_calls[:-_MAX_SLOW_CALLS]
+            self._slow_total += 1
+        self.inc("telemetry.slow_calls")
+        diag = _diag()
+        diag.record_event("slow_call", **record)
+        from .utils import get_logger
+
+        get_logger("telemetry").warning(_slow_call_line(record))
+        diag.flight_recorder().dump(reason=f"slow call: {path} {wall:.2f} s against a median of {median:.2f} s")
+
+    def _sync_flightrec(self) -> None:
+        """Bring `flightrec.events_dropped` up to the flight recorders' own
+        count of overwrites: called where a snapshot, a mark or a delta is
+        taken, so that no overwritten event pays for a counter."""
+        overwritten = _diag().FlightRecorder.overwritten
+        with self._lock:
+            new, self._flightrec_seen = overwritten - self._flightrec_seen, overwritten
+        if new > 0:
+            self.inc("flightrec.events_dropped", float(new))
 
     def record_convergence(self, solver: str, iteration: int, value: float) -> None:
         if not _STATE.on:
@@ -778,7 +895,9 @@ class MetricsRegistry:
         """Machine-readable state: counters, gauges, histogram summaries, and
         per-path span aggregates. Safe to json.dumps. Span aggregates come
         from the `span.<path>` histograms, which see EVERY span — the raw
-        record list is trimmed to a bound and would under-count."""
+        record list is trimmed to a bound and would under-count.
+        `slow_calls`: the records of the last slow calls (`close_call`)."""
+        self._sync_flightrec()
         with self._lock:
             spans: Dict[str, Dict[str, float]] = {}
             for hname, h in self._hists.items():
@@ -798,6 +917,7 @@ class MetricsRegistry:
                     k: {"points": len(v), "last": v[-1] if v else None}
                     for k, v in self._convergence.items()
                 },
+                "slow_calls": [dict(r) for r in self._slow_calls],
             }
         # flight-recorder health rides the snapshot (and therefore the bench
         # JSON "telemetry" embedding) — outside the lock: the recorder has its
@@ -806,15 +926,17 @@ class MetricsRegistry:
         return snap
 
     class _Mark:
-        __slots__ = ("counters", "hists", "spans_total")
+        __slots__ = ("counters", "hists", "spans_total", "slow_total")
 
     def mark(self) -> "MetricsRegistry._Mark":
         """Cheap position marker for `delta()` (fit-scoped metrics)."""
+        self._sync_flightrec()
         m = MetricsRegistry._Mark()
         with self._lock:
             m.counters = dict(self._counters)
             m.hists = {k: dict(v) for k, v in self._hists.items()}
             m.spans_total = self._spans_total
+            m.slow_total = self._slow_total
         return m
 
     def delta(self, m: "MetricsRegistry._Mark") -> Dict[str, Any]:
@@ -822,7 +944,10 @@ class MetricsRegistry:
         `m`, and current gauges — the per-fit view attached to models.
         `spans_dropped` is how many of the spans recorded since `m` the trim
         no longer holds (exact): a reader that sums or averages `spans`
-        must refuse a non-zero value rather than report over a cut list."""
+        must refuse a non-zero value rather than report over a cut list.
+        `slow_calls`: the records of the slow calls since `m` that are
+        still kept (a fit's own, in its model's `_fit_metrics`)."""
+        self._sync_flightrec()
         with self._lock:
             counters = {
                 k: v - m.counters.get(k, 0.0)
@@ -849,15 +974,19 @@ class MetricsRegistry:
             # could resize the dict mid-iteration (found by the
             # guard-discipline rule)
             gauges = dict(self._gauges)
+            slow = min(len(self._slow_calls), self._slow_total - m.slow_total)
+            slow_calls = [dict(r) for r in self._slow_calls[len(self._slow_calls) - slow:]] if slow > 0 else []
         return {
             "counters": counters,
             "gauges": gauges,
             "histograms": hists,
             "spans": spans,
             "spans_dropped": since - kept,
+            "slow_calls": slow_calls,
         }
 
     def reset(self) -> None:
+        overwritten = _diag().FlightRecorder.overwritten
         with self._lock:
             self._counters.clear()
             self._gauges.clear()
@@ -871,6 +1000,65 @@ class MetricsRegistry:
             self._win_cfg = None
             self._win_counters.clear()
             self._win_hists.clear()
+            self._calls.clear()
+            self._slow_calls.clear()
+            self._threads, self._threads_due = None, float("-inf")
+            self._flightrec_seen = overwritten
+
+
+def _thread_table() -> Optional[Dict[str, Any]]:
+    """The process's threads by name with the CPU seconds (user + system)
+    each name has burnt, from `/proc/self/task/*/stat`; None where `/proc`
+    is not there. Two tables subtracted say which thread was busy between
+    them."""
+    try:
+        tids = os.listdir("/proc/self/task")
+    except OSError:
+        return None
+    tick = os.sysconf("SC_CLK_TCK")
+    cpu: Dict[str, float] = {}
+    for tid in tids:
+        try:
+            fd = os.open(f"/proc/self/task/{tid}/stat", os.O_RDONLY)
+        except OSError:  # the thread ended between the listing and the read
+            continue
+        try:
+            head, _, rest = os.read(fd, 1024).decode("ascii", "replace").rpartition(")")
+        finally:
+            os.close(fd)
+        fields = rest.split()  # from field 3 (state): utime and stime are fields 14 and 15
+        name = head.partition("(")[2]
+        cpu[name] = cpu.get(name, 0.0) + (int(fields[11]) + int(fields[12])) / tick
+    return {"t": time.time(), "threads": len(tids), "cpu_s": cpu}
+
+
+def _excess_span(under: List[Dict[str, Any]], usual: Dict[str, float], excess: float) -> Optional[str]:
+    """The path of the deepest span under a slow call that holds at least
+    half of the call's excess (its wall less its path's usual wall); None
+    where no span does (the excess lies in the call's own code)."""
+    held = [r for r in under if r["wall_s"] - usual.get(r["path"], 0.0) >= 0.5 * excess]
+    if not held:
+        return None
+    return max(held, key=lambda r: (r["path"].count("/"), r["wall_s"]))["path"]
+
+
+def _slow_call_line(record: Dict[str, Any]) -> str:
+    """The one WARNING line of a slow call."""
+    span = record["span"]
+    line = f"slow call: {record['path']} {record['wall_s']:.2f} s against a median of {record['median_s']:.2f} s"
+    inside = next((r for r in record["spans"] if r["path"] == record["excess_in"]), None)
+    if inside is not None:
+        line += f": {inside['path']} {inside['wall_s']:.2f} s of which waiting {inside.get('wait_s', 0.0):.2f} s"
+    line += (
+        f"; process cpu {span.get('cpu_s', 0.0):.2f} s, {span.get('minor_faults', 0)} minor faults, "
+        f"{span.get('invol_switches', 0)} involuntary switches"
+    )
+    now, before = record["threads"], record["threads_before"]
+    if now and before:
+        rise = {n: c - before["cpu_s"].get(n, 0.0) for n, c in now["cpu_s"].items()}
+        name = max(rise, key=rise.get)
+        line += f"; busiest thread in the {now['t'] - before['t']:.1f} s before: {name} {rise[name]:.2f} s cpu"
+    return line
 
 
 _REGISTRY = MetricsRegistry()
@@ -1038,20 +1226,25 @@ _NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "logger", "path", "wall_s", "_t0", "_w0", "_ta")
+    __slots__ = ("name", "attrs", "logger", "path", "wall_s", "wait_s", "waits",
+                 "_t0", "_w0", "_ta", "_rank", "_tags", "_opened")
 
     def __init__(self, name: str, logger: Any, attrs: Dict[str, Any]) -> None:
         self.name = name
         self.logger = logger
         self.attrs = attrs
         self.wall_s: Optional[float] = None
+        # what `device_wait` adds while this span is the innermost open one
+        self.wait_s = 0.0
+        self.waits = 0
 
     def __enter__(self) -> "_Span":
         stack = getattr(_LOCAL, "stack", None)
         if stack is None:
             stack = _LOCAL.stack = []
-        stack.append(self.name)
-        self.path = "/".join(stack)
+        self.path = f"{stack[-1].path}/{self.name}" if stack else self.name
+        top = not stack and _STATE.on
+        stack.append(self)
         # xprof alignment: TraceAnnotation is the NVTX-range analog — it tags
         # this wall-clock interval in any ACTIVE jax.profiler trace and is
         # near-free when no trace is running. Spans must never break when the
@@ -1065,11 +1258,24 @@ class _Span:
         except Exception:
             self._ta = None
         self._w0 = time.time()  # wall clock, for cross-rank trace merging
-        _diag().record_event("span_begin", name=self.name, path=self.path)
+        # rank and trace tags resolved once, for the two flight-recorder
+        # events and the record
+        diag = _diag()
+        self._rank, self._tags = diag._rank(), diag.trace_tags()
+        diag.flight_recorder().record_as(
+            self._rank, self._tags, "span_begin", {"name": self.name, "path": self.path}
+        )
         self._t0 = time.perf_counter()
+        # a top-level span carries what the process spent while its clock ran
+        # (`_spent`). The readings lie inside its wall: the span pays for
+        # them, not the caller's time between spans; nested spans take none
+        self._opened = None
+        if top:
+            self._opened = (resource.getrusage(resource.RUSAGE_SELF), time.thread_time(), _REGISTRY.open_call())
         return self
 
     def __exit__(self, exc_type: Any, exc_val: Any, exc_tb: Any) -> bool:
+        spent = self._spent() if self._opened is not None and exc_type is None else None
         self.wall_s = time.perf_counter() - self._t0
         if self._ta is not None:
             try:
@@ -1077,25 +1283,72 @@ class _Span:
             except Exception:
                 pass
         stack = _LOCAL.stack
-        if stack and stack[-1] == self.name:
+        if stack and stack[-1] is self:
             stack.pop()
-        if exc_type is None:
-            _diag().record_event("span_end", name=self.name, path=self.path,
-                                 wall_s=self.wall_s)
-            _REGISTRY.record_span(self.name, self.path, self.wall_s, self.attrs,
-                                  t0=self._w0)
-            if self.logger is not None:
-                self.logger.info("stage %s: %.3fs", self.path, self.wall_s)
-        else:
-            _diag().record_event("span_fail", name=self.name, path=self.path,
-                                 error=exc_type.__name__)
+        recorder = _diag().flight_recorder()
+        if exc_type is not None:
+            recorder.record_as(self._rank, self._tags, "span_fail",
+                               {"name": self.name, "path": self.path, "error": exc_type.__name__})
+            return False
+        recorder.record_as(self._rank, self._tags, "span_end",
+                           {"name": self.name, "path": self.path, "wall_s": self.wall_s})
+        if self.waits:
+            self.attrs.update(wait_s=self.wait_s, waits=self.waits)
+        if spent is not None:
+            self.attrs.update(spent)
+        rec = _REGISTRY.record_span(self.name, self.path, self.wall_s, self.attrs,
+                                    t0=self._w0, rank=self._rank, tags=self._tags)
+        if rec is not None and self._opened is not None:
+            _REGISTRY.close_call(rec, self._opened[2])
+        if self.logger is not None:
+            self.logger.info("stage %s: %.3fs", self.path, self.wall_s)
         return False
+
+    def _spent(self) -> Dict[str, Any]:
+        """What the process spent while this top-level span was open:
+        `cpu_s` (user + system, all threads), `thread_cpu_s` (the calling
+        thread), page faults and context switches."""
+        ru0, thread0, _ = self._opened
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        return {
+            "cpu_s": (ru.ru_utime - ru0.ru_utime) + (ru.ru_stime - ru0.ru_stime),
+            "thread_cpu_s": time.thread_time() - thread0,
+            "minor_faults": ru.ru_minflt - ru0.ru_minflt,
+            "major_faults": ru.ru_majflt - ru0.ru_majflt,
+            "vol_switches": ru.ru_nvcsw - ru0.ru_nvcsw,
+            "invol_switches": ru.ru_nivcsw - ru0.ru_nivcsw,
+        }
 
     def set(self, **attrs: Any) -> None:
         """Attributes known only inside the span (the rung a batch padded to,
         the bytes an extraction returned): host metadata only, never a value
         that has to be fetched from the device."""
         self.attrs.update(attrs)
+
+
+class _Wait:
+    """`device_wait` inside a span: the wait's wall goes onto the innermost
+    open span (`wait_s`, `waits`) and, where an efficiency scope is open,
+    into that scope through `timer`."""
+
+    __slots__ = ("_span", "_timer", "_t0")
+
+    def __init__(self, span: _Span, timer: Any) -> None:
+        self._span = span
+        self._timer = timer
+
+    def __enter__(self) -> "_Wait":
+        if self._timer is not None:
+            self._timer.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        self._span.wait_s += time.perf_counter() - self._t0
+        self._span.waits += 1
+        if self._timer is not None:
+            self._timer.__exit__(*exc)
+        return False
 
 
 def span(name: str, *, logger: Any = None, **attrs: Any):
@@ -1152,15 +1405,19 @@ def _efficiency():
 
 def device_wait(stage: str):
     """Time a `block_until_ready`/`np.asarray` wait at a boundary that
-    ALREADY host-fetches, attributing the wall to the active attribution
-    scope's `execute` kind under `stage`. Shared no-op when telemetry is
-    disabled or no scope is open on this thread."""
+    ALREADY host-fetches: the wall goes to the active attribution scope's
+    `execute` kind under `stage`, and onto the innermost open span of this
+    thread as `wait_s` / `waits`, so that a span's `wall_s - wait_s` is the
+    host's own time in it. Shared no-op when telemetry is disabled or
+    neither a scope nor a span is open on this thread."""
     if not _STATE.on:
         return _NOOP_SPAN
     eff = _efficiency()
-    if eff is None or not eff.active():
-        return _NOOP_SPAN
-    return eff.device_wait_timer(stage)
+    timer = eff.device_wait_timer(stage) if eff is not None and eff.active() else None
+    stack = getattr(_LOCAL, "stack", None)
+    if not stack:
+        return _NOOP_SPAN if timer is None else timer
+    return _Wait(stack[-1], timer)
 
 
 def host_section(stage: str):

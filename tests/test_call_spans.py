@@ -27,7 +27,9 @@ TRANSFORM_PATHS = [
     "transform.assemble",
 ]
 SOLVE_CHILDREN = ["fit/solve/init", "fit/solve/loop", "fit/solve/finish"]
-_RECORD_KEYS = {"kind", "name", "path", "wall_s", "rank", "t0", "trace_id", "fit_id"}
+# what the process spent, on a top-level span's record (tests/test_slow_call.py holds them)
+SPENT_KEYS = {"cpu_s", "thread_cpu_s", "minor_faults", "major_faults", "vol_switches", "invol_switches"}
+_RECORD_KEYS = {"kind", "name", "path", "wall_s", "rank", "t0", "trace_id", "fit_id", "wait_s", "waits"} | SPENT_KEYS
 
 
 @pytest.fixture
@@ -70,6 +72,9 @@ def test_transform_records_its_steps_in_order(tele, rng):
     assert isinstance(by_path["transform/dispatch"]["new_shape"], bool)
     assert by_path["transform/fetch"] == {"rows": 600}
     assert by_path["transform.assemble"] == {"rows": 600, "columns": 1}
+    # the one wait of a one-piece call is the fetch's; the three top-level spans say what the process spent
+    assert {s["path"]: s["waits"] for s in spans if "waits" in s} == {"transform/fetch": 1}
+    assert [s["path"] for s in spans if SPENT_KEYS <= set(s)] == ["transform.extract", "transform", "transform.assemble"]
     assert delta["counters"]["transform.bytes_extracted"] == 600 * 8 * 4
     assert delta["counters"]["transform.rows"] == 600 and delta["counters"]["transform.batches"] == 1
     assert delta["spans_dropped"] == 0
@@ -109,6 +114,9 @@ def test_kmeans_fit_records_init_loop_finish_under_solve(tele, rng, workers, pat
         "tile_access": "sliced",
     }
     assert by_path["fit/solve/finish"] == {}
+    # the waits that were there, each on the span that holds it: the deferred shift of iterations 2 and 3,
+    # the final inertia and the model's attributes
+    assert {s["path"]: s["waits"] for s in spans if "waits" in s} == {"fit/solve/loop": 2, "fit/solve/finish": 2}
     wall = {s["path"]: s["wall_s"] for s in spans}
     assert sum(wall[p] for p in SOLVE_CHILDREN) <= wall["fit/solve"]
 
@@ -137,6 +145,8 @@ def test_logistic_fit_records_init_loop_finish_under_solve(tele, rng):
     # the jnp form (CPU): the two products, nothing speculated (tests/test_logistic_fused.py)
     assert by_path["fit/solve/loop"] == {"solver_path": "dense", "glm_pass": "two_products", "speculated": 0}
     assert by_path["fit/solve/init"] == {} and by_path["fit/solve/finish"] == {}
+    # one asynchronous program launched in `loop`, waited for in `finish`
+    assert {s["path"]: s["waits"] for s in spans if "waits" in s} == {"fit/solve/finish": 1}
 
 
 def test_telemetry_off_every_call_site_gets_the_shared_noop_span(rng, monkeypatch):
@@ -152,10 +162,16 @@ def test_telemetry_off_every_call_site_gets_the_shared_noop_span(rng, monkeypatc
         return sp
 
     monkeypatch.setattr(telemetry, "span", watching)
+    waits = []
+    real_wait = telemetry.device_wait
+    monkeypatch.setattr(telemetry, "device_wait", lambda stage: waits.append((stage, real_wait(stage))) or waits[-1][1])
     model = _kmeans(df)
     model.transform(df)
     LogisticRegression(maxIter=3).setFeaturesCol("features").setLabelCol("label").fit(df)
     names = [n for n, _ in handed]
+    # the waits the spans hold (tests/test_slow_call.py) cost a disabled call the same nothing
+    assert {"kmeans_shift", "kmeans_inertia", "kmeans_finish", "predict_fetch", "finish"} <= {stage for stage, _ in waits}
+    assert all(w is telemetry._NOOP_SPAN for _, w in waits)
     for name in ("transform.extract", "construct", "pad", "dispatch", "fetch", "transform.assemble"):
         assert names.count(name) == 1, name
     for name in ("init", "loop", "finish"):
@@ -164,6 +180,7 @@ def test_telemetry_off_every_call_site_gets_the_shared_noop_span(rng, monkeypatc
     telemetry._NOOP_SPAN.set(rows=1)  # attributes set inside a span cost nothing either
     snap = telemetry.snapshot()
     assert snap["spans"] == {} and "transform.bytes_extracted" not in snap["counters"]
+    assert snap["slow_calls"] == [] and "telemetry.slow_calls" not in snap["counters"]
 
 
 def test_span_set_adds_attributes_known_inside(tele):
