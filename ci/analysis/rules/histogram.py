@@ -1,25 +1,27 @@
 #
 # Hand-rolled binned-accumulation detector (the `raw-distance` taint pattern
 # extended to histograms, seeded for ROADMAP item 4): the RF/tree family's
-# `bin ids -> (node, feature, bin) accumulation` inner loop is about to get
-# ONE shared Pallas histogram core (the same consolidation ops/distance.py
-# performed for the neighbor family), and this rule is the ratchet that
-# porting lands against — private copies of the loop are findings from day
-# one, so the port can delete them without new ones growing back.
+# `bin ids -> (node, feature, bin) accumulation` inner loop has ONE Pallas
+# histogram core, ops/histogram.py (since PR 39: the accumulate over rows
+# sorted by node as the kernel `srml_hist_accumulate_bf16`; the same
+# consolidation ops/distance.py performed for the neighbor family), and this
+# rule is the ratchet porting lands against — private copies of the loop are
+# findings from day one, so a port can delete them without new ones growing
+# back.
 #
 #   an accumulation sink — `segment_sum`, `scatter_add`, an
 #   `.at[bins].add(...)` scatter, or a one-hot matmul (`one_hot(bins) @ x`,
 #   `jnp.dot(one_hot(bins).T, x)`) — whose segment/index operand was built
 #   from a LOCAL binning call (`jnp.digitize`, `jnp.searchsorted`,
-#   `bucketize`) is a finding anywhere in the framework outside the future
-#   histogram core (ops/histogram.py, reserved).
+#   `bucketize`) is a finding anywhere in the framework outside the
+#   histogram core (ops/histogram.py).
 #
 # Taint is function-scoped and shallow exactly like raw-distance: names
 # bound to binning-derived expressions are tainted, taint flows through
 # arithmetic, subscripts, `astype`/`clip`/`reshape`/`ravel` and the
 # shape-preserving combinators, and any other call launders — a bin tensor
 # produced by one function and accumulated by another is the factored shape
-# the future core will own, not a hand-rolled loop. Genuinely different
+# the core owns, not a hand-rolled loop. Genuinely different
 # shapes waive with `# histogram-ok: <reason>`. The baseline lands EMPTY:
 # today's tree bins (ops/trees.py `_bin_features`) and accumulates
 # (`_grow_level`) in separate functions, which is exactly the boundary the
@@ -53,7 +55,7 @@ class HistogramLoopRule(RuleBase):
     id = "histogram-loop"
     waiver = "histogram"
     tree_scope = ("spark_rapids_ml_tpu",)
-    exempt_files = frozenset({"histogram.py"})  # the (future) core owns the loop
+    exempt_files = frozenset({"histogram.py"})  # the core owns the loop
     description = (
         "hand-rolled binned accumulation (segment_sum/scatter/one-hot-matmul "
         "over locally-binned ids) outside the histogram core"

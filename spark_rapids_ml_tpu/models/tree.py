@@ -307,12 +307,14 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                 sp.set(
                     passes_per_tree=summary["passes_per_tree"], accumulate=summary["accumulate"],
                     level_programs=(grown // inputs.mesh.devices.size) * len(plan), trees_grown=grown,
-                    sorted_levels=summary["sorted_levels"],
+                    sorted_levels=summary["sorted_levels"], kernel_levels=summary["kernel_levels"],
                 )
                 reg = telemetry.registry()
                 reg.inc("forest.trees", grown)
                 reg.inc("forest.levels", grown * len(plan))
                 reg.inc("forest.row_passes", grown * summary["passes_per_tree"])
+                # the passes `ops.histogram`'s kernel ran (0 where XLA's form did: a CPU, weights, over 256 bins)
+                reg.inc("forest.kernel_passes", grown * summary["kernel_levels"])
             with telemetry.span("finish"):  # ONE fetch: the forest's three arrays
                 with telemetry.device_wait("finish"):
                     out = jax.device_get(state)

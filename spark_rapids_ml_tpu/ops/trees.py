@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import distance, histogram
 
 # ---------------------------------------------------------------------------
 # Binning
@@ -328,6 +329,7 @@ def plan_summary(plan: List[Dict[str, Any]]) -> Dict[str, Any]:
     return {
         "passes_per_tree": sum(lv["passes"] for lv in plan),
         "sorted_levels": sum(lv["rows"] == "sorted" for lv in plan),
+        "kernel_levels": sum(bool(lv.get("kernel")) for lv in plan),
         "accumulate": forms.pop() if len(forms) == 1 else "mixed",
         "deepest_chunk": max((lv["chunk"] for lv in plan), default=1),
     }
@@ -344,11 +346,14 @@ def _level_histogram(
     bins: int,
     form: str,
     ordered: Tuple[jax.Array, ...] = (),  # `order_rows`' results for a pass over the sorted rows
+    kernel: str = "",  # the level's `kernel` of `_forest_programs`' plan: the sorted pass as `ops.histogram`'s kernel
 ) -> jax.Array:
     """One pass over the rows: hist[s, c, j, b] = the sum of stats[s] over the
     active rows at node c0 + c whose feature fids[c, j] lies in bin b.
     [S, chunk, m, bins] in the statistics' dtype. With `ordered` it is
-    `_sorted_histogram`'s pass. Else rows go a tile at a time where they lie;
+    `_sorted_histogram`'s pass, or, where the plan says `kernel`, the Mosaic
+    kernel's (`ops.histogram.sorted_histogram`: the same integers). Else rows
+    go a tile at a time where they lie;
     each row's m bin ids are picked at ITS node's subset (a contraction with
     the nodes' selection matrix up to `MATMUL_GATHER_MAX_NODES` nodes a pass,
     a per-element gather beyond). `onehot`: the tile's
@@ -359,6 +364,8 @@ def _level_histogram(
     from ..parallel.mesh import ROWS_AXIS
 
     if ordered:
+        if kernel:
+            return histogram.sorted_histogram(Xb, *ordered, fids, bins=bins, interpret=kernel == "interpret")
         return _sorted_histogram(Xb, *ordered, fids, bins=bins)
     n, d = Xb.shape
     S = stats.shape[0]
@@ -531,6 +538,7 @@ def _tree_level(
         fids = jax.lax.dynamic_slice_in_dim(fids_level, lo, chunk, 0)  # [chunk, m]
         hist = _level_histogram(
             Xb, stats, node_id, active, fids, c0, bins=B, form=level["accumulate"], ordered=ordered,
+            kernel=level.get("kernel", ""),
         )
         gain, total = _split_gains(hist, params["impurity"], params["min_instances"])
         flat_best = jnp.argmax(gain.reshape(chunk, -1), axis=1)
@@ -591,11 +599,15 @@ def _tree_final_level(stats, node_id, active, node_stats, max_depth: int):
 def _forest_programs(
     mesh, n_rows: int, n_features: int, n_stats: int, dtype: str, trees_per_dev: int, max_depth: int, max_bins: int,
     max_features: int, impurity: str, node_chunk: int, integer_stats: bool, bootstrap: bool,
-    subsample_rate: float, min_instances: float, min_info_gain: float,
+    subsample_rate: float, min_instances: float, min_info_gain: float, kernel_mode: str = "jnp",
 ):
     """The jitted programs of a forest fit on one mesh at one shape, built
     once and kept: the estimator seed and the round are traced arguments, so
-    a refit with another seed compiles nothing."""
+    a refit with another seed compiles nothing. `kernel_mode` is
+    `distance.kernel_mode()`'s answer, resolved by `forest_fit` outside any
+    trace and part of the key: where it is not `jnp`, a sorted level whose
+    shard `ops.histogram` takes runs the accumulate as its kernel (the plan's
+    entry says `kernel`)."""
     from jax import shard_map
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
@@ -606,6 +618,10 @@ def _forest_programs(
     S = n_stats
     M = 2 ** (max_depth + 1) - 1
     plan = level_plan(max_depth, max_features, max_bins, S, node_chunk, integer_stats)
+    if kernel_mode != "jnp" and histogram.takes(
+        n_rows // n_dev, binned_cols(n_features), S, min(max_features, n_features), max_bins,
+    ):
+        plan = [dict(lv, kernel=kernel_mode) if lv["rows"] == "sorted" else lv for lv in plan]
     params = {
         "n_features": n_features, "max_depth": max_depth, "max_bins": max_bins, "max_features": max_features,
         "impurity": impurity, "min_instances": min_instances, "min_info_gain": min_info_gain,
@@ -677,6 +693,10 @@ def _forest_programs(
                 rows_spec, rows_spec,
                 P(ROWS_AXIS, None), P(ROWS_AXIS, None), P(ROWS_AXIS, None, None),
             ),
+            # the kernel's body mixes a shard's values with loop indices, program ids and iotas: typed as
+            # varying over the mesh axis they would need a `pvary` each, which Mosaic lowers no more than
+            # the interpreter evaluates (`distance.shard_map_check_vma`). A level has no collective to check
+            check_vma=not level.get("kernel"),
         ))
 
     def final_fn(stw_l, nid_l, act_l, nst_b):
@@ -756,7 +776,7 @@ def forest_fit(
     progs = _forest_programs(
         mesh, int(Xb.shape[0]), int(n_features), int(stats_row.shape[1]), jnp.dtype(stats_row.dtype).name, trees_per_dev, int(max_depth), int(max_bins),
         int(max_features), str(impurity), int(node_chunk), bool(integer_stats), bool(bootstrap),
-        float(subsample_rate), float(min_instances), float(min_info_gain),
+        float(subsample_rate), float(min_instances), float(min_info_gain), distance.kernel_mode(),
     )
     seed32 = np.uint32(int(seed) & 0xFFFFFFFF)
     rounds = []

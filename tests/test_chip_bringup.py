@@ -517,19 +517,23 @@ def test_the_logistic_loop_at_d3000_reads_x_once_where_it_lies(monkeypatch):
     assert fit.memory_analysis().temp_size_in_bytes < 0.3 * 2**30
 
 
-@pytest.mark.parametrize("program", ["boot", "level_4_in_place", "level_12_sorted"])
+@pytest.mark.parametrize("program", ["boot", "level_4_in_place", "level_12_sorted", "level_12_sorted_four_chips"])
 def test_a_forests_programs_at_the_protocols_shape_loop_only_in_the_accumulate(monkeypatch, program):
     """The programs of the `rfc-p3k` fit (393,216 rows of 3,072 uint8 columns,
-    depth 13, 54 of 3,000 features a node, two classes) compiled for a v5e,
-    each in seconds (as it stood a deep level's program compiled in 12 to 70 s).
-    EVERY `while` of a level program carries the scope `srml_hist_accumulate`
-    in its metadata, and the bootstrap's program holds none: a trace's op
-    names do not carry the scope, so `kernel.hist_ms_per_fit` finds the
-    accumulate as the loops of the fit's programs, and a loop that is not the
-    accumulate's must not come into one of them without this test saying so.
-    A sorted level fetches whole rows of the binned X, which lies row-major by
-    the device's own choice at 3,072 columns: its temporaries stay under the
-    deepest histogram's few arrays, nothing of the binned X's size."""
+    depth 13, 54 of 3,000 features a node, two classes) compiled for a v5e as
+    a TPU process builds them (`kernel_mode` "pallas"), each in seconds (as it
+    stood a deep level's program compiled in 12 to 70 s). EVERY `while` of a
+    level program carries the scope `srml_hist_accumulate` in its metadata,
+    and the bootstrap's program holds none: a trace's op names do not carry
+    the scope, so `kernel.hist_ms_per_fit` finds the accumulate as the loops
+    of the fit's programs (and the kernel by its name), and a loop that is not
+    the accumulate's must not come into one of them without this test saying
+    so. A sorted level's accumulate is ONE Mosaic custom call named
+    `srml_hist_accumulate_bf16` (`ops/histogram.py`), a device's own under
+    `shard_map` on the four chips of a host too, and no loop is left in its
+    program; it reads the binned X where it lies (row-major by the device's
+    own choice at 3,072 columns): its temporaries stay under the deepest
+    histogram's few arrays, nothing of the binned X's size."""
     import re
     import time
 
@@ -538,15 +542,16 @@ def test_a_forests_programs_at_the_protocols_shape_loop_only_in_the_accumulate(m
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from spark_rapids_ml_tpu.ops import trees
+    from spark_rapids_ml_tpu.ops import distance, trees
     from spark_rapids_ml_tpu.parallel.mesh import ROWS_AXIS
 
     try:
         topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
     except Exception as e:  # no libtpu on this machine: nothing to compile with
         pytest.skip(f"no TPU compiler available ahead of time: {type(e).__name__}: {e}")
-    mesh = Mesh(np.asarray(topo.devices[:1]), (ROWS_AXIS,))
-    n, d, m, bins, S, depth = 393_216, 3000, 54, 128, 2, 13
+    n_dev = 4 if program.endswith("four_chips") else 1
+    mesh = Mesh(np.asarray(topo.devices[:n_dev]), (ROWS_AXIS,))
+    n, d, m, bins, S, depth = 393_216 * n_dev, 3000, 54, 128, 2, 13
     nodes = 2 ** (depth + 1) - 1
 
     def struct(shape, dtype, *spec):
@@ -557,15 +562,20 @@ def test_a_forests_programs_at_the_protocols_shape_loop_only_in_the_accumulate(m
     stw = struct((S, n), jnp.float32, None, ROWS_AXIS)
     level_args = (
         struct((n, trees.binned_cols(d)), jnp.uint8, ROWS_AXIS, None), stw, *rows,
-        struct((1, nodes), jnp.int32, ROWS_AXIS, None), struct((1, nodes), jnp.int32, ROWS_AXIS, None),
-        struct((1, nodes, S), jnp.float32, ROWS_AXIS, None, None), scalar(jnp.uint32), scalar(jnp.int32),
+        struct((n_dev, nodes), jnp.int32, ROWS_AXIS, None), struct((n_dev, nodes), jnp.int32, ROWS_AXIS, None),
+        struct((n_dev, nodes, S), jnp.float32, ROWS_AXIS, None, None), scalar(jnp.uint32), scalar(jnp.int32),
     )
-    ordered = (struct((n,), jnp.int32, ROWS_AXIS), struct((n,), jnp.int32, ROWS_AXIS), stw, struct((1,), jnp.int32, ROWS_AXIS))
+    ordered = (struct((n,), jnp.int32, ROWS_AXIS), struct((n,), jnp.int32, ROWS_AXIS), stw, struct((n_dev,), jnp.int32, ROWS_AXIS))
     assert trees.binned_cols(d) == 3072
-    with jax.enable_x64(False):
-        progs = trees._forest_programs(mesh, n, d, S, "float32", 7, depth, bins, m, "gini", 0, True, True, 1.0, 1.0, 0.0)
+    kernel = distance.kernel_name("hist_accumulate", True)
+    assert kernel == "srml_hist_accumulate_bf16" and kernel.startswith(trees.HIST_SCOPE)  # the prefix the benchmark's metrics read
+    # as a fit traces them: under `mesh.dtype_scope`'s matmul precision, which Mosaic takes for a kernel's
+    # contractions too where they state none (the kernel states one pass: "Bad lhs type" for bfloat16 otherwise)
+    with jax.enable_x64(False), jax.default_matmul_precision("float32"):
+        progs = trees._forest_programs(mesh, n, d, S, "float32", 7, depth, bins, m, "gini", 0, True, True, 1.0, 1.0, 0.0, "pallas")
         plan = progs["plan"]
         assert [lv["rows"] for lv in plan] == ["in_place"] * 5 + ["sorted"] * 8 and all(lv["passes"] == 1 for lv in plan)
+        assert [lv.get("kernel", "") for lv in plan] == [""] * 5 + ["pallas"] * 8
         t0 = time.perf_counter()
         if program == "boot":
             lowered = progs["boot"].lower(struct((n, S), jnp.float32, ROWS_AXIS, None), struct((n,), jnp.float32, ROWS_AXIS),
@@ -576,11 +586,17 @@ def test_a_forests_programs_at_the_protocols_shape_loop_only_in_the_accumulate(m
             lowered = progs["levels"][12].lower(*level_args, *ordered)
         compiled = lowered.compile()
         assert time.perf_counter() - t0 < 60  # 3 to 7 s here
-    loops = re.findall(r'= [^\n]* while\([^\n]*op_name="([^"]*)"', compiled.as_text())
+    text = compiled.as_text()
+    loops = re.findall(r'= [^\n]* while\([^\n]*op_name="([^"]*)"', text)
+    calls = re.findall(r'(%[\w.\-]+) = [^\n]* custom-call\([^\n]*custom_call_target="tpu_custom_call"', text)
     if program == "boot":
-        assert loops == []
-    else:
+        assert loops == [] and calls == []
+    elif program == "level_4_in_place":
         assert loops and all(f"/{trees.HIST_SCOPE}/" in name for name in loops), loops
-        assert len(loops) == (1 if program == "level_4_in_place" else 2)  # the row tiles; the sorted tiles and their windows
+        assert len(loops) == 1 and calls == []  # the row tiles
+    else:
+        assert loops == [], loops  # the sorted tiles and their windows went into the kernel
+        assert len(calls) == 1 and calls[0].lstrip("%").startswith(kernel), calls  # one call a device
+    if program != "boot":
         deepest = S * 4096 * m * bins * 4
-        assert compiled.memory_analysis().temp_size_in_bytes < 4 * deepest < n * trees.binned_cols(d)
+        assert compiled.memory_analysis().temp_size_in_bytes < 4 * deepest < n // n_dev * trees.binned_cols(d)

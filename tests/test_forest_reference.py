@@ -24,7 +24,7 @@ from chipbench.families import rfc  # noqa: E402
 from spark_rapids_ml_tpu import core, memory, telemetry  # noqa: E402
 from spark_rapids_ml_tpu.models.classification import RandomForestClassifier  # noqa: E402
 from spark_rapids_ml_tpu.models.regression import RandomForestRegressor  # noqa: E402
-from spark_rapids_ml_tpu.ops import trees  # noqa: E402
+from spark_rapids_ml_tpu.ops import distance, histogram, trees  # noqa: E402
 
 LIMITS = checks.limits("rfc-p3k.refit.tiny")
 ROWS, D = 4096, 64
@@ -75,10 +75,14 @@ def fit_and_compare(cfg, data, seed, **overrides):
     return model, rfc.compare_fit(cfg, rfc.outputs(model), ref, data, blocks), (ref, blocks)
 
 
-@pytest.mark.parametrize("workers,trees", [(1, 3), (8, 16)], ids=["one_device", "eight_devices"])
-def test_estimator_against_the_reference(telemetry_on, workers, trees):
+@pytest.mark.parametrize("workers,trees,mode", [(1, 3, "jnp"), (8, 16, "jnp"), (1, 3, "interpret"), (8, 16, "interpret")],
+                         ids=["one_device", "eight_devices", "one_device_kernel", "eight_devices_kernel"])
+def test_estimator_against_the_reference(telemetry_on, monkeypatch, workers, trees, mode):
     """Every node of two trees re-derived: the counts exact, the split the
-    best of its node's subset, the thresholds the reference's edges."""
+    best of its node's subset, the thresholds the reference's edges. In
+    `interpret` mode the deepest level's accumulate is `ops.histogram`'s
+    kernel, on the eight-device mesh a device's own under `shard_map`."""
+    monkeypatch.setattr(distance, "_MODE", mode)
     data = Data(11)
     cfg = config(workers, numTrees=trees)
     for seed in (5, 2**31 + 9):
@@ -86,6 +90,8 @@ def test_estimator_against_the_reference(telemetry_on, workers, trees):
         assert within(read), read
         assert read["counts_gap"] == 0 and read["threshold_gap"] == 0 and read["shape_gap"] == 0
         assert model.feature.shape == (trees, 2**7 - 1)
+        grow = rfc._span(model, "fit/solve/grow")
+        assert (grow["sorted_levels"], grow["kernel_levels"]) == (1, int(mode == "interpret"))
 
 
 @pytest.mark.parametrize("node_chunk,passes", [(8, 4 + 2 + 4), (3, 1 + 1 + 2 + 3 + 6 + 11)])
@@ -124,21 +130,28 @@ def test_scatter_and_onehot_accumulate_agree(telemetry_on, monkeypatch):
     np.testing.assert_array_equal(scatter.feature, onehot.feature)
 
 
-@pytest.mark.parametrize("window,tile,sorted_levels", [(16, 1024, 1), (2, 1000, 4), (5, 256, 3)])
-def test_rows_sorted_by_node_give_the_same_forest(telemetry_on, monkeypatch, window, tile, sorted_levels):
+@pytest.mark.parametrize("window,tile,sorted_levels,mode", [
+    (16, 1024, 1, "jnp"), (2, 1000, 4, "jnp"), (5, 256, 3, "jnp"),
+    (16, 1024, 1, "interpret"), (2, 512, 4, "interpret"), (5, 256, 3, "interpret"),
+])
+def test_rows_sorted_by_node_give_the_same_forest(telemetry_on, monkeypatch, window, tile, sorted_levels, mode):
     """Beyond `WINDOW_NODES` nodes a pass the one-hot accumulate visits the
     rows sorted by node, a window of nodes at a time (a tile that spans
     several windows, a last tile clamped back, a window past the level's
-    end): the forest is the forest of the rows in place, bit for bit, and
-    the reference re-derives every node of it."""
+    end), in `interpret` mode as `ops.histogram`'s kernel (tiles of `tile`
+    rows in chunks of an eighth): the forest is the forest of the rows in
+    place, bit for bit, and the reference re-derives every node of it."""
     data = Data(19)
     cfg = config()
+    monkeypatch.setattr(distance, "_MODE", mode)
     monkeypatch.setattr(trees, "WINDOW_NODES", 1 << 20)
     trees._forest_programs.cache_clear()
     in_place = rfc.estimator(cfg, 4).fit(data.frame)
     assert rfc._span(in_place, "fit/solve/grow")["sorted_levels"] == 0
     monkeypatch.setattr(trees, "WINDOW_NODES", window)
     monkeypatch.setattr(trees, "SORTED_TILE_ROWS", tile)
+    monkeypatch.setattr(histogram, "TILE_ROWS", tile)
+    monkeypatch.setattr(histogram, "CHUNK_ROWS", tile // 8)
     trees._forest_programs.cache_clear()
     try:
         model, read, _ = fit_and_compare(cfg, data, 4)
@@ -146,6 +159,7 @@ def test_rows_sorted_by_node_give_the_same_forest(telemetry_on, monkeypatch, win
         trees._forest_programs.cache_clear()
     grow = rfc._span(model, "fit/solve/grow")
     assert grow["sorted_levels"] == sorted_levels and grow["accumulate"] == "onehot" and grow["passes_per_tree"] == 6
+    assert grow["kernel_levels"] == (sorted_levels if mode == "interpret" else 0)
     assert within(read) and read["counts_gap"] == 0, read
     np.testing.assert_array_equal(model.feature, in_place.feature)
     np.testing.assert_array_equal(model.threshold, in_place.threshold)
@@ -335,7 +349,14 @@ def test_the_draws_are_the_family_s(telemetry_on):
     assert counts.sum() == 1000 and not counts[::3].any()
 
 
-def test_spans_counters_and_one_fetch(telemetry_on, monkeypatch):
+@pytest.mark.parametrize("mode,window,kernel_levels", [("jnp", 16, 0), ("jnp", 4, 0), ("interpret", 4, 2)])
+def test_spans_counters_and_one_fetch(telemetry_on, monkeypatch, mode, window, kernel_levels):
+    """`forest.kernel_passes` counts the passes `ops.histogram`'s kernel ran:
+    none in `jnp` mode whatever the levels that go sorted, trees x sorted
+    levels where a kernel mode is on."""
+    monkeypatch.setattr(distance, "_MODE", mode)
+    monkeypatch.setattr(trees, "WINDOW_NODES", window)
+    trees._forest_programs.cache_clear()
     data = Data(18)
     fetches = []
     real_get = jax.device_get
@@ -350,6 +371,9 @@ def test_spans_counters_and_one_fetch(telemetry_on, monkeypatch):
         {"trees": 3, "depth": 5, "bins": 16, "features_per_node": 8, "passes_per_tree": 5, "level_programs": 15, "accumulate": "onehot"}
     counters = model._fit_metrics["counters"]
     assert (counters["forest.bin_passes"], counters["forest.trees"], counters["forest.levels"], counters["forest.row_passes"]) == (1, 3, 15, 15)
+    assert (grow["sorted_levels"], grow["kernel_levels"]) == (2 * (window == 4), kernel_levels)
+    assert counters.get("forest.kernel_passes", 0) == 3 * kernel_levels
+    trees._forest_programs.cache_clear()
     parts = sum(spans[p]["wall_s"] for p in ("fit/solve/bin", "fit/solve/grow", "fit/solve/finish"))
     assert 0.8 * spans["fit/solve"]["wall_s"] < parts <= spans["fit/solve"]["wall_s"]
 
