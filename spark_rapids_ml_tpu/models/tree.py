@@ -186,18 +186,32 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
         # statistics and gains (four arrays of its size); a row's node id,
         # flag, statistics and bootstrapped statistics. A classifier is
         # priced at two classes: the labels are not read before admission.
-        from ..ops.trees import BIN_TILE_CELLS, binned_cols, level_plan, plan_summary
+        # A regressor's float32 statistics in pieces (`onehot_split`) add the
+        # deepest pass's picked bin ids (bfloat16, feature-major), the rows'
+        # statistic pieces, and the kernel's blocks of piece sums twice: as
+        # the kernel writes them and rearranged to [piece, node, feature, bin].
+        from ..ops import histogram
+        from ..ops.trees import BIN_TILE_CELLS, STAT_PIECES, binned_cols, level_plan, plan_summary
 
         bins, S = int(params["n_bins"]), (2 if self._is_classification else 3)
         m = resolve_max_features(params["max_features"], n_cols, self._is_classification)
-        plan = level_plan(int(params["max_depth"]), m, bins, S, int(params["node_chunk"]))
+        integer = self._is_classification and not self.isSet("weightCol")  # as `_fit` decides it from the rows
+        plan = level_plan(int(params["max_depth"]), m, bins, S, int(params["node_chunk"]), integer_stats=integer,
+                          split_stats=itemsize == 4)
+        summary = plan_summary(plan)
         tile_rows = min(rows_per_device, max(1024, BIN_TILE_CELLS // max(n_cols, 1)))
-        return {
+        terms = {
             "binned_X": rows_per_device * binned_cols(n_cols) * (1 if bins <= 256 else 4),
             "bin_tile": 5 * tile_rows * n_cols * 4,
-            "histogram": 4 * S * plan_summary(plan)["deepest_chunk"] * m * bins * 4,
+            "histogram": 4 * S * summary["deepest_chunk"] * m * bins * 4,
             "row_state": rows_per_device * (4 + 1 + 2 * S * itemsize),
         }
+        if summary["split_passes"]:
+            P, m_pad = STAT_PIECES * S, histogram.split_features(m)
+            groups = -(-summary["deepest_chunk"] // histogram._group_nodes(P))
+            terms["split_accumulate"] = (rows_per_device * (2 * m_pad + 2 * P * 4)
+                                         + 2 * groups * m_pad * histogram._bin_lanes(bins) * 128 * 4)
+        return terms
 
     def _placement_bins(self, inputs: FitInputs, extracted: ExtractedData, max_bins: int) -> Dict[str, Any]:
         """What a forest fit needs of the placement and nothing of the fit's
@@ -308,13 +322,17 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                     passes_per_tree=summary["passes_per_tree"], accumulate=summary["accumulate"],
                     level_programs=(grown // inputs.mesh.devices.size) * len(plan), trees_grown=grown,
                     sorted_levels=summary["sorted_levels"], kernel_levels=summary["kernel_levels"],
+                    stat_pieces=summary["stat_pieces"],
                 )
                 reg = telemetry.registry()
                 reg.inc("forest.trees", grown)
                 reg.inc("forest.levels", grown * len(plan))
                 reg.inc("forest.row_passes", grown * summary["passes_per_tree"])
-                # the passes `ops.histogram`'s kernel ran (0 where XLA's form did: a CPU, weights, over 256 bins)
+                # the passes `ops.histogram`'s kernels ran (0 where XLA's forms did: a CPU, over 256 bins)
                 reg.inc("forest.kernel_passes", grown * summary["kernel_levels"])
+                # float32 statistics in exact bfloat16 pieces, and the passes left to the scatter (float64, > 256 bins)
+                reg.inc("forest.split_stat_passes", grown * summary["split_passes"])
+                reg.inc("forest.scatter_passes", grown * summary["scatter_passes"])
             with telemetry.span("finish"):  # ONE fetch: the forest's three arrays
                 with telemetry.device_wait("finish"):
                     out = jax.device_get(state)

@@ -289,6 +289,161 @@ def sorted_histogram(Xb, key_s, order, st_s, n_counted, fids, *, bins: int, inte
     return hist[:, :chunk].astype(st_s.dtype)
 
 
+# ---------------------------------------------------------------------------
+# Float32 statistics in pieces over many features a node: `srml_hist_accumulate_split_bf16`
+# ---------------------------------------------------------------------------
+#
+# A regressor's (w, wy, wy²) are not small integers and its node takes 1,000
+# of 3,000 features: the statistics go in as their three exact bfloat16 pieces
+# each (`ops.trees.stat_pieces`), and a node's bin ids at its features are
+# picked before the kernel (`ops.trees._picked_sorted`: XLA's whole-row fetch
+# and one selection contraction a node a tile) into a feature-major [m_pad,
+# rows] operand that the kernel reads in blocks where it lies. The grid goes
+# over pieces of `SPLIT_FEATURES` features, then over the sorted rows: a
+# chunk's (feature, bin) one-hot of the piece is built in VMEM, contracted
+# with the chunk's (piece of a statistic, node of the group) one-hot, and the
+# group's float32 sums are carried in VMEM and written once when the sorted
+# rows leave the group, as `sorted_histogram` carries them. Products of a 0/1
+# and a piece are exact and the sums are float32: a float32 sum of each
+# statistic's exact values.
+
+SPLIT_FEATURES = 128  # features a grid step: the carried [128 · 128 bins, 128] float32 block is 8 MiB
+
+
+def split_features(m: int) -> int:
+    """The picked ids' rows: m rounded up to whole grid steps of features."""
+    return -(-m // SPLIT_FEATURES) * SPLIT_FEATURES
+
+
+def takes_split(n_pieces: int, bins: int) -> bool:
+    """Whether `split_histogram` takes a pass of `n_pieces` statistic pieces
+    (three a float32 statistic) over `bins` bins: a bin one-hot of one lane
+    tile and a group of one node at least. Anything else takes
+    `ops.trees._sorted_histogram` with the pieces."""
+    return bins <= _LANES and n_pieces <= _LANES
+
+
+def _split_kernel(
+    lo_ref, hi_ref,  # prefetched: each chunk's first and last node that counts (lo > hi: none)
+    picked_ref,  # [SPLIT_FEATURES, tile] bfloat16: the piece's features' bin ids of the tile's rows
+    key_ref,  # [1, tile] int32: the row's node (the chunk's size for a row that does not count)
+    st_ref,  # [pieces, tile] float32: values exact in bfloat16
+    _zeros_ref,  # HBM: the output's buffer, zeros
+    out_ref,  # HBM [feature steps, groups, SPLIT_FEATURES · bin lanes, 128] float32
+    hot, acc, group, out_sem,
+    *, P: int, bin_lanes: int,
+):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    R = CHUNK_ROWS
+    per_tile = key_ref.shape[1] // R
+    F = picked_ref.shape[0]
+    NG = _group_nodes(P)
+    p, t = pl.program_id(0), pl.program_id(1)
+
+    def flush():
+        copy = pltpu.make_async_copy(acc, out_ref.at[p, group[0]], out_sem.at[0])
+        copy.start()
+        copy.wait()  # blocking-ok: a DMA semaphore inside the kernel, on the device: no peer, no host thread
+
+    @pl.when(t == 0)
+    def _():
+        group[0] = -1
+
+    def accumulate(g, r0):
+        """The chunk's rows at group g's nodes, added to the carried block."""
+        @pl.when(g != group[0])
+        def _():
+            @pl.when(group[0] >= 0)
+            def _():
+                flush()
+
+            acc[...] = jnp.zeros_like(acc)
+            group[0] = g
+
+        rel = key_ref[:, pl.ds(r0, R)] - g * NG  # [1, R]
+        at = jax.lax.broadcasted_iota(jnp.int32, (_LANES, R), 0)
+        lhs = jnp.zeros((_LANES, R), jnp.float32)
+        for s in range(P):
+            lhs = jnp.where((at == rel + s * NG) & (rel >= 0) & (rel < NG), st_ref[s:s + 1, pl.ds(r0, R)], lhs)
+        acc[...] += jax.lax.dot_general(
+            hot[...], lhs.astype(jnp.bfloat16), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32, precision=_ONE_PASS,
+        )  # [F · bins, rows] x [128, rows]ᵀ
+
+    def chunk(u):
+        q = t * per_tile + u
+
+        @pl.when(lo_ref[q] <= hi_ref[q])
+        def _():
+            r0 = pl.multiple_of(u * R, R)
+            ids = picked_ref[:, pl.ds(r0, R)].astype(jnp.float32).astype(jnp.int32)  # [F, R]: features on the sublanes
+            bin_at = jax.lax.broadcasted_iota(jnp.int32, (bin_lanes, R), 0)
+            for j in range(F):
+                hot[j * bin_lanes:(j + 1) * bin_lanes, :] = _hot(bin_at == ids[j:j + 1, :])
+            _loop(lo_ref[q] // NG, hi_ref[q] // NG + 1, lambda g: accumulate(g, r0))
+
+    _loop(0, per_tile, chunk)
+
+    @pl.when((t == pl.num_programs(1) - 1) & (group[0] >= 0))
+    def _():
+        flush()
+
+
+def split_histogram(picked, key_s, pieces, chunk: int, m: int, *, bins: int, interpret: bool = False) -> jax.Array:
+    """[pieces, chunk, m, bins] float32: the sums of each statistic piece of
+    the sorted rows (`ops.trees.order_rows`' keys and order; `pieces` in that
+    order, 0 for a row that does not count) at each node's (feature, bin),
+    from `ops.trees._picked_sorted`'s [m_pad, rows] ids. Call it where
+    `takes_split` says so."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m_pad, n = picked.shape
+    P = pieces.shape[0]
+    T, R, F = TILE_ROWS, CHUNK_ROWS, SPLIT_FEATURES
+    n_pad = -(-n // T) * T
+    n_tiles, n_chunks, steps = n_pad // T, n_pad // R, m_pad // F
+    bin_lanes, NG = _bin_lanes(bins), _group_nodes(P)
+    n_groups = -(-chunk // NG)
+
+    key_p = jnp.pad(key_s, (0, n_pad - n), constant_values=chunk)
+    by_chunk = key_p.reshape(n_chunks, R)  # sorted: a chunk's first and last row bound its nodes
+    chunk_lo, chunk_hi = by_chunk[:, 0], jnp.minimum(by_chunk[:, -1], chunk - 1)
+    out_shape = (steps, n_groups, F * bin_lanes, _LANES)
+    call = pl.pallas_call(
+        partial(_split_kernel, P=P, bin_lanes=bin_lanes),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(steps, n_tiles),
+            in_specs=[
+                pl.BlockSpec((F, T), lambda p, t, *_: (p, t)),
+                pl.BlockSpec((1, T), lambda p, t, *_: (0, t)),
+                pl.BlockSpec((P, T), lambda p, t, *_: (0, t)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((F * bin_lanes, R), jnp.bfloat16),  # the piece's (feature, bin) one-hot, the rows on the lanes
+                pltpu.VMEM((F * bin_lanes, _LANES), jnp.float32),  # a group's sums
+                pltpu.SMEM((1,), jnp.int32),  # the group they belong to
+                pltpu.SemaphoreType.DMA((1,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+        input_output_aliases={5: 0},  # the zeros below are the output's buffer
+        name=distance.kernel_name("hist_accumulate_split", True),
+        **distance._call_params(interpret),
+    )
+    hist = call(
+        chunk_lo, chunk_hi, jnp.pad(picked, ((0, 0), (0, n_pad - n))), key_p[None, :],
+        jnp.pad(pieces.astype(jnp.float32), ((0, 0), (0, n_pad - n))), jnp.zeros(out_shape, jnp.float32),
+    )
+    # [step, group, feature of the step, bin, (piece, node of the group)] -> [piece, node, feature, bin]
+    hist = hist.reshape(steps, n_groups, F, bin_lanes, _LANES)[:, :, :, :bins, :P * NG]
+    hist = hist.reshape(steps, n_groups, F, bins, P, NG).transpose(4, 1, 5, 0, 2, 3)
+    return hist.reshape(P, n_groups * NG, m_pad, bins)[:, :chunk, :m]
+
+
 def _loop(lo, hi, body) -> None:
     """`body(i)` for i in [lo, hi), the index int32 whatever the x64 mode (Mosaic lowers no int64)."""
     jax.lax.fori_loop(jnp.int32(lo), jnp.int32(hi), lambda i, c: (body(i), c)[1], jnp.int32(0))

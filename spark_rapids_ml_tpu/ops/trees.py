@@ -13,7 +13,9 @@
 #    active row at once (`_level_histogram`: a one-hot contraction on the MXU
 #    where the statistics are small integers, over the rows SORTED BY NODE
 #    beyond `WINDOW_NODES` nodes a pass so that a row tile meets a few nodes
-#    and not all of them; a `segment_sum` scatter otherwise), prefix sums over
+#    and not all of them; float32 statistics go in as three bfloat16 pieces
+#    each (`stat_pieces`) over the sorted rows at every level; a `segment_sum`
+#    scatter where neither holds), prefix sums over
 #    bins give every candidate split's left/right stats, and the best
 #    (feature, bin) per node is an argmax — all static shapes, fully jittable;
 #  * a level whose histogram would outgrow `SEGMENT_BUDGET` is processed in
@@ -194,18 +196,15 @@ def _split_gains(hist: jax.Array, impurity: str, min_instances: float):
         weighted_child = (cnt_l * imp_l + cnt_r * imp_r) / jnp.maximum(cnt_p_b, 1e-30)
         gain = imp_p[:, None, None] - weighted_child
     else:  # variance (regression): S = (w, wy, wyy)
-        w_l, wy_l, wyy_l = left[0], left[1], left[2]
-        w_r, wy_r, wyy_r = right[0], right[1], right[2]
-        w_p = total_s[0][:, None, None]
-
-        def var_sum(w_, wy_, wyy_):  # Σw·(y-μ)² = Σwy² − (Σwy)²/Σw
-            return wyy_ - wy_ * wy_ / jnp.maximum(w_, 1e-30)
-
-        ss_p = var_sum(total_s[0], total_s[1], total_s[2])[:, None, None]
-        ss_child = var_sum(w_l, wy_l, wyy_l) + var_sum(w_r, wy_r, wyy_r)
-        gain = (ss_p - ss_child) / jnp.maximum(w_p, 1e-30)
+        w_l, wy_l = left[0], left[1]
+        w_r, wy_r = right[0], right[1]
+        w_p = jnp.maximum(total_s[0][:, None, None], 1e-30)
+        # the variance a split removes, (ss_p − ss_l − ss_r) / w_p with ss = Σwy² − (Σwy)²/Σw, as the
+        # between-sides sum of squares w_l w_r / w_p · (μ_l − μ_r)²: the same number without the float32
+        # difference of two Σwy² of the node's size (a mean far from 0 cancelled most of its digits)
+        mu_gap = wy_l / jnp.maximum(w_l, 1e-30) - wy_r / jnp.maximum(w_r, 1e-30)
+        gain = (w_l * w_r / w_p) * mu_gap * mu_gap / w_p
         cnt_l, cnt_r = w_l, w_r
-        cnt_p_b = w_p
 
     valid = (cnt_l >= min_instances) & (cnt_r >= min_instances)
     # the last bin means "everything left" — never a real split
@@ -293,19 +292,31 @@ MATMUL_GATHER_MAX_NODES = 128
 # 512 or 2,048 rows and windows of 8 or 32 nodes read within a fifth of it
 # (PERF.md, PR 36).
 WINDOW_NODES = 16
+# A window's selection matrix has at most this many columns where a node has more features than
+# `WINDOW_NODES` nodes' worth of them fill (one node a window at the regressor's 1,000)
+WINDOW_COLUMNS = 1024
 SORTED_TILE_ROWS = 1024
 HIST_SCOPE = "srml_hist_accumulate"  # the accumulate's ops carry this scope in a trace's metadata
+# A float32 statistic is the exact sum of this many bfloat16 pieces (`stat_pieces`): 8 + 8 + 8
+# significant bits hold its 24
+STAT_PIECES = 3
 
 
 def level_plan(
     max_depth: int, max_features: int, max_bins: int, n_stats: int,
-    node_chunk: int = 0, integer_stats: bool = False,
+    node_chunk: int = 0, integer_stats: bool = False, split_stats: bool = True,
 ) -> List[Dict[str, Any]]:
     """What each level of a tree runs: its nodes, the node chunk of a pass,
-    the passes over the rows, and the accumulate's form. `node_chunk` > 0 is
-    the caller's cap on a pass's nodes; 0 takes as many as `SEGMENT_BUDGET`
-    holds. The one-hot form needs statistics that bfloat16 holds exactly
-    (`integer_stats`: class counts times bootstrap counts, no row weights)."""
+    the passes over the rows, the accumulate's form and the bfloat16 pieces
+    a statistic goes in as. `node_chunk` > 0 is the caller's cap on a pass's
+    nodes; 0 takes as many as `SEGMENT_BUDGET` holds. The one-hot form takes
+    statistics that bfloat16 holds exactly (`integer_stats`: class counts
+    times bootstrap counts, no row weights) as they are; float32 statistics
+    (`split_stats`: a regressor's (w, wy, wy²), rows with weights) as
+    `STAT_PIECES` pieces each, `onehot_split`, over the rows sorted by node
+    at every level (a node's features need not fit a window of several
+    nodes). The scatter is left for what neither holds: float64 statistics,
+    over 256 bins, a level in node chunks of float statistics."""
     cap = max(1, SEGMENT_BUDGET // max(max_features * max_bins, 1))
     if node_chunk > 0:
         cap = min(cap, int(node_chunk))
@@ -315,11 +326,14 @@ def level_plan(
         chunk = min(nodes, cap)
         # bin ids of a uint8 X are exact in bfloat16: the sorted form picks them by contraction alone
         # (a level of one pass: a pass of a node chunk visits the rows where they lie)
-        by_node = integer_stats and max_bins <= 256 and chunk == nodes and chunk > WINDOW_NODES
+        whole = max_bins <= 256 and chunk == nodes
+        split = not integer_stats and split_stats and whole
+        by_node = split or (integer_stats and whole and chunk > WINDOW_NODES)
         onehot = integer_stats and (by_node or chunk * n_stats <= ONEHOT_MAX_ROWS)
         plan.append({
             "depth": depth, "nodes": nodes, "chunk": chunk, "passes": -(-nodes // chunk),
-            "accumulate": "onehot" if onehot else "scatter", "rows": "sorted" if by_node else "in_place",
+            "accumulate": "onehot_split" if split else "onehot" if onehot else "scatter",
+            "rows": "sorted" if by_node else "in_place", "stat_pieces": STAT_PIECES if split else 1,
         })
     return plan
 
@@ -332,7 +346,34 @@ def plan_summary(plan: List[Dict[str, Any]]) -> Dict[str, Any]:
         "kernel_levels": sum(bool(lv.get("kernel")) for lv in plan),
         "accumulate": forms.pop() if len(forms) == 1 else "mixed",
         "deepest_chunk": max((lv["chunk"] for lv in plan), default=1),
+        "stat_pieces": max((lv["stat_pieces"] for lv in plan), default=1),
+        "split_passes": sum(lv["passes"] for lv in plan if lv["accumulate"] == "onehot_split"),
+        "scatter_passes": sum(lv["passes"] for lv in plan if lv["accumulate"] == "scatter"),
     }
+
+
+def stat_pieces(stats: jax.Array) -> jax.Array:
+    """[S, n] float32 -> [STAT_PIECES · S, n] float32 (piece-major: all
+    statistics' first pieces, then the second, then the third), each value
+    exact in bfloat16 and the pieces of a statistic summing to it exactly:
+    the first keeps the top 8 significant bits (the low 16 bits of the word
+    cleared), the second the top 8 of what is left, the third the rest (at
+    most 8 bits). Cleared bits, not a rounding convert, so that no compiler
+    may keep a piece in float32 (XLA's excess precision)."""
+    def top8(x):
+        return jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(x, jnp.uint32) & jnp.uint32(0xFFFF0000), jnp.float32)
+
+    x = stats.astype(jnp.float32)
+    hi = top8(x)
+    mid = top8(x - hi)
+    return jnp.concatenate([hi, mid, x - hi - mid])
+
+
+def joined_pieces(hist: jax.Array) -> jax.Array:
+    """`stat_pieces`' inverse on sums: [STAT_PIECES · S, ...] -> [S, ...]."""
+    hi, mid, lo = jnp.split(hist, STAT_PIECES)
+    return (hi + mid) + lo
 
 
 def _level_histogram(
@@ -352,7 +393,8 @@ def _level_histogram(
     active rows at node c0 + c whose feature fids[c, j] lies in bin b.
     [S, chunk, m, bins] in the statistics' dtype. With `ordered` it is
     `_sorted_histogram`'s pass, or, where the plan says `kernel`, the Mosaic
-    kernel's (`ops.histogram.sorted_histogram`: the same integers). Else rows
+    kernel's (`ops.histogram.sorted_histogram`: the same integers); the form
+    `onehot_split` is `_split_sorted_histogram`'s. Else rows
     go a tile at a time where they lie;
     each row's m bin ids are picked at ITS node's subset (a contraction with
     the nodes' selection matrix up to `MATMUL_GATHER_MAX_NODES` nodes a pass,
@@ -364,6 +406,8 @@ def _level_histogram(
     from ..parallel.mesh import ROWS_AXIS
 
     if ordered:
+        if form == "onehot_split":
+            return _split_sorted_histogram(Xb, *ordered, fids, bins=bins, kernel=kernel)
         if kernel:
             return histogram.sorted_histogram(Xb, *ordered, fids, bins=bins, interpret=kernel == "interpret")
         return _sorted_histogram(Xb, *ordered, fids, bins=bins)
@@ -442,30 +486,28 @@ def order_rows(stats: jax.Array, node_id: jax.Array, active: jax.Array, c0, node
 
 def _sorted_histogram(Xb, key_s, order, st_s, n_counted, fids, *, bins: int) -> jax.Array:
     """`_level_histogram`'s one-hot pass over the rows SORTED BY NODE
-    (`order_rows`), for a pass of many nodes. A tile of `SORTED_TILE_ROWS`
-    sorted rows is fetched once (a gather of whole rows of the row-major
-    `Xb`) and holds the rows of consecutive nodes lo .. hi: for each window
-    of `WINDOW_NODES` nodes among them, the tile times the window's [columns,
-    window · m] selection matrix picks each row's m bin ids at ITS node's
-    features (bin ids under 256 and 0/1 are exact in bfloat16), and the
-    tile's (statistic, node of the window) one-hot times its (feature, bin)
-    one-hot adds the window's rows of the histogram. At most tiles + nodes /
-    `WINDOW_NODES` windows a pass, each the same small contraction, where the
-    rows in place cost rows x nodes: the per-element gather of 21.2 M bin ids
-    (269 ms a pass on a v5e) and the [rows, nodes · S] one-hot operand (283 ms
-    at 4,096 nodes) are both gone (PERF.md, PR 36). Sums of small integers in
-    float32: the same histogram, bit for bit, whatever the order."""
+    (`order_rows`), for a pass of many nodes. `_picked_sorted` gives each
+    sorted row's m bin ids at ITS node's features (a tile of
+    `SORTED_TILE_ROWS` sorted rows fetched once, one small selection
+    contraction a window of its nodes); then, a tile at a time, for each
+    window of `window_nodes` nodes among the tile's, the tile's (statistic,
+    node of the window) one-hot times its (feature, bin) one-hot adds the
+    window's rows of the histogram. At most tiles + nodes / `WINDOW_NODES`
+    windows a pass, each the same small contraction, where the rows in place
+    cost rows x nodes: the per-element gather of 21.2 M bin ids (269 ms a pass
+    on a v5e) and the [rows, nodes · S] one-hot operand (283 ms at 4,096
+    nodes) are both gone (PERF.md, PR 36). Sums of small integers in float32:
+    the same histogram, bit for bit, whatever the order."""
     from ..parallel.mesh import ROWS_AXIS
 
     n = Xb.shape[0]
     S = st_s.shape[0]
     chunk, m = fids.shape
-    K = WINDOW_NODES
+    K = window_nodes(m)
     T = min(n, SORTED_TILE_ROWS)
     chunk_pad = -(-chunk // K) * K  # windows start at multiples of K: the last one may pass the chunk's end
-    fids_pad = jnp.pad(fids, ((0, chunk_pad - chunk), (0, 0)))
-    columns = jnp.arange(Xb.shape[1], dtype=jnp.int32)
     zero = jnp.int32(0)
+    picked = _picked_sorted(Xb, key_s, order, n_counted, fids, m)  # [m, n]
 
     with jax.named_scope(HIST_SCOPE):
         n_tiles = (n_counted + T - 1) // T  # the rows that count come first
@@ -477,19 +519,15 @@ def _sorted_histogram(Xb, key_s, order, st_s, n_counted, fids, *, bins: int) -> 
             k_t = jax.lax.dynamic_slice(key_s, (r0,), (T,))
             ok = (k_t < chunk) & fresh
             st_t = jnp.where(ok[None, :], jax.lax.dynamic_slice(st_s, (zero, r0), (S, T)), 0).astype(jnp.bfloat16)
-            xb_t = Xb[jax.lax.dynamic_slice(order, (r0,), (T,))].astype(jnp.bfloat16)  # [T, columns]: whole rows
+            xb_sub = jax.lax.dynamic_slice(picked, (zero, r0), (m, T)).astype(jnp.int32).T  # [T, m]
+            rhs = jax.nn.one_hot(xb_sub, bins, dtype=jnp.bfloat16).reshape(T, m * bins)
             lo = jnp.min(jnp.where(ok, k_t, chunk))
             hi = jnp.max(jnp.where(ok, k_t, -1))
 
             def window_body(carry):
                 w0, hist = carry
-                f_w = jax.lax.dynamic_slice(fids_pad, (w0, zero), (K, m)).reshape(-1)
-                select = (columns[:, None] == f_w[None, :]).astype(jnp.bfloat16)  # [columns, K · m]
-                picked = jnp.dot(xb_t, select, preferred_element_type=jnp.float32)  # [T, K · m]
                 own = ((k_t - w0)[:, None] == jnp.arange(K)[None, :]) & ok[:, None]  # [T, K]: the row's node in the window
-                xb_sub = jnp.sum(picked.reshape(T, K, m) * own[:, :, None], axis=1).astype(jnp.int32)  # [T, m]
                 lhs = (st_t[:, :, None] * own.astype(jnp.bfloat16)[None]).transpose(1, 0, 2).reshape(T, S * K)
-                rhs = jax.nn.one_hot(xb_sub, bins, dtype=jnp.bfloat16).reshape(T, m * bins)
                 part = jax.lax.dot_general(lhs, rhs, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
                 at = (zero, w0, zero)
                 seen = jax.lax.dynamic_slice(hist, at, (S, K, m * bins))
@@ -500,6 +538,79 @@ def _sorted_histogram(Xb, key_s, order, st_s, n_counted, fids, *, bins: int) -> 
         hist0 = jax.lax.pcast(jnp.zeros((S, chunk_pad, m * bins), st_s.dtype), ROWS_AXIS, to="varying")
         hist = jax.lax.fori_loop(0, n_tiles, tile_body, hist0)
     return hist[:, :chunk].reshape(S, chunk, m, bins)
+
+
+def window_nodes(m: int) -> int:
+    """Nodes a window of the sorted form: `WINDOW_NODES`, fewer where their
+    features would pass `WINDOW_COLUMNS` (at least one)."""
+    return max(1, min(WINDOW_NODES, WINDOW_COLUMNS // max(m, 1)))
+
+
+def _split_sorted_histogram(Xb, key_s, order, st_s, n_counted, fids, *, bins: int, kernel: str = "") -> jax.Array:
+    """The sorted pass of float32 statistics, `onehot_split`: each statistic
+    goes in as its `stat_pieces`, 0/1 and bfloat16 operands whose products
+    are exact, float32 sums; the pieces' sums are joined after. [S, chunk, m,
+    bins] float32: a float32 sum of each statistic's exact values, in the
+    order the contraction takes them. With `kernel`, the rows' bin ids at
+    their node's features are picked by `_picked_sorted` and accumulated by
+    `ops.histogram.split_histogram`; else by `_sorted_histogram`."""
+    pieces = stat_pieces(st_s)
+    if kernel:
+        chunk, m = fids.shape
+        picked = _picked_sorted(Xb, key_s, order, n_counted, fids, histogram.split_features(m))
+        hist = histogram.split_histogram(picked, key_s, pieces, chunk, m, bins=bins, interpret=kernel == "interpret")
+    else:
+        hist = _sorted_histogram(Xb, key_s, order, pieces, n_counted, fids, bins=bins)
+    return joined_pieces(hist)
+
+
+def _picked_sorted(Xb, key_s, order, n_counted, fids, m_pad: int) -> jax.Array:
+    """Each sorted row's m bin ids at ITS node's features, feature-major:
+    [m_pad, n] bfloat16 (ids under 256 are exact; the features past m and
+    the rows that do not count are 0): the picking of XLA's sorted form
+    (`_sorted_histogram`) and of the float statistics' kernel
+    (`ops.histogram.split_histogram`). A tile of `SORTED_TILE_ROWS` sorted
+    rows is fetched whole once, and each window of its nodes (`window_nodes`,
+    one at 1,000 features) contracts the window's [window · m_pad, columns]
+    selection matrix with it, so that the cost follows the rows. What is
+    left, the (feature, bin) one-hot of 128,000 columns a row, is the
+    accumulate's: in the kernel it never leaves VMEM."""
+    from ..parallel.mesh import ROWS_AXIS
+
+    n, cols = Xb.shape
+    chunk, m = fids.shape
+    K = window_nodes(m_pad)
+    T = min(n, SORTED_TILE_ROWS)
+    chunk_pad = -(-chunk // K) * K
+    fids_pad = jnp.pad(fids, ((0, chunk_pad - chunk), (0, m_pad - m)), constant_values=-1)
+    columns = jnp.arange(cols, dtype=jnp.int32)
+    zero = jnp.int32(0)
+
+    with jax.named_scope(HIST_SCOPE):
+        n_tiles = (n_counted + T - 1) // T  # the rows that count come first
+
+        def tile_body(ti, picked):
+            r0 = jnp.minimum(ti * T, n - T)  # the last tile clamped back: its first rows are picked again, alike
+            k_t = jax.lax.dynamic_slice(key_s, (r0,), (T,))
+            ok = k_t < chunk
+            xb_t = Xb[jax.lax.dynamic_slice(order, (r0,), (T,))].astype(jnp.bfloat16)  # [T, columns]: whole rows
+            lo = jnp.min(jnp.where(ok, k_t, chunk))
+            hi = jnp.max(jnp.where(ok, k_t, -1))
+
+            def window_body(carry):
+                w0, ids = carry
+                f_w = jax.lax.dynamic_slice(fids_pad, (w0, zero), (K, m_pad)).reshape(-1)
+                select = (f_w[:, None] == columns[None, :]).astype(jnp.bfloat16)  # [K · m_pad, columns]
+                got = jax.lax.dot_general(select, xb_t, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                own = ((k_t - w0)[None, :] == jnp.arange(K)[:, None]) & ok[None, :]  # [K, T]
+                return w0 + K, ids + jnp.sum(got.reshape(K, m_pad, T) * own[:, None, :], axis=0)
+
+            ids0 = jax.lax.pcast(jnp.zeros((m_pad, T), jnp.float32), ROWS_AXIS, to="varying")
+            ids = jax.lax.while_loop(lambda c: c[0] <= hi, window_body, ((lo // K) * K, ids0))[1]
+            return jax.lax.dynamic_update_slice(picked, ids.astype(jnp.bfloat16), (zero, r0))
+
+        picked0 = jax.lax.pcast(jnp.zeros((m_pad, n), jnp.bfloat16), ROWS_AXIS, to="varying")
+        return jax.lax.fori_loop(0, n_tiles, tile_body, picked0)
 
 
 # ---------------------------------------------------------------------------
@@ -617,11 +728,15 @@ def _forest_programs(
     n_dev = mesh.devices.size
     S = n_stats
     M = 2 ** (max_depth + 1) - 1
-    plan = level_plan(max_depth, max_features, max_bins, S, node_chunk, integer_stats)
-    if kernel_mode != "jnp" and histogram.takes(
-        n_rows // n_dev, binned_cols(n_features), S, min(max_features, n_features), max_bins,
-    ):
-        plan = [dict(lv, kernel=kernel_mode) if lv["rows"] == "sorted" else lv for lv in plan]
+    # float32 statistics that are not small integers go in as bfloat16 pieces; float64 ones keep the scatter
+    plan = level_plan(max_depth, max_features, max_bins, S, node_chunk, integer_stats, split_stats=dtype == "float32")
+    m = min(max_features, n_features)
+    if kernel_mode != "jnp":
+        takes = {
+            "onehot": histogram.takes(n_rows // n_dev, binned_cols(n_features), S, m, max_bins),
+            "onehot_split": histogram.takes_split(STAT_PIECES * S, max_bins),
+        }
+        plan = [dict(lv, kernel=kernel_mode) if lv["rows"] == "sorted" and takes[lv["accumulate"]] else lv for lv in plan]
     params = {
         "n_features": n_features, "max_depth": max_depth, "max_bins": max_bins, "max_features": max_features,
         "impurity": impurity, "min_instances": min_instances, "min_info_gain": min_info_gain,
@@ -770,7 +885,8 @@ def forest_fit(
     in round-major order), not waited for, and `plan`: what `level_plan` gave
     each level. `integer_stats` promises statistics that bfloat16 holds
     exactly (class counts, no row weights), which admits the one-hot
-    accumulate."""
+    accumulate as they are; float32 statistics otherwise take it in pieces
+    (`onehot_split`)."""
     n_dev = mesh.devices.size
     trees_per_dev = -(-n_trees // n_dev)  # reference _estimators_per_worker
     progs = _forest_programs(

@@ -111,8 +111,9 @@ def test_node_chunks_engage_and_change_nothing(telemetry_on, node_chunk, passes)
 
 
 def test_scatter_and_onehot_accumulate_agree(telemetry_on, monkeypatch):
-    """The two forms of the accumulate give the same forest (integer counts
-    are exact in both); rows with weights of their own take the scatter."""
+    """The forms of the accumulate give the same forest (integer counts are
+    exact in each); rows with weights of their own take the float32
+    statistics' exact pieces (`onehot_split`), float64 statistics the scatter."""
     data = Data(13)
     cfg = config()
     onehot = rfc.estimator(cfg, 3).fit(data.frame)
@@ -125,7 +126,12 @@ def test_scatter_and_onehot_accumulate_agree(telemetry_on, monkeypatch):
     np.testing.assert_array_equal(mixed.node_stats, onehot.node_stats)
     trees._forest_programs.cache_clear()
     weighted = data.frame.assign(w=1.0)
-    scatter = rfc.estimator(cfg, 3, {"weightCol": "w"}).fit(weighted)
+    split = rfc.estimator(cfg, 3, {"weightCol": "w"}).fit(weighted)
+    assert rfc._span(split, "fit/solve/grow")["accumulate"] == "onehot_split"
+    np.testing.assert_array_equal(split.feature, onehot.feature)
+    np.testing.assert_array_equal(split.node_stats, onehot.node_stats)
+    trees._forest_programs.cache_clear()
+    scatter = rfc.estimator(cfg, 3, {"weightCol": "w", "float32_inputs": False}).fit(weighted)
     assert rfc._span(scatter, "fit/solve/grow")["accumulate"] == "scatter"
     np.testing.assert_array_equal(scatter.feature, onehot.feature)
 
