@@ -322,7 +322,7 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                     passes_per_tree=summary["passes_per_tree"], accumulate=summary["accumulate"],
                     level_programs=(grown // inputs.mesh.devices.size) * len(plan), trees_grown=grown,
                     sorted_levels=summary["sorted_levels"], kernel_levels=summary["kernel_levels"],
-                    stat_pieces=summary["stat_pieces"],
+                    stat_pieces=summary["stat_pieces"], advance=summary["advance"],
                 )
                 reg = telemetry.registry()
                 reg.inc("forest.trees", grown)
@@ -330,6 +330,8 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                 reg.inc("forest.row_passes", grown * summary["passes_per_tree"])
                 # the passes `ops.histogram`'s kernels ran (0 where XLA's forms did: a CPU, over 256 bins)
                 reg.inc("forest.kernel_passes", grown * summary["kernel_levels"])
+                # the levels whose row advance read the row's bin id by a masked reduce over X, no per-row gather
+                reg.inc("forest.masked_advances", grown * summary["masked_advances"])
                 # float32 statistics in exact bfloat16 pieces, and the passes left to the scatter (float64, > 256 bins)
                 reg.inc("forest.split_stat_passes", grown * summary["split_passes"])
                 reg.inc("forest.scatter_passes", grown * summary["scatter_passes"])

@@ -240,16 +240,22 @@ def tree_key(seed, tree):
     return jax.random.fold_in(jax.random.PRNGKey(seed), tree)
 
 
-def bootstrap_counts(key, valid: jax.Array, n_draws: int) -> jax.Array:
-    """How often each row was drawn: `n_draws` draws with replacement,
-    uniform over the valid (non-padding, unmasked) rows. int32 [n]. Integer
-    arithmetic only, so every backend draws the same rows: a draw r names
-    the r-th valid row; the draws are counted by r, and a valid row reads
-    the count at its own rank among the valid rows."""
+def bootstrap_draws(key, valid: jax.Array, n_draws: int) -> jax.Array:
+    """`n_draws` draws with replacement, uniform over the valid (non-padding,
+    unmasked) rows: int32 [n_draws], a draw r naming the r-th valid row.
+    Integer arithmetic only, so every backend draws the same rows. Its own
+    program: compiled for a v5e ahead of time, these draws and
+    `bootstrap_counts`' scatter take 2 and 9 s apart and 41 s as one."""
     k1, _ = jax.random.split(key)
+    return jax.random.randint(k1, (n_draws,), 0, jnp.maximum(jnp.sum(valid, dtype=jnp.int32), 1), dtype=jnp.int32)
+
+
+def bootstrap_counts(draws: jax.Array, valid: jax.Array) -> jax.Array:
+    """How often each row was drawn (`bootstrap_draws`): int32 [n]. The draws
+    are counted by r, and a valid row reads the count at its own rank among
+    the valid rows."""
     below = jnp.cumsum(valid.astype(jnp.int32))  # a valid row's rank among them, plus one
-    r = jax.random.randint(k1, (n_draws,), 0, jnp.maximum(below[-1], 1), dtype=jnp.int32)
-    per_rank = jnp.zeros(valid.shape, jnp.int32).at[r].add(1)  # histogram-ok: counts the draws a rank got (n scalars), not a (node, feature, bin) histogram
+    per_rank = jnp.zeros(valid.shape, jnp.int32).at[draws].add(1)  # histogram-ok: counts the draws a rank got (n scalars), not a (node, feature, bin) histogram
     return jnp.where(valid, per_rank[jnp.maximum(below - 1, 0)], 0)
 
 
@@ -300,6 +306,11 @@ HIST_SCOPE = "srml_hist_accumulate"  # the accumulate's ops carry this scope in 
 # A float32 statistic is the exact sum of this many bfloat16 pieces (`stat_pieces`): 8 + 8 + 8
 # significant bits hold its 24
 STAT_PIECES = 3
+# The row advance (`advance_rows`) at 393,216 rows x 3,072 uint8 columns on a v5e: the three per-row gathers
+# 10.5-10.7 ms a level (the node's feature 3.4, its split bin 3.4, the row's bin id 4.9-5.1); the masked
+# reduces 2.0 ms at 1 and 32 nodes, 2.6 at 1,024, 4.5 at 4,096 (PERF.md section 6). The reduce over the nodes
+# grows with them: beyond this many the node tables are gathered (8.3 ms a level at 8,192 nodes)
+MASKED_ADVANCE_NODES = 4096
 
 
 def level_plan(
@@ -334,6 +345,8 @@ def level_plan(
             "depth": depth, "nodes": nodes, "chunk": chunk, "passes": -(-nodes // chunk),
             "accumulate": "onehot_split" if split else "onehot" if onehot else "scatter",
             "rows": "sorted" if by_node else "in_place", "stat_pieces": STAT_PIECES if split else 1,
+            # the row advance (`advance_rows`) reads a uint8 X whole; an int32 one (over 256 bins) is four times the bytes
+            "advance": "masked" if max_bins <= 256 else "gather",
         })
     return plan
 
@@ -341,6 +354,8 @@ def level_plan(
 def plan_summary(plan: List[Dict[str, Any]]) -> Dict[str, Any]:
     forms = {lv["accumulate"] for lv in plan}
     return {
+        "masked_advances": sum(lv["advance"] == "masked" for lv in plan),
+        "advance": plan[0]["advance"] if plan else "gather",
         "passes_per_tree": sum(lv["passes"] for lv in plan),
         "sorted_levels": sum(lv["rows"] == "sorted" for lv in plan),
         "kernel_levels": sum(bool(lv.get("kernel")) for lv in plan),
@@ -674,13 +689,39 @@ def _tree_level(
         )
 
     # advance rows: split nodes send rows to children; leaf rows deactivate
-    node_f = feature[node_id]
-    went_split = active & (node_f >= 0)
-    row_bin = jnp.take_along_axis(Xb, jnp.maximum(node_f, 0)[:, None], axis=1)[:, 0]
-    go_left = row_bin.astype(jnp.int32) <= split_bin[node_id]
-    child = 2 * node_id + jnp.where(go_left, 1, 2)
-    node_id = jnp.where(went_split, child, node_id)
+    node_id, went_split = advance_rows(Xb, node_id, active, feature, split_bin, offset, level_size,
+                                       masked=level["advance"] == "masked")
     return node_id, went_split, feature, split_bin, node_stats
+
+
+def advance_rows(Xb, node_id, active, feature, split_bin, offset: int, nodes: int, *, masked: bool):
+    """The row advance after a level of `nodes` nodes from level-order id
+    `offset` on: each row at a split node moves to the child its bin id at
+    the node's feature picks (left where it is at most the split bin); a row
+    at a leaf deactivates. (node_id, went_split). `masked` (uint8 bin ids,
+    `level_plan`'s `advance`) reads the row's bin id by a masked reduce over
+    the columns, one pass over X where it lies, and the node's feature and
+    split bin by one over the level's nodes up to `MASKED_ADVANCE_NODES`;
+    else per-row gathers, 12.7 ns an element on a v5e (PERF.md section 6). Every
+    row of a grown tree is at one of the level's nodes or inactive: there
+    the two agree bit for bit."""
+    if masked and nodes <= MASKED_ADVANCE_NODES:
+        own = (node_id - offset)[:, None] == jnp.arange(nodes, dtype=jnp.int32)[None, :]  # [n, nodes]: none outside the level
+
+        def of_node(table, none):
+            return jnp.max(jnp.where(own, jax.lax.dynamic_slice_in_dim(table, offset, nodes)[None, :], none), axis=1)
+
+        node_f, node_bin = of_node(feature, -1), of_node(split_bin, 0)
+    else:
+        node_f, node_bin = feature[node_id], split_bin[node_id]
+    went_split = active & (node_f >= 0)
+    if masked:
+        columns = jnp.arange(Xb.shape[1], dtype=jnp.int32)
+        row_bin = jnp.max(jnp.where(columns[None, :] == node_f[:, None], Xb, jnp.zeros((), Xb.dtype)), axis=1)
+    else:
+        row_bin = jnp.take_along_axis(Xb, jnp.maximum(node_f, 0)[:, None], axis=1)[:, 0]
+    go_left = row_bin.astype(jnp.int32) <= node_bin
+    return jnp.where(went_split, 2 * node_id + jnp.where(go_left, 1, 2), node_id), went_split
 
 
 def _tree_final_level(stats, node_id, active, node_stats, max_depth: int):
@@ -748,21 +789,27 @@ def _forest_programs(
         rank = jax.lax.axis_index(ROWS_AXIS)
         return tree_key(seed, rank * trees_per_dev + tree_i)
 
-    def boot_fn(stats_l, w_l, seed, tree_i):
-        # per-device bootstrap weighting for THIS round's tree; the result is
-        # stat-major [S, n_l] (the row axis in the lanes)
+    n_draws = int(max(1, round(subsample_rate * (n_rows // n_dev))))
+
+    def draw_fn(w_l, seed, tree_i):
+        # draw UNIFORMLY over valid (non-padding) rows; the user weights
+        # already scale the statistics, so weighting the draw too would apply
+        # them twice (w² effective weighting)
+        return bootstrap_draws(this_tree_key(seed, tree_i), w_l > 0, n_draws)
+
+    draw_step = jax.jit(shard_map(draw_fn, mesh=mesh, in_specs=(rows_spec, P(), P()), out_specs=rows_spec))
+
+    def boot_fn(stats_l, w_l, seed, tree_i, *draws_l):
+        # per-device bootstrap weighting for THIS round's tree (`draw_step`'s
+        # draws where it bootstraps); the result is stat-major [S, n_l] (the
+        # row axis in the lanes)
         n_l = stats_l.shape[0]
-        key = this_tree_key(seed, tree_i)
-        n_draws = int(max(1, round(subsample_rate * n_l)))
         if bootstrap:
-            # draw UNIFORMLY over valid (non-padding) rows; the user weights
-            # already scale stats_l, so weighting the draw too would apply
-            # them twice (w² effective weighting)
-            wb = bootstrap_counts(key, w_l > 0, n_draws).astype(stats_l.dtype)
+            wb = bootstrap_counts(draws_l[0], w_l > 0).astype(stats_l.dtype)
         elif subsample_rate < 1.0:
             # subsample without replacement (Spark bootstrap=False semantics);
             # padding rows drawn here contribute nothing (stats are w-scaled)
-            k1, _ = jax.random.split(key)
+            k1, _ = jax.random.split(this_tree_key(seed, tree_i))
             idx = jax.random.choice(k1, n_l, (n_draws,), replace=False)
             wb = jnp.zeros((n_l,), stats_l.dtype).at[idx].set(1.0)
         else:
@@ -771,7 +818,7 @@ def _forest_programs(
 
     boot_step = jax.jit(shard_map(
         boot_fn, mesh=mesh,
-        in_specs=(P(ROWS_AXIS, None), rows_spec, P(), P()),
+        in_specs=(P(ROWS_AXIS, None), rows_spec, P(), P(), *((rows_spec,) if bootstrap else ())),
         out_specs=stat_major,
     ))
 
@@ -851,7 +898,7 @@ def _forest_programs(
         out_shardings=(rep, rep, rep),
     )
     return {
-        "plan": plan, "boot": boot_step, "order": order_step, "levels": [make_level_step(lv) for lv in plan],
+        "plan": plan, "draw": draw_step if bootstrap else None, "boot": boot_step, "order": order_step, "levels": [make_level_step(lv) for lv in plan],
         "final": final_step, "init": tree_init, "stack": stack,
     }
 
@@ -898,7 +945,8 @@ def forest_fit(
     rounds = []
     for t_i in range(trees_per_dev):
         ti = np.int32(t_i)
-        stw = progs["boot"](stats_row, w, seed32, ti)
+        draws = (progs["draw"](w, seed32, ti),) if progs["draw"] else ()
+        stw = progs["boot"](stats_row, w, seed32, ti, *draws)
         nid, act, feat_b, bin_b, nst_b = progs["init"]()
         for lv, level_step in zip(progs["plan"], progs["levels"]):
             ordered = ()

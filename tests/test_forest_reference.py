@@ -345,13 +345,14 @@ def test_the_draws_are_the_family_s(telemetry_on):
     valid = np.ones(1000, bool)
     for seed, tree in ((3, 0), (2**31 + 5, 6)):
         key = trees.tree_key(np.uint32(seed & 0xFFFFFFFF), tree)
-        np.testing.assert_array_equal(np.asarray(trees.bootstrap_counts(key, jax.numpy.asarray(valid), 1000)),
+        draws = trees.bootstrap_draws(key, jax.numpy.asarray(valid), 1000)
+        np.testing.assert_array_equal(np.asarray(trees.bootstrap_counts(draws, jax.numpy.asarray(valid))),
                                       rfc.bootstrap_counts(seed, tree, 1000))
         fids = trees._feature_subset_ids(jax.random.fold_in(key, 7919 + 3), 8, 200, 14)
         np.testing.assert_array_equal(np.asarray(fids), rfc.node_features(seed, tree, 3, 200, 14))
     # rows without weight are never drawn, and every draw lands on a row
     valid[::3] = False
-    counts = np.asarray(trees.bootstrap_counts(key, jax.numpy.asarray(valid), 1000))
+    counts = np.asarray(trees.bootstrap_counts(trees.bootstrap_draws(key, jax.numpy.asarray(valid), 1000), jax.numpy.asarray(valid)))
     assert counts.sum() == 1000 and not counts[::3].any()
 
 

@@ -285,8 +285,8 @@ def test_the_regressors_programs_compile_for_a_v5e(monkeypatch):
     features a node, nine pieces) compiled for a v5e as a TPU process builds
     them: the accumulate is ONE Mosaic call `srml_hist_accumulate_split_bf16`
     beside the picking's loops, every loop of which carries the scope
-    `srml_hist_accumulate`; the temporaries are the picked ids and the piece
-    sums."""
+    `srml_hist_accumulate`, and the row advance takes no per-row gather;
+    the temporaries are the picked ids and the piece sums."""
     import re
 
     monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
@@ -328,6 +328,7 @@ def test_the_regressors_programs_compile_for_a_v5e(monkeypatch):
     calls = re.findall(r'(%[\w.\-]+) = [^\n]* custom-call\([^\n]*custom_call_target="tpu_custom_call"', text)
     assert loops and all(f"/{trees.HIST_SCOPE}/" in name for name in loops), loops
     assert len(calls) == 1 and calls[0].lstrip("%").startswith(kernel), calls
+    assert not re.search(rf"= \S+\[{n}\]\S* gather\(", text)  # the row advance: masked reduces over X, no gather a row
     # picked ids 768 MiB, the piece sums 256 MiB, the histogram's few arrays: under 1.5 GiB
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2**30
     assert final.memory_analysis().temp_size_in_bytes < 64 * 2**20  # the last level: a segment_sum, no [rows, leaves]
