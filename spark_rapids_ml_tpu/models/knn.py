@@ -131,11 +131,9 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
         rows for ITS OWN queries."""
         import pandas as pd
 
-        from ..parallel import PartitionDescriptor, TpuContext, get_mesh, make_global_rows
-        from ..parallel.context import allgather_ndarray
-        from ..parallel.mesh import default_devices, dtype_scope
-
-        from ..ops.knn import exact_knn
+        from .. import telemetry
+        from ..parallel import TpuContext
+        from ..parallel.mesh import dtype_scope
 
         assert self._item_pdf is not None, "model is not bound to an item dataframe"
         k = int(self._solver_params["n_neighbors"])
@@ -158,142 +156,230 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
                 {"query_id": np.zeros(0, dtype=np.int64), "indices": [], "distances": []}
             )
             return item_out, query_out, knn_df
-        query_ex = self._pre_process_data(query_df, for_fit=False)
-        item_ids = self._ensure_id(self._item_pdf, item_ex)
-        query_ids = self._ensure_id(query_pdf, query_ex)
-
-        active = TpuContext.current()
-        spmd = active is not None and active.is_spmd
-
-        np_dtype = np.float32 if self._float32_inputs else np.float64
-        with dtype_scope(np_dtype):
-            import jax
-
-            items = item_ex.features
-            queries = query_ex.features
-            if hasattr(queries, "todense"):
-                queries = np.asarray(queries.todense())
-            queries = np.asarray(queries, dtype=np_dtype)
-
-            if item_ex.is_sparse and not spmd:
-                # CSR item set: tile-densify with a running top-k (never fully
-                # densified — the reference's sparse kNN capability)
-                from ..ops.knn import exact_knn_sparse
-
-                if k > item_ex.n_rows:
-                    raise ValueError(
-                        f"k={k} exceeds the number of item rows {item_ex.n_rows}"
+        with telemetry.span("kneighbors", model=type(self).__name__, rows=len(query_pdf)):
+            with telemetry.span("extract", rows=len(query_pdf)):
+                query_ex = self._pre_process_data(query_df, for_fit=False)
+                query_ids = self._ensure_id(query_pdf, query_ex)
+                queries = query_ex.features
+                if hasattr(queries, "todense"):
+                    queries = np.asarray(queries.todense())
+                np_dtype = np.float32 if self._float32_inputs else np.float64
+                queries = np.asarray(queries, dtype=np_dtype)
+            active = TpuContext.current()
+            with dtype_scope(np_dtype):
+                if active is not None and active.is_spmd:
+                    dist, indices, item_ids, query_ids = self._search_spmd(
+                        queries, query_ex, query_ids, k, np_dtype, active
                     )
-                d_np, gidx_np = exact_knn_sparse(items, queries, k)
-                dist = np.asarray(d_np, dtype=np.float64)
-                indices = item_ids[np.maximum(np.asarray(gidx_np), 0)]
-            elif item_ex.is_sparse and spmd:
-                # SPMD sparse: each rank runs the exact tile-densify search on
-                # its LOCAL CSR block for ALL queries, then the per-rank exact
-                # top-k sets are merged on the control plane — the union of
-                # exact local results IS the exact global result
-                from ..ops.knn import exact_knn_sparse
-                from ..parallel.context import allgather_concat
-
-                rdv = active.rendezvous
-                counts = [int(c) for c in rdv.allgather(str(item_ex.n_rows))]
-                if k > sum(counts):
-                    raise ValueError(f"k={k} exceeds the number of item rows {sum(counts)}")
-                if item_ex.row_id is None:
-                    item_ids = item_ids + sum(counts[: active.rank])
-                if query_ex.row_id is None:
-                    qcounts = [int(c) for c in rdv.allgather(str(len(query_ids)))]
-                    query_ids = query_ids + sum(qcounts[: active.rank])
-                queries_global, q_offset = allgather_concat(rdv, queries)
-                nq_local = len(query_pdf)
-                d_np, lidx = exact_knn_sparse(items, queries_global, k)
-                local_user_ids = np.where(
-                    np.asarray(lidx) >= 0, item_ids[np.maximum(np.asarray(lidx), 0)], -1
-                )
-                d_all = np.concatenate(
-                    allgather_ndarray(rdv, np.asarray(d_np, dtype=np.float64)), axis=1
-                )
-                i_all = np.concatenate(
-                    allgather_ndarray(rdv, local_user_ids.astype(np.int64)), axis=1
-                )
-                order = np.argsort(d_all, axis=1, kind="stable")[:, :k]
-                dist = np.take_along_axis(d_all, order, axis=1)[q_offset : q_offset + nq_local]
-                indices = np.take_along_axis(i_all, order, axis=1)[q_offset : q_offset + nq_local]
-            else:
-                if hasattr(items, "todense"):
-                    items = np.asarray(items.todense())
-
-                if spmd:
-                    mesh = active.mesh
-                    # agree on the global item layout (ragged local blocks ->
-                    # common padded per-process size), like _build_fit_inputs
-                    desc = PartitionDescriptor.build(
-                        [items.shape[0]], item_ex.n_cols,
-                        rank=active.rank, rendezvous=active.rendezvous,
-                    )
-                    if k > desc.m:
-                        raise ValueError(f"k={k} exceeds the number of item rows {desc.m}")
-                    # default row-number ids are rank-local — offset by the
-                    # lower-rank row counts so they're globally unique (same
-                    # rule as the sparse-SPMD and ANN-SPMD branches)
-                    if item_ex.row_id is None:
-                        item_ids = item_ids + desc.row_offset_of(active.rank)
-                    n_local_dev = jax.local_device_count()
-                    max_rows = max(r for _, r in desc.parts_rank_size)
-                    local_rows_target = -(-max_rows // n_local_dev) * n_local_dev
-                    X, w, _ = make_global_rows(
-                        mesh, items.astype(np_dtype), local_rows_target=local_rows_target
-                    )
-                    # global padded-position -> user item id map (pad with -1)
-                    ids_padded = np.full(local_rows_target, -1, np.int64)
-                    ids_padded[: len(item_ids)] = item_ids
-                    global_item_ids = np.concatenate(
-                        allgather_ndarray(active.rendezvous, ids_padded)
-                    )
-                    # replicate the query blocks; remember this rank's slice
-                    q_blocks = allgather_ndarray(active.rendezvous, queries)
-                    q_offset = sum(len(b) for b in q_blocks[: active.rank])
-                    if query_ex.row_id is None:
-                        query_ids = query_ids + q_offset
-                    nq_local = queries.shape[0]
-                    queries_global = np.concatenate(q_blocks, axis=0)
-                    Q = jax.device_put(queries_global)
                 else:
-                    if k > item_ex.n_rows:
-                        raise ValueError(
-                            f"k={k} exceeds the number of item rows {item_ex.n_rows}"
-                        )
-                    n_dev = min(self.num_workers, len(default_devices()))
-                    mesh = get_mesh(n_dev)
-                    X, w, _ = make_global_rows(mesh, items.astype(np_dtype))
-                    global_item_ids = item_ids
-                    Q = jax.device_put(queries)
-                    q_offset, nq_local = 0, queries.shape[0]
-
-                d_dev, gidx_dev = exact_knn(
-                    X, w > 0, Q, mesh=mesh, k=k,
-                    # 0 -> None: resolves config["distance_tile_rows"]
-                    batch_queries=int(self._solver_params["batch_queries"]) or None,
-                )
-                dist = np.asarray(d_dev, dtype=np.float64)[q_offset : q_offset + nq_local]
-                gidx = np.asarray(gidx_dev)[q_offset : q_offset + nq_local]
-                indices = global_item_ids[gidx]  # global row position -> user item id
-
-        knn_df = pd.DataFrame(
-            {
-                "query_id": query_ids,
-                "indices": list(indices),
-                "distances": list(dist),
-            }
-        )
-        item_out = self._item_pdf.copy(deep=False)
-        id_col = self.getOrDefault("idCol") if self.isDefined("idCol") else alias.row_number
-        if id_col not in item_out.columns:
-            item_out[id_col] = item_ids
-        query_out = query_pdf.copy(deep=False)
-        if id_col not in query_out.columns:
-            query_out[id_col] = query_ids
+                    dist, indices, item_ids = self._search_local(queries, k, np_dtype)
+        with telemetry.span("kneighbors.assemble", rows=len(query_pdf)):
+            knn_df = pd.DataFrame(
+                {
+                    "query_id": query_ids,
+                    "indices": list(indices),
+                    "distances": list(dist),
+                }
+            )
+            id_col = self.getOrDefault("idCol") if self.isDefined("idCol") else alias.row_number
+            item_out = self._item_frame(id_col, item_ids)
+            query_out = query_pdf.copy(deep=False)
+            if id_col not in query_out.columns:
+                query_out[id_col] = query_ids
         return item_out, query_out, knn_df
+
+    def _item_frame(self, id_col: str, item_ids: np.ndarray):
+        """The item frame `kneighbors` returns: the fitted frame with its id
+        column, made once for the held id map (10,000,000 ids are 80 MB to
+        assign) and handed out as a shallow copy (copy-on-write: what a
+        caller does to it stays with the caller)."""
+        held = self.__dict__.get("_item_out")
+        if held is None or held[0] != id_col or held[1] is not item_ids:
+            frame = self._item_pdf.copy(deep=False)
+            if id_col not in frame.columns:
+                frame[id_col] = item_ids
+            held = self._item_out = (id_col, item_ids, frame)
+        return held[2].copy(deep=False)
+
+    def _resident_items(self, n_dev: int, np_dtype) -> Dict[str, Any]:
+        """The item set on the device, placed at the first search and kept
+        while the model lives: X (row-major, the layout the top-k kernel
+        reads where it lies), the validity mask, the item norms and the map
+        from positions to ids. Placed again only for another worker count or
+        dtype. Counted: `knn.item_placements`, `knn.item_reuses`."""
+        import jax
+
+        from .. import telemetry
+        from ..ops.knn import _row_sq
+        from ..parallel import get_mesh, make_global_rows
+        from ..parallel.mesh import X_ROW_MAJOR
+
+        key = (int(n_dev), np.dtype(np_dtype).name)
+        held = self.__dict__.get("_resident")
+        if held is not None and held["key"] == key:
+            telemetry.registry().inc("knn.item_reuses")
+            return held
+        self._resident = None  # the old placement goes before the new one comes
+        item_ex = self._item_extracted
+        with telemetry.span("place", rows=int(item_ex.n_rows), cols=int(item_ex.n_cols), workers=int(n_dev)):
+            items = item_ex.features
+            if hasattr(items, "todense"):
+                items = np.asarray(items.todense())
+            mesh = get_mesh(n_dev)
+            X, w, _ = make_global_rows(mesh, np.asarray(items, dtype=np_dtype), x_layout=X_ROW_MAJOR)
+            held = {
+                "key": key, "mesh": mesh, "X": X, "valid": w > 0, "item_sq": _row_sq(X),
+                "ids": self._ensure_id(self._item_pdf, item_ex),
+            }
+            with telemetry.device_wait("knn_place"):
+                jax.block_until_ready((held["valid"], held["item_sq"]))
+        self._resident = held
+        telemetry.registry().inc("knn.item_placements")
+        return held
+
+    def _search_local(self, queries: np.ndarray, k: int, np_dtype):
+        """Single controller: (euclidean distances, item ids, item id column).
+        Dense items search where they are held (`_resident_items`): one
+        device through the host-looped query tiles, several through the
+        sharded search; CSR items tile-densify with a running top-k (never
+        fully densified — the reference's sparse kNN capability)."""
+        import jax
+
+        from .. import telemetry
+        from ..ops import knn as ops_knn
+        from ..ops.distance import topk_plan
+        from ..parallel.mesh import default_devices
+
+        item_ex = self._item_extracted
+        if k > item_ex.n_rows:
+            raise ValueError(f"k={k} exceeds the number of item rows {item_ex.n_rows}")
+        if item_ex.is_sparse:
+            with telemetry.span("search", queries=len(queries), items=int(item_ex.n_rows), k=k, topk_path="sparse"):
+                d_np, gidx_np = ops_knn.exact_knn_sparse(item_ex.features, queries, k)
+            item_ids = self._ensure_id(self._item_pdf, item_ex)
+            return np.asarray(d_np, dtype=np.float64), item_ids[np.maximum(np.asarray(gidx_np), 0)], item_ids
+        n_dev = min(self.num_workers, len(default_devices()))
+        held = self._resident_items(n_dev, np_dtype)
+        X, valid = held["X"], held["valid"]
+        nq, (n_pad, d) = len(queries), X.shape
+        kk = min(k, n_pad // n_dev)
+        batch = int(self._solver_params["batch_queries"]) or None  # 0: config["distance_tile_rows"]
+        tile, path = ops_knn.search_tile(nq, n_pad // n_dev, d, kk, np_dtype, batch)
+        blocks = topk_plan(tile, n_pad // n_dev, d, kk, np_dtype, False) or (None, None)
+        attrs = dict(queries=nq, items=int(item_ex.n_rows), d=int(d), k=k, block_rows=blocks[0],
+                     block_items=blocks[1], topk_path=path, precision=np.dtype(np_dtype).name)
+        with telemetry.span("search", **attrs):
+            Q = jax.device_put(queries)
+            if n_dev == 1:
+                out = ops_knn.dispatch_1dev(X, valid, Q, k, batch, item_sq=held["item_sq"])
+                programs = len(out)
+            else:
+                out = ops_knn._exact_knn_sharded(X, valid, Q, mesh=held["mesh"], k=k, batch_queries=batch)
+                programs = 1
+            with telemetry.device_wait("knn_search"):
+                jax.block_until_ready(out)
+        if path == "kernel":
+            telemetry.registry().inc("knn.kernel_calls", programs)
+        telemetry.registry().inc("knn.candidates", nq * int(item_ex.n_rows))
+        with telemetry.span("fetch", rows=nq):
+            if n_dev == 1:
+                dist, gidx = ops_knn.fetch_1dev(out, k, np_dtype)
+            else:
+                dist, gidx = np.asarray(out[0]), np.asarray(out[1])  # host-fetch-ok: the search's one result fetch
+            dist = dist.astype(np.float64)
+        return dist, held["ids"][gidx], held["ids"]
+
+    def _search_spmd(self, queries, query_ex, query_ids, k, np_dtype, active):
+        """Multi-process SPMD: (distances, item ids, item id column, query
+        ids) of this rank's queries. Every rank holds LOCAL item and query
+        blocks; items are laid out globally on the mesh, query blocks are
+        rendezvous-replicated, every rank computes the full result and keeps
+        the rows of its own queries."""
+        import jax
+
+        from .. import telemetry
+        from ..ops.knn import exact_knn, exact_knn_sparse
+        from ..parallel import PartitionDescriptor, make_global_rows
+        from ..parallel.context import allgather_concat, allgather_ndarray
+        from ..parallel.mesh import X_ROW_MAJOR
+
+        item_ex = self._item_extracted
+        items = item_ex.features
+        item_ids = self._ensure_id(self._item_pdf, item_ex)
+        rdv = active.rendezvous
+        if item_ex.is_sparse:
+            # each rank runs the exact tile-densify search on its LOCAL CSR
+            # block for ALL queries, then the per-rank exact top-k sets are
+            # merged on the control plane — the union of exact local results
+            # IS the exact global result
+            counts = [int(c) for c in rdv.allgather(str(item_ex.n_rows))]
+            if k > sum(counts):
+                raise ValueError(f"k={k} exceeds the number of item rows {sum(counts)}")
+            if item_ex.row_id is None:
+                item_ids = item_ids + sum(counts[: active.rank])
+            if query_ex.row_id is None:
+                qcounts = [int(c) for c in rdv.allgather(str(len(query_ids)))]
+                query_ids = query_ids + sum(qcounts[: active.rank])
+            queries_global, q_offset = allgather_concat(rdv, queries)
+            nq_local = len(queries)
+            with telemetry.span("search", queries=len(queries_global), items=sum(counts), k=k, topk_path="sparse"):
+                d_np, lidx = exact_knn_sparse(items, queries_global, k)
+            local_user_ids = np.where(
+                np.asarray(lidx) >= 0, item_ids[np.maximum(np.asarray(lidx), 0)], -1
+            )
+            d_all = np.concatenate(allgather_ndarray(rdv, np.asarray(d_np, dtype=np.float64)), axis=1)
+            i_all = np.concatenate(allgather_ndarray(rdv, local_user_ids.astype(np.int64)), axis=1)
+            order = np.argsort(d_all, axis=1, kind="stable")[:, :k]
+            dist = np.take_along_axis(d_all, order, axis=1)[q_offset : q_offset + nq_local]
+            indices = np.take_along_axis(i_all, order, axis=1)[q_offset : q_offset + nq_local]
+            return dist, indices, item_ids, query_ids
+        if hasattr(items, "todense"):
+            items = np.asarray(items.todense())
+        mesh = active.mesh
+        # agree on the global item layout (ragged local blocks -> common
+        # padded per-process size), like _build_fit_inputs
+        desc = PartitionDescriptor.build(
+            [items.shape[0]], item_ex.n_cols, rank=active.rank, rendezvous=rdv,
+        )
+        if k > desc.m:
+            raise ValueError(f"k={k} exceeds the number of item rows {desc.m}")
+        # default row-number ids are rank-local — offset by the lower-rank
+        # row counts so they're globally unique (same rule as the sparse-SPMD
+        # and ANN-SPMD branches)
+        if item_ex.row_id is None:
+            item_ids = item_ids + desc.row_offset_of(active.rank)
+        n_local_dev = jax.local_device_count()
+        max_rows = max(r for _, r in desc.parts_rank_size)
+        local_rows_target = -(-max_rows // n_local_dev) * n_local_dev
+        with telemetry.span("place", rows=int(items.shape[0]), cols=int(item_ex.n_cols), workers=mesh.devices.size):
+            X, w, _ = make_global_rows(
+                mesh, np.asarray(items, dtype=np_dtype), local_rows_target=local_rows_target,
+                x_layout=X_ROW_MAJOR,
+            )
+        telemetry.registry().inc("knn.item_placements")
+        # global padded-position -> user item id map (pad with -1)
+        ids_padded = np.full(local_rows_target, -1, np.int64)
+        ids_padded[: len(item_ids)] = item_ids
+        global_item_ids = np.concatenate(allgather_ndarray(rdv, ids_padded))
+        # replicate the query blocks; remember this rank's slice
+        q_blocks = allgather_ndarray(rdv, queries)
+        q_offset = sum(len(b) for b in q_blocks[: active.rank])
+        if query_ex.row_id is None:
+            query_ids = query_ids + q_offset
+        queries_global = np.concatenate(q_blocks, axis=0)
+        with telemetry.span("search", queries=len(queries_global), items=int(desc.m), k=k, topk_path="sharded"):
+            d_dev, gidx_dev = exact_knn(
+                X, w > 0, jax.device_put(queries_global), mesh=mesh, k=k,
+                # 0 -> None: resolves config["distance_tile_rows"]
+                batch_queries=int(self._solver_params["batch_queries"]) or None,
+            )
+            with telemetry.device_wait("knn_search"):
+                jax.block_until_ready((d_dev, gidx_dev))
+        with telemetry.span("fetch", rows=len(queries)):
+            rows = slice(q_offset, q_offset + len(queries))
+            dist = np.asarray(d_dev, dtype=np.float64)[rows]
+            gidx = np.asarray(gidx_dev)[rows]
+        return dist, global_item_ids[gidx], item_ids, query_ids
 
     def exactNearestNeighborsJoin(self, query_df: Any, distCol: str = "distCol") -> Any:
         """Exploded (item, query, distance) join (reference knn.py:421-468).
@@ -363,10 +449,16 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
 
     def _serve_placement_terms(self) -> Dict[str, int]:
         # the resident state is the ITEM BLOCK (plus its row norms and the
-        # int64 id map), not the tiny param surface
+        # int64 id map), not the tiny param surface; where the top-k kernel
+        # runs compiled, the block is row-major and every row fills whole
+        # 128-lane tiles (`_serve_program`'s construct)
+        from ..ops import distance as dist
+
         itemsize = 4 if self._float32_inputs else 8
         n = int(self._item_extracted.n_rows) if self._item_extracted is not None else 0
         d = self._serve_n_cols()
+        if dist.kernel_mode() == "pallas":
+            d = -(-d // 128) * 128
         return {
             "items": n * d * itemsize,
             "item_sq": n * itemsize,
@@ -374,40 +466,44 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
         }
 
     def _serve_workspace_terms(self, bucket_rows_count, itemsize) -> Dict[str, int]:
-        # the tiled top-k merge's live blocks per dispatched bucket: the
-        # [bucket, k_tile] distance block (VMEM-sized item tiles on the
-        # kernel path; the one-matmul [bucket, n] form on CPU) plus the
-        # [bucket, k] best-list carry x2 (d2 + index) —
-        # the distance core is exactly why no [bucket, n_items] block lands
-        # in HBM on the kernel path
+        # what one dispatched bucket adds in HBM. The fused top-k kernel
+        # (`distance.topk_plan`) keeps its distance blocks in VMEM: the
+        # -2 q copy padded to its query block, the +inf-padded norms row,
+        # the zero-filled ragged item block, its [rows, TOPK_LANES] lists
+        # (distances and int32 ids) and the sorted [bucket, k] answer. The
+        # jnp form holds the one-matmul [bucket, n_items] block and the
+        # [bucket, k] best list x2 (d2 + index)
         from ..ops import distance as dist
 
-        n_items = int(self._item_extracted.n_rows) if self._item_extracted is not None else 0
-        k = int(self._solver_params["n_neighbors"])
+        n_items = max(1, int(self._item_extracted.n_rows) if self._item_extracted is not None else 0)
+        d = self._serve_n_cols()
+        kk = min(int(self._solver_params["n_neighbors"]), n_items)
         b = max(1, int(bucket_rows_count))
-        if dist.kernel_mode() == "jnp":
-            k_tile = max(1, n_items)
-        else:
-            plan = dist.plan_blocks(
-                b, max(1, n_items), self._serve_n_cols(),
-                np.float32 if itemsize == 4 else np.float64,
-            )
-            k_tile = max(plan[1], 128) if plan is not None else max(1, n_items)
+        plan = dist.topk_plan(b, n_items, d, kk, np.float32 if itemsize == 4 else np.float64, False)
+        if plan is None:
+            return {"topk_block": b * n_items * itemsize, "topk_carry": 2 * b * kk * itemsize}
+        br, bk = plan
+        rows = -(-b // br) * br
+        blocks = -(-n_items // bk)
         return {
-            "topk_block": b * min(k_tile, max(1, n_items)) * itemsize,
-            "topk_carry": 2 * b * min(k, max(1, n_items)) * itemsize,
+            "topk_queries": rows * d * itemsize,
+            "topk_norms": blocks * bk * itemsize,
+            "topk_tail": bk * d * itemsize if n_items % bk else 0,
+            "topk_lists": rows * dist.TOPK_LANES * (itemsize + 4),
+            "topk_carry": b * kk * (itemsize + 4),
         }
 
     def _serve_flop_estimate(self, n_rows, n_cols):
-        # roofline numerator: the full [queries, items] squared-distance
-        # sweep (~3*n*m*d); top-k selection epilogue omitted (lower bound)
+        # roofline numerator: the [queries, items] contraction the search
+        # makes (2*n*m*d); the norms and the top-k selection are left out
         n_items = int(self._item_extracted.n_rows) if self._item_extracted is not None else 0
-        return 3.0 * n_rows * max(1, n_items) * n_cols
+        return 2.0 * n_rows * max(1, n_items) * n_cols
 
     def _serve_program(self, serve_dtype=None, *, cap=None):
-        """kNN serving hook: queries route through the PR-10 tiled distance
-        core (`ops/distance.topk_tile`) so no `[batch, n_items]` distance
-        block lands in HBM on the kernel path. Returns per query row
+        """kNN serving hook: queries route through the tiled distance core
+        (`ops/distance.topk_tile`, the fused top-k kernel where it plans
+        one) so no `[batch, n_items]` distance block lands in HBM on the
+        kernel path. Returns per query row
         (euclidean distances [B, k], USER item ids [B, k]) — the same values
         `kneighbors`' knn_df carries. `serve_dtype="bf16"` scores through the
         core's parity-tested fast-bf16 mode (docs/serving.md "bf16 serving")."""
@@ -416,7 +512,7 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
 
         from ..core import PredictProgram
         from ..ops import distance as dist
-        from ..parallel.mesh import default_local_device
+        from ..parallel.mesh import X_ROW_MAJOR, default_local_device, place_on_device
 
         self._serve_check(serve_dtype)  # dtype surface + bound item set
         fast = serve_dtype == "bf16"
@@ -432,7 +528,7 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
 
         def construct():
             dev = default_local_device()
-            it = jax.device_put(items_np, dev)
+            it = place_on_device(items_np, dev, X_ROW_MAJOR)  # the top-k kernel's layout
             return (it, dist.row_sq(it), jax.device_put(ids_np, dev))
 
         @jax.jit

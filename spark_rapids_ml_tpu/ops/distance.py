@@ -41,6 +41,7 @@
 #
 from __future__ import annotations
 
+import math
 import os
 from typing import Optional, Tuple
 
@@ -117,7 +118,9 @@ def block_vmem_bytes(br: int, bk: int, d: int, dtype, fast: bool) -> int:
       * `_pl_accumulate`'s [bk, d] f32 dot result before it lands in the
         output block;
       * the [br, bk] f32 distance / one-hot block, its bf16 or int32 twin,
-        and `_pl_d2_block`'s double-buffered [br, bk] output;
+        and a third [br, bk] block (the unfused top-k's output, gone since
+        the fused kernel has a budget of its own: kept, so that no plan
+        moves);
       * the lane-sparse (n, 1) column blocks (weights, assignments, minima,
         counts): one 128-lane tile per 8 rows, double-buffered.
 
@@ -153,6 +156,71 @@ def plan_blocks(
     if block_vmem_bytes(br, bk, d, dtype, fast) > limit:
         return None
     return min(br, max(1, n_rows)), min(bk, max(1, k_side))
+
+
+# The fused top-k kernel (`_pl_knn_topk`) keeps each query row's best list in
+# one 128-lane row: k up to this takes the kernel, a larger k the jnp form.
+TOPK_LANES = 128
+_TOPK_CHUNK = 8  # rows the fused top-k merges at once: one float32 sublane tile
+_TOPK_BLOCKS = (512, 4096)  # (query rows, items) a grid step, before VMEM shrinks them
+_TOPK_FLAG_UNROLL = 8  # chunks a step of the branch-free flag loop (v5e: 1.61 s a call at 1, 1.22 at 8)
+
+
+def topk_vmem_bytes(br: int, bk: int, d: int, dtype, fast: bool) -> int:
+    """Upper bound on the VMEM one grid step of `_pl_knn_topk` holds at
+    blocks (br query rows, bk items), its own budget beside
+    `block_vmem_bytes`: the [br, d] query block and the [bk, d] item and
+    tail blocks at their stored dtype, double-buffered; what the MXU
+    contraction peels off both operands (bf16 copies on the fast path, the
+    hi/mid/lo splits and residuals, 17 bytes per element, otherwise); the
+    [br, bk] contraction result and the scratch it lands in; the [1, bk]
+    norms row (one 8-sublane tile); and the two [br, TOPK_LANES] best lists
+    (distances and int32 ids), double-buffered. The 8-row merge chunk lives
+    in vector registers."""
+    item = jnp.dtype(dtype).itemsize
+    dp = -(-d // _LANES) * _LANES
+    blocks = 2 * (br + 2 * bk) * dp * item
+    peeled = (br + bk) * dp * (2 if fast else 17)
+    dots = 2 * br * bk * item
+    norms = 2 * 8 * bk * item
+    lists = 2 * br * TOPK_LANES * (item + 4)
+    return blocks + peeled + dots + norms + lists
+
+
+def plan_topk(
+    n_queries: int, n_items: int, d: int, dtype=jnp.float32, fast: bool = False
+) -> Optional[Tuple[int, int]]:
+    """(block_rows, block_items) of the fused top-k kernel: `_TOPK_BLOCKS`
+    shrunk (items first, to 512, then rows, then items again) until
+    `topk_vmem_bytes` fits the declared scoped-VMEM limit, then cut to the
+    call (rows to a multiple of 8, items to one of 128). None where even the
+    floor blocks do not fit."""
+    limit = vmem_limit_bytes()
+    br, bk = _TOPK_BLOCKS
+    while topk_vmem_bytes(br, bk, d, dtype, fast) > limit and (br > 8 or bk > 128):
+        if bk > 512:
+            bk //= 2
+        elif br > 8:
+            br //= 2
+        else:
+            bk //= 2
+    if topk_vmem_bytes(br, bk, d, dtype, fast) > limit:
+        return None
+    up = lambda v, m: -(-max(1, v) // m) * m
+    return min(br, up(n_queries, 8)), min(bk, up(n_items, 128))
+
+
+def topk_plan(
+    n_queries: int, n_items: int, d: int, kk: int, dtype, fast: bool
+) -> Optional[Tuple[int, int]]:
+    """The blocks a top-k dispatch of this shape takes in this process —
+    `plan_topk`'s answer — or None where it takes the jnp form: no kernel
+    mode, more than `TOPK_LANES` neighbours a row, no plan fits, or float64
+    on the compiled path (as `block_plan`). The first call resolves
+    `kernel_mode()`: make it outside a trace."""
+    if not _use_kernel() or kk > TOPK_LANES or (not _interpret() and jnp.dtype(dtype).itemsize > 4):
+        return None
+    return plan_topk(n_queries, n_items, d, dtype, fast)
 
 
 def kernel_name(kernel: str, fast: bool) -> str:
@@ -411,6 +479,19 @@ def _kdot(a: jax.Array, b: jax.Array, fast: bool) -> jax.Array:
     return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
+def _kdot_nt(a: jax.Array, b: jax.Array, fast: bool) -> jax.Array:
+    """`_kdot(a, b.T, fast)` as one contraction over both operands' last
+    axis, so that Mosaic feeds the [bk, d] block to the MXU as it lies
+    instead of turning it first."""
+    dims = (((1,), (1,)), ((), ()))
+    if fast:
+        return jax.lax.dot_general(
+            a.astype(jnp.bfloat16), b.astype(jnp.bfloat16), dims,
+            precision=jax.lax.Precision.DEFAULT, preferred_element_type=jnp.float32,
+        ).astype(a.dtype)
+    return jax.lax.dot_general(a, b, dims, precision=jax.lax.Precision.HIGHEST)
+
+
 def _pl_argmin(
     x: jax.Array,  # [B, d] row tile, or with `tile` the whole [n, d] block it lies in
     c_pad: jax.Array,  # [kp, d] centers, padded to a block_k multiple
@@ -554,42 +635,155 @@ def _pl_accumulate(
     return sums, counts[:, 0]
 
 
-def _pl_d2_block(
-    q: jax.Array,  # [B, d] query/row tile
-    xt: jax.Array,  # [bk_total, d] item tile (fully VMEM-resident per block)
-    xt_sq: jax.Array,  # [bk_total]
+def _pl_knn_topk(
+    qm2: jax.Array,  # [B, d] query rows times -2, B a multiple of block_rows
+    items: Optional[jax.Array],  # [n, d]: its first n_full item blocks are read where they lie
+    tail: Optional[jax.Array],  # [block_items, d]: the items after those blocks, zero-filled
+    xsq: jax.Array,  # [1, n_blocks * block_items]: ||x||^2, +inf where no valid item is
     *,
+    kk: int,
     block_rows: int,
+    block_items: int,
+    n_full: int,
     fast: bool,
     interpret: bool,
-) -> jax.Array:
-    """One [B, k_tile] distance block (WITHOUT the ||q||^2 term): the inner
-    matmul of the top-k merge loop. Grid over row blocks only — the item
-    tile is sized by the caller to fit VMEM whole."""
+) -> Tuple[jax.Array, jax.Array]:
+    """Fused distance + running top-kk: (d2 [B, TOPK_LANES] WITHOUT the
+    ||q||^2 term, item index [B, TOPK_LANES] int32), each row's kk best in
+    lanes [0, kk), UNSORTED; an unfilled slot holds +inf and a negative
+    index. Grid = (query blocks, item blocks), items innermost: each step
+    contracts one [block_rows, d] x [block_items, d] pair into a VMEM
+    scratch and merges it into the per-row lists held in the output blocks.
+    Only the lists ever reach HBM.
+
+    The merge goes 8 rows at a time. A branch-free pass flags, in SMEM, the
+    chunks with a row whose block minimum is under its worst kept distance
+    (unrolled by `_TOPK_FLAG_UNROLL`: each chunk's flag is a vector-to-scalar
+    move, a long serial wait unless several overlap); a second pass takes
+    the flagged chunks' minima out one at a time, lowest index first among
+    equal distances, each replacing its row's worst entry (the highest
+    index among equal worst distances) while it is strictly better. Item
+    blocks come in ascending index order, so a kept entry is never displaced
+    by an equal distance of a later item: the kept set is `lax.top_k`'s.
+
+    The item blocks past `n_full` are `tail` (the ragged end, one block);
+    `xsq` is +inf on padding, invalid and fill rows, so those are never
+    taken. A distance that is not a number counts as +inf."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    B, d = q.shape
-    kt = xt.shape[0]
-    n_rb = B // block_rows
-    dtype = q.dtype
-    vma, (q, xt, xt_sq) = _vary_alike(q, xt, xt_sq)
+    B, d = qm2.shape
+    bk, dtype = block_items, qm2.dtype
+    n_blocks = n_full + (tail is not None)
+    lanes, rows = TOPK_LANES, math.gcd(block_rows, _TOPK_CHUNK)
+    operands = [o for o in (qm2, items if n_full else None, tail, xsq) if o is not None]
+    vma, operands = _vary_alike(*operands)
 
-    def kernel(q_ref, x_ref, xsq_ref, out_ref):
-        out_ref[...] = xsq_ref[...] - 2.0 * _kdot(q_ref[...], x_ref[...].T, fast)
+    in_specs = [pl.BlockSpec((block_rows, d), lambda i, j: (i, 0))]
+    if n_full:  # the tail's step asks for the last whole block again: no fetch
+        in_specs.append(pl.BlockSpec((bk, d), lambda i, j: (jnp.minimum(j, n_full - 1), 0)))
+    if tail is not None:
+        in_specs.append(pl.BlockSpec((bk, d), lambda i, j: (0, 0)))
+    in_specs.append(pl.BlockSpec((1, bk), lambda i, j: (0, j)))
 
-    return pl.pallas_call(
+    def kernel(*refs):
+        q_ref, *x_refs, s_ref, bd_ref, bi_ref, dot_ref, flags_ref = refs
+        j = pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _init():
+            lane = jax.lax.broadcasted_iota(jnp.int32, (block_rows, lanes), 1)
+            bd_ref[...] = jnp.where(lane < kk, jnp.inf, -jnp.inf).astype(dtype)
+            bi_ref[...] = -1 - lane
+
+        def contract(x_ref):
+            dot_ref[...] = _kdot_nt(q_ref[...], x_ref[...], fast)
+
+        if len(x_refs) == 2:
+            pl.when(j < n_full)(lambda: contract(x_refs[0]))
+            pl.when(j == n_full)(lambda: contract(x_refs[1]))
+        else:
+            contract(x_refs[0])
+
+        xsq_b = s_ref[...]
+        base = j * bk
+        inf = jnp.asarray(jnp.inf, dtype)
+        lane_b = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+        lowest = jnp.iinfo(jnp.int32).min
+
+        def more(state):
+            _, _, _, m, worst = state
+            return jnp.max(jnp.where(m < worst, jnp.int32(1), jnp.int32(0))) > 0  # int32 under x64 too
+
+        def take_one(state):
+            dc, bd, bi, m, worst = state
+            arg = jnp.min(jnp.where(dc == m, lane_b, bk), axis=1, keepdims=True)
+            at_worst = bd == worst
+            evict = jnp.max(jnp.where(at_worst, bi, lowest), axis=1, keepdims=True)
+            put = at_worst & (bi == evict) & (m < worst)
+            bd = jnp.where(put, m, bd)
+            bi = jnp.where(put, base + arg, bi)
+            dc = jnp.where(lane_b == arg, inf, dc)
+            return dc, bd, bi, jnp.min(dc, axis=1, keepdims=True), jnp.max(bd, axis=1, keepdims=True)
+
+        def flag(c, carry):
+            # branch-free, so that the chunks' reductions overlap: whether any
+            # row of the chunk has a block minimum under its worst kept
+            # distance, into SMEM for the loop below. A minimum that is not a
+            # number flags its chunk too; the merge leaves such entries out
+            at = pl.ds(pl.multiple_of(c * rows, rows), rows)
+            m = jnp.min(xsq_b + dot_ref[at, :], axis=1, keepdims=True)
+            worst = jnp.max(bd_ref[at, :], axis=1, keepdims=True)
+            flags_ref[c] = jnp.max(jnp.where((m < worst) | (m != m), jnp.int32(1), jnp.int32(0)))
+            return carry
+
+        def merge(c, carry):
+            @pl.when(flags_ref[c] > 0)
+            def _flagged():
+                at = pl.ds(pl.multiple_of(c * rows, rows), rows)
+                dc = xsq_b + dot_ref[at, :]
+                dc = jnp.where(dc < inf, dc, inf)  # not a number: never taken
+                # through an op, as the loop's own values come: under shard_map
+                # a value loaded from an output block is typed as varying, one
+                # computed in the kernel is not, and the loop's carry must agree
+                bd, bi = bd_ref[at, :] * 1, bi_ref[at, :] * 1
+                state = (dc, bd, bi, jnp.min(dc, axis=1, keepdims=True), jnp.max(bd, axis=1, keepdims=True))
+
+                @pl.when(more(state))
+                def _merge():
+                    _, bd2, bi2, _, _ = jax.lax.while_loop(more, take_one, state)
+                    bd_ref[at, :] = bd2
+                    bi_ref[at, :] = bi2
+
+            return carry
+
+        chunks = block_rows // rows
+        unroll = math.gcd(chunks, _TOPK_FLAG_UNROLL)  # Mosaic lowers no partial unroll: by hand
+
+        def flags(g, carry):
+            for u in range(unroll):  # independent chunks, so that their latencies overlap
+                flag(g * unroll + u, carry)
+            return carry
+
+        jax.lax.fori_loop(0, chunks // unroll, flags, 0)
+        jax.lax.fori_loop(0, chunks, merge, 0)
+
+    out = (pl.BlockSpec((block_rows, lanes), lambda i, j: (i, 0)),) * 2
+    bd, bi = pl.pallas_call(
         kernel,
-        grid=(n_rb,),
-        in_specs=[
-            pl.BlockSpec((block_rows, d), lambda r: (r, 0)),
-            pl.BlockSpec((kt, d), lambda r: (0, 0)),
-            pl.BlockSpec((1, kt), lambda r: (0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0, grid=(B // block_rows, n_blocks), in_specs=in_specs,
+            out_specs=list(out),
+            scratch_shapes=[pltpu.VMEM((block_rows, bk), dtype), pltpu.SMEM((block_rows // rows,), jnp.int32)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, lanes), dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, lanes), jnp.int32, vma=vma),
         ],
-        out_specs=pl.BlockSpec((block_rows, kt), lambda r: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, kt), dtype, vma=vma),
-        name=kernel_name("d2_block", fast),
+        name=kernel_name("knn_topk", fast),
         **_call_params(interpret),
-    )(q, xt, xt_sq[None, :])
+    )(*operands)
+    return bd, bi
 
 
 def _pad_rows_multiple(a: jax.Array, mult: int) -> Tuple[jax.Array, int]:
@@ -830,44 +1024,39 @@ def topk_tile(
     """Running top-kk of one query tile against ALL items: (d2 [B, kk]
     WITHOUT the ||q||^2 term, item index [B, kk] int32), ascending by
     distance with `jax.lax.top_k` tie semantics (lower index first — pinned
-    vs a full-matrix top_k by tests/test_distance.py).
+    vs a full-matrix top_k by tests/test_distance.py and
+    tests/test_knn_topk.py).
 
-    The item axis is scanned in `k_tile` blocks with the [B, kk] best list
-    as the loop carry, so the [B, n] distance matrix never materializes; the
-    last block is clamped back and its overlap columns masked +inf (already
-    merged). On the kernel path each block's distances come from the Pallas
-    d2-block kernel; the fallback runs the same merge with a plain matmul —
-    identical selection logic, bit-compatible results."""
+    Where `topk_plan` gives blocks, ONE fused kernel (`_pl_knn_topk`) forms
+    the distances block by block in VMEM and keeps each row's best list
+    there: only [B, kk] reaches HBM. `block_rows` / `k_tile` override the
+    plan's (query rows, items) blocks. The items are read where they lie,
+    so a caller that holds them row-major (`make_global_rows(...,
+    x_layout=X_ROW_MAJOR)`) has them copied by nothing; the ragged last
+    block is one zero-filled copy of at most `k_tile` rows.
+
+    The jnp form scans the item axis in `k_tile` blocks (default: one block,
+    the one-matmul shape right for CPU) with the [B, kk] best list as the
+    loop carry; the last block is clamped back and its overlap columns
+    masked +inf (already merged)."""
     _note("distance.topk_programs")
     n, d = items.shape
     kk = min(kk, n)
     if item_sq is None:
         item_sq = row_sq(items)
-    plan = block_plan(q.shape[0], n, d, q.dtype, fast)
-    use_kernel = plan is not None
-    if k_tile is None:
-        # fallback: one block (today's one-matmul shape, right for CPU);
-        # kernel: VMEM-sized item blocks
-        k_tile = max(plan[1], 128) if use_kernel else n
-    kt = min(k_tile, n)
+    plan = topk_plan(q.shape[0], n, d, kk, q.dtype, fast)
+    if plan is not None:
+        return _fused_topk(q, items, valid, item_sq, kk, block_rows or plan[0],
+                           k_tile or plan[1], fast)
+    kt = min(k_tile or n, n)
     big = jnp.asarray(jnp.inf, items.dtype)
-
-    def block_d2(xt, xt_sq):
-        if use_kernel:
-            br = block_rows or plan[0]
-            qp, nq = _pad_rows_multiple(q, br)
-            out = _pl_d2_block(
-                qp, xt, xt_sq, block_rows=br, fast=fast, interpret=_interpret()
-            )
-            return out[:nq]
-        return xt_sq[None, :] - 2.0 * _mm(q, xt.T, fast)
 
     def masked_block(start):
         s0 = jnp.minimum(start, n - kt)
         xt = jax.lax.dynamic_slice_in_dim(items, s0, kt, 0)
         sq = jax.lax.dynamic_slice_in_dim(item_sq, s0, kt, 0)
         ids = s0 + jnp.arange(kt, dtype=jnp.int32)
-        d2 = block_d2(xt, sq)
+        d2 = sq[None, :] - 2.0 * _mm(q, xt.T, fast)
         keep = ids >= start  # clamp-back overlap: already merged columns
         if valid is not None:
             keep = keep & jax.lax.dynamic_slice_in_dim(valid, s0, kt, 0)
@@ -901,6 +1090,30 @@ def topk_tile(
     return jax.lax.fori_loop(0, n_tiles, body, init)
 
 
+def _fused_topk(q, items, valid, item_sq, kk, block_rows, block_items, fast):
+    """`topk_tile` on the kernel path: the operands `_pl_knn_topk` takes,
+    the call, and its lists cut to kk and sorted by (distance, index) — the
+    order `lax.top_k` gives. An unfilled slot (fewer valid items than kk)
+    reads +inf at index 0."""
+    n = items.shape[0]
+    nq = q.shape[0]
+    n_full, rem = divmod(n, block_items)
+    sq = item_sq if valid is None else jnp.where(valid, item_sq, jnp.inf)
+    xsq = jnp.pad(sq.astype(q.dtype), (0, (n_full + bool(rem)) * block_items - n),
+                  constant_values=jnp.inf)[None, :]
+    tail = None
+    if rem:
+        tail = jnp.pad(items[n_full * block_items:], ((0, block_items - rem), (0, 0)))
+    # -2 q, exact in every precision: the kernel adds ||x||^2 to its contraction
+    qm2, _ = _pad_rows_multiple(q * -2.0, block_rows)
+    bd, bi = _pl_knn_topk(
+        qm2, items, tail, xsq, kk=kk, block_rows=block_rows, block_items=block_items,
+        n_full=n_full, fast=fast, interpret=_interpret(),
+    )
+    d2, idx = jax.lax.sort((bd[:nq, :kk], bi[:nq, :kk]), dimension=1, num_keys=2)
+    return d2, jnp.maximum(idx, 0)
+
+
 def tile_topk(
     items: jax.Array,  # [n_loc, d]
     queries: jax.Array,  # [nq, d]
@@ -909,16 +1122,14 @@ def tile_topk(
     batch_queries: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Exact top-k of every query against one device's items: (dist [nq, k]
-    SQUARED incl. the ||q||^2 term, idx [nq, k] local), scanning query tiles
-    of `batch_queries` rows (default `config["distance_tile_rows"]`).
-    Padding items get +inf distance; k past the shard's row count is padded
-    with +inf so a global merge never selects it."""
+    SQUARED incl. the ||q||^2 term, idx [nq, k] local). On the kernel path
+    (`topk_plan`) all queries go to one fused kernel, whose grid walks the
+    query blocks itself; the jnp form scans query tiles of `batch_queries`
+    rows (default `config["distance_tile_rows"]`). Padding items get +inf
+    distance; k past the shard's row count is padded with +inf so a global
+    merge never selects it."""
     n_loc, d = items.shape
     nq = queries.shape[0]
-    bq = batch_queries or tile_rows()
-    n_tiles = max(1, -(-nq // bq))
-    pad = n_tiles * bq - nq
-    qp = jnp.pad(queries, ((0, pad), (0, 0)))
     item_sq = row_sq(items)
     kk = min(k, n_loc)
 
@@ -930,6 +1141,12 @@ def tile_topk(
             idx = jnp.pad(idx, ((0, 0), (0, k - kk)))
         return d_out, idx
 
+    if nq and topk_plan(nq, n_loc, d, kk, queries.dtype, False) is not None:
+        return one_tile(queries)
+    bq = batch_queries or tile_rows()
+    n_tiles = max(1, -(-nq // bq))
+    pad = n_tiles * bq - nq
+    qp = jnp.pad(queries, ((0, pad), (0, 0)))
     qt = qp.reshape(n_tiles, bq, d)
     dists, idxs = jax.lax.map(one_tile, qt)
     return dists.reshape(-1, k)[:nq], idxs.reshape(-1, k)[:nq]

@@ -39,9 +39,10 @@ from .distance import (
 # row-tiled nearest-centroid assignment (shared core), compiled once per shape
 _assign_rows = jax.jit(argmin_assign)
 
-# the per-device query-tile scan is the SHARED core's (ops/distance.py):
-# query tiles of config["distance_tile_rows"] rows, item axis k-tiled so the
-# [tile, n_loc] distance block never materializes on the kernel path
+# the per-device search is the SHARED core's (ops/distance.py): on the
+# kernel path one fused distance + top-k kernel over all queries, so no
+# [queries, n_loc] distance block reaches HBM; the jnp form scans query tiles
+# of config["distance_tile_rows"] rows
 _tile_topk = tile_topk
 
 
@@ -58,44 +59,68 @@ def _topk_tile_1dev(items, valid, item_sq, q, *, kk):
     return d2 + row_sq(q)[:, None], idx
 
 
-def _exact_knn_1dev(items, valid, queries, k, batch_queries):
-    """Single-device exact kNN with a HOST loop over query tiles: each tile is
-    one top-level program over the shared core (distance.topk_tile). The
-    shard_map/in-program tiling form costs a full copy of the item matrix at
-    benchmark scale (measured +11 GiB at 1M x 3k -> OOM), same XLA behavior
-    as the KMeans tile loop."""
+# On the kernel path one program takes up to this many `distance_tile_rows`
+# of queries: the fused kernel walks the query blocks itself and holds no
+# [tile, items] block, so the items are read once a query block, not once a
+# tile per query block.
+_KERNEL_TILES = 8
+
+
+def search_tile(nq: int, n_items: int, d: int, kk: int, dtype, batch_queries=None):
+    """(query rows a program, top-k path) of the one-device search:
+    `batch_queries` (default `config["distance_tile_rows"]`) on the jnp
+    path; on the kernel path (`distance.topk_plan`) every query, up to
+    `_KERNEL_TILES` tiles, in one program."""
+    from .distance import tile_rows, topk_plan
+
+    tile = batch_queries or tile_rows()
+    if topk_plan(min(nq, tile), n_items, d, kk, dtype, False) is not None:
+        return max(1, min(nq, _KERNEL_TILES * tile)), "kernel"
+    return max(1, min(nq, tile)), "jnp"
+
+
+def dispatch_1dev(items, valid, queries, k, batch_queries=None, item_sq=None):
+    """Launch the one-device search and return what `fetch_1dev` takes: the
+    device results of each query tile with the rows it adds, not waited
+    for. A HOST loop over query tiles, each one top-level program over the
+    shared core (distance.topk_tile), every tile the same shape (the last
+    clamped back, its overlap dropped at the fetch) so the program compiles
+    once. The shard_map/in-program tiling form costs a full copy of the
+    item matrix at benchmark scale (measured +11 GiB at 1M x 3k -> OOM),
+    same XLA behavior as the KMeans tile loop."""
+    nq = queries.shape[0]
+    kk = min(k, items.shape[0])
+    tile, _ = search_tile(nq, items.shape[0], items.shape[1], kk, queries.dtype, batch_queries)
+    if item_sq is None:
+        item_sq = _row_sq(items)
+    parts = []
+    for start in range(0, nq, tile):
+        s0 = min(start, nq - tile)
+        d2, idx = _topk_tile_1dev(items, valid, item_sq, queries[s0 : s0 + tile], kk=kk)
+        parts.append((d2, idx, start - s0))
+    return parts
+
+
+def fetch_1dev(parts, k, dtype):
+    """Host (euclidean distances [nq, k], item positions [nq, k]) of
+    `dispatch_1dev`'s tiles; k past the items is padded with +inf."""
     import numpy as np
 
-    from .distance import tile_rows
-
-    batch_queries = batch_queries or tile_rows()
-    nq = queries.shape[0]
-    if nq == 0:
-        return (
-            np.zeros((0, k), dtype=np.asarray(queries).dtype),
-            np.zeros((0, k), dtype=np.int32),
-        )
-    kk = min(k, items.shape[0])
-    batch_queries = min(batch_queries, nq)
-    item_sq = _row_sq(items)
-    d_parts, i_parts = [], []
-    for start in range(0, nq, batch_queries):
-        # keep every tile the SAME shape (clamp back + drop the overlap) so the
-        # tile program compiles exactly once
-        s0 = min(start, nq - batch_queries)
-        q = queries[s0 : s0 + batch_queries]
-        d2, idx = _topk_tile_1dev(items, valid, item_sq, q, kk=kk)
-        fresh = start - s0
-        d_parts.append(np.asarray(d2)[fresh:])  # host-fetch-ok: per-TILE result fetch — every caller consumes numpy (comment below), a device round-trip here is pure waste
-        i_parts.append(np.asarray(idx)[fresh:])  # host-fetch-ok: per-TILE result fetch — see above
-    # results stay HOST numpy: every caller fetches to numpy immediately, so a
-    # device round-trip here would be pure waste
-    d2 = np.concatenate(d_parts, axis=0)
-    idx = np.concatenate(i_parts, axis=0)
+    if not parts:
+        return np.zeros((0, k), dtype=dtype), np.zeros((0, k), dtype=np.int32)
+    # results stay HOST numpy: every caller consumes numpy
+    d2 = np.concatenate([np.asarray(d)[fresh:] for d, _, fresh in parts], axis=0)  # host-fetch-ok: the search's one result fetch, per query tile
+    idx = np.concatenate([np.asarray(i)[fresh:] for _, i, fresh in parts], axis=0)  # host-fetch-ok: see above
+    kk = d2.shape[1]
     if kk < k:
         d2 = np.pad(d2, ((0, 0), (0, k - kk)), constant_values=np.inf)
         idx = np.pad(idx, ((0, 0), (0, k - kk)))
     return np.sqrt(np.maximum(d2, 0.0)), idx
+
+
+def _exact_knn_1dev(items, valid, queries, k, batch_queries):
+    """Single-device exact kNN: `dispatch_1dev`, then `fetch_1dev`."""
+    return fetch_1dev(dispatch_1dev(items, valid, queries, k, batch_queries), k, queries.dtype)
 
 
 @partial(jax.jit, static_argnames=())

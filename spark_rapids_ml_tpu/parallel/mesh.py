@@ -637,6 +637,12 @@ def _place_blocks(blocks: Sequence[np.ndarray], devices: Sequence, x_layout: str
     return jax.device_put(list(blocks), list(devices))
 
 
+def place_on_device(x: np.ndarray, device, x_layout: str = X_DEFAULT) -> jax.Array:
+    """One host block on one device, in the layout `x_layout` asks for
+    (`_place_blocks`): a model's resident state outside a mesh."""
+    return _place_blocks([np.ascontiguousarray(x)], [device], x_layout)[0]
+
+
 def shard_row_slices(x: np.ndarray, n_dev: int) -> Tuple[list, int]:
     """Cut a host row block into `n_dev` equal per-shard pieces.
 

@@ -373,6 +373,44 @@ def test_distance_kernels_compile_for_a_v5e_ahead_of_time(monkeypatch):
         )
 
 
+def test_the_knn_search_at_the_deep10m_shape_is_one_kernel_on_items_where_they_lie(monkeypatch):
+    """The program `kneighbors` dispatches for the cell `knn-deep10m.kneighbors`
+    (10,000 queries against 10,000,000 x 96 float32 items held row-major, k =
+    10), compiled for a v5e: one Mosaic call named `srml_knn_topk_f32` whose
+    item operand is the program's parameter itself, no `copy` of the items,
+    and nothing of [queries, items] anywhere in the program."""
+    import re
+
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.ops.knn import _topk_tile_1dev
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    except Exception as e:  # no libtpu on this machine: nothing to compile with
+        pytest.skip(f"no TPU compiler available ahead of time: {type(e).__name__}: {e}")
+    dev = topo.devices[0]
+    monkeypatch.setattr(distance, "_MODE", "pallas")
+    n, d, nq = 10_000_000, 96, 10_000
+    one = SingleDeviceSharding(dev)
+    placed = mesh_mod.row_major_format((n, d), np.float32, dev)
+    assert placed is not None  # at d = 96 the device alone would lie the items column-major
+    s = lambda shape, dtype=jnp.float32, sharding=one: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    with mesh_mod.chip_scope([dev]), jax.enable_x64(False):
+        assert distance.topk_plan(nq, n, d, 10, jnp.float32, False) == (512, 4096)
+        text = _topk_tile_1dev.lower(
+            s((n, d), sharding=placed), s((n,), jnp.bool_), s((n,)), s((nq, d)), kk=10
+        ).compile().as_text()
+    calls = re.findall(r"%(srml_\w+?)(?:\.\d+)? = [^\n]*? custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", text)
+    assert [name for name, _ in calls] == ["srml_knn_topk_f32"]
+    items = calls[0][1].split(", ")[1]
+    assert re.search(r"%s = f32\[%d,%d\]\{1,0[^}]*\} parameter\(" % (re.escape(items), n, d), text), items
+    assert not re.findall(r"= f32\[%d,%d\]\{[^}]*\} copy\(" % (n, d), text)
+    assert "[10000,10000000]" not in text and "[10240,4096]" not in text
+
+
 def test_the_pca_finish_at_d3000_decomposes_no_whole_matrix(monkeypatch):
     """The parent's finish program called `eigh` on the whole 3,000 x 3,000
     covariance to keep three eigenpairs: on a TPU that lowers to a QDWH

@@ -498,9 +498,10 @@ def test_the_benchmark_files_autotune_switch_is_inert(interpret_mode):
 
 @pytest.mark.parametrize("bucket", [128, 1024, 8192])
 def test_knn_serving_budget_and_topk_program_take_one_item_block(interpret_mode, planted_table, monkeypatch, bucket):
-    """`_serve_workspace_terms` budgets the [bucket, k_tile] distance block
-    of the program `_serve_program` runs: the item block `topk_tile` hands the
-    d2-block kernel is the `k_tile` of the budget, table or no table."""
+    """`_serve_workspace_terms` budgets the blocks of the program
+    `_serve_program` runs: the query rows and item block `topk_tile` hands
+    the fused top-k kernel are the ones the budget pads the bucket to and
+    counts the norms row in, table or no table."""
     import pandas as pd
 
     from spark_rapids_ml_tpu.models.knn import NearestNeighbors
@@ -509,18 +510,23 @@ def test_knn_serving_budget_and_topk_program_take_one_item_block(interpret_mode,
     items = np.random.default_rng(5).normal(size=(n_items, d)).astype(np.float32)
     model = NearestNeighbors(k=kk).setInputCol("features").fit(pd.DataFrame({"features": list(items)}))
     terms = model._serve_workspace_terms(bucket, 4)
-    k_tile = terms["topk_block"] // (bucket * 4)
     taken = []
 
-    def d2_block(qp, xt, xt_sq, **kw):
-        taken.append((kw["block_rows"], xt.shape[0]))
-        return jnp.zeros((qp.shape[0], xt.shape[0]), qp.dtype)
+    def knn_topk(qm2, items, tail, xsq, **kw):
+        taken.append((qm2.shape[0], kw["block_rows"], kw["block_items"], xsq.shape[1]))
+        lists = (qm2.shape[0], distance.TOPK_LANES)
+        return jnp.zeros(lists, qm2.dtype), jnp.zeros(lists, jnp.int32)
 
-    monkeypatch.setattr(distance, "_pl_d2_block", d2_block)
+    monkeypatch.setattr(distance, "_pl_knn_topk", knn_topk)
     struct = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
     jax.eval_shape(lambda q, it: distance.topk_tile(q, it, None, kk), struct(bucket, d), struct(n_items, d))
     assert _table_key(bucket, n_items, d, False) in planted_table
-    assert taken and set(taken) == {(min(bucket, 512), k_tile)} and k_tile == 512
+    (rows, br, bk, norms), = set(taken)
+    assert (br, bk) == distance.plan_topk(bucket, n_items, d) and rows % br == 0
+    assert terms["topk_lists"] == rows * distance.TOPK_LANES * 8
+    assert terms["topk_queries"] == rows * d * 4
+    assert terms["topk_norms"] == norms * 4 and norms % bk == 0
+    assert distance.topk_vmem_bytes(br, bk, d, jnp.float32, False) <= distance.vmem_limit_bytes()
 
 
 @pytest.mark.parametrize("site", ["assign_argmin", "assign_accumulate", "topk_tile"])
@@ -532,7 +538,7 @@ def test_float64_rows_take_the_jnp_form_on_the_compiled_path(monkeypatch, site):
     def refuse(*a, **kw):
         raise AssertionError("a kernel was reached")
 
-    for kernel in ("_pl_argmin", "_pl_accumulate", "_pl_d2_block"):
+    for kernel in ("_pl_argmin", "_pl_accumulate", "_pl_knn_topk"):
         monkeypatch.setattr(distance, kernel, refuse)
     X, C, w = _data(40, 6, 8, np.float64)
     call = {
@@ -563,6 +569,7 @@ def test_kernel_name_contract():
     assert distance.kernel_name("argmin", True) == "srml_argmin_bf16"
     assert distance.kernel_name("accumulate", False) == "srml_accumulate_f32"
     assert distance.kernel_name("d2_block", False) == "srml_d2_block_f32"
+    assert distance.kernel_name("knn_topk", False) == "srml_knn_topk_f32"
 
 
 def test_kernels_pass_their_names_to_pallas_call(interpret_mode, monkeypatch):
@@ -592,7 +599,7 @@ def test_kernels_pass_their_names_to_pallas_call(interpret_mode, monkeypatch):
     assert names == ["srml_argmin_f32"]
     del names[:]
     distance.tile_topk(x, x[:7], jnp.ones((70,), jnp.bool_), 3)
-    assert names and set(names) == {"srml_d2_block_f32"}
+    assert names and set(names) == {"srml_knn_topk_f32"}
 
 
 def test_compiled_kernels_carry_their_names_on_one_chip_and_under_shard_map(monkeypatch):
