@@ -247,7 +247,7 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
 
         from .. import telemetry
         from ..ops import knn as ops_knn
-        from ..ops.distance import topk_plan
+        from ..ops.distance import topk_chunk_steps, topk_plan
         from ..parallel.mesh import default_devices
 
         item_ex = self._item_extracted
@@ -283,10 +283,13 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
         telemetry.registry().inc("knn.candidates", nq * int(item_ex.n_rows))
         with telemetry.span("fetch", rows=nq):
             if n_dev == 1:
-                dist, gidx = ops_knn.fetch_1dev(out, k, np_dtype)
+                dist, gidx, merged = ops_knn.fetch_1dev(out, k, np_dtype)
             else:
                 dist, gidx = np.asarray(out[0]), np.asarray(out[1])  # host-fetch-ok: the search's one result fetch
             dist = dist.astype(np.float64)
+        if path == "kernel" and n_dev == 1:  # the sharded search fetches no count
+            telemetry.registry().inc("knn.merged_pairs", merged)
+            telemetry.registry().inc("knn.flag_pairs", programs * topk_chunk_steps(tile, n_pad, *blocks))
         return dist, held["ids"][gidx], held["ids"]
 
     def _search_spmd(self, queries, query_ex, query_ids, k, np_dtype, active):
@@ -470,7 +473,8 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
         # (`distance.topk_plan`) keeps its distance blocks in VMEM: the
         # -2 q copy padded to its query block, the +inf-padded norms row,
         # the zero-filled ragged item block, its [rows, TOPK_LANES] lists
-        # (distances and int32 ids) and the sorted [bucket, k] answer. The
+        # (distances and int32 ids), its [8, 128] int32 count of merged
+        # chunks a query block and the sorted [bucket, k] answer. The
         # jnp form holds the one-matmul [bucket, n_items] block and the
         # [bucket, k] best list x2 (d2 + index)
         from ..ops import distance as dist
@@ -490,6 +494,7 @@ class NearestNeighborsModel(_KNNParams, _TpuModel):
             "topk_norms": blocks * bk * itemsize,
             "topk_tail": bk * d * itemsize if n_items % bk else 0,
             "topk_lists": rows * dist.TOPK_LANES * (itemsize + 4),
+            "topk_merged": rows // br * 8 * dist.TOPK_LANES * 4,
             "topk_carry": b * kk * (itemsize + 4),
         }
 

@@ -163,7 +163,7 @@ def plan_blocks(
 TOPK_LANES = 128
 _TOPK_CHUNK = 8  # rows the fused top-k merges at once: one float32 sublane tile
 _TOPK_BLOCKS = (512, 4096)  # (query rows, items) a grid step, before VMEM shrinks them
-_TOPK_FLAG_UNROLL = 8  # chunks a step of the branch-free flag loop (v5e: 1.61 s a call at 1, 1.22 at 8)
+_TOPK_FLAG_UNROLL = 8  # chunks a step of the branch-free flag loop, whose vector-to-scalar waits overlap
 
 
 def topk_vmem_bytes(br: int, bk: int, d: int, dtype, fast: bool) -> int:
@@ -174,9 +174,11 @@ def topk_vmem_bytes(br: int, bk: int, d: int, dtype, fast: bool) -> int:
     contraction peels off both operands (bf16 copies on the fast path, the
     hi/mid/lo splits and residuals, 17 bytes per element, otherwise); the
     [br, bk] contraction result and the scratch it lands in; the [1, bk]
-    norms row (one 8-sublane tile); and the two [br, TOPK_LANES] best lists
-    (distances and int32 ids), double-buffered. The 8-row merge chunk lives
-    in vector registers."""
+    norms row (one 8-sublane tile); the two [br, TOPK_LANES] best lists
+    (distances and int32 ids), double-buffered; the [br, 1] int32 column
+    of rows that merge (one 128-lane tile wide); and the [8, 128] int32
+    count of merged chunks, double-buffered. The 8-row merge chunk lives in
+    vector registers."""
     item = jnp.dtype(dtype).itemsize
     dp = -(-d // _LANES) * _LANES
     blocks = 2 * (br + 2 * bk) * dp * item
@@ -184,7 +186,9 @@ def topk_vmem_bytes(br: int, bk: int, d: int, dtype, fast: bool) -> int:
     dots = 2 * br * bk * item
     norms = 2 * 8 * bk * item
     lists = 2 * br * TOPK_LANES * (item + 4)
-    return blocks + peeled + dots + norms + lists
+    merging = br * _LANES * 4
+    count = 2 * 8 * _LANES * 4
+    return blocks + peeled + dots + norms + lists + merging + count
 
 
 def plan_topk(
@@ -208,6 +212,14 @@ def plan_topk(
         return None
     up = lambda v, m: -(-max(1, v) // m) * m
     return min(br, up(n_queries, 8)), min(bk, up(n_items, 128))
+
+
+def topk_chunk_steps(n_queries: int, n_items: int, block_rows: int, block_items: int) -> int:
+    """The (8-row chunk, item block) pairs of one `_pl_knn_topk` call at
+    these blocks, each of which may merge: every chunk of the padded query
+    rows against every item block, the ragged last one included."""
+    chunks = -(-n_queries // block_rows) * (block_rows // math.gcd(block_rows, _TOPK_CHUNK))
+    return chunks * -(-n_items // block_items)
 
 
 def topk_plan(
@@ -647,28 +659,38 @@ def _pl_knn_topk(
     n_full: int,
     fast: bool,
     interpret: bool,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Fused distance + running top-kk: (d2 [B, TOPK_LANES] WITHOUT the
-    ||q||^2 term, item index [B, TOPK_LANES] int32), each row's kk best in
-    lanes [0, kk), UNSORTED; an unfilled slot holds +inf and a negative
-    index. Grid = (query blocks, item blocks), items innermost: each step
-    contracts one [block_rows, d] x [block_items, d] pair into a VMEM
-    scratch and merges it into the per-row lists held in the output blocks.
-    Only the lists ever reach HBM.
+    ||q||^2 term, item index [B, TOPK_LANES] int32, merged chunks [B /
+    block_rows * 8, 128] int32), each row's kk best in lanes [0, kk),
+    UNSORTED; an unfilled slot holds +inf and a negative index. Grid =
+    (query blocks, item blocks), items innermost: each step contracts one
+    [block_rows, d] x [block_items, d] pair into a VMEM scratch and merges
+    it into the per-row lists held in the output blocks. Only the lists
+    (and the counts) ever reach HBM.
 
-    The merge goes 8 rows at a time. A branch-free pass flags, in SMEM, the
-    chunks with a row whose block minimum is under its worst kept distance
-    (unrolled by `_TOPK_FLAG_UNROLL`: each chunk's flag is a vector-to-scalar
-    move, a long serial wait unless several overlap); a second pass takes
-    the flagged chunks' minima out one at a time, lowest index first among
-    equal distances, each replacing its row's worst entry (the highest
-    index among equal worst distances) while it is strictly better. Item
-    blocks come in ascending index order, so a kept entry is never displaced
-    by an equal distance of a later item: the kept set is `lax.top_k`'s.
+    The selection reads the contraction's value as it lands: for each row,
+    the lowest and highest index of the block's items under its worst kept
+    distance (the step's first), and the least such distance. A row with
+    exactly one such item takes it there, every row at once, in place of
+    its worst entry (the highest index among equal worst distances). Only
+    where a row has two or more (one wait a step says whether any has) do
+    the 8-row chunks that hold one merge: a branch-free pass (unrolled by
+    `_TOPK_FLAG_UNROLL`: each chunk's flag is a vector-to-scalar move, a
+    long serial wait unless several overlap) flags them in SMEM, then each
+    flagged chunk's rows of two or more take the block's items out one at a
+    time, lowest index first among equal distances, each replacing the
+    row's worst entry while it is strictly better. Item blocks come in
+    ascending index order and a row's takes in a block in ascending
+    (distance, index) order, so a kept entry is never displaced by an equal
+    distance of a later item: the kept set is `lax.top_k`'s. Each query
+    block counts its merged chunks, in every element of its [8, 128] count
+    block.
 
     The item blocks past `n_full` are `tail` (the ragged end, one block);
     `xsq` is +inf on padding, invalid and fill rows, so those are never
-    taken. A distance that is not a number counts as +inf."""
+    taken. A distance that is not a number is never under a worst, and
+    counts as +inf."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -687,17 +709,39 @@ def _pl_knn_topk(
     in_specs.append(pl.BlockSpec((1, bk), lambda i, j: (0, j)))
 
     def kernel(*refs):
-        q_ref, *x_refs, s_ref, bd_ref, bi_ref, dot_ref, flags_ref = refs
+        q_ref, *x_refs, s_ref, bd_ref, bi_ref, n_ref, dot_ref, multi_ref, flags_ref, count_ref = refs
         j = pl.program_id(1)
+        base = j * bk
+        inf = jnp.asarray(jnp.inf, dtype)
+        lowest = jnp.iinfo(jnp.int32).min
 
         @pl.when(j == 0)
         def _init():
             lane = jax.lax.broadcasted_iota(jnp.int32, (block_rows, lanes), 1)
             bd_ref[...] = jnp.where(lane < kk, jnp.inf, -jnp.inf).astype(dtype)
             bi_ref[...] = -1 - lane
+            count_ref[0] = jnp.int32(0)
+
+        worst0 = jnp.max(bd_ref[...], axis=1, keepdims=True)  # each row's worst, the step's first
+        lane_k = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
 
         def contract(x_ref):
-            dot_ref[...] = _kdot_nt(q_ref[...], x_ref[...], fast)
+            dots = _kdot_nt(q_ref[...], x_ref[...], fast)
+            dot_ref[...] = dots
+            dc = s_ref[...] + dots
+            under = dc < worst0
+            first = jnp.min(jnp.where(under, lane_k, bk), axis=1, keepdims=True)
+            last = jnp.max(jnp.where(under, lane_k, -1), axis=1, keepdims=True)
+            least = jnp.min(jnp.where(under, dc, inf), axis=1, keepdims=True)
+            # the lists read after the contraction, not held across it (v5e:
+            # 80 ms a call of the Deep1B 10M shape's 1.08 s when held)
+            bd0, bi0 = bd_ref[...], bi_ref[...]
+            at_worst = bd0 == worst0
+            slot = at_worst & (bi0 == jnp.max(jnp.where(at_worst, bi0, lowest), axis=1, keepdims=True))
+            put = slot & (first == last)  # one item under the worst: taken here, the worst entry evicted
+            bd_ref[...] = jnp.where(put, least, bd0)
+            bi_ref[...] = jnp.where(put, base + first, bi0)
+            multi_ref[...] = jnp.where(first < last, jnp.int32(1), jnp.int32(0))
 
         if len(x_refs) == 2:
             pl.when(j < n_full)(lambda: contract(x_refs[0]))
@@ -705,11 +749,7 @@ def _pl_knn_topk(
         else:
             contract(x_refs[0])
 
-        xsq_b = s_ref[...]
-        base = j * bk
-        inf = jnp.asarray(jnp.inf, dtype)
         lane_b = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
-        lowest = jnp.iinfo(jnp.int32).min
 
         def more(state):
             _, _, _, m, worst = state
@@ -727,33 +767,30 @@ def _pl_knn_topk(
             return dc, bd, bi, jnp.min(dc, axis=1, keepdims=True), jnp.max(bd, axis=1, keepdims=True)
 
         def flag(c, carry):
-            # branch-free, so that the chunks' reductions overlap: whether any
-            # row of the chunk has a block minimum under its worst kept
-            # distance, into SMEM for the loop below. A minimum that is not a
-            # number flags its chunk too; the merge leaves such entries out
+            # branch-free, so that the chunks' reductions overlap: whether a
+            # row of the chunk has two or more items under its worst
             at = pl.ds(pl.multiple_of(c * rows, rows), rows)
-            m = jnp.min(xsq_b + dot_ref[at, :], axis=1, keepdims=True)
-            worst = jnp.max(bd_ref[at, :], axis=1, keepdims=True)
-            flags_ref[c] = jnp.max(jnp.where((m < worst) | (m != m), jnp.int32(1), jnp.int32(0)))
+            flags_ref[c] = jnp.max(multi_ref[at, :])
             return carry
 
         def merge(c, carry):
             @pl.when(flags_ref[c] > 0)
             def _flagged():
                 at = pl.ds(pl.multiple_of(c * rows, rows), rows)
-                dc = xsq_b + dot_ref[at, :]
-                dc = jnp.where(dc < inf, dc, inf)  # not a number: never taken
+                dc = s_ref[...] + dot_ref[at, :]
+                # only its rows of two or more: a row of one took it above; a
+                # distance that is not a number is never taken
+                dc = jnp.where((multi_ref[at, :] > 0) & (dc < inf), dc, inf)
                 # through an op, as the loop's own values come: under shard_map
                 # a value loaded from an output block is typed as varying, one
                 # computed in the kernel is not, and the loop's carry must agree
                 bd, bi = bd_ref[at, :] * 1, bi_ref[at, :] * 1
                 state = (dc, bd, bi, jnp.min(dc, axis=1, keepdims=True), jnp.max(bd, axis=1, keepdims=True))
-
-                @pl.when(more(state))
-                def _merge():
-                    _, bd2, bi2, _, _ = jax.lax.while_loop(more, take_one, state)
-                    bd_ref[at, :] = bd2
-                    bi_ref[at, :] = bi2
+                # a flagged row takes: the first take waits on no scalar answer
+                _, bd2, bi2, _, _ = jax.lax.while_loop(more, take_one, take_one(state))
+                bd_ref[at, :] = bd2
+                bi_ref[at, :] = bi2
+                count_ref[0] += 1
 
             return carry
 
@@ -765,25 +802,36 @@ def _pl_knn_topk(
                 flag(g * unroll + u, carry)
             return carry
 
-        jax.lax.fori_loop(0, chunks // unroll, flags, 0)
-        jax.lax.fori_loop(0, chunks, merge, 0)
+        @pl.when(jnp.max(multi_ref[...]) > 0)
+        def _merges():
+            jax.lax.fori_loop(0, chunks // unroll, flags, 0)
+            jax.lax.fori_loop(0, chunks, merge, 0)
 
-    out = (pl.BlockSpec((block_rows, lanes), lambda i, j: (i, 0)),) * 2
-    bd, bi = pl.pallas_call(
+        @pl.when(j == n_blocks - 1)
+        def _count():
+            n_ref[...] = jnp.full((8, _LANES), count_ref[0], jnp.int32)
+
+    out = [pl.BlockSpec((block_rows, lanes), lambda i, j: (i, 0))] * 2
+    out.append(pl.BlockSpec((8, _LANES), lambda i, j: (i, 0)))
+    bd, bi, merged = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0, grid=(B // block_rows, n_blocks), in_specs=in_specs,
-            out_specs=list(out),
-            scratch_shapes=[pltpu.VMEM((block_rows, bk), dtype), pltpu.SMEM((block_rows // rows,), jnp.int32)],
+            out_specs=out,
+            scratch_shapes=[
+                pltpu.VMEM((block_rows, bk), dtype), pltpu.VMEM((block_rows, 1), jnp.int32),
+                pltpu.SMEM((block_rows // rows,), jnp.int32), pltpu.SMEM((1,), jnp.int32),
+            ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B, lanes), dtype, vma=vma),
             jax.ShapeDtypeStruct((B, lanes), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((B // block_rows * 8, _LANES), jnp.int32, vma=vma),
         ],
         name=kernel_name("knn_topk", fast),
         **_call_params(interpret),
     )(*operands)
-    return bd, bi
+    return bd, bi, merged
 
 
 def _pad_rows_multiple(a: jax.Array, mult: int) -> Tuple[jax.Array, int]:
@@ -1020,12 +1068,16 @@ def topk_tile(
     fast: bool = False,
     k_tile: Optional[int] = None,
     block_rows: Optional[int] = None,
-) -> Tuple[jax.Array, jax.Array]:
+    count_merges: bool = False,
+) -> Tuple[jax.Array, ...]:
     """Running top-kk of one query tile against ALL items: (d2 [B, kk]
     WITHOUT the ||q||^2 term, item index [B, kk] int32), ascending by
     distance with `jax.lax.top_k` tie semantics (lower index first — pinned
     vs a full-matrix top_k by tests/test_distance.py and
-    tests/test_knn_topk.py).
+    tests/test_knn_topk.py). With `count_merges` a third value: the
+    (8-row chunk, item block) pairs that merged on the kernel path (int32
+    scalar; 0 on the jnp form), for a caller that fetches it with the
+    lists.
 
     Where `topk_plan` gives blocks, ONE fused kernel (`_pl_knn_topk`) forms
     the distances block by block in VMEM and keeps each row's best list
@@ -1046,8 +1098,16 @@ def topk_tile(
         item_sq = row_sq(items)
     plan = topk_plan(q.shape[0], n, d, kk, q.dtype, fast)
     if plan is not None:
-        return _fused_topk(q, items, valid, item_sq, kk, block_rows or plan[0],
-                           k_tile or plan[1], fast)
+        d2, idx, merged = _fused_topk(q, items, valid, item_sq, kk, block_rows or plan[0],
+                                      k_tile or plan[1], fast)
+        return (d2, idx, merged) if count_merges else (d2, idx)
+    d2, idx = _scan_topk(q, items, valid, item_sq, kk, k_tile, fast)
+    return (d2, idx, jnp.int32(0)) if count_merges else (d2, idx)
+
+
+def _scan_topk(q, items, valid, item_sq, kk, k_tile, fast):
+    """`topk_tile`'s jnp form."""
+    n = items.shape[0]
     kt = min(k_tile or n, n)
     big = jnp.asarray(jnp.inf, items.dtype)
 
@@ -1093,8 +1153,8 @@ def topk_tile(
 def _fused_topk(q, items, valid, item_sq, kk, block_rows, block_items, fast):
     """`topk_tile` on the kernel path: the operands `_pl_knn_topk` takes,
     the call, and its lists cut to kk and sorted by (distance, index) — the
-    order `lax.top_k` gives. An unfilled slot (fewer valid items than kk)
-    reads +inf at index 0."""
+    order `lax.top_k` gives — with the call's merged chunks (int32 scalar).
+    An unfilled slot (fewer valid items than kk) reads +inf at index 0."""
     n = items.shape[0]
     nq = q.shape[0]
     n_full, rem = divmod(n, block_items)
@@ -1106,12 +1166,12 @@ def _fused_topk(q, items, valid, item_sq, kk, block_rows, block_items, fast):
         tail = jnp.pad(items[n_full * block_items:], ((0, block_items - rem), (0, 0)))
     # -2 q, exact in every precision: the kernel adds ||x||^2 to its contraction
     qm2, _ = _pad_rows_multiple(q * -2.0, block_rows)
-    bd, bi = _pl_knn_topk(
+    bd, bi, merged = _pl_knn_topk(
         qm2, items, tail, xsq, kk=kk, block_rows=block_rows, block_items=block_items,
         n_full=n_full, fast=fast, interpret=_interpret(),
     )
     d2, idx = jax.lax.sort((bd[:nq, :kk], bi[:nq, :kk]), dimension=1, num_keys=2)
-    return d2, jnp.maximum(idx, 0)
+    return d2, jnp.maximum(idx, 0), jnp.sum(merged[::8, 0], dtype=jnp.int32)
 
 
 def tile_topk(

@@ -54,9 +54,9 @@ def _row_sq(x):
 @partial(jax.jit, static_argnames=("kk",))
 def _topk_tile_1dev(items, valid, item_sq, q, *, kk):
     """One compiled query-tile program over the shared core (the host-looped
-    single-device path below)."""
-    d2, idx = topk_tile(q, items, valid, kk, item_sq=item_sq)
-    return d2 + row_sq(q)[:, None], idx
+    single-device path below), with the kernel's merged pairs."""
+    d2, idx, merged = topk_tile(q, items, valid, kk, item_sq=item_sq, count_merges=True)
+    return d2 + row_sq(q)[:, None], idx, merged
 
 
 # On the kernel path one program takes up to this many `distance_tile_rows`
@@ -96,31 +96,34 @@ def dispatch_1dev(items, valid, queries, k, batch_queries=None, item_sq=None):
     parts = []
     for start in range(0, nq, tile):
         s0 = min(start, nq - tile)
-        d2, idx = _topk_tile_1dev(items, valid, item_sq, queries[s0 : s0 + tile], kk=kk)
-        parts.append((d2, idx, start - s0))
+        d2, idx, merged = _topk_tile_1dev(items, valid, item_sq, queries[s0 : s0 + tile], kk=kk)
+        parts.append((d2, idx, merged, start - s0))
     return parts
 
 
 def fetch_1dev(parts, k, dtype):
-    """Host (euclidean distances [nq, k], item positions [nq, k]) of
-    `dispatch_1dev`'s tiles; k past the items is padded with +inf."""
+    """Host (euclidean distances [nq, k], item positions [nq, k], merged
+    pairs) of `dispatch_1dev`'s tiles, in one fetch; k past the items is
+    padded with +inf. The merged pairs (the fused kernel's count, 0 on the
+    jnp form) are summed over the tiles, the clamped last tile's in full."""
     import numpy as np
 
     if not parts:
-        return np.zeros((0, k), dtype=dtype), np.zeros((0, k), dtype=np.int32)
+        return np.zeros((0, k), dtype=dtype), np.zeros((0, k), dtype=np.int32), 0
     # results stay HOST numpy: every caller consumes numpy
-    d2 = np.concatenate([np.asarray(d)[fresh:] for d, _, fresh in parts], axis=0)  # host-fetch-ok: the search's one result fetch, per query tile
-    idx = np.concatenate([np.asarray(i)[fresh:] for _, i, fresh in parts], axis=0)  # host-fetch-ok: see above
+    host = jax.device_get([part[:3] for part in parts])  # host-fetch-ok: the search's one result fetch
+    d2 = np.concatenate([d[fresh:] for (d, _, _), (*_, fresh) in zip(host, parts)], axis=0)
+    idx = np.concatenate([i[fresh:] for (_, i, _), (*_, fresh) in zip(host, parts)], axis=0)
     kk = d2.shape[1]
     if kk < k:
         d2 = np.pad(d2, ((0, 0), (0, k - kk)), constant_values=np.inf)
         idx = np.pad(idx, ((0, 0), (0, k - kk)))
-    return np.sqrt(np.maximum(d2, 0.0)), idx
+    return np.sqrt(np.maximum(d2, 0.0)), idx, sum(int(m) for _, _, m in host)
 
 
 def _exact_knn_1dev(items, valid, queries, k, batch_queries):
     """Single-device exact kNN: `dispatch_1dev`, then `fetch_1dev`."""
-    return fetch_1dev(dispatch_1dev(items, valid, queries, k, batch_queries), k, queries.dtype)
+    return fetch_1dev(dispatch_1dev(items, valid, queries, k, batch_queries), k, queries.dtype)[:2]
 
 
 @partial(jax.jit, static_argnames=())

@@ -377,8 +377,9 @@ def test_the_knn_search_at_the_deep10m_shape_is_one_kernel_on_items_where_they_l
     """The program `kneighbors` dispatches for the cell `knn-deep10m.kneighbors`
     (10,000 queries against 10,000,000 x 96 float32 items held row-major, k =
     10), compiled for a v5e: one Mosaic call named `srml_knn_topk_f32` whose
-    item operand is the program's parameter itself, no `copy` of the items,
-    and nothing of [queries, items] anywhere in the program."""
+    item operand is the program's parameter itself and whose outputs are the
+    lists and the count of merged chunks, no `copy` of the items, and nothing
+    of [queries, items] anywhere in the program."""
     import re
 
     monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
@@ -405,6 +406,11 @@ def test_the_knn_search_at_the_deep10m_shape_is_one_kernel_on_items_where_they_l
         ).compile().as_text()
     calls = re.findall(r"%(srml_\w+?)(?:\.\d+)? = [^\n]*? custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", text)
     assert [name for name, _ in calls] == ["srml_knn_topk_f32"]
+    # its outputs: the two [10,240, 128] lists and the merged chunks, one
+    # [8, 128] int32 block for each of the 20 query blocks
+    (head,) = re.findall(r"%srml_knn_topk_f32(?:\.\d+)? = (.*?) custom-call\(", text)
+    assert re.findall(r"(f32|s32)\[(\d+),(\d+)\]", head) == [
+        ("f32", "10240", "128"), ("s32", "10240", "128"), ("s32", "160", "128")]
     items = calls[0][1].split(", ")[1]
     assert re.search(r"%s = f32\[%d,%d\]\{1,0[^}]*\} parameter\(" % (re.escape(items), n, d), text), items
     assert not re.findall(r"= f32\[%d,%d\]\{[^}]*\} copy\(" % (n, d), text)

@@ -515,7 +515,8 @@ def test_knn_serving_budget_and_topk_program_take_one_item_block(interpret_mode,
     def knn_topk(qm2, items, tail, xsq, **kw):
         taken.append((qm2.shape[0], kw["block_rows"], kw["block_items"], xsq.shape[1]))
         lists = (qm2.shape[0], distance.TOPK_LANES)
-        return jnp.zeros(lists, qm2.dtype), jnp.zeros(lists, jnp.int32)
+        merged = (qm2.shape[0] // kw["block_rows"] * 8, distance.TOPK_LANES)
+        return jnp.zeros(lists, qm2.dtype), jnp.zeros(lists, jnp.int32), jnp.zeros(merged, jnp.int32)
 
     monkeypatch.setattr(distance, "_pl_knn_topk", knn_topk)
     struct = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
@@ -524,6 +525,7 @@ def test_knn_serving_budget_and_topk_program_take_one_item_block(interpret_mode,
     (rows, br, bk, norms), = set(taken)
     assert (br, bk) == distance.plan_topk(bucket, n_items, d) and rows % br == 0
     assert terms["topk_lists"] == rows * distance.TOPK_LANES * 8
+    assert terms["topk_merged"] == rows // br * 8 * distance.TOPK_LANES * 4
     assert terms["topk_queries"] == rows * d * 4
     assert terms["topk_norms"] == norms * 4 and norms % bk == 0
     assert distance.topk_vmem_bytes(br, bk, d, jnp.float32, False) <= distance.vmem_limit_bytes()

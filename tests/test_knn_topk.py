@@ -169,6 +169,140 @@ def test_the_sharded_search_takes_the_kernel_on_the_8_device_mesh(interpret_mode
     np.testing.assert_allclose(np.asarray(d1), np.asarray(d8), rtol=1e-5, atol=1e-5)
 
 
+# ------------------------------------------ the selection: a take, or a merge --
+
+# (query rows, items) a step: one 8-row chunk, two, and five chunks of a step
+_BLOCKS = [(8, 128), (16, 256), (40, 512)]
+
+
+def _far_items(rng, n, d, block_items):
+    """Integer items far from every query, each item block 1,000 farther
+    than the one before, so that a row's best list fills in the first block
+    and only planted items improve it after; integers keep every distance
+    exact, so ties are bit for bit."""
+    items = rng.integers(40, 60, size=(n, d)).astype(np.float64)
+    items[:, 0] += 1000.0 * (np.arange(n) // block_items)
+    return items
+
+
+def _check(q, items, kk, block_rows, block_items, valid=None):
+    d2, idx = distance.topk_tile(q, items, valid, kk, k_tile=block_items, block_rows=block_rows)
+    d2r, idxr = _full_sort(q, items, kk, valid)
+    np.testing.assert_array_equal(np.asarray(idx), idxr)
+    np.testing.assert_array_equal(np.asarray(d2), d2r)
+    assert all(len(set(row)) == kk for row in np.asarray(idx).tolist())
+    return np.asarray(idx)
+
+
+@pytest.mark.parametrize("block_rows,block_items", _BLOCKS)
+def test_a_rows_only_improving_item_in_its_blocks_last_lane_is_taken(interpret_mode, block_rows, block_items):
+    # each row's one better item sits in the last lane of the last whole
+    # block: the step's single take, at the block's last index
+    rng = np.random.default_rng(10)
+    q = rng.integers(-2, 3, size=(block_rows, 8)).astype(np.float64)
+    items = _far_items(rng, 3 * block_items, 8, block_items)
+    at = 3 * block_items - 1
+    items[at] = q[0]
+    idx = _check(q, items, 4, block_rows, block_items)
+    assert idx[0, 0] == at
+
+
+@pytest.mark.parametrize("block_rows,block_items", _BLOCKS)
+def test_ties_across_a_block_boundary_go_to_the_lower_index(interpret_mode, block_rows, block_items):
+    # the same item in the last lane of block 1 and the first of block 2, for
+    # every query: one takes it from each block alone (a take a block), the
+    # rest of the list full of far items; k = 1 keeps the lower index only,
+    # k = 2 keeps both, the lower first
+    rng = np.random.default_rng(11)
+    q = rng.integers(-2, 3, size=(block_rows, 8)).astype(np.float64)
+    items = _far_items(rng, 3 * block_items, 8, block_items)
+    planted = rng.integers(-2, 3, size=8).astype(np.float64)
+    items[2 * block_items - 1] = items[2 * block_items] = planted
+    for kk in (1, 2):
+        idx = _check(q, items, kk, block_rows, block_items)
+        assert (idx[:, 0] == 2 * block_items - 1).all()
+
+
+@pytest.mark.parametrize("block_rows,block_items", _BLOCKS)
+def test_ties_inside_a_block_go_to_the_lower_index_through_the_merge(interpret_mode, block_rows, block_items):
+    # three copies of one item in one block, under every row's worst: the
+    # rows merge, and of the copies the lower indices are kept first
+    rng = np.random.default_rng(12)
+    q = rng.integers(-2, 3, size=(block_rows, 8)).astype(np.float64)
+    items = _far_items(rng, 2 * block_items + 37, 8, block_items)
+    planted = rng.integers(-2, 3, size=8).astype(np.float64)
+    copies = [block_items + 5, block_items + 70, 2 * block_items + 36]
+    items[copies] = planted
+    idx = _check(q, items, 2, block_rows, block_items)
+    assert (idx == copies[:2]).all()
+
+
+@pytest.mark.parametrize("block_rows,block_items", _BLOCKS)
+def test_an_item_that_is_not_a_number_blanks_no_other_block(interpret_mode, block_rows, block_items):
+    # a NaN item in block 1 beside each row's better item there (a take), and
+    # two better items a row in block 2 (a merge): all of them are kept
+    rng = np.random.default_rng(13)
+    q = rng.integers(-2, 3, size=(block_rows, 8)).astype(np.float64)
+    items = _far_items(rng, 3 * block_items, 8, block_items)
+    items[block_items + 3, 1] = np.nan
+    items[block_items + 9] = q[0]
+    items[[2 * block_items + 1, 3 * block_items - 2]] = q[-1]
+    keep = np.ones(len(items), bool)
+    keep[block_items + 3] = False
+    d2, idx = distance.topk_tile(q, items, None, 3, k_tile=block_items, block_rows=block_rows)
+    d2r, idxr = _full_sort(q, np.nan_to_num(items), 3, keep)
+    np.testing.assert_array_equal(np.asarray(idx), idxr)
+    assert not (np.asarray(idx) == block_items + 3).any()
+
+
+@pytest.mark.parametrize("block_rows,block_items", _BLOCKS)
+def test_a_ragged_tail_takes_its_items_and_never_its_fill(interpret_mode, block_rows, block_items):
+    # the last block holds 3 items and fill: a take from its last real item,
+    # and a merge of its first two for another row
+    rng = np.random.default_rng(14)
+    q = rng.integers(-2, 3, size=(block_rows, 8)).astype(np.float64)
+    n = 2 * block_items + 3
+    items = _far_items(rng, n, 8, block_items)
+    items[n - 1] = q[0]
+    items[[n - 3, n - 2]] = q[-1]
+    idx = _check(q, items, 5, block_rows, block_items)
+    assert idx[0, 0] == n - 1 and (idx.max() < n)
+
+
+@pytest.mark.parametrize("block_rows,block_items", _BLOCKS)
+def test_a_take_and_a_merge_in_one_chunk_take_each_item_once(interpret_mode, block_rows, block_items):
+    # in one 8-row chunk, row 0 has one better item in block 1 (taken with
+    # the contraction) and row 1 has four (merged): the merge passes row 0
+    # by, so its item is not taken twice
+    rng = np.random.default_rng(15)
+    q = rng.integers(-2, 3, size=(block_rows, 8)).astype(np.float64)
+    q[1] = q[0] + 1
+    items = _far_items(rng, 2 * block_items, 8, block_items)
+    items[block_items + 2] = q[0]
+    items[[block_items + 4, block_items + 6, block_items + 8, block_items + 10]] = q[1]
+    idx = _check(q, items, 6, block_rows, block_items)
+    assert idx[0, 0] == block_items + 2 and (np.sort(idx[1, :4]) == block_items + np.array([4, 6, 8, 10])).all()
+
+
+@pytest.mark.parametrize("block_rows,block_items", _BLOCKS)
+def test_merged_chunks_are_counted(interpret_mode, block_rows, block_items):
+    # every chunk merges in the first block, where its lists fill; in block 1
+    # each row has one better item (a take, no merge), in block 2 three
+    # (every chunk merges again)
+    rng = np.random.default_rng(16)
+    q = rng.integers(-2, 3, size=(block_rows, 8)).astype(np.float64)
+    items = _far_items(rng, 3 * block_items, 8, block_items)
+    items[block_items + 1] = q[0]
+    items[[2 * block_items, 2 * block_items + 1, 2 * block_items + 2]] = q[0]
+    d2, idx, merged = distance.topk_tile(q, items, None, 5, k_tile=block_items, block_rows=block_rows,
+                                         count_merges=True)
+    chunks = block_rows // 8
+    assert distance.topk_chunk_steps(block_rows, len(items), block_rows, block_items) == 3 * chunks
+    assert int(merged) == 2 * chunks
+    _, idxr = _full_sort(q, items, 5)
+    np.testing.assert_array_equal(np.asarray(idx), idxr)
+
+
 def _frames(rng, n_items, n_queries, d):
     items = rng.normal(size=(n_items, d)).astype(np.float32)
     queries = [rng.normal(size=(n_queries, d)).astype(np.float32) for _ in range(2)]
@@ -200,6 +334,34 @@ def test_kneighbors_places_the_items_once_and_reports_the_kernel(interpret_mode,
         np.testing.assert_array_equal(np.stack(knn_df["indices"].to_numpy()), idxr)
         ref = np.sqrt(np.maximum(_full_sort(q, items, 10)[0] + np.sum(q.astype(np.float64) ** 2, 1)[:, None], 0))
         np.testing.assert_allclose(np.stack(knn_df["distances"].to_numpy()), ref, rtol=1e-4, atol=1e-4)
+
+
+def test_kneighbors_counts_the_chunks_that_merged(interpret_mode, tele):
+    # 5,000 items: one whole block of 4,096 and a ragged tail; 30 queries: one
+    # block of 32 rows, four chunks. Every chunk merges in the first block
+    from spark_rapids_ml_tpu.models.knn import NearestNeighbors
+
+    rng = np.random.default_rng(9)
+    items, item_df, query_dfs, queries = _frames(rng, 5000, 30, 8)
+    model = NearestNeighbors(k=10, num_workers=1).setInputCol("features").fit(item_df)
+    for df in query_dfs:
+        model.kneighbors(df)
+    counters = tele.snapshot()["counters"]
+    assert distance.plan_topk(30, 5000, 8) == (32, 4096)
+    assert counters["knn.flag_pairs"] == 2 * distance.topk_chunk_steps(30, 5000, 32, 4096) == 2 * 8
+    assert 2 * 4 <= counters["knn.merged_pairs"] <= counters["knn.flag_pairs"]
+
+
+def test_the_sharded_search_leaves_the_merge_counters_alone(interpret_mode, tele):
+    from spark_rapids_ml_tpu.models.knn import NearestNeighbors
+
+    rng = np.random.default_rng(17)
+    items, item_df, query_dfs, queries = _frames(rng, 300, 9, 8)
+    model = NearestNeighbors(k=3, num_workers=4).setInputCol("features").fit(item_df)
+    _, _, knn_df = model.kneighbors(query_dfs[0])
+    counters = tele.snapshot()["counters"]
+    assert "knn.merged_pairs" not in counters and "knn.flag_pairs" not in counters
+    np.testing.assert_array_equal(np.stack(knn_df["indices"].to_numpy()), _full_sort(queries[0], items, 3)[1])
 
 
 def test_a_new_worker_count_places_the_items_again(tele):
@@ -257,4 +419,11 @@ def test_the_topk_plan_fits_its_own_budget(d, plan):
     got = distance.plan_topk(10_000, 10_000_000, d)
     assert got == plan
     assert distance.topk_vmem_bytes(*got, d, jnp.float32, False) <= distance.vmem_limit_bytes()
+    # the [rows, 1] int32 column of rows that merge, one 128-lane tile wide, and
+    # the [8, 128] int32 count block, double-buffered, over the operands, the
+    # contraction and the lists
+    br, bk = got
+    dp = -(-d // 128) * 128
+    rest = 2 * (br + 2 * bk) * dp * 4 + (br + bk) * dp * 17 + 2 * br * bk * 4 + 2 * 8 * bk * 4 + 2 * br * 128 * 8
+    assert distance.topk_vmem_bytes(br, bk, d, jnp.float32, False) - rest == br * 128 * 4 + 2 * 8 * 128 * 4
     assert distance.plan_topk(37, 300, d)[0] == 40  # rows cut to the call, a multiple of 8
