@@ -959,12 +959,19 @@ def assign_accumulate_rows(
     of X itself and no copy of any part of X is made. The CALLER vouches
     for what `assign_accumulate` asks of a `tile`, the layout first: handed
     a column-major X whole, XLA would turn all of it before every call."""
+    xb, wb, qb, tile = _tile_operands(X, w, x_sq, start, rows, in_place)
+    return assign_accumulate(xb, wb, centers, fast=fast, x_sq=qb, tile=tile)
+
+
+def _tile_operands(X, w, x_sq, start, rows: int, in_place: bool):
+    """(x, w, x_sq, tile) of rows [start, start + rows) of X, as the `*_rows`
+    entry points hand them on: `w` and `x_sq` sliced; X whole with `tile`
+    where the kernels read the rows `in_place`, else sliced with no `tile`."""
     wb = jax.lax.dynamic_slice_in_dim(w, start, rows, 0)
     qb = jax.lax.dynamic_slice_in_dim(x_sq, start, rows, 0)
     if in_place:
-        return assign_accumulate(X, wb, centers, fast=fast, x_sq=qb, tile=(start, rows))
-    xb = jax.lax.dynamic_slice_in_dim(X, start, rows, 0)
-    return assign_accumulate(xb, wb, centers, fast=fast, x_sq=qb)
+        return X, wb, qb, (start, rows)
+    return jax.lax.dynamic_slice_in_dim(X, start, rows, 0), wb, qb, None
 
 
 def tile_assign_accumulate(
@@ -1010,6 +1017,13 @@ def tile_assign_accumulate(
         jnp.zeros((k,), Xl.dtype),
         jnp.zeros((), Xl.dtype),
     )
+    return _scan_tiles(step, init, nl, batch_rows, in_place)
+
+
+def _scan_tiles(step, init, nl: int, batch_rows: int, in_place: bool):
+    """The in-program tile loop of the `tile_assign_*` scans: `step(carry,
+    start, rows, tile_in_place)` over the full tiles in a fori_loop, as
+    `in_place` says, then once over the ragged tail, sliced."""
     batch_rows = min(batch_rows, nl)
     n_full = (nl // batch_rows) * batch_rows
     carry = jax.lax.fori_loop(
@@ -1020,6 +1034,78 @@ def tile_assign_accumulate(
     if nl - n_full:
         carry = step(carry, n_full, nl - n_full, False)
     return carry
+
+
+# ------------------------------------------- the inertia alone (KMeans) ----
+#
+# A fit's final pass reads only the inertia of the final centres: these run
+# `assign_accumulate`'s argmin call and its sum, tile for tile and in the same
+# order, and no one-hot accumulate, so the scalar is theirs bit for bit.
+
+
+def assign_inertia(
+    xb: jax.Array,  # [B, d] one row tile, or with `tile` the whole [n, d] block it lies in
+    wb: jax.Array,  # [B] weights (0 on padding rows)
+    centers: jax.Array,  # [k, d]
+    *,
+    x_sq: Optional[jax.Array] = None,
+    tile: Optional[Tuple[jax.Array, int]] = None,
+) -> jax.Array:
+    """`assign_accumulate(xb, wb, centers, x_sq=x_sq, tile=tile)[2]`, the
+    float32 inertia of one row tile, without the sums and counts: the
+    float32 argmin kernel alone (`srml_argmin_f32`) on the kernel path.
+    `x_sq` and `tile` as there, refused as there."""
+    k, d = centers.shape
+    n = xb.shape[0] if tile is None else tile[1]
+    plan = block_plan(n, k, d, xb.dtype, False)
+    if tile is not None and (x_sq is None or plan is None or n % plan[0]):
+        raise ValueError(
+            f"a {n}-row tile read in place needs its row norms, the kernels and "
+            f"whole row blocks (plan {plan}): slice the tile instead"
+        )
+    if x_sq is None:
+        x_sq = row_sq(xb)
+    if plan is None:
+        d2 = _c_sq(centers)[None, :] - 2.0 * _mm(xb, centers.T, False)
+        min_d2 = jnp.min(d2, axis=1) + x_sq
+    else:
+        br, bk = plan
+        xp = xb if tile is not None else _pad_rows_multiple(xb, br)[0]
+        cp, _ = _pad_rows_multiple(centers, bk)
+        csq_p = jnp.pad(_c_sq(centers), (0, cp.shape[0] - k), constant_values=jnp.inf)
+        mind, _ = _pl_argmin(
+            xp, cp, csq_p, block_rows=br, block_k=min(bk, cp.shape[0]), fast=False,
+            interpret=_interpret(), tile=tile,
+        )
+        min_d2 = mind[:n] + x_sq
+    return jnp.sum(jnp.maximum(min_d2, 0.0) * wb)
+
+
+def assign_inertia_rows(
+    X: jax.Array, w: jax.Array, centers: jax.Array, x_sq: jax.Array, start, rows: int,
+    *, in_place: bool = False,
+) -> jax.Array:
+    """`assign_inertia` over rows [start, start + rows) of X, the tile got
+    at as `assign_accumulate_rows` gets at it."""
+    xb, wb, qb, tile = _tile_operands(X, w, x_sq, start, rows, in_place)
+    return assign_inertia(xb, wb, centers, x_sq=qb, tile=tile)
+
+
+def tile_assign_inertia(
+    Xl: jax.Array, wl: jax.Array, centers: jax.Array, x_sq: jax.Array,
+    batch_rows: int, in_place: bool = False,
+) -> jax.Array:
+    """One device's inertia: `tile_assign_accumulate(...)[2]` at full
+    precision, over the same tiles in the same order."""
+    _note("distance.assign_programs")
+
+    def step(inertia, start, rows, tile_in_place):
+        return inertia + assign_inertia_rows(
+            Xl, wl, centers, x_sq, start, rows, in_place=tile_in_place
+        )
+
+    (init,) = _vary_like(Xl, jnp.zeros((), Xl.dtype))
+    return _scan_tiles(step, init, Xl.shape[0], batch_rows, in_place)
 
 
 # ---------------------------------------------------- row-tiled assignment --

@@ -29,11 +29,14 @@ from .distance import (
     argmin_assign,
     assign_accumulate,
     assign_accumulate_rows,
+    assign_inertia,
+    assign_inertia_rows,
     block_plan,
     min_d2_update,
     row_sq,
     shard_map_check_vma,
     tile_assign_accumulate as _tile_assign_accumulate,
+    tile_assign_inertia as _tile_assign_inertia,
 )
 
 # jitted once per shape: the seeding paths dispatch these eagerly per round
@@ -91,6 +94,25 @@ def _lloyd_step(X, w, centers, x_sq, *, mesh, batch_rows, fast=False, in_place=F
     return _finish_centers(sums, counts, inertia, centers)
 
 
+@partial(jax.jit, static_argnames=("mesh", "batch_rows", "in_place"))
+def _inertia_pass(X, w, centers, x_sq, *, mesh, batch_rows, in_place=False):
+    """The final pass of `_lloyd_step`'s fit: the float32 inertia of the
+    final centres alone, each shard's tile scan through the argmin kernel
+    only, and the scalar the one value `psum`'d."""
+
+    def local(Xl, wl, ql):
+        inertia = _tile_assign_inertia(Xl, wl, centers, ql, batch_rows, in_place)
+        return jax.lax.psum(inertia, ROWS_AXIS)
+
+    return shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(ROWS_AXIS, None), P(ROWS_AXIS), P(ROWS_AXIS)),
+        out_specs=P(),
+        check_vma=shard_map_check_vma(),
+    )(X, w, x_sq)
+
+
 @partial(jax.jit, static_argnames=("batch_rows", "fast", "in_place"))
 def _lloyd_step_fused_1dev(X, w, centers, x_sq, *, batch_rows, fast=False, in_place=False):
     """One Lloyd iteration as ONE local program (no mesh, no collectives):
@@ -106,6 +128,13 @@ def _lloyd_step_fused_1dev(X, w, centers, x_sq, *, batch_rows, fast=False, in_pl
         X, w, centers, x_sq, batch_rows, fast, in_place
     )
     return _finish_centers(sums, counts, inertia, centers)
+
+
+@partial(jax.jit, static_argnames=("batch_rows", "in_place"))
+def _inertia_pass_fused_1dev(X, w, centers, x_sq, *, batch_rows, in_place=False):
+    """The final pass of `_lloyd_step_fused_1dev`'s fit: its tile scan
+    through the float32 argmin kernel alone, the inertia its one output."""
+    return _tile_assign_inertia(X, w, centers, x_sq, batch_rows, in_place)
 
 
 @partial(jax.jit, static_argnames=("size", "fast", "in_place"), donate_argnums=(4, 5, 6))
@@ -138,30 +167,52 @@ def _tile_accum_1dev(
     return sums + s, counts + c, inertia + i
 
 
+@partial(jax.jit, static_argnames=("size", "in_place"), donate_argnums=(4,))
+def _tile_inertia_1dev(X, w, centers, x_sq, inertia, start, *, size, in_place=False):
+    """`_tile_accum_1dev`'s inertia alone, for the final pass: one tile a
+    program through the float32 argmin kernel, the scalar carry donated."""
+    return inertia + assign_inertia_rows(X, w, centers, x_sq, start, size, in_place=in_place)
+
+
+def _host_tiles(n: int, batch_rows: int, in_place: bool):
+    """(start, rows, in_place) of each program of a host-tiled pass over n
+    rows: the full tiles as the fit says, then the ragged tail, sliced."""
+    import numpy as np
+
+    batch_rows = min(batch_rows, n)
+    n_full = (n // batch_rows) * batch_rows
+    for start in range(0, n_full, batch_rows):
+        yield np.int32(start), batch_rows, in_place
+    if n - n_full:
+        yield np.int32(n_full), n - n_full, False
+
+
 def _lloyd_step_1dev(X, w, centers, x_sq, batch_rows, fast=False, in_place=False):
     """Host-tiled Lloyd iteration for a 1-device mesh (see _tile_accum_1dev);
     the ragged tail is sliced whatever `in_place` says of the full tiles."""
-    import numpy as np
-
     n, d = X.shape
     k = centers.shape[0]
     dtype = X.dtype
-    batch_rows = min(batch_rows, n)
     sums = jnp.zeros((k, d), dtype)
     counts = jnp.zeros((k,), dtype)
     inertia = jnp.zeros((), dtype)
-    n_full = (n // batch_rows) * batch_rows
-    for start in range(0, n_full, batch_rows):
+    for start, size, tile_in_place in _host_tiles(n, batch_rows, in_place):
         sums, counts, inertia = _tile_accum_1dev(
-            X, w, centers, x_sq, sums, counts, inertia, np.int32(start),
-            size=batch_rows, fast=fast, in_place=in_place,
-        )
-    if n - n_full:
-        sums, counts, inertia = _tile_accum_1dev(
-            X, w, centers, x_sq, sums, counts, inertia, np.int32(n_full),
-            size=n - n_full, fast=fast,
+            X, w, centers, x_sq, sums, counts, inertia, start,
+            size=size, fast=fast, in_place=tile_in_place,
         )
     return _finish_centers_jit(sums, counts, inertia, centers)
+
+
+def _inertia_pass_1dev(X, w, centers, x_sq, batch_rows, in_place=False):
+    """The final pass of `_lloyd_step_1dev`'s fit: the same tiles, one
+    `_tile_inertia_1dev` program each."""
+    inertia = jnp.zeros((), X.dtype)
+    for start, size, tile_in_place in _host_tiles(X.shape[0], batch_rows, in_place):
+        inertia = _tile_inertia_1dev(
+            X, w, centers, x_sq, inertia, start, size=size, in_place=tile_in_place
+        )
+    return inertia
 
 
 # Below this size a 1-device fit takes the SAME one-dispatch-per-iteration
@@ -188,8 +239,16 @@ def block_assign_accumulate(
     double-buffered pipeline; padding rows carry zero weight, so they
     contribute nothing — exactly the resident pad contract. `fast` runs the
     chunk's distance matmuls in the parity-tested fast-bf16 mode; the
-    streaming driver keeps its final inertia pass at full precision."""
+    streaming driver's final pass takes `block_inertia`."""
     return assign_accumulate(xb, wb, centers, fast=fast)
+
+
+@jax.jit
+def block_inertia(xb: jax.Array, wb: jax.Array, centers: jax.Array) -> jax.Array:
+    """One streaming chunk's float32 inertia alone, for the out-of-core
+    driver's final pass: `block_assign_accumulate(xb, wb, centers)[2]`
+    through the argmin kernel only."""
+    return assign_inertia(xb, wb, centers)
 
 
 def kmeans_ckpt_key(init_centers, max_iter: int, tol: float) -> str:
@@ -247,9 +306,11 @@ def kmeans_fit(
     the last shift fetch, with the path taken, the block plan, the layout X
     has on the device (`x_layout`) and how the kernels get at a tile of it
     (`tile_access`: `in_place` or `sliced`) as attributes — and `finish` — the final
-    inertia pass and, through `to_host` (the estimator's conversion of the
-    returned state, run inside the span), the model's attributes brought to
-    the host. Neither adds a device synchronisation.
+    inertia pass (`final_pass`: `inertia`, the float32 argmin alone, counted
+    by `kmeans.inertia_passes`; `none` without one) and, through `to_host`
+    (the estimator's conversion of the returned state, run inside the span),
+    the model's attributes brought to the host. Neither adds a device
+    synchronisation.
 
     Convergence: squared center movement <= tol (sklearn/cuML semantics; the
     reference maps Spark's `tol` straight through, clustering.py:96-108).
@@ -257,8 +318,10 @@ def kmeans_fit(
     for why the loop is not a `lax.while_loop`): on a mesh the `shard_map`
     program `_lloyd_step`; on one device `_lloyd_step_fused_1dev`, one program
     an iteration, while X is under `_ONE_DISPATCH_MAX_BYTES`, and above it
-    the host-tiled `_lloyd_step_1dev`, one program a tile. In all three the
-    kernels' blocks are `distance.block_plan`'s.
+    the host-tiled `_lloyd_step_1dev`, one program a tile. Each has a final
+    pass of the same form that computes the inertia and nothing else
+    (`_inertia_pass`, `_inertia_pass_fused_1dev`, `_inertia_pass_1dev`). In
+    all of them the kernels' blocks are `distance.block_plan`'s.
 
     The deferred (pipelined) convergence check means `n_iter_` can be ONE
     HIGHER than sklearn/cuML would report for the same tol crossing — the
@@ -281,6 +344,7 @@ def kmeans_fit(
     n_iter = 0
     one_dev = mesh.devices.size == 1
     host_tiled = one_dev and X.size * X.dtype.itemsize > _ONE_DISPATCH_MAX_BYTES
+    solver_path = "host_tiled" if host_tiled else "fused_1dev" if one_dev else "shard_map"
 
     rows_dev = max(1, -(-X.shape[0] // mesh.devices.size))
     tile = min(batch_rows, rows_dev)
@@ -302,16 +366,17 @@ def kmeans_fit(
         f: row_major and plan is not None and tile % plan[0] == 0 for f, plan in plans.items()
     }
 
-    def step(c, x_sq, f):
-        if host_tiled:
-            return _lloyd_step_1dev(X, w, c, x_sq, batch_rows, fast=f, in_place=in_place[f])
-        if one_dev:  # meshless local program (see _lloyd_step_fused_1dev)
-            return _lloyd_step_fused_1dev(
-                X, w, c, x_sq, batch_rows=batch_rows, fast=f, in_place=in_place[f]
-            )
-        return _lloyd_step(
-            X, w, c, x_sq, mesh=mesh, batch_rows=batch_rows, fast=f, in_place=in_place[f]
-        )
+    # each path's Lloyd step and its final pass, which computes the float32
+    # inertia alone; the 1-device programs are meshless (see
+    # _lloyd_step_fused_1dev)
+    lloyd_step, inertia_pass = {
+        "host_tiled": (_lloyd_step_1dev, _inertia_pass_1dev),
+        "fused_1dev": (_lloyd_step_fused_1dev, _inertia_pass_fused_1dev),
+        "shard_map": (partial(_lloyd_step, mesh=mesh), partial(_inertia_pass, mesh=mesh)),
+    }[solver_path]
+
+    def step(c, x_sq):
+        return lloyd_step(X, w, c, x_sq, batch_rows=batch_rows, fast=fast, in_place=in_place[fast])
 
     # convergence is tested one iteration LATE: fetching the shift scalar
     # synchronizes with the device; checking the PREVIOUS iteration's shift
@@ -368,7 +433,7 @@ def kmeans_fit(
             prev_shift = None if ps is None else float(ps)
     with telemetry.span(
         "loop",
-        solver_path="host_tiled" if host_tiled else "fused_1dev" if one_dev else "shard_map",
+        solver_path=solver_path,
         tiles_per_iter=-(-rows_dev // tile),
         block_rows=block_rows,
         block_k=block_k,
@@ -378,7 +443,7 @@ def kmeans_fit(
         x_sq = _row_norms(X)  # one asynchronous program a fit; nothing waits for it here
         while n_iter < max_iter:
             step_in = centers
-            centers, inertia, shift = step(centers, x_sq, fast)
+            centers, inertia, shift = step(centers, x_sq)
             n_iter += 1
             if prev_shift is not None:
                 # the deferred shift fetch is Lloyd's per-iteration sync — the
@@ -441,15 +506,19 @@ def kmeans_fit(
                 preemption_point("kmeans", n_iter)
     if telemetry.enabled():
         telemetry.record_solver_result("kmeans", n_iter=n_iter)
-    with telemetry.span("finish"):
+    with telemetry.span("finish", final_pass="inertia" if final_inertia else "none"):
         # inertia reported is one iteration stale; recompute once with final
-        # centers — always at high precision. Callers that don't consume inertia
-        # (e.g. the IVF coarse quantizer) skip the pass: the high-precision
-        # program is a separate ~79s compile in a fresh process. The stale value
-        # must not leak to them either — return NaN so accidental consumption is
-        # loud instead of subtly wrong.
+        # centers — always at high precision, through the float32 argmin alone
+        # (no sums: nothing reads them). Callers that don't consume inertia
+        # (e.g. the IVF coarse quantizer) skip the pass: its program is a
+        # compile of its own in a fresh process. The stale value must not
+        # leak to them either — return NaN so accidental consumption is loud
+        # instead of subtly wrong.
         if final_inertia:
-            _, inertia, _ = step(centers, x_sq, False)
+            inertia = inertia_pass(
+                X, w, centers, x_sq, batch_rows=batch_rows, in_place=in_place[False]
+            )
+            telemetry.registry().inc("kmeans.inertia_passes")
             with telemetry.device_wait("kmeans_inertia"):
                 inertia_host = float(inertia)
             if not math.isfinite(inertia_host):

@@ -380,6 +380,7 @@ def kmeans_fit_streaming(
         _finish_centers_jit,
         _raise_diverged,
         block_assign_accumulate,
+        block_inertia,
         kmeans_ckpt_key,
     )
 
@@ -389,10 +390,10 @@ def kmeans_fit_streaming(
     centers = jnp.asarray(np.asarray(init_centers), dtype=dtype)
     _nc = numcheck.hook()  # SRML_NUMCHECK=1: chunk partials + iterate boundary
 
-    def step(c, f=False):
+    def step(c):
         sums = counts = inertia = None
         for blk in stream_place_blocks(inputs.mesh, _dense_block_iter(inputs, {"w": w})):
-            s, n_, i_ = block_assign_accumulate(blk["X"], blk["w"], c, fast=f)
+            s, n_, i_ = block_assign_accumulate(blk["X"], blk["w"], c, fast=fast)
             with telemetry.device_wait("stream_chunk"):
                 s, n_, i_ = np.asarray(s), np.asarray(n_), np.asarray(i_)  # host-fetch-ok: out-of-core by design — per-CHUNK [k,d] assignment partials accumulate on host
             if _nc is not None:
@@ -406,6 +407,19 @@ def kmeans_fit_streaming(
             jnp.asarray(sums, dtype), jnp.asarray(counts, dtype),
             jnp.asarray(inertia, dtype), c,
         )
+
+    def inertia_of(c):
+        # the final pass: each chunk's float32 inertia alone, summed in
+        # `step`'s order, so the scalar is the one a float32 `step(c)` gives
+        inertia = None
+        for blk in stream_place_blocks(inputs.mesh, _dense_block_iter(inputs, {"w": w})):
+            i_ = block_inertia(blk["X"], blk["w"], c)
+            with telemetry.device_wait("stream_chunk"):
+                i_ = np.asarray(i_)  # host-fetch-ok: out-of-core by design — the per-chunk partial accumulates on host
+            if _nc is not None:
+                _nc("kmeans_stream.chunk", solver="kmeans_stream", inertia=i_)
+            inertia = i_ if inertia is None else inertia + i_
+        return jnp.asarray(inertia, dtype)
 
     inertia = jnp.zeros((), dtype)
     n_iter = 0
@@ -430,7 +444,7 @@ def kmeans_fit_streaming(
             prev_shift = None if ps is None else float(ps)
     while n_iter < max_iter:
         step_in = centers
-        centers, inertia, shift = step(centers, fast)
+        centers, inertia, shift = step(centers)
         n_iter += 1
         if prev_shift is not None:
             with telemetry.device_wait("kmeans_shift"):
@@ -470,7 +484,8 @@ def kmeans_fit_streaming(
     if final_inertia:
         # always at full precision: the REPORTED inertia (and the divergence
         # guard on it) must never see bf16 rounding, resident-loop parity
-        _, inertia, _ = step(centers, False)
+        inertia = inertia_of(centers)
+        telemetry.registry().inc("kmeans.inertia_passes")
         inertia_host = float(inertia)
         if not math.isfinite(inertia_host):
             _raise_diverged(n_iter, last_good, f"final inertia = {inertia_host}")

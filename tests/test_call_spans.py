@@ -113,7 +113,7 @@ def test_kmeans_fit_records_init_loop_finish_under_solve(tele, rng, workers, pat
         "solver_path": path, "tiles_per_iter": 1, "block_rows": None, "block_k": None, "x_layout": "default",
         "tile_access": "sliced",
     }
-    assert by_path["fit/solve/finish"] == {}
+    assert by_path["fit/solve/finish"] == {"final_pass": "inertia"}
     # the waits that were there, each on the span that holds it: the deferred shift of iterations 2 and 3,
     # the final inertia and the model's attributes
     assert {s["path"]: s["waits"] for s in spans if "waits" in s} == {"fit/solve/loop": 2, "fit/solve/finish": 2}

@@ -576,7 +576,8 @@ def test_kernel_name_contract():
 
 def test_kernels_pass_their_names_to_pallas_call(interpret_mode, monkeypatch):
     """Where no TPU compiler is at hand: the names `pallas_call` is given,
-    through the interpreter, for the Lloyd tile (both modes), predict, top-k."""
+    through the interpreter, for the Lloyd tile (both modes), the final
+    inertia pass, predict, top-k."""
     from jax.experimental import pallas as pl
 
     names = []
@@ -594,8 +595,11 @@ def test_kernels_pass_their_names_to_pallas_call(interpret_mode, monkeypatch):
     distance.assign_accumulate(x, w, c, fast=True)
     assert names == ["srml_argmin_bf16", "srml_accumulate_bf16"]
     del names[:]
-    distance.assign_accumulate(x, w, c, fast=False)  # the final inertia pass's mode
+    distance.assign_accumulate(x, w, c, fast=False)  # a `high`-precision loop's mode
     assert names == ["srml_argmin_f32", "srml_accumulate_f32"]
+    del names[:]
+    distance.assign_inertia(x, w, c)  # the final inertia pass: the argmin alone
+    assert names == ["srml_argmin_f32"]
     del names[:]
     distance.argmin_assign(x, c)  # the predict program's call
     assert names == ["srml_argmin_f32"]
@@ -673,17 +677,18 @@ def v5e():
         env.undo()
 
 
-def _lloyd_program_text(v5e, program, d, x_layout, in_place=False, fast=True):
+def _lloyd_program_text(v5e, program, d, x_layout, in_place=False, fast=True, final_pass=False):
     """Compiled text of one Lloyd program at the benchmark cells' shapes
     (`kmeans-p3k`: 393,216 rows on one chip through the host-tiled
     `_tile_accum_1dev`; `kmeans-p3k-host4`: 1,048,576 rows over four chips
-    through `_lloyd_step`), with X's struct in the layout `make_global_rows`
-    gives it for `x_layout` and the tile access `kmeans_fit` would state for
-    it, and the tile's and one device's X shape."""
+    through `_lloyd_step`), or with `final_pass` of the fit's final pass
+    (`_tile_inertia_1dev`, `_inertia_pass`), with X's struct in the layout
+    `make_global_rows` gives it for `x_layout` and the tile access
+    `kmeans_fit` would state for it, and the tile's and one device's X shape."""
     from jax.experimental.layout import Format
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-    from spark_rapids_ml_tpu.ops.kmeans import _lloyd_step, _tile_accum_1dev
+    from spark_rapids_ml_tpu.ops.kmeans import _inertia_pass, _lloyd_step, _tile_accum_1dev, _tile_inertia_1dev
     from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
 
     k, tile = 1000, 32768
@@ -696,19 +701,23 @@ def _lloyd_program_text(v5e, program, d, x_layout, in_place=False, fast=True):
         if program == "tile_1dev":
             one = SingleDeviceSharding(devices[0])
             s = lambda shape, dtype=jnp.float32, sharding=one: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-            lowered = _tile_accum_1dev.lower(
-                s((rows_dev, d), sharding=placed or one), s((rows_dev,)), s((k, d)), s((rows_dev,)),
-                s((k, d)), s((k,)), s(()), s((), jnp.int32), size=tile, fast=fast, in_place=in_place,
-            )
+            operands = (s((rows_dev, d), sharding=placed or one), s((rows_dev,)), s((k, d)), s((rows_dev,)))
+            if final_pass:
+                lowered = _tile_inertia_1dev.lower(*operands, s(()), s((), jnp.int32), size=tile, in_place=in_place)
+            else:
+                lowered = _tile_accum_1dev.lower(
+                    *operands, s((k, d)), s((k,)), s(()), s((), jnp.int32), size=tile, fast=fast, in_place=in_place,
+                )
         else:
             mesh = Mesh(np.asarray(devices), (mesh_mod.ROWS_AXIS,))
             rows, rep = NamedSharding(mesh, P(mesh_mod.ROWS_AXIS)), NamedSharding(mesh, P())
             x_sharding = rows if placed is None else Format(placed.layout, rows)
             s = lambda shape, sharding: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
-            lowered = _lloyd_step.lower(
-                s((4 * rows_dev, d), x_sharding), s((4 * rows_dev,), rows), s((k, d), rep),
-                s((4 * rows_dev,), rows), mesh=mesh, batch_rows=tile, fast=fast, in_place=in_place,
-            )
+            operands = (s((4 * rows_dev, d), x_sharding), s((4 * rows_dev,), rows), s((k, d), rep), s((4 * rows_dev,), rows))
+            if final_pass:
+                lowered = _inertia_pass.lower(*operands, mesh=mesh, batch_rows=tile, in_place=in_place)
+            else:
+                lowered = _lloyd_step.lower(*operands, mesh=mesh, batch_rows=tile, fast=fast, in_place=in_place)
         return lowered.compile().as_text(), (tile, d), (rows_dev, d)
 
 
@@ -738,8 +747,8 @@ def _kernel_calls(text):
 def test_row_major_x_reaches_the_kernels_without_a_tile_copy(v5e, monkeypatch, program, d):
     """Placed as KMeans asks, X enters the program row-major ({1,0}) and the
     kernels index it where it lies (`tile_access` `in_place`): no value of the
-    tile's shape exists in the compiled program, bf16 loop step and float32
-    final pass alike, no `copy` has X's shape, and both custom calls take as
+    tile's shape exists in the compiled program, bf16 and float32 loop steps
+    alike, no `copy` has X's shape, and both custom calls take as
     their X operand a value of one device's whole X. Sliced (what a ragged
     tile keeps), the row-major tile is written out once and still never
     turned. The control: at d = 3,000 the default layout is column-major and
@@ -774,3 +783,28 @@ def test_row_major_x_reaches_the_kernels_without_a_tile_copy(v5e, monkeypatch, p
         control, _, _ = _lloyd_program_text(v5e, program, d, mesh_mod.X_DEFAULT)
         assert _x_entry_layout(control, x_shape) == "0,1"
         assert tile_shape in _copied_shapes(control) and x_shape not in _copied_shapes(control)
+
+
+@pytest.mark.parametrize("program", ["tile_1dev", "lloyd_step_4chips"])
+@pytest.mark.parametrize("d", [3000, 3072])
+def test_the_final_pass_runs_the_float32_argmin_alone(v5e, monkeypatch, program, d):
+    """The fit's final pass, as the one-chip tile program and as the
+    four-chip `shard_map` program: one Mosaic call, `srml_argmin_f32`, no
+    accumulate; it takes X where X lies (row-major, the program's parameter
+    or the tile loop's pass-through of it), and no `copy` has the tile's or
+    X's shape. On four chips the one value all-reduced is the scalar."""
+    import re
+
+    from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
+
+    monkeypatch.setattr(distance, "_MODE", "pallas")
+    text, tile_shape, x_shape = _lloyd_program_text(v5e, program, d, mesh_mod.X_ROW_MAJOR, in_place=True, final_pass=True)
+    assert _x_entry_layout(text, x_shape) == "1,0"
+    assert "f32[%d,%d]" % tile_shape not in text
+    assert not _copied_shapes(text) & {tile_shape, x_shape}
+    calls = _kernel_calls(text)
+    assert [name for name, _ in calls] == ["srml_argmin_f32"]
+    x_operand = calls[0][1].split(", ")[1]
+    assert re.search(r"%s = f32\[%d,%d\]\{1,0[^}]*\} (parameter|get-tuple-element)\(" % ((re.escape(x_operand),) + x_shape), text), x_operand
+    reduced = re.findall(r"= (\w+\[[\d,]*\])\S* all-reduce(?:-start)?\(", text)
+    assert reduced == ([] if program == "tile_1dev" else ["f32[]"])
