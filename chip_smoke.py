@@ -49,8 +49,8 @@ class Sizes:
     knn_k: int
 
 
-# 262,144 x 3,000 f32 = 3.1 GB: above ops/kmeans._ONE_DISPATCH_MAX_BYTES, so
-# one chip takes the host-tiled Lloyd path the 1M x 3k protocol shape takes
+# 262,144 x 3,000 f32 = 3.1 GB: one chip runs the one-program Lloyd step at the
+# protocol's width (d = 3,000, k = 1,000) and its 32,768-row tiles
 FULL = Sizes(rows=262_144, d=3000, k=1000, kmeans_iters=4, logreg_iters=10,
              knn_queries=256, knn_k=64)
 REHEARSAL = Sizes(rows=2048, d=200, k=64, kmeans_iters=3, logreg_iters=10,

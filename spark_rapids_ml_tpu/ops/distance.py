@@ -874,6 +874,38 @@ def assign_argmin(
     return mind[:n], best[:n]
 
 
+def _nearest(xb, centers, fast, block_rows, block_k, x_sq, tile):
+    """The head `assign_accumulate` and `assign_inertia` share, for one row
+    tile: (min d2 [B] WITH the ||x||^2 term, assignment [B], kernel), where
+    `kernel` is None on the jnp form and on the kernel path the rest of
+    what the accumulate takes: (the rows, padded unless read in place,
+    block rows, block k, the padded center count). Arguments as
+    `assign_accumulate` takes them, refused as there."""
+    k, d = centers.shape
+    n = xb.shape[0] if tile is None else tile[1]
+    plan = block_plan(n, k, d, xb.dtype, fast)
+    br, bk = (block_rows or plan[0], block_k or plan[1]) if plan else (None, None)
+    if tile is not None and (x_sq is None or plan is None or n % br):
+        raise ValueError(
+            f"a {n}-row tile read in place needs its row norms, the kernels and "
+            f"whole row blocks (plan {plan}): slice the tile instead"
+        )
+    if x_sq is None:
+        x_sq = row_sq(xb)
+    if plan is None:
+        d2 = _c_sq(centers)[None, :] - 2.0 * _mm(xb, centers.T, fast)
+        return jnp.min(d2, axis=1) + x_sq, jnp.argmin(d2, axis=1), None
+    xp = xb if tile is not None else _pad_rows_multiple(xb, br)[0]
+    cp, _ = _pad_rows_multiple(centers, bk)
+    bk = min(bk, cp.shape[0])
+    csq_p = jnp.pad(_c_sq(centers), (0, cp.shape[0] - k), constant_values=jnp.inf)
+    mind, best = _pl_argmin(
+        xp, cp, csq_p, block_rows=br, block_k=bk, fast=fast,
+        interpret=_interpret(), tile=tile,
+    )
+    return mind[:n] + x_sq, best, (xp, br, bk, cp.shape[0])
+
+
 def assign_accumulate(
     xb: jax.Array,  # [B, d] one row tile, or with `tile` the whole [n, d] block it lies in
     wb: jax.Array,  # [B] weights (0 on padding rows — they contribute nothing)
@@ -899,148 +931,19 @@ def assign_accumulate(
     from the committed array), the kernels are on and `rows` is a multiple
     of the plan's block rows (`block_plan`); anything else is the caller's
     error."""
-    k, d = centers.shape
-    n = xb.shape[0] if tile is None else tile[1]
-    plan = block_plan(n, k, d, xb.dtype, fast)
-    br, bk = (block_rows or plan[0], block_k or plan[1]) if plan else (None, None)
-    if tile is not None and (x_sq is None or plan is None or n % br):
-        raise ValueError(
-            f"a {n}-row tile read in place needs its row norms, the kernels and "
-            f"whole row blocks (plan {plan}): slice the tile instead"
-        )
-    if x_sq is None:
-        x_sq = row_sq(xb)
-    if plan is None:
-        c_sq = _c_sq(centers)
-        d2 = c_sq[None, :] - 2.0 * _mm(xb, centers.T, fast)
-        assign = jnp.argmin(d2, axis=1)
-        min_d2 = jnp.min(d2, axis=1) + x_sq
-        oh = jax.nn.one_hot(assign, k, dtype=xb.dtype) * wb[:, None]
-        return (
-            _mm(oh.T, xb, fast),
-            jnp.sum(oh, axis=0),
-            jnp.sum(jnp.maximum(min_d2, 0.0) * wb),
-        )
-    xp, wp = xb, wb
-    if tile is None:
-        xp, _ = _pad_rows_multiple(xb, br)
-        wp, _ = _pad_rows_multiple(wb, br)
-    cp, _ = _pad_rows_multiple(centers, bk)
-    bk = min(bk, cp.shape[0])
-    csq_p = jnp.pad(_c_sq(centers), (0, cp.shape[0] - k), constant_values=jnp.inf)
-    mind, best = _pl_argmin(
-        xp, cp, csq_p, block_rows=br, block_k=bk, fast=fast,
-        interpret=_interpret(), tile=tile,
-    )
-    sums_p, counts_p = _pl_accumulate(
-        xp, wp, best, cp.shape[0], block_rows=br, block_k=bk, fast=fast,
-        interpret=_interpret(), tile=tile,
-    )
-    min_d2 = mind[:n] + x_sq
-    inertia = jnp.sum(jnp.maximum(min_d2, 0.0) * wb)
-    return sums_p[:k], counts_p[:k], inertia
-
-
-def assign_accumulate_rows(
-    X: jax.Array,  # [n, d] one device's rows
-    w: jax.Array,  # [n]
-    centers: jax.Array,  # [k, d]
-    x_sq: jax.Array,  # [n] squared row norms of X
-    start,  # first row of the tile (traced, or a Python int)
-    rows: int,
-    *,
-    fast: bool = False,
-    in_place: bool = False,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """`assign_accumulate` over rows [start, start + rows) of X. `w` and
-    `x_sq` are sliced (1-D, 128 KB a tile at 32,768 rows). X is sliced too,
-    which makes the tile a buffer of its own (a Mosaic operand is a whole
-    array), unless `in_place`: then the kernels fetch the tile's blocks out
-    of X itself and no copy of any part of X is made. The CALLER vouches
-    for what `assign_accumulate` asks of a `tile`, the layout first: handed
-    a column-major X whole, XLA would turn all of it before every call."""
-    xb, wb, qb, tile = _tile_operands(X, w, x_sq, start, rows, in_place)
-    return assign_accumulate(xb, wb, centers, fast=fast, x_sq=qb, tile=tile)
-
-
-def _tile_operands(X, w, x_sq, start, rows: int, in_place: bool):
-    """(x, w, x_sq, tile) of rows [start, start + rows) of X, as the `*_rows`
-    entry points hand them on: `w` and `x_sq` sliced; X whole with `tile`
-    where the kernels read the rows `in_place`, else sliced with no `tile`."""
-    wb = jax.lax.dynamic_slice_in_dim(w, start, rows, 0)
-    qb = jax.lax.dynamic_slice_in_dim(x_sq, start, rows, 0)
-    if in_place:
-        return X, wb, qb, (start, rows)
-    return jax.lax.dynamic_slice_in_dim(X, start, rows, 0), wb, qb, None
-
-
-def tile_assign_accumulate(
-    Xl: jax.Array, wl: jax.Array, centers: jax.Array, x_sq: jax.Array,
-    batch_rows: int, fast: bool = False, in_place: bool = False,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Scan one device's rows in tiles; returns (sums [k,d], counts [k],
-    inertia) — the whole-shard Lloyd accumulation every KMeans path shares.
-    `x_sq` holds the rows' squared norms (made once a fit, not once a tile).
-
-    `in_place` (see `assign_accumulate_rows`; the caller's statement that Xl
-    lies row-major and a full tile is whole row blocks): the kernels of
-    each full tile index Xl itself by the loop's tile offset. The compiled
-    step then has no value of the tile's shape and no copy of Xl, inside the
-    fori_loop as outside one: XLA does not duplicate an operand that a
-    Mosaic kernel indexes (tests/test_distance.py pins the text for a v5e).
-
-    Otherwise tiles are cut with `dynamic_slice` DIRECTLY out of Xl inside
-    the fori_loop, and the ragged tail is one such step either way. Neither
-    `jnp.pad` of the shard nor a `lax.scan` over a reshaped view is safe
-    here: both make XLA materialize a second X-sized buffer (11 GiB at the
-    1M x 3k benchmark shape, measured) — the slice-in-loop form keeps X
-    single-buffered, at the price of one tile-sized buffer written and read
-    back a tile; for an Xl that a TPU keeps column-major (its default at
-    d = 3,000) the slice is also turned by a `copy` before the kernels
-    (docs/performance.md "Tiled distance core")."""
-    _note("distance.assign_programs")
-    nl, d = Xl.shape
     k = centers.shape[0]
-
-    def step(carry, start, rows, tile_in_place):
-        sums, counts, inertia = carry
-        s, c, i = assign_accumulate_rows(
-            Xl, wl, centers, x_sq, start, rows, fast=fast, in_place=tile_in_place
-        )
-        return sums + s, counts + c, inertia + i
-
-    # under a shard_map the carry varies like the per-shard accumulators
-    # (vma typing); the meshless 1-device program has no axis to cast over
-    init = _vary_like(
-        Xl,
-        jnp.zeros((k, d), Xl.dtype),
-        jnp.zeros((k,), Xl.dtype),
-        jnp.zeros((), Xl.dtype),
+    min_d2, assign, kernel = _nearest(xb, centers, fast, block_rows, block_k, x_sq, tile)
+    inertia = jnp.sum(jnp.maximum(min_d2, 0.0) * wb)
+    if kernel is None:
+        oh = jax.nn.one_hot(assign, k, dtype=xb.dtype) * wb[:, None]
+        return _mm(oh.T, xb, fast), jnp.sum(oh, axis=0), inertia
+    xp, br, bk, kp = kernel
+    wp = wb if tile is not None else _pad_rows_multiple(wb, br)[0]
+    sums_p, counts_p = _pl_accumulate(
+        xp, wp, assign, kp, block_rows=br, block_k=bk, fast=fast,
+        interpret=_interpret(), tile=tile,
     )
-    return _scan_tiles(step, init, nl, batch_rows, in_place)
-
-
-def _scan_tiles(step, init, nl: int, batch_rows: int, in_place: bool):
-    """The in-program tile loop of the `tile_assign_*` scans: `step(carry,
-    start, rows, tile_in_place)` over the full tiles in a fori_loop, as
-    `in_place` says, then once over the ragged tail, sliced."""
-    batch_rows = min(batch_rows, nl)
-    n_full = (nl // batch_rows) * batch_rows
-    carry = jax.lax.fori_loop(
-        0, n_full // batch_rows,
-        lambda i, carry: step(carry, i * batch_rows, batch_rows, in_place),
-        init,
-    )
-    if nl - n_full:
-        carry = step(carry, n_full, nl - n_full, False)
-    return carry
-
-
-# ------------------------------------------- the inertia alone (KMeans) ----
-#
-# A fit's final pass reads only the inertia of the final centres: these run
-# `assign_accumulate`'s argmin call and its sum, tile for tile and in the same
-# order, and no one-hot accumulate, so the scalar is theirs bit for bit.
+    return sums_p[:k], counts_p[:k], inertia
 
 
 def assign_inertia(
@@ -1053,42 +956,87 @@ def assign_inertia(
 ) -> jax.Array:
     """`assign_accumulate(xb, wb, centers, x_sq=x_sq, tile=tile)[2]`, the
     float32 inertia of one row tile, without the sums and counts: the
-    float32 argmin kernel alone (`srml_argmin_f32`) on the kernel path.
-    `x_sq` and `tile` as there, refused as there."""
-    k, d = centers.shape
-    n = xb.shape[0] if tile is None else tile[1]
-    plan = block_plan(n, k, d, xb.dtype, False)
-    if tile is not None and (x_sq is None or plan is None or n % plan[0]):
-        raise ValueError(
-            f"a {n}-row tile read in place needs its row norms, the kernels and "
-            f"whole row blocks (plan {plan}): slice the tile instead"
-        )
-    if x_sq is None:
-        x_sq = row_sq(xb)
-    if plan is None:
-        d2 = _c_sq(centers)[None, :] - 2.0 * _mm(xb, centers.T, False)
-        min_d2 = jnp.min(d2, axis=1) + x_sq
-    else:
-        br, bk = plan
-        xp = xb if tile is not None else _pad_rows_multiple(xb, br)[0]
-        cp, _ = _pad_rows_multiple(centers, bk)
-        csq_p = jnp.pad(_c_sq(centers), (0, cp.shape[0] - k), constant_values=jnp.inf)
-        mind, _ = _pl_argmin(
-            xp, cp, csq_p, block_rows=br, block_k=min(bk, cp.shape[0]), fast=False,
-            interpret=_interpret(), tile=tile,
-        )
-        min_d2 = mind[:n] + x_sq
+    float32 argmin kernel alone (`srml_argmin_f32`) on the kernel path. The
+    argmin and the sum are `assign_accumulate`'s own (`_nearest`), so the
+    scalar is its third output bit for bit. `x_sq` and `tile` as there,
+    refused as there."""
+    min_d2, _, _ = _nearest(xb, centers, False, None, None, x_sq, tile)
     return jnp.sum(jnp.maximum(min_d2, 0.0) * wb)
 
 
-def assign_inertia_rows(
-    X: jax.Array, w: jax.Array, centers: jax.Array, x_sq: jax.Array, start, rows: int,
-    *, in_place: bool = False,
-) -> jax.Array:
-    """`assign_inertia` over rows [start, start + rows) of X, the tile got
-    at as `assign_accumulate_rows` gets at it."""
-    xb, wb, qb, tile = _tile_operands(X, w, x_sq, start, rows, in_place)
-    return assign_inertia(xb, wb, centers, x_sq=qb, tile=tile)
+def _tile_operands(X, w, x_sq, start, rows: int, in_place: bool):
+    """(x, w, x_sq, tile) of rows [start, start + rows) of X, as
+    `_scan_tiles` hands them to a step: `w` and `x_sq` sliced (1-D, 128 KB a
+    tile at 32,768 rows); X whole with `tile` where the kernels read the
+    rows `in_place`, else sliced with no `tile`, which makes the tile a
+    buffer of its own (a Mosaic operand is a whole array)."""
+    wb = jax.lax.dynamic_slice_in_dim(w, start, rows, 0)
+    qb = jax.lax.dynamic_slice_in_dim(x_sq, start, rows, 0)
+    if in_place:
+        return X, wb, qb, (start, rows)
+    return jax.lax.dynamic_slice_in_dim(X, start, rows, 0), wb, qb, None
+
+
+def _scan_tiles(step, init, Xl, wl, x_sq, batch_rows: int, in_place: bool):
+    """The in-program tile loop of the `tile_assign_*` scans: `step(carry,
+    x, w, x_sq, tile)` on each tile's operands (`_tile_operands`), over the
+    full tiles in a fori_loop, as `in_place` says, then once over the
+    ragged tail, sliced."""
+    nl = Xl.shape[0]
+    batch_rows = min(batch_rows, nl)
+    n_full = (nl // batch_rows) * batch_rows
+
+    def at(carry, start, rows, tile_in_place):
+        return step(carry, *_tile_operands(Xl, wl, x_sq, start, rows, tile_in_place))
+
+    carry = jax.lax.fori_loop(
+        0, n_full // batch_rows,
+        lambda i, carry: at(carry, i * batch_rows, batch_rows, in_place),
+        init,
+    )
+    if nl - n_full:
+        carry = at(carry, n_full, nl - n_full, False)
+    return carry
+
+
+def tile_assign_accumulate(
+    Xl: jax.Array, wl: jax.Array, centers: jax.Array, x_sq: jax.Array,
+    batch_rows: int, fast: bool = False, in_place: bool = False,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Scan one device's rows in tiles; returns (sums [k,d], counts [k],
+    inertia) — the whole-device Lloyd accumulation of ops/kmeans.py
+    `_lloyd_step`, on one device and under `shard_map` alike. `x_sq` holds
+    the rows' squared norms (made once a fit, not once a tile).
+
+    The full tiles are one fori_loop. `in_place` (the caller's statement
+    that Xl lies row-major and a full tile is whole row blocks: see
+    `assign_accumulate`'s `tile`): the kernels of each full tile index Xl
+    itself by the loop's tile offset, and the compiled step holds no value
+    of the tile's shape and no copy of Xl (tests/test_distance.py pins the
+    text for a v5e). Otherwise each tile is cut out of Xl by a
+    `dynamic_slice` inside the loop, one tile-sized buffer written and read
+    back a tile; for an Xl that a TPU keeps column-major (its default at
+    d = 3,000) the slice is also turned by a `copy` before the kernels
+    (docs/performance.md "Tiled distance core"). The ragged tail is one
+    sliced step after the loop either way. The shard is neither padded nor
+    reshaped: either would make a second buffer of X's size."""
+    _note("distance.assign_programs")
+    nl, d = Xl.shape
+    k = centers.shape[0]
+
+    def step(carry, xb, wb, qb, tile):
+        s, c, i = assign_accumulate(xb, wb, centers, fast=fast, x_sq=qb, tile=tile)
+        return carry[0] + s, carry[1] + c, carry[2] + i
+
+    # under a shard_map the carry varies like the per-shard accumulators
+    # (vma typing); the meshless 1-device program has no axis to cast over
+    init = _vary_like(
+        Xl,
+        jnp.zeros((k, d), Xl.dtype),
+        jnp.zeros((k,), Xl.dtype),
+        jnp.zeros((), Xl.dtype),
+    )
+    return _scan_tiles(step, init, Xl, wl, x_sq, batch_rows, in_place)
 
 
 def tile_assign_inertia(
@@ -1096,16 +1044,15 @@ def tile_assign_inertia(
     batch_rows: int, in_place: bool = False,
 ) -> jax.Array:
     """One device's inertia: `tile_assign_accumulate(...)[2]` at full
-    precision, over the same tiles in the same order."""
+    precision, over the same tiles in the same order (the final pass of a
+    KMeans fit)."""
     _note("distance.assign_programs")
 
-    def step(inertia, start, rows, tile_in_place):
-        return inertia + assign_inertia_rows(
-            Xl, wl, centers, x_sq, start, rows, in_place=tile_in_place
-        )
+    def step(inertia, xb, wb, qb, tile):
+        return inertia + assign_inertia(xb, wb, centers, x_sq=qb, tile=tile)
 
     (init,) = _vary_like(Xl, jnp.zeros((), Xl.dtype))
-    return _scan_tiles(step, init, Xl.shape[0], batch_rows, in_place)
+    return _scan_tiles(step, init, Xl, wl, x_sq, batch_rows, in_place)
 
 
 # ---------------------------------------------------- row-tiled assignment --

@@ -2,15 +2,17 @@
 # Distributed KMeans solver — the in-tree replacement for
 # `cuml.cluster.kmeans_mg.KMeansMG` (consumed by reference clustering.py:353).
 #
-# Lloyd iterations as an explicit SPMD program (`shard_map` over the rows axis):
-# each device scans its row block in fixed-size tiles (the reference's
-# `max_samples_per_batch` memory knob, clustering.py:110-121) through the
-# SHARED tiled distance core (ops/distance.py — fused assignment + one-hot
-# accumulation, Pallas-k-tiled on TPU); partial (k,d) sums/counts/inertia are
-# `psum`'d across devices — the NCCL allreduce the cuML MG solver does
-# internally. The outer loop is a `lax.while_loop` on center movement +
-# max_iter, so the whole fit is ONE XLA program: no per-iteration host
-# round-trips.
+# One XLA program a Lloyd iteration, `_lloyd_step`: each device scans its rows
+# in fixed-size tiles (the reference's `max_samples_per_batch` memory knob,
+# clustering.py:110-121) through the SHARED tiled distance core
+# (ops/distance.py — fused assignment + one-hot accumulation, Pallas-k-tiled
+# on TPU). On a mesh the scan runs under `shard_map` and the partial (k,d)
+# sums/counts/inertia are `psum`'d across devices — the NCCL allreduce the
+# cuML MG solver does internally; on one device the same scan runs with no
+# mesh. The iteration loop is on the host (`kmeans_fit`): one dispatch an
+# iteration, the convergence check deferred by one so that its fetch
+# overlaps the next step, and the checkpoints, the divergence guard and the
+# preemption points where that fetch already is.
 #
 from __future__ import annotations
 
@@ -28,9 +30,7 @@ from ..parallel.mesh import ROWS_AXIS, lies_row_major, x_layout_of
 from .distance import (
     argmin_assign,
     assign_accumulate,
-    assign_accumulate_rows,
     assign_inertia,
-    assign_inertia_rows,
     block_plan,
     min_d2_update,
     row_sq,
@@ -52,57 +52,25 @@ def _finish_centers(sums, counts, inertia, centers):
     return new_centers, inertia, shift
 
 
-_finish_centers_jit = jax.jit(_finish_centers)
-
-
 # |x|^2 of every row, made ONCE a fit (X does not change within one): one
 # read of X, and every Lloyd step, the float32 final pass included, slices
 # its tile's 128 KB out of the result
 _row_norms = jax.jit(row_sq)
 
 
-@partial(jax.jit, static_argnames=("mesh", "batch_rows", "fast", "in_place"))
-def _lloyd_step(X, w, centers, x_sq, *, mesh, batch_rows, fast=False, in_place=False):
-    """One Lloyd iteration as a TOP-LEVEL XLA program: per-shard tiled
-    assignment + accumulation, psum'd (k,d) sums/counts/inertia, center update.
-
-    Kept out of a `lax.while_loop` deliberately: XLA duplicates an array that
-    a SLICE inside nested loops consumes (the tile scan inside a while body
-    costs +1 full copy of X — 11 GiB at the 1M x 3k benchmark shape, an OOM on
-    one chip; an X that the kernels index `in_place` is not duplicated by a
-    loop round them, and whether that holds two loops deep is not tried). The
-    iteration loop lives on the host instead; each step is one dispatch (~ms)
-    against seconds of compute, and the convergence scalar is a replicated
-    global value so every SPMD rank steps identically."""
+def _over_rows(scan, mesh, X, w, x_sq):
+    """`scan(X, w, x_sq)`, one device's partial sums over its rows, for all
+    the rows: on a mesh under `shard_map`, each partial `psum`'d across the
+    devices; with `mesh=None` called as it is. The one-device form must NOT
+    touch a Mesh: under multi-process SPMD a 1-device `get_mesh(1)` holds
+    GLOBAL device 0, which other ranks cannot address, while per-rank local
+    fits (e.g. each rank's ANN coarse quantizer) run on the rank's own
+    default device."""
+    if mesh is None:
+        return scan(X, w, x_sq)
 
     def local(Xl, wl, ql):
-        sums, counts, inertia = _tile_assign_accumulate(
-            Xl, wl, centers, ql, batch_rows, fast, in_place
-        )
-        sums = jax.lax.psum(sums, ROWS_AXIS)
-        counts = jax.lax.psum(counts, ROWS_AXIS)
-        inertia = jax.lax.psum(inertia, ROWS_AXIS)
-        return sums, counts, inertia
-
-    sums, counts, inertia = shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(ROWS_AXIS, None), P(ROWS_AXIS), P(ROWS_AXIS)),
-        out_specs=(P(), P(), P()),
-        check_vma=shard_map_check_vma(),
-    )(X, w, x_sq)
-    return _finish_centers(sums, counts, inertia, centers)
-
-
-@partial(jax.jit, static_argnames=("mesh", "batch_rows", "in_place"))
-def _inertia_pass(X, w, centers, x_sq, *, mesh, batch_rows, in_place=False):
-    """The final pass of `_lloyd_step`'s fit: the float32 inertia of the
-    final centres alone, each shard's tile scan through the argmin kernel
-    only, and the scalar the one value `psum`'d."""
-
-    def local(Xl, wl, ql):
-        inertia = _tile_assign_inertia(Xl, wl, centers, ql, batch_rows, in_place)
-        return jax.lax.psum(inertia, ROWS_AXIS)
+        return jax.tree.map(lambda v: jax.lax.psum(v, ROWS_AXIS), scan(Xl, wl, ql))
 
     return shard_map(
         local,
@@ -113,119 +81,31 @@ def _inertia_pass(X, w, centers, x_sq, *, mesh, batch_rows, in_place=False):
     )(X, w, x_sq)
 
 
-@partial(jax.jit, static_argnames=("batch_rows", "fast", "in_place"))
-def _lloyd_step_fused_1dev(X, w, centers, x_sq, *, batch_rows, fast=False, in_place=False):
-    """One Lloyd iteration as ONE local program (no mesh, no collectives):
-    the in-program tile scan of `_tile_assign_accumulate` plus the center
-    update. This is the small-dataset single-device path — it must NOT touch
-    a Mesh: under multi-process SPMD a 1-device `get_mesh(1)` holds GLOBAL
-    device 0, which other ranks cannot address, while per-rank local fits
-    (e.g. each rank's ANN coarse quantizer) run on the rank's own default
-    device. Where the tiles are sliced the in-program scan may double-buffer
-    X (see _tile_accum_1dev) — affordable below _ONE_DISPATCH_MAX_BYTES,
-    where this path is used."""
-    sums, counts, inertia = _tile_assign_accumulate(
-        X, w, centers, x_sq, batch_rows, fast, in_place
+@partial(jax.jit, static_argnames=("mesh", "batch_rows", "fast", "in_place"))
+def _lloyd_step(X, w, centers, x_sq, *, mesh, batch_rows, fast=False, in_place=False):
+    """One Lloyd iteration as one XLA program: each device's tile scan
+    (`distance.tile_assign_accumulate`, its tiles `batch_rows` rows and, as
+    `in_place` says, indexed where they lie in X), the (k,d) sums, counts
+    and inertia summed over the devices (`_over_rows`; `mesh=None` on one
+    device), and the center update. The shift it returns is a replicated
+    scalar, so every SPMD rank steps identically."""
+    sums, counts, inertia = _over_rows(
+        lambda Xl, wl, ql: _tile_assign_accumulate(Xl, wl, centers, ql, batch_rows, fast, in_place),
+        mesh, X, w, x_sq,
     )
     return _finish_centers(sums, counts, inertia, centers)
 
 
-@partial(jax.jit, static_argnames=("batch_rows", "in_place"))
-def _inertia_pass_fused_1dev(X, w, centers, x_sq, *, batch_rows, in_place=False):
-    """The final pass of `_lloyd_step_fused_1dev`'s fit: its tile scan
-    through the float32 argmin kernel alone, the inertia its one output."""
-    return _tile_assign_inertia(X, w, centers, x_sq, batch_rows, in_place)
-
-
-@partial(jax.jit, static_argnames=("size", "fast", "in_place"), donate_argnums=(4, 5, 6))
-def _tile_accum_1dev(
-    X, w, centers, x_sq, sums, counts, inertia, start, *, size, fast=False, in_place=False
-):
-    """Single-device tile accumulation: one tile a program, at the PROGRAM
-    TOP LEVEL (no in-program loop over X at all). XLA's choice to duplicate
-    an operand that a slice inside a loop consumes is size-dependent — at
-    the 1M x 3k benchmark shape even the fori_loop-of-dynamic_slice form
-    gets a full X copy — so on one device the tile loop lives on the host
-    and the (k,d) accumulators are DONATED device buffers updated in place.
-    The per-tile math is the shared core's fused assign+accumulate
-    (ops/distance.py `assign_accumulate_rows`).
-
-    The program is compiled for the layout the committed X has, which the
-    placement chose (parallel/mesh.py `make_global_rows`; KMeans asks for
-    row-major through `_x_layout`), and `kmeans_fit` says `in_place` where
-    that is row-major: the kernels then fetch their row blocks out of X by
-    `start`, and the compiled program holds no value of the tile's shape and
-    no copy (so the host loop is no longer what keeps X single-buffered:
-    folding it into one program is ROADMAP D3). Sliced, a row-major tile is
-    written out once by the `dynamic_slice`; in a TPU's default for
-    [n, 3000] (column-major) it is sliced and turned by a tile-sized
-    `copy`, 12 times an iteration at the benchmark shape
-    (tests/test_distance.py pins the three texts)."""
-    s, c, i = assign_accumulate_rows(
-        X, w, centers, x_sq, start, size, fast=fast, in_place=in_place
+@partial(jax.jit, static_argnames=("mesh", "batch_rows", "in_place"))
+def _inertia_pass(X, w, centers, x_sq, *, mesh, batch_rows, in_place=False):
+    """The final pass of `_lloyd_step`'s fit: the float32 inertia of the
+    final centres alone, each device's tile scan through the argmin kernel
+    only (`distance.tile_assign_inertia`) and, on a mesh, the scalar the one
+    value `psum`'d."""
+    return _over_rows(
+        lambda Xl, wl, ql: _tile_assign_inertia(Xl, wl, centers, ql, batch_rows, in_place),
+        mesh, X, w, x_sq,
     )
-    return sums + s, counts + c, inertia + i
-
-
-@partial(jax.jit, static_argnames=("size", "in_place"), donate_argnums=(4,))
-def _tile_inertia_1dev(X, w, centers, x_sq, inertia, start, *, size, in_place=False):
-    """`_tile_accum_1dev`'s inertia alone, for the final pass: one tile a
-    program through the float32 argmin kernel, the scalar carry donated."""
-    return inertia + assign_inertia_rows(X, w, centers, x_sq, start, size, in_place=in_place)
-
-
-def _host_tiles(n: int, batch_rows: int, in_place: bool):
-    """(start, rows, in_place) of each program of a host-tiled pass over n
-    rows: the full tiles as the fit says, then the ragged tail, sliced."""
-    import numpy as np
-
-    batch_rows = min(batch_rows, n)
-    n_full = (n // batch_rows) * batch_rows
-    for start in range(0, n_full, batch_rows):
-        yield np.int32(start), batch_rows, in_place
-    if n - n_full:
-        yield np.int32(n_full), n - n_full, False
-
-
-def _lloyd_step_1dev(X, w, centers, x_sq, batch_rows, fast=False, in_place=False):
-    """Host-tiled Lloyd iteration for a 1-device mesh (see _tile_accum_1dev);
-    the ragged tail is sliced whatever `in_place` says of the full tiles."""
-    n, d = X.shape
-    k = centers.shape[0]
-    dtype = X.dtype
-    sums = jnp.zeros((k, d), dtype)
-    counts = jnp.zeros((k,), dtype)
-    inertia = jnp.zeros((), dtype)
-    for start, size, tile_in_place in _host_tiles(n, batch_rows, in_place):
-        sums, counts, inertia = _tile_accum_1dev(
-            X, w, centers, x_sq, sums, counts, inertia, start,
-            size=size, fast=fast, in_place=tile_in_place,
-        )
-    return _finish_centers_jit(sums, counts, inertia, centers)
-
-
-def _inertia_pass_1dev(X, w, centers, x_sq, batch_rows, in_place=False):
-    """The final pass of `_lloyd_step_1dev`'s fit: the same tiles, one
-    `_tile_inertia_1dev` program each."""
-    inertia = jnp.zeros((), X.dtype)
-    for start, size, tile_in_place in _host_tiles(X.shape[0], batch_rows, in_place):
-        inertia = _tile_inertia_1dev(
-            X, w, centers, x_sq, inertia, start, size=size, in_place=tile_in_place
-        )
-    return inertia
-
-
-# Below this size a 1-device fit takes the SAME one-dispatch-per-iteration
-# program as the mesh path (fori_loop of tiles inside one program). The
-# host-tiled `_lloyd_step_1dev` exists to keep the big-X regime
-# single-buffered (XLA copies an X that a slice inside a loop consumes at
-# the 1M×3k protocol shape; not one that the kernels index `in_place`, so
-# for a row-major X the split has lost its reason: ROADMAP D3), but it costs
-# one dispatch PER TILE; below this cap the in-program
-# X copy is affordable and one dispatch per iteration is the simpler form.
-# The iteration loop itself stays on the host (see `_lloyd_step`). Where the
-# cap belongs on the current machine is not measured (ROADMAP D12).
-_ONE_DISPATCH_MAX_BYTES = 2 << 30
 
 
 @partial(jax.jit, static_argnames=("fast",))
@@ -314,14 +194,12 @@ def kmeans_fit(
 
     Convergence: squared center movement <= tol (sklearn/cuML semantics; the
     reference maps Spark's `tol` straight through, clustering.py:96-108).
-    Host-stepped loop, one step an iteration (see `_lloyd_step`'s docstring
-    for why the loop is not a `lax.while_loop`): on a mesh the `shard_map`
-    program `_lloyd_step`; on one device `_lloyd_step_fused_1dev`, one program
-    an iteration, while X is under `_ONE_DISPATCH_MAX_BYTES`, and above it
-    the host-tiled `_lloyd_step_1dev`, one program a tile. Each has a final
-    pass of the same form that computes the inertia and nothing else
-    (`_inertia_pass`, `_inertia_pass_fused_1dev`, `_inertia_pass_1dev`). In
-    all of them the kernels' blocks are `distance.block_plan`'s.
+    Host-stepped loop, one `_lloyd_step` program an iteration, under
+    `shard_map` on a mesh of more than one device (`solver_path`
+    `shard_map`) and with no mesh on one (`fused_1dev`), whatever the size
+    of X; then the final pass `_inertia_pass`, placed the same way, which
+    computes the inertia and nothing else. In both the kernels' blocks are
+    `distance.block_plan`'s.
 
     The deferred (pipelined) convergence check means `n_iter_` can be ONE
     HIGHER than sklearn/cuML would report for the same tol crossing — the
@@ -343,12 +221,11 @@ def kmeans_fit(
     inertia = jnp.zeros((), X.dtype)
     n_iter = 0
     one_dev = mesh.devices.size == 1
-    host_tiled = one_dev and X.size * X.dtype.itemsize > _ONE_DISPATCH_MAX_BYTES
-    solver_path = "host_tiled" if host_tiled else "fused_1dev" if one_dev else "shard_map"
+    solver_path = "fused_1dev" if one_dev else "shard_map"
 
     rows_dev = max(1, -(-X.shape[0] // mesh.devices.size))
     tile = min(batch_rows, rows_dev)
-    # resolved OUTSIDE any trace: the plan the tile programs will take, per
+    # resolved OUTSIDE any trace: the plan the Lloyd programs' tiles take, per
     # precision mode (None = the jnp form; the float32 final pass plans its own
     # blocks), which also settles `distance.kernel_mode()` eagerly
     plans = {
@@ -360,23 +237,19 @@ def kmeans_fit(
     # the tile is whole row blocks they index X itself, else the tile is sliced
     # out first. A column-major X (the TPU's default at d = 3,000: every
     # caller that did not place it row-major) must stay sliced: handed whole
-    # to a Mosaic operand it would be turned whole in every tile program.
+    # to a Mosaic operand it would be turned whole in every step.
     row_major = lies_row_major(X)
     in_place = {
         f: row_major and plan is not None and tile % plan[0] == 0 for f, plan in plans.items()
     }
 
-    # each path's Lloyd step and its final pass, which computes the float32
-    # inertia alone; the 1-device programs are meshless (see
-    # _lloyd_step_fused_1dev)
-    lloyd_step, inertia_pass = {
-        "host_tiled": (_lloyd_step_1dev, _inertia_pass_1dev),
-        "fused_1dev": (_lloyd_step_fused_1dev, _inertia_pass_fused_1dev),
-        "shard_map": (partial(_lloyd_step, mesh=mesh), partial(_inertia_pass, mesh=mesh)),
-    }[solver_path]
+    # the programs are meshless on one device (see `_over_rows`)
+    step_mesh = None if one_dev else mesh
 
     def step(c, x_sq):
-        return lloyd_step(X, w, c, x_sq, batch_rows=batch_rows, fast=fast, in_place=in_place[fast])
+        return _lloyd_step(
+            X, w, c, x_sq, mesh=step_mesh, batch_rows=batch_rows, fast=fast, in_place=in_place[fast]
+        )
 
     # convergence is tested one iteration LATE: fetching the shift scalar
     # synchronizes with the device; checking the PREVIOUS iteration's shift
@@ -515,8 +388,8 @@ def kmeans_fit(
         # leak to them either — return NaN so accidental consumption is loud
         # instead of subtly wrong.
         if final_inertia:
-            inertia = inertia_pass(
-                X, w, centers, x_sq, batch_rows=batch_rows, in_place=in_place[False]
+            inertia = _inertia_pass(
+                X, w, centers, x_sq, mesh=step_mesh, batch_rows=batch_rows, in_place=in_place[False]
             )
             telemetry.registry().inc("kmeans.inertia_passes")
             with telemetry.device_wait("kmeans_inertia"):
@@ -760,8 +633,8 @@ def scalable_kmeans_init_device(
 
     Size bound: the per-round `xd[idx]` candidate gather is the fancy-index
     pattern XLA may answer with a full temporary copy of xd at very large
-    shapes (see the 1-device KMeans notes) — callers keep xd below a few GB
-    (the ANN index builds, whose per-partition data is well under that)."""
+    shapes — callers keep xd below a few GB (the ANN index builds, whose
+    per-partition data is well under that)."""
     n, d = xd.shape
     l = max(1, min(2 * k, n))  # top_k sample size cannot exceed n
     sw = (

@@ -377,7 +377,7 @@ def kmeans_fit_streaming(
     from .. import checkpoint as _ckpt
     from ..parallel import chaos
     from .kmeans import (
-        _finish_centers_jit,
+        _finish_centers,
         _raise_diverged,
         block_assign_accumulate,
         block_inertia,
@@ -389,6 +389,7 @@ def kmeans_fit_streaming(
     w = np.asarray(inputs.w, dtype=dtype)
     centers = jnp.asarray(np.asarray(init_centers), dtype=dtype)
     _nc = numcheck.hook()  # SRML_NUMCHECK=1: chunk partials + iterate boundary
+    finish = jax.jit(_finish_centers)  # the center update, one program for the fit
 
     def step(c):
         sums = counts = inertia = None
@@ -403,7 +404,7 @@ def kmeans_fit_streaming(
                 sums, counts, inertia = s, n_, i_
             else:
                 sums, counts, inertia = sums + s, counts + n_, inertia + i_
-        return _finish_centers_jit(
+        return finish(
             jnp.asarray(sums, dtype), jnp.asarray(counts, dtype),
             jnp.asarray(inertia, dtype), c,
         )

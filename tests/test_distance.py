@@ -11,6 +11,7 @@
 #
 import json
 import pathlib
+from functools import partial
 
 import numpy as np
 import pytest
@@ -159,13 +160,18 @@ def test_tile_read_in_place_is_bit_identical_to_the_slice(interpret_mode, fast, 
 
 @pytest.mark.parametrize("fast", [False, True])
 def test_traced_tile_offset_matches_the_slice(interpret_mode, fast):
-    # the host-tiled step's form: ONE jitted program, the offset an argument
+    # a tile scan step's form: ONE jitted program, the offset an argument
+
+    @partial(jax.jit, static_argnames=("in_place",))
+    def program(X, w, C, x_sq, start, *, in_place):
+        xb, wb, qb, tile = distance._tile_operands(X, w, x_sq, start, 32, in_place)
+        return distance.assign_accumulate(xb, wb, C, fast=fast, x_sq=qb, tile=tile)
+
     X, C, w, x_sq, _ = _tile_case(0, fast)
-    program = jax.jit(distance.assign_accumulate_rows, static_argnames=("rows", "fast", "in_place"))
     for start in (0, 32, 64):
         args = (X, w, C, x_sq, np.int32(start))
-        sliced = program(*args, rows=32, fast=fast, in_place=False)
-        whole = program(*args, rows=32, fast=fast, in_place=True)
+        sliced = program(*args, in_place=False)
+        whole = program(*args, in_place=True)
         for a, b in zip(sliced, whole):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -611,7 +617,7 @@ def test_kernels_pass_their_names_to_pallas_call(interpret_mode, monkeypatch):
 def test_compiled_kernels_carry_their_names_on_one_chip_and_under_shard_map(monkeypatch):
     """The op names a device trace will show, read from programs compiled
     ahead of time for a v5e (no chip needed): `%srml_<kernel>_<mode>.<n> =
-    ... custom-call`, in the one-chip tile program, the predict program and
+    ... custom-call`, in the one-chip Lloyd step, the predict program and
     the four-chip `shard_map` Lloyd step."""
     import re
 
@@ -619,7 +625,7 @@ def test_compiled_kernels_carry_their_names_on_one_chip_and_under_shard_map(monk
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-    from spark_rapids_ml_tpu.ops.kmeans import _lloyd_step, _tile_accum_1dev, kmeans_predict
+    from spark_rapids_ml_tpu.ops.kmeans import _lloyd_step, kmeans_predict
     from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
 
     try:
@@ -636,12 +642,11 @@ def test_compiled_kernels_carry_their_names_on_one_chip_and_under_shard_map(monk
         return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(dev))
 
     with mesh_mod.chip_scope([dev]), jax.enable_x64(False):
-        acc = (one((1024, 256)), one((1024,)), one((40, 256)), one((1024,)),
-               one((40, 256)), one((40,)), one(()), one((), jnp.int32))
+        operands = (one((1024, 256)), one((1024,)), one((40, 256)), one((1024,)))
         for fast, mode in ((True, "bf16"), (False, "f32")):
             for in_place in (False, True):  # the kernels keep their names whichever way they get at the tile
-                tile = _tile_accum_1dev.lower(*acc, size=512, fast=fast, in_place=in_place).compile()
-                assert kernels(tile) == [f"srml_accumulate_{mode}", f"srml_argmin_{mode}"]
+                step = _lloyd_step.lower(*operands, mesh=None, batch_rows=512, fast=fast, in_place=in_place).compile()
+                assert kernels(step) == [f"srml_accumulate_{mode}", f"srml_argmin_{mode}"]
         assert kernels(kmeans_predict.lower(one((1024, 256)), one((40, 256))).compile()) == ["srml_argmin_f32"]
         mesh = Mesh(np.asarray(topo.devices), (mesh_mod.ROWS_AXIS,))
         rows, rep = P(mesh_mod.ROWS_AXIS), P()
@@ -677,48 +682,55 @@ def v5e():
         env.undo()
 
 
-def _lloyd_program_text(v5e, program, d, x_layout, in_place=False, fast=True, final_pass=False):
+@pytest.fixture(scope="module")
+def programs():
+    """The Lloyd programs' (text, temporary bytes) by what was asked of
+    `_lloyd_program_text`: each compiled once for the module's tests."""
+    return {}
+
+
+def _lloyd_program_text(v5e, programs, program, d, x_layout, in_place=False, fast=True, final_pass=False):
     """Compiled text of one Lloyd program at the benchmark cells' shapes
-    (`kmeans-p3k`: 393,216 rows on one chip through the host-tiled
-    `_tile_accum_1dev`; `kmeans-p3k-host4`: 1,048,576 rows over four chips
-    through `_lloyd_step`), or with `final_pass` of the fit's final pass
-    (`_tile_inertia_1dev`, `_inertia_pass`), with X's struct in the layout
-    `make_global_rows` gives it for `x_layout` and the tile access
-    `kmeans_fit` would state for it, and the tile's and one device's X shape."""
+    (`kmeans-p3k`: 393,216 rows on one chip, `tile_1dev`, through the
+    meshless `_lloyd_step` and its tile scan; `kmeans-p3k-host4`: 1,048,576
+    rows over four chips through the `shard_map` `_lloyd_step`), or with
+    `final_pass` of the fit's final pass (`_inertia_pass`, placed the same
+    way), with X's struct in the layout `make_global_rows` gives it for
+    `x_layout` and the tile access `kmeans_fit` would state for it; the
+    tile's and one device's X shape, and the program's temporary bytes."""
     from jax.experimental.layout import Format
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-    from spark_rapids_ml_tpu.ops.kmeans import _inertia_pass, _lloyd_step, _tile_accum_1dev, _tile_inertia_1dev
+    from spark_rapids_ml_tpu.ops.kmeans import _inertia_pass, _lloyd_step
     from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
 
     k, tile = 1000, 32768
     devices = list(v5e.devices) if program == "lloyd_step_4chips" else [v5e.devices[0]]
     rows_dev = 262144 if program == "lloyd_step_4chips" else 393216
-    placed = None  # what `_place_blocks` asks of each device for its [rows_dev, d] block
-    if x_layout == mesh_mod.X_ROW_MAJOR:
-        placed = mesh_mod.row_major_format((rows_dev, d), np.float32, devices[0])
-    with mesh_mod.chip_scope(devices), jax.enable_x64(False):
-        if program == "tile_1dev":
-            one = SingleDeviceSharding(devices[0])
-            s = lambda shape, dtype=jnp.float32, sharding=one: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-            operands = (s((rows_dev, d), sharding=placed or one), s((rows_dev,)), s((k, d)), s((rows_dev,)))
-            if final_pass:
-                lowered = _tile_inertia_1dev.lower(*operands, s(()), s((), jnp.int32), size=tile, in_place=in_place)
+    key = (program, d, x_layout, in_place, fast, final_pass)
+    if key not in programs:
+        placed = None  # what `_place_blocks` asks of each device for its [rows_dev, d] block
+        if x_layout == mesh_mod.X_ROW_MAJOR:
+            placed = mesh_mod.row_major_format((rows_dev, d), np.float32, devices[0])
+        with mesh_mod.chip_scope(devices), jax.enable_x64(False):
+            if program == "tile_1dev":
+                mesh, one = None, SingleDeviceSharding(devices[0])
+                s = lambda shape, sharding=one: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+                operands = (s((rows_dev, d), placed or one), s((rows_dev,)), s((k, d)), s((rows_dev,)))
             else:
-                lowered = _tile_accum_1dev.lower(
-                    *operands, s((k, d)), s((k,)), s(()), s((), jnp.int32), size=tile, fast=fast, in_place=in_place,
-                )
-        else:
-            mesh = Mesh(np.asarray(devices), (mesh_mod.ROWS_AXIS,))
-            rows, rep = NamedSharding(mesh, P(mesh_mod.ROWS_AXIS)), NamedSharding(mesh, P())
-            x_sharding = rows if placed is None else Format(placed.layout, rows)
-            s = lambda shape, sharding: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
-            operands = (s((4 * rows_dev, d), x_sharding), s((4 * rows_dev,), rows), s((k, d), rep), s((4 * rows_dev,), rows))
+                mesh = Mesh(np.asarray(devices), (mesh_mod.ROWS_AXIS,))
+                rows, rep = NamedSharding(mesh, P(mesh_mod.ROWS_AXIS)), NamedSharding(mesh, P())
+                x_sharding = rows if placed is None else Format(placed.layout, rows)
+                s = lambda shape, sharding: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+                operands = (s((4 * rows_dev, d), x_sharding), s((4 * rows_dev,), rows), s((k, d), rep), s((4 * rows_dev,), rows))
             if final_pass:
                 lowered = _inertia_pass.lower(*operands, mesh=mesh, batch_rows=tile, in_place=in_place)
             else:
                 lowered = _lloyd_step.lower(*operands, mesh=mesh, batch_rows=tile, fast=fast, in_place=in_place)
-        return lowered.compile().as_text(), (tile, d), (rows_dev, d)
+            compiled = lowered.compile()
+            programs[key] = compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+    text, temp_bytes = programs[key]
+    return text, (tile, d), (rows_dev, d), temp_bytes
 
 
 def _copied_shapes(text):
@@ -744,7 +756,7 @@ def _kernel_calls(text):
 
 @pytest.mark.parametrize("program", ["tile_1dev", "lloyd_step_4chips"])
 @pytest.mark.parametrize("d", [3000, 3072])
-def test_row_major_x_reaches_the_kernels_without_a_tile_copy(v5e, monkeypatch, program, d):
+def test_row_major_x_reaches_the_kernels_without_a_tile_copy(v5e, programs, monkeypatch, program, d):
     """Placed as KMeans asks, X enters the program row-major ({1,0}) and the
     kernels index it where it lies (`tile_access` `in_place`): no value of the
     tile's shape exists in the compiled program, bf16 and float32 loop steps
@@ -761,7 +773,7 @@ def test_row_major_x_reaches_the_kernels_without_a_tile_copy(v5e, monkeypatch, p
 
     monkeypatch.setattr(distance, "_MODE", "pallas")
     for fast, mode in ((True, "bf16"), (False, "f32")):
-        text, tile_shape, x_shape = _lloyd_program_text(v5e, program, d, mesh_mod.X_ROW_MAJOR, in_place=True, fast=fast)
+        text, tile_shape, x_shape, _ = _lloyd_program_text(v5e, programs, program, d, mesh_mod.X_ROW_MAJOR, in_place=True, fast=fast)
         assert _x_entry_layout(text, x_shape) == "1,0"
         assert "f32[%d,%d]" % tile_shape not in text
         assert not _copied_shapes(text) & {tile_shape, x_shape}
@@ -772,7 +784,7 @@ def test_row_major_x_reaches_the_kernels_without_a_tile_copy(v5e, monkeypatch, p
             # one chip, the tile loop's pass-through of it under shard_map
             x_operand = operands.split(", ")[1]
             assert re.search(r"%s = f32\[%d,%d\]\{1,0[^}]*\} (parameter|get-tuple-element)\(" % ((re.escape(x_operand),) + x_shape), text), x_operand
-    sliced, _, _ = _lloyd_program_text(v5e, program, d, mesh_mod.X_ROW_MAJOR)
+    sliced, _, _, _ = _lloyd_program_text(v5e, programs, program, d, mesh_mod.X_ROW_MAJOR)
     assert _x_entry_layout(sliced, x_shape) == "1,0"
     assert "f32[%d,%d]" % tile_shape in sliced
     assert not _copied_shapes(sliced) & {tile_shape, x_shape}
@@ -780,16 +792,16 @@ def test_row_major_x_reaches_the_kernels_without_a_tile_copy(v5e, monkeypatch, p
     asks = mesh_mod.row_major_format(x_shape, np.float32, v5e.devices[0]) is not None
     assert asks == (d % 128 != 0)
     if asks:
-        control, _, _ = _lloyd_program_text(v5e, program, d, mesh_mod.X_DEFAULT)
+        control, _, _, _ = _lloyd_program_text(v5e, programs, program, d, mesh_mod.X_DEFAULT)
         assert _x_entry_layout(control, x_shape) == "0,1"
         assert tile_shape in _copied_shapes(control) and x_shape not in _copied_shapes(control)
 
 
 @pytest.mark.parametrize("program", ["tile_1dev", "lloyd_step_4chips"])
 @pytest.mark.parametrize("d", [3000, 3072])
-def test_the_final_pass_runs_the_float32_argmin_alone(v5e, monkeypatch, program, d):
-    """The fit's final pass, as the one-chip tile program and as the
-    four-chip `shard_map` program: one Mosaic call, `srml_argmin_f32`, no
+def test_the_final_pass_runs_the_float32_argmin_alone(v5e, programs, monkeypatch, program, d):
+    """The fit's final pass, as the one-chip program and as the four-chip
+    `shard_map` program: one Mosaic call, `srml_argmin_f32`, no
     accumulate; it takes X where X lies (row-major, the program's parameter
     or the tile loop's pass-through of it), and no `copy` has the tile's or
     X's shape. On four chips the one value all-reduced is the scalar."""
@@ -798,7 +810,7 @@ def test_the_final_pass_runs_the_float32_argmin_alone(v5e, monkeypatch, program,
     from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
 
     monkeypatch.setattr(distance, "_MODE", "pallas")
-    text, tile_shape, x_shape = _lloyd_program_text(v5e, program, d, mesh_mod.X_ROW_MAJOR, in_place=True, final_pass=True)
+    text, tile_shape, x_shape, _ = _lloyd_program_text(v5e, programs, program, d, mesh_mod.X_ROW_MAJOR, in_place=True, final_pass=True)
     assert _x_entry_layout(text, x_shape) == "1,0"
     assert "f32[%d,%d]" % tile_shape not in text
     assert not _copied_shapes(text) & {tile_shape, x_shape}
@@ -808,3 +820,32 @@ def test_the_final_pass_runs_the_float32_argmin_alone(v5e, monkeypatch, program,
     assert re.search(r"%s = f32\[%d,%d\]\{1,0[^}]*\} (parameter|get-tuple-element)\(" % ((re.escape(x_operand),) + x_shape), text), x_operand
     reduced = re.findall(r"= (\w+\[[\d,]*\])\S* all-reduce(?:-start)?\(", text)
     assert reduced == ([] if program == "tile_1dev" else ["f32[]"])
+
+
+@pytest.mark.parametrize("x_layout", ["row_major", "default"])
+@pytest.mark.parametrize("program, fast, final_pass", [
+    pytest.param("tile_1dev", True, False, id="step_bf16_1dev"),
+    pytest.param("tile_1dev", False, False, id="step_f32_1dev"),
+    pytest.param("tile_1dev", False, True, id="final_pass_1dev"),
+    pytest.param("lloyd_step_4chips", True, False, id="step_bf16_4chips"),
+])
+def test_the_lloyd_programs_hold_no_second_x_at_the_cells_shapes(v5e, programs, monkeypatch, program, fast, final_pass, x_layout):
+    """The in-program tile scan at the cells' shapes (393,216 x 3,000 float32
+    on one device, 4 x 262,144 x 3,000 on four; k = 1,000, 32,768-row
+    tiles), X placed row-major as KMeans asks (tiles read in place) or in
+    the device's default layout (tiles sliced and turned): no `copy` has
+    X's shape, and the program's temporary buffers are under half of one
+    device's X. Compiled for a v5e they read 0.016 GiB row-major and 0.742
+    GiB in the default layout (one sliced tile and its turned copy), against
+    4.39 GiB of X on one device and 2.93 GiB on each of four; a second X
+    would read 100 % or more."""
+    from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
+
+    monkeypatch.setattr(distance, "_MODE", "pallas")
+    in_place = x_layout == mesh_mod.X_ROW_MAJOR
+    text, _, x_shape, temp_bytes = _lloyd_program_text(
+        v5e, programs, program, 3000, x_layout, in_place=in_place, fast=fast, final_pass=final_pass
+    )
+    assert _x_entry_layout(text, x_shape) == ("1,0" if in_place else "0,1")
+    assert x_shape not in _copied_shapes(text)
+    assert temp_bytes < x_shape[0] * x_shape[1] * 4 / 2

@@ -1,10 +1,10 @@
 #
-# A KMeans fit's final pass (ops/kmeans.py `_inertia_pass*`, the out-of-core
+# A KMeans fit's final pass (ops/kmeans.py `_inertia_pass`, the out-of-core
 # driver's `block_inertia`): the float32 inertia of the final centres through
 # the argmin kernel alone, with no one-hot accumulate. The reported inertia
 # must be, bit for bit, the one the whole float32 Lloyd step over the same
-# centres gives, on every path: host-tiled, the fused one-device program, the
-# `shard_map` step and the streaming chunks.
+# centres gives, on every path: the one-device program, the `shard_map` step
+# and the streaming chunks.
 #
 from functools import partial
 
@@ -18,12 +18,9 @@ from spark_rapids_ml_tpu.models.clustering import KMeans
 from spark_rapids_ml_tpu.ops import distance
 from spark_rapids_ml_tpu.ops import kmeans as kmeans_ops
 
-# path -> (worker count, the final pass it takes, the Lloyd step it stands for)
-PATHS = {
-    "host_tiled": (1, "_inertia_pass_1dev", "_lloyd_step_1dev"),
-    "fused_1dev": (1, "_inertia_pass_fused_1dev", "_lloyd_step_fused_1dev"),
-    "shard_map": (4, "_inertia_pass", "_lloyd_step"),
-}
+# resident path -> worker count; each takes `_inertia_pass`, which stands for
+# the float32 `_lloyd_step`, with a mesh or none
+PATHS = {"fused_1dev": 1, "shard_map": 4}
 # (path, kernel mode, how the kernels get at a tile): the streaming chunks
 # are placed blocks of their own, so no tile of theirs is read in place
 CASES = [
@@ -123,10 +120,7 @@ def test_the_final_pass_gives_the_whole_steps_inertia(tele, monkeypatch, path, m
             monkeypatch.setitem(core_mod.config, "solver_precision", "bf16")
         n_dev, passes = 1, ["block_inertia"]
     else:
-        if path == "host_tiled":
-            monkeypatch.setattr(kmeans_ops, "_ONE_DISPATCH_MAX_BYTES", 0)
-        n_dev, final_pass, _ = PATHS[path]
-        passes = [final_pass]
+        n_dev, passes = PATHS[path], ["_inertia_pass"]
     df = _df(n_dev)
 
     calls = _recording_pallas_calls(monkeypatch, passes)
@@ -147,7 +141,7 @@ def test_the_final_pass_gives_the_whole_steps_inertia(tele, monkeypatch, path, m
     if path == "streaming":
         monkeypatch.setattr(kmeans_ops, "block_inertia", lambda xb, wb, c: kmeans_ops.block_assign_accumulate(xb, wb, c)[2])
     else:
-        monkeypatch.setattr(kmeans_ops, final_pass, _whole_step(getattr(kmeans_ops, PATHS[path][2]), seen))
+        monkeypatch.setattr(kmeans_ops, "_inertia_pass", _whole_step(kmeans_ops._lloyd_step, seen))
     whole = _fit(df, n_dev, precision)
     assert tele.snapshot()["counters"]["kmeans.inertia_passes"] == 2  # one a fit
     assert seen == ([] if path == "streaming" else [tiles == "in_place"])
@@ -161,14 +155,12 @@ def test_the_final_pass_gives_the_whole_steps_inertia(tele, monkeypatch, path, m
 def test_no_final_pass_without_final_inertia(tele, monkeypatch, path):
     """`final_inertia=False` (the ANN quantizers): NaN, no pass, not counted,
     `final_pass` `none`."""
-    n_dev, final_pass, _ = PATHS[path]
-    if path == "host_tiled":
-        monkeypatch.setattr(kmeans_ops, "_ONE_DISPATCH_MAX_BYTES", 0)
+    n_dev = PATHS[path]
 
     def refused(*args, **kwargs):
         raise AssertionError("a final pass ran for a fit that asked for no inertia")
 
-    monkeypatch.setattr(kmeans_ops, final_pass, refused)
+    monkeypatch.setattr(kmeans_ops, "_inertia_pass", refused)
     monkeypatch.setattr(kmeans_ops, "kmeans_fit", partial(kmeans_ops.kmeans_fit, final_inertia=False))
     model = _fit(_df(n_dev), n_dev, "fast")
     assert np.isnan(model.inertia_)

@@ -252,7 +252,7 @@ def _loop_span(model):
     return loop
 
 
-@pytest.mark.parametrize("solver_path, num_workers", [("fused_1dev", 1), ("host_tiled", 1), ("shard_map", 4)])
+@pytest.mark.parametrize("solver_path, num_workers", [("fused_1dev", 1), ("shard_map", 4)])
 def test_centres_do_not_depend_on_how_the_kernels_get_at_a_tile(tele, monkeypatch, rng, solver_path, num_workers):
     """Kernels through the interpreter, 10 iterations, four 64-row tiles a
     device: read in place, the fit gives the sliced fit's centres bit for
@@ -260,8 +260,6 @@ def test_centres_do_not_depend_on_how_the_kernels_get_at_a_tile(tele, monkeypatc
     from spark_rapids_ml_tpu.ops import kmeans as kmeans_ops
 
     monkeypatch.setattr(distance, "_MODE", "interpret")
-    if solver_path == "host_tiled":
-        monkeypatch.setattr(kmeans_ops, "_ONE_DISPATCH_MAX_BYTES", 0)
     df = _df(rng, n=256 * num_workers, d=9)
 
     def fit():
